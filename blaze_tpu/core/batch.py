@@ -660,12 +660,7 @@ class ColumnarBatch:
                 if len({a.type for a in arrs}) > 1:
                     # mixed dictionary/plain encodings cannot concat raw
                     arrs = [decode_dictionary(a, c0.dtype) for a in arrs]
-                try:
-                    arr = pa.concat_arrays(arrs)
-                except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
-                    # dictionary unification fallback (older arrow builds)
-                    arr = pa.chunked_array(arrs).combine_chunks()
-                cols[i] = HostColumn(c0.dtype, arr)
+                cols[i] = HostColumn(c0.dtype, pa.concat_arrays(arrs))
         return ColumnarBatch(schema, cols, total)
 
     # --- host boundary -------------------------------------------------------

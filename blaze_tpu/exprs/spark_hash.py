@@ -147,44 +147,14 @@ def murmur3_int64_np(values, seeds):
 
 
 def murmur3_bytes_np(offsets: np.ndarray, data: np.ndarray, seeds: np.ndarray) -> np.ndarray:
-    """Spark hashUnsafeBytes over n variable-length byte strings.
+    """Spark hashUnsafeBytes over n variable-length byte strings, on the
+    host, by the native kernel (native/src/blaze_native.cc).
 
     offsets: int64 (n+1), data: uint8 concatenated bytes, seeds: uint32 (n,).
-    Uses the native C++ kernel when built (native/src/blaze_native.cc);
-    numpy fallback is vectorized per word position, then per tail byte
-    (tail bytes are *signed*, each through a full mix round).
     """
     from blaze_tpu.utils import native
 
-    out = native.murmur3_bytes(offsets, data, seeds)
-    if out is not None:
-        return out
-    offsets = np.asarray(offsets, dtype=np.int64)
-    data = np.asarray(data, dtype=np.uint8)
-    starts = offsets[:-1]
-    lengths = (offsets[1:] - starts).astype(np.int64)
-    h = seeds.astype(np.uint32).copy()
-    aligned = lengths & ~np.int64(3)
-    max_aligned = int(aligned.max(initial=0))
-    for wstart in range(0, max_aligned, 4):
-        mask = aligned > wstart
-        idx = starts[mask] + wstart
-        k = (
-            data[idx].astype(np.uint32)
-            | (data[idx + 1].astype(np.uint32) << np.uint32(8))
-            | (data[idx + 2].astype(np.uint32) << np.uint32(16))
-            | (data[idx + 3].astype(np.uint32) << np.uint32(24))
-        )
-        h[mask] = _np_mix_h1(h[mask], _np_mix_k1(k))
-    tail_len = lengths - aligned
-    for t in range(3):
-        mask = tail_len > t
-        if not mask.any():
-            break
-        idx = starts[mask] + aligned[mask] + t
-        b = data[idx].view(np.int8).astype(np.int32).view(np.uint32)
-        h[mask] = _np_mix_h1(h[mask], _np_mix_k1(b))
-    return _np_fmix(h, lengths.astype(np.uint32))
+    return native.murmur3_bytes(offsets, data, seeds)
 
 
 # --------------------------------------------------------------------------
@@ -267,111 +237,11 @@ def xxhash64_int32_np(values, seeds):
 
 
 def xxhash64_bytes_np(offsets: np.ndarray, data: np.ndarray, seeds: np.ndarray) -> np.ndarray:
-    """Standard XXH64 over n variable-length byte strings (Spark XXH64).
-
-    Native C++ kernel when built; numpy fallback runs the stripe loop
-    (32-byte blocks with 4 lanes), then 8-byte chunks, 4-byte chunk, single
-    unsigned bytes, then the final avalanche.
-    """
+    """Standard XXH64 over n variable-length byte strings (Spark XXH64), on
+    the host, by the native kernel (native/src/blaze_native.cc)."""
     from blaze_tpu.utils import native
 
-    out = native.xxh64_bytes(offsets, data, seeds)
-    if out is not None:
-        return out
-    offsets = np.asarray(offsets, dtype=np.int64)
-    data = np.asarray(data, dtype=np.uint8)
-    starts = offsets[:-1]
-    lengths = (offsets[1:] - starts).astype(np.int64)
-    n = len(starts)
-    u64 = np.uint64
-
-    def get_u64(idx):
-        out = np.zeros(len(idx), dtype=np.uint64)
-        for b in range(8):
-            out |= data[idx + b].astype(np.uint64) << u64(8 * b)
-        return out
-
-    def get_u32(idx):
-        out = np.zeros(len(idx), dtype=np.uint64)
-        for b in range(4):
-            out |= data[idx + b].astype(np.uint64) << u64(8 * b)
-        return out
-
-    with np.errstate(over="ignore"):
-        seeds = seeds.astype(np.uint64)
-        acc = np.empty(n, dtype=np.uint64)
-        long_mask = lengths >= 32
-        # --- stripe phase for strings >= 32 bytes
-        if long_mask.any():
-            lm = long_mask
-            v1 = seeds[lm] + u64(_P1) + u64(_P2)
-            v2 = seeds[lm] + u64(_P2)
-            v3 = seeds[lm].copy()
-            v4 = seeds[lm] - u64(_P1)
-            nstripes = (lengths[lm] >> 5).astype(np.int64)
-            max_stripes = int(nstripes.max())
-            pos = starts[lm].copy()
-            for s in range(max_stripes):
-                m = nstripes > s
-                base = pos[m] + 32 * s
-
-                def rnd(v, off):
-                    k = get_u64(base + off)
-                    return _np_rotl64(v + k * u64(_P2), 31) * u64(_P1)
-
-                v1[m] = rnd(v1[m], 0)
-                v2[m] = rnd(v2[m], 8)
-                v3[m] = rnd(v3[m], 16)
-                v4[m] = rnd(v4[m], 24)
-            h = (
-                _np_rotl64(v1, 1)
-                + _np_rotl64(v2, 7)
-                + _np_rotl64(v3, 12)
-                + _np_rotl64(v4, 18)
-            )
-
-            def merge(h, v):
-                h = h ^ (_np_rotl64(v * u64(_P2), 31) * u64(_P1))
-                return h * u64(_P1) + u64(_P4)
-
-            h = merge(h, v1)
-            h = merge(h, v2)
-            h = merge(h, v3)
-            h = merge(h, v4)
-            acc[lm] = h
-        acc[~long_mask] = seeds[~long_mask] + u64(_P5)
-        acc += lengths.astype(np.uint64)
-
-        # --- tail: position after stripes
-        pos = starts + (lengths & ~np.int64(31))
-        rem = lengths & np.int64(31)
-        # 8-byte chunks
-        max_chunks = int((rem >> 3).max(initial=0))
-        for c in range(max_chunks):
-            m = (rem >> 3) > c
-            k = get_u64(pos[m] + 8 * c)
-            k = _np_rotl64(k * u64(_P2), 31) * u64(_P1)
-            acc[m] = _np_rotl64(acc[m] ^ k, 27) * u64(_P1) + u64(_P4)
-        pos = pos + (rem & ~np.int64(7))
-        rem = rem & np.int64(7)
-        # 4-byte chunk
-        m = rem >= 4
-        if m.any():
-            k = get_u32(pos[m])
-            acc[m] = _np_rotl64(acc[m] ^ (k * u64(_P1)), 23) * u64(_P2) + u64(_P3)
-            pos = pos + np.where(m, 4, 0)
-            rem = rem - np.where(m, 4, 0)
-        # single bytes (unsigned)
-        for t in range(3):
-            m = rem > t
-            if not m.any():
-                break
-            b = data[pos[m] + t].astype(np.uint64)
-            acc[m] = _np_rotl64(acc[m] ^ (b * u64(_P5)), 11) * u64(_P1)
-        # avalanche
-        acc = (acc ^ (acc >> u64(33))) * u64(_P2)
-        acc = (acc ^ (acc >> u64(29))) * u64(_P3)
-        return acc ^ (acc >> u64(32))
+    return native.xxh64_bytes(offsets, data, seeds)
 
 
 # --------------------------------------------------------------------------

@@ -161,11 +161,8 @@ def serialize_batch(batch, transpose: Optional[bool] = None,
             if transpose and data.dtype.itemsize > 1 and n:
                 from blaze_tpu.utils import native
 
-                t = native.transpose(data, n, data.dtype.itemsize, forward=True)
-                if t is None:
-                    t = np.ascontiguousarray(
-                        data.view(np.uint8).reshape(n, -1).T)
-                buffers.append(t.tobytes())
+                buffers.append(native.transpose(
+                    data, n, data.dtype.itemsize, forward=True).tobytes())
             else:
                 buffers.append(data.view(np.uint8).tobytes())
             buffers.append(np.packbits(validity.astype(np.uint8), bitorder="little").tobytes())
@@ -273,12 +270,10 @@ def deserialize_batch(payload,
             npdt = f.dtype.np_dtype
             itemsize = npdt.itemsize
             arr = np.frombuffer(raw, dtype=np.uint8)
-            if meta["transposed"]:
+            if meta["transposed"] and n:
                 from blaze_tpu.utils import native
 
-                t = native.transpose(arr, n, itemsize, forward=False)
-                arr = t if t is not None else np.ascontiguousarray(
-                    arr.reshape(itemsize, n).T)
+                arr = native.transpose(arr, n, itemsize, forward=False)
             data = arr.view(npdt).reshape(n) if n else np.zeros(0, dtype=npdt)
             validity = unpack_bitmap(vraw, n) if n else np.zeros(0, dtype=bool)
             dev_items.append((f.dtype, data, validity))
@@ -526,10 +521,8 @@ def _lz4_compress(payload: bytes):
     from blaze_tpu.utils import native
 
     l = native.lib()
-    if l is None or not hasattr(l, "bt_lz4_available") or not l.bt_lz4_available():
+    if not l.bt_lz4_available():
         return None
-    import numpy as np
-
     src = np.frombuffer(payload, dtype=np.uint8)
     bound = l.bt_lz4_compress_bound(len(payload))
     if bound <= 0:
@@ -546,10 +539,8 @@ def _lz4_decompress(payload: bytes, raw_len: int) -> bytes:
     from blaze_tpu.utils import native
 
     l = native.lib()
-    if l is None or not hasattr(l, "bt_lz4_available") or not l.bt_lz4_available():
+    if not l.bt_lz4_available():
         raise RuntimeError("lz4 frame but liblz4 unavailable")
-    import numpy as np
-
     src = np.frombuffer(payload, dtype=np.uint8)
     dst = np.empty(max(raw_len, 1), dtype=np.uint8)
     r = l.bt_lz4_decompress(src.ctypes.data, len(payload),
@@ -563,22 +554,16 @@ def _zstd_compress(payload: bytes, level: int) -> bytes:
     from blaze_tpu.utils import native
 
     l = native.lib()
-    if l is not None:
-        import numpy as np
-
-        src = np.frombuffer(payload, dtype=np.uint8)
-        bound = l.bt_zstd_compress_bound(len(payload))
-        if bound > 0:
-            dst = np.empty(bound, dtype=np.uint8)
-            r = l.bt_zstd_compress(src.ctypes.data, len(payload),
-                                   dst.ctypes.data, bound, level)
-            if r > 0:
-                return dst[:r].tobytes()
+    src = np.frombuffer(payload, dtype=np.uint8)
+    bound = l.bt_zstd_compress_bound(len(payload))
+    if bound > 0:  # <= 0: the library was built without zstd headers
+        dst = np.empty(bound, dtype=np.uint8)
+        r = l.bt_zstd_compress(src.ctypes.data, len(payload),
+                               dst.ctypes.data, bound, level)
+        if r > 0:
+            return dst[:r].tobytes()
     sz = native.system_zstd()
     if sz is not None:
-        import numpy as np
-
-        src = np.frombuffer(payload, dtype=np.uint8)
         bound = sz.ZSTD_compressBound(len(payload))
         dst = np.empty(bound, dtype=np.uint8)
         r = sz.ZSTD_compress(dst.ctypes.data, bound,
@@ -594,9 +579,7 @@ def _zstd_decompress(payload: bytes, raw_len: int) -> bytes:
     from blaze_tpu.utils import native
 
     l = native.lib()
-    if l is not None and raw_len > 0:
-        import numpy as np
-
+    if raw_len > 0:
         src = np.frombuffer(payload, dtype=np.uint8)
         dst = np.empty(raw_len, dtype=np.uint8)
         r = l.bt_zstd_decompress(src.ctypes.data, len(payload),
@@ -605,8 +588,6 @@ def _zstd_decompress(payload: bytes, raw_len: int) -> bytes:
             return dst.tobytes()
     sz = native.system_zstd()
     if sz is not None and raw_len > 0:
-        import numpy as np
-
         src = np.frombuffer(payload, dtype=np.uint8)
         dst = np.empty(raw_len, dtype=np.uint8)
         r = sz.ZSTD_decompress(dst.ctypes.data, raw_len,
@@ -623,8 +604,8 @@ def _zstd_decompress(payload: bytes, raw_len: int) -> bytes:
 class BatchWriter:
     """Length-prefixed compressed frames, one per batch (reference:
     IpcCompressionWriter over lz4/zstd framed streams). Compression runs in
-    the native library when built (native/src/blaze_native.cc), else via the
-    python zstandard binding."""
+    the native library (native/src/blaze_native.cc); where that was built
+    without zstd headers, via the system libzstd or the python binding."""
 
     def __init__(self, fileobj: BinaryIO, codec: Optional[str] = None,
                  dict_refs: bool = False, raw: bool = False):

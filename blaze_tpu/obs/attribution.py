@@ -350,14 +350,10 @@ FUSION_BREAK_REASONS = (
     "schema_error",       # schema resolution raised mid-walk
     "cost_below_min_saved",  # saved dispatches < fusion_min_saved_dispatches
     "agg_filter_guard",   # filter left for the fused_filter_agg kernel
-    "broken_fingerprint",  # runtime compile failure pinned this chain shape
 )
 
 PLACEMENT_DECLINE_REASONS = (
     "conf_forced_host",          # device_placement="host"
-    "no_measurable_input",       # zero estimated bytes, nothing measured
-    "measured_cost",             # measured wall beat the device cost model
-    "cost_model_transfer_bound",  # static cost model: link dominates
 )
 
 _TM_FUSION_BREAKS = get_registry().counter(

@@ -14,16 +14,17 @@ cache then keys on the (capacity-bucket, dtype) shapes, and every dispatch
 reports whether it hit that cache — the ``jit_cache_hits`` /
 ``jit_cache_misses`` tripwire counters.
 
-Safety: the fusion pass only admits statically-traceable chains, and any
-batch the closure cannot take (host/dictionary-encoded columns, mixed
-capacities, a trace failure on a combination the whitelist missed) falls
-back per-batch to an eager evaluation with the same semantics as the
-unfused operators (``fused_fallback_batches`` counts them).
+The fusion pass only admits statically-traceable chains. A batch the
+closure cannot take because it is not all-device (host/dictionary-encoded
+columns, mixed capacities) runs per-batch through an eager evaluation with
+the same semantics as the unfused operators (``fused_fallback_batches``
+counts them). A closure that fails to trace, compile or run is an error:
+``FusedStageError`` carries the chain fingerprint and the backend's message,
+and nothing reroutes the batch.
 """
 
 from __future__ import annotations
 
-import logging
 import threading
 from typing import Dict, List, Optional
 
@@ -38,14 +39,22 @@ from blaze_tpu.ir import types as T
 from blaze_tpu.ir.fusion import chain_steps, fused_fingerprint
 from blaze_tpu.ops.base import Operator
 
-log = logging.getLogger(__name__)
-
 # process-global jitted-closure cache: fingerprint -> jitted fn. Shared
 # across batches, partitions, and queries — the second query with the same
 # subplan shape skips straight to a jit-cache hit.
 _CLOSURE_CACHE: Dict[str, object] = {}
-_BROKEN: Dict[str, str] = {}  # fingerprint -> first failure (stays fallback)
 _CACHE_LOCK = threading.Lock()
+
+
+class FusedStageError(RuntimeError):
+    """A fused closure failed to trace, compile or execute."""
+
+    def __init__(self, fingerprint: str, err: BaseException):
+        super().__init__(
+            f"fused segment {fingerprint} failed: "
+            f"{type(err).__name__}: {err}")
+        self.fingerprint = fingerprint
+
 
 _EXEC_NAMES = {
     N.Projection: "ProjectExec",
@@ -60,7 +69,6 @@ def clear_fused_cache():
     """Test hook: drop all cached closures (and their jit caches)."""
     with _CACHE_LOCK:
         _CLOSURE_CACHE.clear()
-        _BROKEN.clear()
 
 
 class _FusedSegment:
@@ -76,23 +84,11 @@ class _FusedSegment:
     def closure(self):
         fp = self.fingerprint
         with _CACHE_LOCK:
-            if fp in _BROKEN:
-                return None
             fn = _CLOSURE_CACHE.get(fp)
             if fn is None:
                 fn = jax.jit(build_fused_closure(self.in_schema, self.steps))
                 _CLOSURE_CACHE[fp] = fn
         return fn
-
-    def mark_broken(self, err: Exception):
-        with _CACHE_LOCK:
-            if self.fingerprint not in _BROKEN:
-                _BROKEN[self.fingerprint] = repr(err)
-                log.warning("fused segment %s fell back to eager: %r",
-                            self.fingerprint, err)
-                from blaze_tpu.obs import attribution as _audit
-
-                _audit.note_fusion_break("broken_fingerprint")
 
 
 class FusedStageExec(Operator):
@@ -182,22 +178,18 @@ class FusedStageExec(Operator):
         fusable = (
             cols and all(isinstance(c, DeviceColumn) for c in cols)
             and len({c.capacity for c in cols}) == 1)
-        fn = seg.closure() if fusable else None
-        if fn is None:
+        if not fusable:
             metrics.add("fused_fallback_batches", 1)
             yield from self._eager_steps(seg, batch)
             return
         try:
             (groups, counts), compiled = kernels.fused_dispatch(
-                fn,
+                seg.closure(),
                 tuple(c.data for c in cols),
                 tuple(c.validity for c in cols),
                 jnp.int64(batch.num_rows))
-        except Exception as err:  # noqa: BLE001 — per-subtree fallback
-            seg.mark_broken(err)
-            metrics.add("fused_fallback_batches", 1)
-            yield from self._eager_steps(seg, batch)
-            return
+        except Exception as err:
+            raise FusedStageError(seg.fingerprint, err) from err
         metrics.add("jit_cache_misses" if compiled else "jit_cache_hits", 1)
         yield from self._emit_groups(seg, batch.num_rows, groups, counts)
 
@@ -223,8 +215,7 @@ class FusedStageExec(Operator):
         closure returns for that batch (the body squeezes the stack axis
         and calls the same jitted closure), so output bits do not depend on
         the mesh size. Non-fusable batches, shape changes, and short tails
-        flush the stack; any sharded-dispatch failure retries the stack
-        per-batch on the single-device path without poisoning the closure."""
+        flush the stack; a sharded-dispatch failure raises like any other."""
         buf = []            # [(batch, datas, valids)] awaiting dispatch
         key = None          # (closure id, capacity, dtypes) of the stack
         fn_cell = [None]
@@ -244,12 +235,8 @@ class FusedStageExec(Operator):
                     [d for _, d, _ in staged],
                     [v for _, _, v in staged],
                     [b.num_rows for b, _, _ in staged])
-            except Exception as err:  # noqa: BLE001 — retry per batch
-                log.warning("sharded fused dispatch fell back per-batch: %r",
-                            err)
-                for b, _, _ in staged:
-                    yield from self._single_batch(seg, b, metrics)
-                return
+            except Exception as err:
+                raise FusedStageError(seg.fingerprint, err) from err
             if not sharded_seen[0]:
                 metrics.add("sharded_stages", 1)
                 sharded_seen[0] = True
@@ -264,13 +251,13 @@ class FusedStageExec(Operator):
             fusable = (
                 cols and all(isinstance(c, DeviceColumn) for c in cols)
                 and len({c.capacity for c in cols}) == 1)
-            fn = seg.closure() if fusable else None
-            if fn is None:
+            if not fusable:
                 yield from flush()
                 key = None
                 metrics.add("fused_fallback_batches", 1)
                 yield from self._eager_steps(seg, batch)
                 continue
+            fn = seg.closure()
             k = (id(fn), cols[0].capacity,
                  tuple(c.data.dtype.name for c in cols),
                  tuple(c.validity.dtype.name for c in cols))

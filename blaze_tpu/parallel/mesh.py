@@ -32,10 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x keeps it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from blaze_tpu.exprs.spark_hash import murmur3_int64
 

@@ -98,8 +98,8 @@ class _Worker:
 
     def spawn(self):
         env = dict(os.environ)
-        env.setdefault("BLAZE_WORKER_PLATFORM", "cpu")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # the driver holds the chip; its children never ask for it
+        env["JAX_PLATFORMS"] = "cpu"
         # slot-stable failpoint stream salt (runtime/failpoints._salt):
         # symmetric workers must not draw identical injection streams
         env["BLAZE_TPU_FAILPOINT_SALT"] = str(self.wid + 1)

@@ -225,7 +225,7 @@ class _QueryRun:
 
     __slots__ = ("qid", "token", "mem_group", "label", "stage_meta",
                  "shuffle_dirs", "resource_ids", "stats", "cursor", "pause",
-                 "boundary_idx", "placement_idx")
+                 "boundary_idx")
 
     def __init__(self, qid: int, token=None, mem_group: Optional[str] = None,
                  label: Optional[str] = None):
@@ -240,7 +240,6 @@ class _QueryRun:
         self.cursor: Optional[StageCursor] = None  # set for pausable runs
         self.pause: Optional[PauseToken] = None
         self.boundary_idx = 0  # pre-order stage-boundary counter
-        self.placement_idx = 0  # ordinal of the next exchange's prior-stats
 
 
 class Session:
@@ -258,11 +257,13 @@ class Session:
         TaskDefinitions to a pool of OS worker processes (runtime/cluster.py)
         — real process isolation with task retry on worker loss, the
         standalone analogue of Spark executors running the native engine."""
-        import blaze_tpu
-        from blaze_tpu.utils.native import ensure_built_async
+        from blaze_tpu.runtime import placement
+        from blaze_tpu.utils import native
 
-        blaze_tpu.setup_compile_cache()  # after any platform pin
-        ensure_built_async()  # background; numpy fallbacks serve meanwhile
+        # this process drives the chip (or the CPU it was pinned to): open
+        # the backend here, and have the host kernels built, before any query
+        placement.require_backend()
+        native.ensure_built()
         self.conf = conf or get_config()
         self.work_dir = work_dir or tempfile.mkdtemp(prefix="blaze_tpu_session_")
         self.max_workers = max_workers or self.conf.num_io_threads
@@ -547,7 +548,7 @@ class Session:
             query["result_keys"] = [f"result_{p}" for p in range(nparts)]
             query["stages"] = [qrun.stage_meta[s]
                                for s in sorted(qrun.stage_meta)]
-            where = self._decide_placement(lowered, "result")
+            where = self._decide_placement("result")
         except BaseException as exc:
             err_holder[0] = exc
             if isinstance(exc, StagePaused):
@@ -861,49 +862,15 @@ class Session:
 
     # -- internals ------------------------------------------------------------
 
-    def _decide_placement(self, stage_root: N.PlanNode, label: str,
-                          record: Optional[dict] = None) -> str:
-        """Adaptive device placement per stage (runtime/placement.py — the
-        TPU analogue of removeInefficientConverts): consult the measured
-        link cost model, refined by the prior run's stage record when the
-        stats plane has one; record the decision in the metric tree."""
+    def _decide_placement(self, label: str) -> str:
+        """Stage placement (runtime/placement.py): the process's backend
+        unless the configuration forces host; recorded in the metric tree."""
         from blaze_tpu.runtime import placement
 
-        where = placement.decide(stage_root, self.resources, self.conf,
-                                 record=record)
+        where = placement.decide(self.conf)
         self.metrics.add(f"placement_{where}_stages", 1)
         self.metrics.named_child(label).add(f"placement_{where}", 1)
         return where
-
-    def _prior_exchange_record(self) -> Optional[dict]:
-        """Prior-run statistics for the exchange about to lower, matched by
-        ordinal among the profile's map-stage records (stage ids differ
-        between runs; ordinals are stable for a fixed plan fingerprint).
-        This is what makes the mesh-vs-files decision STATS-DRIVEN: the
-        roofline estimate gets replaced by measured bytes and device time
-        from the PR 11 stats plane once the query has run once."""
-        qrun = self._qrun()
-        if qrun is None or qrun.stats is None:
-            return None
-        idx = qrun.placement_idx
-        qrun.placement_idx += 1
-        fp = qrun.stats.fingerprint
-        prof = self.profiles.get(fp)
-        if prof is None:
-            from blaze_tpu.obs.stats import load_profile
-
-            try:
-                prof = load_profile(fp, self.conf)
-            except Exception:
-                prof = None
-            if prof:
-                self.profiles[fp] = prof
-        if not prof:
-            return None
-        stages = [s for s in (prof.get("stages") or [])
-                  if str(s.get("kind", "")).startswith(("shuffle_map",
-                                                        "mesh_map"))]
-        return stages[idx] if idx < len(stages) else None
 
     def _record_stage(self, stage: int, kind: str, num_tasks: int,
                       child_op: Operator, wrapper: Optional[str] = None):
@@ -1045,13 +1012,10 @@ class Session:
                 node, partitioning=self._sample_range_bounds(node))
         # reducer counts beyond the mesh size group G = ceil(R/n)
         # reducers per device (parallel/mesh.py), so any partitioning
-        # lowers onto the collective — gated per-exchange by the placement
-        # cost model (refined by the prior run's measured stage record):
-        # host-heavy stages keep the file/segment shuffle even under a mesh
+        # lowers onto the collective — unless placement is forced to host,
+        # which keeps the file/segment shuffle even under a mesh
         if self.mesh is not None:
-            record = self._prior_exchange_record()
-            where = self._decide_placement(node.child, "exchange_gate",
-                                           record=record)
+            where = self._decide_placement("exchange_gate")
             if where == "device":
                 return self._run_mesh_exchange(node)
             if self.rss_sock_path is not None:
@@ -1186,7 +1150,7 @@ class Session:
 
             if not where_cell:
                 where_cell.append(
-                    self._decide_placement(node.child, f"stage_{stage}"))
+                    self._decide_placement(f"stage_{stage}"))
             data, index = paths_for(m)
             writer = ShuffleWriterExec(
                 child_op, node.partitioning, data, index,
@@ -1520,7 +1484,7 @@ class Session:
         if self.pool is not None:
             shipped = self._run_rss_stage_on_pool(node, stage, num_maps, wid)
         if shipped is None:
-            where = self._decide_placement(node.child, f"stage_{stage}")
+            where = self._decide_placement(f"stage_{stage}")
 
             def run_map(m: int):
                 from blaze_tpu.runtime import placement
@@ -1775,7 +1739,7 @@ class Session:
                            wrapper=None if elide else "IpcWriterExec")
         committed: Dict[int, tuple] = {}  # m -> ("batches"|"bytes", items)
         lock = threading.Lock()
-        where = self._decide_placement(child, f"stage_{stage}")
+        where = self._decide_placement(f"stage_{stage}")
         qrun = self._qrun()
 
         def _stats_scope():

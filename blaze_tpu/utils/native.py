@@ -1,14 +1,17 @@
 """ctypes binding for the native host-kernel library (native/).
 
-The engine degrades gracefully: every consumer checks ``lib()`` for None and
-falls back to the numpy implementation. Build once with
-``scripts/build_native.sh`` (cmake + g++); the first import also attempts an
-automatic build when the toolchain is present."""
+The library is built from ``native/src/blaze_native.cc`` into the fixed,
+git-ignored path ``native/build/libblaze_native.so`` — synchronously, when it
+is missing or older than its source, at first use or by the explicit
+``ensure_built()`` that Session, bench.py, chip_smoke.py and the test session
+call before any query. A failed build raises: one set of host kernels serves
+a whole run."""
 
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -16,11 +19,12 @@ from typing import Optional
 import numpy as np
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_SO_PATH = os.path.join(_REPO_ROOT, "native", "build", "libblaze_native.so")
+_SRC_DIR = os.path.join(_REPO_ROOT, "native")
+_SO_PATH = os.path.join(_SRC_DIR, "build", "libblaze_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
+_how: Optional[str] = None  # "built" | "loaded", how this process got _lib
 
 
 def _configure(lib: ctypes.CDLL):
@@ -37,57 +41,49 @@ def _configure(lib: ctypes.CDLL):
     lib.bt_zstd_decompress.restype = ctypes.c_int64
     lib.bt_zstd_decompress.argtypes = [ctypes.c_void_p, ctypes.c_int64,
                                        ctypes.c_void_p, ctypes.c_int64]
-    if hasattr(lib, "bt_lz4_available"):  # absent in v1 prebuilt libraries
-        lib.bt_lz4_available.restype = ctypes.c_int
-        lib.bt_lz4_compress_bound.restype = ctypes.c_int64
-        lib.bt_lz4_compress_bound.argtypes = [ctypes.c_int64]
-        lib.bt_lz4_compress.restype = ctypes.c_int64
-        lib.bt_lz4_compress.argtypes = [ctypes.c_void_p, ctypes.c_int64,
-                                        ctypes.c_void_p, ctypes.c_int64]
-        lib.bt_lz4_decompress.restype = ctypes.c_int64
-        lib.bt_lz4_decompress.argtypes = [ctypes.c_void_p, ctypes.c_int64,
-                                          ctypes.c_void_p, ctypes.c_int64]
+    lib.bt_lz4_available.restype = ctypes.c_int
+    lib.bt_lz4_compress_bound.restype = ctypes.c_int64
+    lib.bt_lz4_compress_bound.argtypes = [ctypes.c_int64]
+    lib.bt_lz4_compress.restype = ctypes.c_int64
+    lib.bt_lz4_compress.argtypes = [ctypes.c_void_p, ctypes.c_int64,
+                                    ctypes.c_void_p, ctypes.c_int64]
+    lib.bt_lz4_decompress.restype = ctypes.c_int64
+    lib.bt_lz4_decompress.argtypes = [ctypes.c_void_p, ctypes.c_int64,
+                                      ctypes.c_void_p, ctypes.c_int64]
 
 
-def build(quiet: bool = True) -> bool:
-    """Build the native library with cmake into a per-process temp build dir,
-    then atomically publish the .so — safe against concurrent builders in
-    other processes; returns success."""
-    import shutil
+def _stale() -> bool:
+    """Missing, or older than what it is built from."""
+    if not os.path.exists(_SO_PATH):
+        return True
+    built = os.path.getmtime(_SO_PATH)
+    return any(os.path.getmtime(os.path.join(_SRC_DIR, f)) > built
+               for f in ("CMakeLists.txt", os.path.join("src", "blaze_native.cc")))
 
-    src = os.path.join(_REPO_ROOT, "native")
-    bld = os.path.join(src, f"build-tmp-{os.getpid()}")
+
+def build():
+    """Build the library with cmake in a per-process temp dir, then publish
+    the .so atomically (concurrent builders in other processes each publish
+    a complete file). Raises with the tool's output when the build fails."""
+    bld = os.path.join(_SRC_DIR, f"build-tmp-{os.getpid()}")
     try:
-        kw = dict(capture_output=quiet, cwd=_REPO_ROOT, timeout=300)
-        built = os.path.join(bld, "libblaze_native.so")
-        if shutil.which("cmake"):
-            subprocess.run(["cmake", "-S", src, "-B", bld,
-                            "-DCMAKE_BUILD_TYPE=Release"], check=True, **kw)
-            subprocess.run(["cmake", "--build", bld, "--", "-j2"], check=True, **kw)
-        elif shutil.which("g++"):
-            # no cmake in the image: drive the compiler directly. zstd links
-            # only when its headers exist (the shared lib alone is served via
-            # system_zstd from python); lz4 dlopens at runtime regardless.
-            os.makedirs(bld, exist_ok=True)
-            cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared",
-                   "-fvisibility=hidden",
-                   os.path.join(src, "src", "blaze_native.cc"), "-o", built,
-                   "-ldl"]
-            if os.path.exists("/usr/include/zstd.h"):
-                cmd[1:1] = ["-DHAVE_ZSTD=1"]
-                cmd.append("-lzstd")
-            subprocess.run(cmd, check=True, **kw)
-        else:
-            return False
-        if not os.path.exists(built):
-            return False
+        for cmd in (["cmake", "-S", _SRC_DIR, "-B", bld,
+                     "-DCMAKE_BUILD_TYPE=Release"],
+                    ["cmake", "--build", bld, "--", "-j2"]):
+            try:
+                r = subprocess.run(cmd, capture_output=True, text=True,
+                                   cwd=_REPO_ROOT, timeout=300)
+            except (OSError, subprocess.TimeoutExpired) as exc:
+                raise RuntimeError(
+                    f"native build could not run {cmd[0]!r}: {exc}") from exc
+            if r.returncode != 0:
+                raise RuntimeError(
+                    f"native build failed ({' '.join(cmd)}):\n"
+                    f"{r.stdout[-2000:]}\n{r.stderr[-2000:]}")
         os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
         tmp_target = _SO_PATH + f".{os.getpid()}"
-        shutil.copy2(built, tmp_target)
-        os.replace(tmp_target, _SO_PATH)  # atomic publish
-        return True
-    except Exception:
-        return False
+        shutil.copy2(os.path.join(bld, "libblaze_native.so"), tmp_target)
+        os.replace(tmp_target, _SO_PATH)
     finally:
         shutil.rmtree(bld, ignore_errors=True)
 
@@ -135,72 +131,40 @@ def system_zstd() -> Optional[ctypes.CDLL]:
         return _sys_zstd
 
 
-def lib() -> Optional[ctypes.CDLL]:
-    """Load the prebuilt library; never compiles on the hot path (numpy
-    fallbacks serve until ensure_built_async's background build lands)."""
-    global _lib, _tried
+def lib() -> ctypes.CDLL:
+    """The loaded library; the first call in a process builds it if stale.
+    Never returns None — a library that cannot be built or loaded raises."""
+    global _lib, _how
     if _lib is not None:
         return _lib
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        if not os.path.exists(_SO_PATH):
-            _tried = True  # recheckable via reset by ensure_built_async
-            return None
-        try:
+        if _lib is None:
+            how = "loaded"
+            if _stale():
+                build()
+                how = "built"
             l = ctypes.CDLL(_SO_PATH)
             _configure(l)
-            assert l.bt_version() >= 1
-            _lib = l
-        except Exception:
-            _tried = True
-            _lib = None
+            _lib, _how = l, how
         return _lib
 
 
-_build_thread: Optional[threading.Thread] = None
-
-
-CURRENT_VERSION = 2
-
-
-def ensure_built_async():
-    """Kick off a background build when the library is missing OR a stale
-    version is on disk; callers keep using numpy fallbacks (and the current
-    features they have) until the fresh build loads (Session starts this)."""
-    global _build_thread
-    if os.environ.get("BLAZE_TPU_NO_NATIVE_BUILD"):
-        return
-    if os.path.exists(_SO_PATH):
-        l = lib()
-        if l is not None and l.bt_version() >= CURRENT_VERSION:
-            return
-        # stale prebuilt: rebuild in the background; the loaded copy keeps
-        # serving its own feature set meanwhile
-    with _lock:
-        if _build_thread is not None:
-            return
-
-        def run():
-            global _tried
-            if build():
-                with _lock:
-                    _tried = False  # allow lib() to load the fresh .so
-
-        _build_thread = threading.Thread(target=run, daemon=True,
-                                         name="blaze-native-build")
-        _build_thread.start()
+def ensure_built() -> str:
+    """Build-or-load now; returns "built" or "loaded" (how THIS process got
+    the library). Call before the first query."""
+    lib()
+    return _how
 
 
 # ---------------------------------------------------------------------------
-# typed wrappers (all fall back to None when the library is absent)
+# typed wrappers
 # ---------------------------------------------------------------------------
 
 
-def transpose(raw: np.ndarray, n: int, itemsize: int, forward: bool) -> Optional[np.ndarray]:
+def transpose(raw: np.ndarray, n: int, itemsize: int, forward: bool) -> np.ndarray:
+    """Byte-plane transpose of ``n`` items of ``itemsize`` > 1 bytes each
+    (``forward``: items -> planes). Callers skip empty and one-byte columns."""
     l = lib()
-    if l is None or n == 0 or itemsize <= 1:
-        return None
     src = np.ascontiguousarray(raw).view(np.uint8).reshape(-1)
     dst = np.empty(n * itemsize, dtype=np.uint8)
     l.bt_transpose(src.ctypes.data, dst.ctypes.data, n, itemsize,
@@ -209,10 +173,8 @@ def transpose(raw: np.ndarray, n: int, itemsize: int, forward: bool) -> Optional
 
 
 def murmur3_bytes(offsets: np.ndarray, data: np.ndarray, seeds: np.ndarray
-                  ) -> Optional[np.ndarray]:
+                  ) -> np.ndarray:
     l = lib()
-    if l is None:
-        return None
     n = len(offsets) - 1
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     data = np.ascontiguousarray(data, dtype=np.uint8)
@@ -224,10 +186,8 @@ def murmur3_bytes(offsets: np.ndarray, data: np.ndarray, seeds: np.ndarray
 
 
 def xxh64_bytes(offsets: np.ndarray, data: np.ndarray, seeds: np.ndarray
-                ) -> Optional[np.ndarray]:
+                ) -> np.ndarray:
     l = lib()
-    if l is None:
-        return None
     n = len(offsets) - 1
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     data = np.ascontiguousarray(data, dtype=np.uint8)

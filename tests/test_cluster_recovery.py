@@ -486,7 +486,7 @@ class CrashFirstNTasks:
     def __call__(self, x):
         import os
 
-        if os.environ.get("BLAZE_WORKER_PLATFORM") is None:
+        if os.environ.get("BLAZE_TPU_FAILPOINT_SALT") is None:
             return x  # in-driver recompute paths survive
         os.makedirs(self.marker_dir, exist_ok=True)
         done = len(os.listdir(self.marker_dir))

@@ -282,6 +282,41 @@ def test_runtime_fallback_on_host_columns():
     assert ctx.metrics.total("fused_fallback_batches") > 0
 
 
+def test_in_list_with_null_item_traces_in_fused_closure():
+    """An IN list traces inside a fused closure, NULL item included (a
+    literal's validity is a tracer there): no eager batches, and the answer
+    is the unfused evaluator's — NULL, not false, for a non-match."""
+    from blaze_tpu.core.batch import ColumnarBatch
+
+    schema = T.Schema.of(("k", T.I64), ("v", T.I64))
+    batch = ColumnarBatch.from_pydict({
+        "k": pa.array([1, 2, 3, None, 5], type=pa.int64()),
+        "v": pa.array([10, 20, 30, 40, 50], type=pa.int64()),
+    }, schema)
+    leaf = N.BatchSource(schema, "unused", 1)
+    in_list = E.InList(col("k"), [lit(1, T.I64), lit(None, T.I64),
+                                  lit(5, T.I64)])
+    proj = N.Projection(leaf, [in_list, E.BinaryExpr(
+        E.BinaryOp.ADD, col("k"), col("v"))], ["hit", "kv"])
+    filt = N.Filter(proj, [E.BinaryExpr(E.BinaryOp.GT, col("kv"),
+                                        lit(0, T.I64))])
+    clear_fused_cache()
+    op = FusedStageExec(mem_scan([[batch]], schema=schema),
+                        N.FusedStage(child=leaf, ops=(proj, filt)))
+    from blaze_tpu.ops.base import ExecContext
+    from blaze_tpu.ops.basic import ProjectExec
+
+    ctx = ExecContext()
+    got = pa.Table.from_batches(
+        [b.to_arrow() for b in op.execute(0, ctx)]).to_pydict()
+    assert ctx.metrics.total("fused_fallback_batches") == 0
+    assert ctx.metrics.total("jit_cache_misses") == 1
+    assert got == {"hit": [True, None, None, True], "kv": [11, 22, 33, 55]}
+    want = collect_pydict(ProjectExec(
+        mem_scan([[batch]], schema=schema), [in_list], ["hit"]))
+    assert want == {"hit": [True, None, None, None, True]}
+
+
 def test_jit_closure_reuse_across_queries(table_path):
     clear_fused_cache()
     plan = _chain_plan(table_path)

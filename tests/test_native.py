@@ -7,10 +7,6 @@ import pytest
 from blaze_tpu.utils import native
 
 
-requires_native = pytest.mark.skipif(native.lib() is None,
-                                     reason="native library not built")
-
-
 def _str_arrays(strings):
     enc = [s.encode("utf-8") for s in strings]
     offsets = np.zeros(len(enc) + 1, dtype=np.int64)
@@ -19,7 +15,6 @@ def _str_arrays(strings):
     return offsets, data
 
 
-@requires_native
 def test_murmur3_native_matches_numpy():
     import tests.test_spark_hash as tsh
 
@@ -35,7 +30,6 @@ def test_murmur3_native_matches_numpy():
     np.testing.assert_array_equal(out, expected)
 
 
-@requires_native
 def test_xxh64_native_matches_numpy():
     import tests.test_spark_hash as tsh
 
@@ -51,7 +45,6 @@ def test_xxh64_native_matches_numpy():
     np.testing.assert_array_equal(out, expected)
 
 
-@requires_native
 def test_transpose_roundtrip():
     rng = np.random.default_rng(2)
     for dtype in (np.int64, np.float32, np.int16):
@@ -77,10 +70,7 @@ def test_lz4_codec_round_trip():
     from blaze_tpu.io.batch_serde import BatchReader, BatchWriter
     from blaze_tpu.utils import native
 
-    l = native.lib()
-    if l is None or not l.bt_lz4_available():
-        import pytest
-
+    if not native.lib().bt_lz4_available():
         pytest.skip("liblz4 unavailable")
     b = ColumnarBatch.from_pydict({
         "a": pa.array(list(range(1000)), type=pa.int64()),
@@ -96,3 +86,23 @@ def test_lz4_codec_round_trip():
     buf.seek(0)
     out = list(BatchReader(buf))
     assert out[0].to_pydict() == b.to_pydict()
+
+
+def test_library_is_built_from_the_tree_and_loaded_once():
+    """conftest built-or-loaded it before any test; later calls hand back the
+    same handle, and it is at least as new as its source."""
+    import os
+
+    assert native.ensure_built() in ("built", "loaded")
+    assert native.lib() is native.lib()
+    assert not native._stale()
+    assert os.path.getmtime(native._SO_PATH) >= os.path.getmtime(
+        os.path.join(native._SRC_DIR, "src", "blaze_native.cc"))
+
+
+def test_failed_build_raises(monkeypatch, tmp_path):
+    """A build that cannot run is an error with the tool's name in it — never
+    a quiet return to numpy."""
+    monkeypatch.setattr(native, "_SRC_DIR", str(tmp_path / "no_such_tree"))
+    with pytest.raises(RuntimeError, match="native build failed"):
+        native.build()

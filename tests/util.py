@@ -67,13 +67,14 @@ class CrashOnce:
 
 class CrashAlways:
     """Worker-crash fixture UDF: hard-kills the hosting WORKER process on
-    every call (retry-budget exhaustion tests). Guarded by an env var the
-    driver process never sets on itself, so in-driver fallback attempts
-    survive and only pool workers die."""
+    every call (retry-budget exhaustion tests). Guarded by the failpoint
+    salt, which WorkerPool.spawn exports to every pool worker and the driver
+    never sets on itself, so in-driver fallback attempts survive and only
+    pool workers die."""
 
     def __call__(self, x):
         import os
 
-        if os.environ.get("BLAZE_WORKER_PLATFORM") is not None:
+        if os.environ.get("BLAZE_TPU_FAILPOINT_SALT") is not None:
             os._exit(9)
         raise RuntimeError("CrashAlways ran outside a pool worker")
