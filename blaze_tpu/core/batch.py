@@ -418,23 +418,24 @@ class ColumnarBatch:
             schema = T.schema_from_arrow(rb.schema)
         n = rb.num_rows
         cap = capacity or get_config().capacity_for(n)
-        from blaze_tpu.utils.device import is_device_dtype
+        from blaze_tpu.utils.device import is_device_dtype, stage_span
 
         # split device-bound columns out so their planes ride one batched
         # device_put; host columns convert in place
         cols: List[Optional[Column]] = [None] * len(schema)
         dev_items, dev_slots = [], []
-        for i in range(len(schema)):
-            arr, dt = rb.column(i), schema.types[i]
-            if isinstance(arr, pa.ChunkedArray):
-                arr = arr.combine_chunks()
-            if is_device_dtype(dt) and not pa.types.is_dictionary(arr.type):
-                dev_items.append((dt,) + arrow_fixed_planes(arr, dt))
-                dev_slots.append(i)
-            else:
-                cols[i] = _arrow_to_column(arr, dt, cap)
-        for slot, col in zip(dev_slots, device_columns(dev_items, cap)):
-            cols[slot] = col
+        with stage_span(n):
+            for i in range(len(schema)):
+                arr, dt = rb.column(i), schema.types[i]
+                if isinstance(arr, pa.ChunkedArray):
+                    arr = arr.combine_chunks()
+                if is_device_dtype(dt) and not pa.types.is_dictionary(arr.type):
+                    dev_items.append((dt,) + arrow_fixed_planes(arr, dt))
+                    dev_slots.append(i)
+                else:
+                    cols[i] = _arrow_to_column(arr, dt, cap)
+            for slot, col in zip(dev_slots, device_columns(dev_items, cap)):
+                cols[slot] = col
         return ColumnarBatch(schema, cols, n)
 
     @staticmethod
@@ -735,10 +736,13 @@ class HostBatch:
         return HostBatch(self.schema, items, length)
 
     def to_columnar(self, capacity: Optional[int] = None) -> ColumnarBatch:
+        from blaze_tpu.utils.device import stage_span
+
         cap = capacity or get_config().capacity_for(self.num_rows)
-        cols: List[Column] = [
-            DeviceColumn.from_numpy(f.dtype, it[0], it[1], cap)
-            if isinstance(it, tuple) else HostColumn(f.dtype, it)
-            for f, it in zip(self.schema.fields, self.items)
-        ]
+        with stage_span(self.num_rows):
+            cols: List[Column] = [
+                DeviceColumn.from_numpy(f.dtype, it[0], it[1], cap)
+                if isinstance(it, tuple) else HostColumn(f.dtype, it)
+                for f, it in zip(self.schema.fields, self.items)
+            ]
         return ColumnarBatch(self.schema, cols, self.num_rows)

@@ -50,10 +50,14 @@ def _telemetry():
 
 
 def _dispatch(fn, *args, **kw):
-    """Run one jitted kernel dispatch under the device-residency clock
-    (utils/device.DEVICE_STATS; on an async backend this times dispatch, on
-    the CPU backend it approximates execution). With tracing enabled each
-    dispatch is a "kernel" span; a dispatch that grew the jit cache (i.e. a
+    """Run one jitted kernel dispatch; its "kernel" span and
+    ``kernel_time_s`` time the ENQUEUE on the chip, not the execution — the
+    wait for the result is the ``sync:*`` span of ``utils/device.wait_int``
+    (benchmark: ``device_wait_s``).
+
+    The clock is utils/device.DEVICE_STATS (on an async backend it times
+    dispatch, on the CPU backend it approximates execution). With tracing
+    enabled each dispatch is a "kernel" span; one that grew the jit cache (a
     fresh trace+compile) is labelled jit_compile instead — compile storms
     show up as wide blocks in the Perfetto timeline. The registry always
     gets the dispatch-time histogram and compile counters (kernel spans
@@ -100,7 +104,9 @@ def _dispatch(fn, *args, **kw):
 
 
 def fused_dispatch(fn, *args):
-    """Dispatch one fused-stage closure and report whether it hit the jit
+    """Dispatch one fused-stage closure (its "kernel" span and
+    ``kernel_time_s`` time the ENQUEUE on the chip; the wait is ``sync:*`` /
+    ``device_wait_s``, see :func:`_dispatch`) and report whether it hit the jit
     cache. Unlike :func:`_dispatch`, the cache-size sample is unconditional:
     the fused-stage hit/miss counters are a fast-path tripwire (recompile
     storms must be visible in every BENCH/SOAK artifact, not only under
@@ -225,8 +231,10 @@ def compact_planes(datas: Sequence[jax.Array], valids: Sequence[jax.Array],
                    mask: jax.Array):
     """Stable device-side compaction of rows where ``mask`` holds (FilterExec
     hot path): one dispatch + one scalar sync for the surviving-row count."""
+    from blaze_tpu.utils.device import wait_int
+
     count, out_d, out_v = _dispatch(_compact, tuple(datas), tuple(valids), mask)
-    return int(count), out_d, out_v
+    return wait_int(count, "compact"), out_d, out_v
 
 
 @functools.partial(jax.jit, static_argnames=("out_cap",))

@@ -32,6 +32,7 @@ from blaze_tpu.config import get_config
 from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn, HostColumn, pack_bitmap, unpack_bitmap
 from blaze_tpu.ir import types as T
 from blaze_tpu.ir.serde import schema_from_json, schema_to_json
+from blaze_tpu.utils.device import stage_span
 
 _MAGIC = b"BTB1"
 
@@ -293,8 +294,9 @@ def deserialize_batch(payload,
                 arr = pa.DictionaryArray.from_arrays(arr, d)
             cols[i] = HostColumn(f.dtype, arr)
     # all device planes of the batch ride one batched device_put
-    for slot, col in zip(dev_slots, device_columns(dev_items, cap)):
-        cols[slot] = col
+    with stage_span(n):
+        for slot, col in zip(dev_slots, device_columns(dev_items, cap)):
+            cols[slot] = col
     return ColumnarBatch(schema, cols, n)
 
 
@@ -476,10 +478,11 @@ def deserialize_batch_raw(payload,
                     arr = arr.combine_chunks()
                 arr = pa.DictionaryArray.from_arrays(arr, d)
             cols[i] = HostColumn(f.dtype, arr)
-    for slot, col in zip(dev_slots,
-                         device_columns_mapped(dev_items, cap, n,
-                                               mapped=mapped)):
-        cols[slot] = col
+    with stage_span(n):
+        for slot, col in zip(dev_slots,
+                             device_columns_mapped(dev_items, cap, n,
+                                                   mapped=mapped)):
+            cols[slot] = col
     return ColumnarBatch(schema, cols, n)
 
 

@@ -26,6 +26,21 @@ Design constraints:
   the most recent span events so incident bundles (obs/dump.py) can show
   what the engine was doing right before a failure — without paying the
   full trace buffer's memory or requiring tracing to have been on.
+- **Per-batch detail only under full tracing**: the spans that name what a
+  task thread is doing batch by batch — ``op`` self-time segments
+  (ops/base.py), ``scan:decode`` / ``scan:decode_wait`` (ops/parquet.py),
+  ``transfer:stage`` (core/batch.py), ``shuffle:fetch_wait``
+  (ops/shuffle/reader.py), ``sync:<what>`` (utils/device.wait_int) and
+  ``kernel`` dispatches — are gated on ``TRACER.enabled`` (:meth:`Tracer.
+  detail`), never on ``active``: they would flood the ring, and with
+  tracing off a site costs one attribute read.
+- **Whose span it is**: every event carries ``args.stage`` / ``args.part``
+  / ``args.q`` from the recording thread's task context
+  (utils/logutil.task_context), so spans of one query share an identifier.
+- **One clock with the device**: while full tracing is on, a span in
+  context-manager form also opens a ``jax.profiler.TraceAnnotation``
+  named ``blaze/<cat>:<name>``, so a profiler trace shows it on the host
+  thread's line beside the device's ``XLA Ops``.
 """
 
 from __future__ import annotations
@@ -37,6 +52,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from blaze_tpu.obs.telemetry import get_registry
+from blaze_tpu.utils.logutil import task_context
 
 _EVENTS_DROPPED = get_registry().counter(
     "blaze_obs_tracer_events_dropped_total",
@@ -49,6 +65,9 @@ class _NoopSpan:
     def __enter__(self):
         return self
 
+    def set(self, **kw):
+        pass
+
     def __exit__(self, *exc):
         return False
 
@@ -57,7 +76,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[dict]):
@@ -67,6 +86,14 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._note = None
+        if self._tracer.enabled:
+            # the same span in the profiler's own trace (a no-op unless a
+            # jax.profiler session is running); it stamps its start when built
+            from jax.profiler import TraceAnnotation
+
+            self._note = TraceAnnotation(f"blaze/{self.cat}:{self.name}")
+            self._note.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -77,8 +104,10 @@ class _Span:
         self.args.update(kw)
 
     def __exit__(self, *exc):
-        self._tracer._record(self.name, self.cat, self._t0,
-                             time.perf_counter_ns() - self._t0, self.args)
+        dur_ns = time.perf_counter_ns() - self._t0
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        self._tracer._record(self.name, self.cat, self._t0, dur_ns, self.args)
         return False
 
 
@@ -157,6 +186,13 @@ class Tracer:
             return _NOOP
         return _Span(self, name, cat, args)
 
+    def detail(self, name: str, cat: str, args: Optional[dict] = None):
+        """:meth:`span` for a per-batch site: recorded under full tracing
+        only, never for the flight-recorder ring alone."""
+        if not self.enabled:
+            return _NOOP
+        return _Span(self, name, cat, args)
+
     def instant(self, name: str, cat: str = "engine",
                 args: Optional[dict] = None):
         if not self.active:
@@ -179,6 +215,11 @@ class Tracer:
               "ts": (t0_ns - self.perf_epoch_ns) / 1e3,
               "dur": dur_ns / 1e3,
               "pid": self.pid, "tid": threading.get_ident()}
+        ctx = task_context()
+        if ctx is not None:
+            # whose span it is; a key the site set itself wins
+            args = {"stage": ctx[0], "part": ctx[1], "q": ctx[2],
+                    **(args or {})}
         if args:
             ev["args"] = args
         self._append(ev)

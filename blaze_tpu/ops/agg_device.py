@@ -31,7 +31,7 @@ from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn
 from blaze_tpu.exprs.compiler import ExprEvaluator, _broadcast
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
-from blaze_tpu.utils.device import is_device_dtype
+from blaze_tpu.utils.device import is_device_dtype, wait_int
 
 _TM_RADIX = None
 
@@ -831,7 +831,8 @@ class DevicePartialAgger:
             table, bases, sizes, out_cap = st
             nbuck = self.conf.radix_agg_buckets if table == "radix" else 0
             outs = self._dense_call(batch, bases, sizes, out_cap, nbuck)
-            num_groups = int(outs[0])  # sync; -1 flags range overflow
+            # sync; -1 flags range overflow
+            num_groups = wait_int(outs[0], "agg_partial")
             if num_groups >= 0:
                 if nbuck:
                     self._note_radix(outs, sizes, nbuck)
@@ -883,7 +884,7 @@ class DevicePartialAgger:
                         list(self.fused_predicates),
                         self.child_schema).evaluate_predicate(jb)
                 outs = self._flow(jb, exists)
-                num_groups = int(outs[0])
+                num_groups = wait_int(outs[0], "agg_partial")
             if num_groups == 0:
                 return None
             return self._assemble(outs, num_groups)
@@ -904,7 +905,7 @@ class DevicePartialAgger:
                             list(self.fused_predicates),
                             self.child_schema).evaluate_predicate(sb)
                     outs = self._flow(sb, exists)
-                    num_groups = int(outs[0])
+                    num_groups = wait_int(outs[0], "agg_partial")
                 if num_groups:
                     parts.append(self._assemble(outs, num_groups))
             if not parts:
@@ -922,7 +923,7 @@ class DevicePartialAgger:
                 else:
                     outs = self._flow(batch, batch.row_exists_mask())
                 # the sync point: kernel completes here
-                num_groups = int(outs[0])
+                num_groups = wait_int(outs[0], "agg_partial")
         if num_groups == 0:
             return None
         return self._assemble(outs, num_groups)
@@ -1626,7 +1627,7 @@ class DeviceMergeAgger:
                 capacity, sizes, out_cap)
             outs = kernel(exists, jnp.asarray(np.asarray(bases, np.int64)),
                           *flat)
-            num_groups = int(outs[0])
+            num_groups = wait_int(outs[0], "agg_merge")
             if num_groups < 0:
                 # probe/pack disagreement (shouldn't happen: the plan comes
                 # from a probe over this very data) — sort fallback
@@ -1638,7 +1639,7 @@ class DeviceMergeAgger:
             kernel = _merge_kernel(tuple(key_dtypes), self.kinds,
                                    tuple(state_dtypes), big.capacity)
             outs = kernel(exists, *flat)
-            num_groups = int(outs[0])
+            num_groups = wait_int(outs[0], "agg_merge")
         if num_groups == 0:
             return []
         out_valid = outs[1]

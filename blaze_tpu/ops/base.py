@@ -166,6 +166,12 @@ class Operator:
         gen = self._execute(partition, ctx, node)
         stack = _time_stack()
         trace = TRACER.active  # full trace OR the flight-recorder ring
+        # full trace only: every stretch charged to a node below is also an
+        # "op" span from the same two stamps, so an operator's op segments
+        # sum to its SELF_TIME_METRIC and a task thread is in exactly one
+        # of them at any instant (the "operator" span in the finally is the
+        # generator's whole life: their parent, and no name for an idle gap)
+        segments = TRACER.enabled
         span_t0 = time.perf_counter_ns() if trace else 0
         rows = 0
         try:
@@ -175,6 +181,9 @@ class Operator:
                 if stack:
                     parent = stack[-1]
                     parent[0].add(SELF_TIME_METRIC, now - parent[1])
+                    if segments:
+                        TRACER.complete(parent[0].name, "op", parent[1],
+                                        now - parent[1])
                 stack.append([node, now])
                 try:
                     batch = next(gen)
@@ -186,6 +195,9 @@ class Operator:
                     now = time.perf_counter_ns()
                     frame = stack.pop()
                     frame[0].add(SELF_TIME_METRIC, now - frame[1])
+                    if segments:
+                        TRACER.complete(node.name, "op", frame[1],
+                                        now - frame[1])
                     if stack:
                         stack[-1][1] = now
                 ctx.check_cancelled()

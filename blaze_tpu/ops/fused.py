@@ -38,6 +38,7 @@ from blaze_tpu.ir import nodes as N
 from blaze_tpu.ir import types as T
 from blaze_tpu.ir.fusion import chain_steps, fused_fingerprint
 from blaze_tpu.ops.base import Operator
+from blaze_tpu.utils.device import wait_int
 
 # process-global jitted-closure cache: fingerprint -> jitted fn. Shared
 # across batches, partitions, and queries — the second query with the same
@@ -196,7 +197,8 @@ class FusedStageExec(Operator):
     def _emit_groups(self, seg: _FusedSegment, batch_rows: int, groups, counts):
         for g, (datas, valids) in enumerate(groups):
             if seg.group_flags[g]:
-                count = int(counts[g])  # one scalar sync, as FilterExec
+                # one scalar sync, as FilterExec
+                count = wait_int(counts[g], "fused_filter")
                 if count == 0:
                     continue
             else:

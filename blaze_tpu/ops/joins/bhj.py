@@ -242,7 +242,7 @@ class _HashJoinBase(Operator):
             return NotImplemented
         import jax.numpy as jnp
 
-        from blaze_tpu.utils.device import DEVICE_STATS
+        from blaze_tpu.utils.device import DEVICE_STATS, wait_int
 
         if bmap._dev_cell[0] is None:
             bmap._dev_cell[0] = jnp.asarray(
@@ -261,7 +261,7 @@ class _HashJoinBase(Operator):
         with DEVICE_STATS.kernel_span():
             outs = kernel(bmap._dev_cell[0], jnp.int64(batch.num_rows),
                           cols[0].data, cols[0].validity, *flat)
-            count = int(outs[0])  # sync point
+            count = wait_int(outs[0], "bhj_probe")  # sync point
         metrics.add("device_inner_batches", 1)
         # The probe itself ran on device inside the fused kernel; count it
         # under device_probe_batches too so the metric stays meaningful for

@@ -19,7 +19,9 @@ from typing import Iterable
 from blaze_tpu.io.batch_serde import BatchReader
 from blaze_tpu.ir import types as T
 from blaze_tpu.obs.telemetry import get_registry
+from blaze_tpu.obs.tracer import TRACER
 from blaze_tpu.ops.base import Operator
+from blaze_tpu.utils.logutil import adopt_task_context, task_context
 
 _TM_FETCH_SECS = get_registry().histogram(
     "blaze_shuffle_fetch_seconds",
@@ -111,16 +113,19 @@ class IpcReaderExec(Operator):
             _TM_ELIDED.inc()
             return batch
 
+        # the prefetch and decode threads' spans are this task's
+        task = task_context()
         pool = ThreadPoolExecutor(max_workers=self._DECODE_WORKERS,
-                                  thread_name_prefix="ipc-decode")
+                                  thread_name_prefix="ipc-decode",
+                                  initializer=adopt_task_context,
+                                  initargs=(task,))
 
         def produce():
             # the prefetch side is where fetch+decode time actually goes;
             # the consumer side only measures queue wait
             import time
 
-            from blaze_tpu.obs.tracer import TRACER
-
+            adopt_task_context(task)
             trace = TRACER.active
             t0 = time.perf_counter_ns()
             nblocks = 0
@@ -203,7 +208,8 @@ class IpcReaderExec(Operator):
         t.start()
         try:
             while True:
-                with metrics.timer("shuffle_read_wait_time_ns"):
+                with metrics.timer("shuffle_read_wait_time_ns"), \
+                        TRACER.detail("fetch_wait", "shuffle"):
                     item = q.get()
                     if isinstance(item, Future):
                         item = item.result()  # re-raises worker exceptions

@@ -566,7 +566,7 @@ class Session:
             from blaze_tpu.runtime import placement
 
             ctx = self._make_ctx(p, qrun=qrun)
-            set_task_context(0, p)
+            set_task_context(0, p, qrun.qid)
             scope = (STATS_HUB.scoped(qrun.stats.scope_key(StatsPlane.RESULT_STAGE))
                      if qrun.stats is not None else contextlib.nullcontext())
             try:
@@ -892,6 +892,11 @@ class Session:
     def _qrun(self) -> Optional[_QueryRun]:
         return getattr(self._tls, "qrun", None)
 
+    def _qid(self) -> Optional[int]:
+        """The running query's id on this thread (tracer spans carry it)."""
+        qrun = self._qrun()
+        return qrun.qid if qrun is not None else None
+
     def _register_resource(self, rid: str, provider):
         """Resource-map insert that also charges the resource to the current
         query, so _release_query can drop it without a session close."""
@@ -1161,7 +1166,7 @@ class Session:
             scope = (STATS_HUB.scoped(qrun.stats.scope_key(stage))
                      if qrun is not None and qrun.stats is not None
                      else contextlib.nullcontext())
-            set_task_context(stage, m)
+            set_task_context(stage, m, self._qid())
             try:
                 with placement.placed(where_cell[0]), scope, \
                         TRACER.span("task", "task",
@@ -1498,7 +1503,7 @@ class Session:
                 scope = (STATS_HUB.scoped(qr.stats.scope_key(stage))
                          if qr is not None and qr.stats is not None
                          else contextlib.nullcontext())
-                set_task_context(stage, m)
+                set_task_context(stage, m, self._qid())
                 try:
                     with placement.placed(where), scope, \
                             TRACER.span("task", "task",
@@ -1566,7 +1571,7 @@ class Session:
 
             ctx = self._make_ctx(m, stage)
             task_metrics = self.metrics.named_child(f"stage_{stage}").named_child(f"map_{m}")
-            set_task_context(stage, m)
+            set_task_context(stage, m, self._qid())
             try:
                 repart = create_repartitioner(node.partitioning, schema)
                 batches, pids = [], []
@@ -1780,7 +1785,7 @@ class Session:
                 _TM_SERIALIZED.inc(bw.bytes_written)
                 return buf.getvalue()
 
-            set_task_context(stage, m)
+            set_task_context(stage, m, self._qid())
             try:
                 with placement.placed(where), _stats_scope(), \
                         TRACER.span("task", "task",
@@ -1816,7 +1821,7 @@ class Session:
             ctx = self._make_ctx(m, stage)
             task_metrics = self.metrics.named_child(
                 f"stage_{stage}").named_child(f"map_{m}")
-            set_task_context(stage, m)
+            set_task_context(stage, m, self._qid())
             try:
                 with placement.placed(where), _stats_scope(), \
                         TRACER.span("task", "task",

@@ -14,6 +14,7 @@ never ``dtype.is_fixed_width`` directly.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -21,14 +22,21 @@ import time
 import numpy as np
 
 from blaze_tpu.ir import types as T
+from blaze_tpu.obs.tracer import TRACER
 
 
 class DeviceStats:
-    """Process-wide device-residency accounting (round-1 verdict item 9: the
+    """Process-wide device counters; ``kernel_time_s`` (and the operators'
+    ``device_time_ns``) time the ENQUEUE of each dispatch on the chip, not
+    its execution — the wait for the device is the ``sync:*`` spans of
+    :func:`wait_int` (the benchmark's ``device_wait_s``), counted here as
+    ``sync_calls``.
+
+    Device-residency accounting (round-1 verdict item 9: the
     TPU-first analogue of the reference's pervasive ``elapsed_compute``
     discipline, execution_context.rs:705-730). Tracks device<->host transfer
-    bytes/calls and jitted-kernel dispatches; surfaced at /debug/device and
-    in the bench output.
+    bytes/calls, blocking syncs and jitted-kernel dispatches; surfaced at
+    /debug/device and in the bench output.
 
     ``kernel_time_s`` is the UNION of all kernel-active intervals, not the
     sum of per-dispatch durations: timed phases nest (agg_device wraps a
@@ -58,18 +66,32 @@ class DeviceStats:
             self.kernel_time_s = 0.0
             self.mapped_calls = 0
             self.mapped_bytes = 0
+            self.sync_calls = 0
             self._active = 0
             self._active_t0 = 0.0
 
     def add_to_host(self, nbytes: int):
+        """One blocking pull of ``nbytes``: a transfer and a sync."""
         with self._mu:
             self.to_host_calls += 1
             self.to_host_bytes += int(nbytes)
+            self.sync_calls += 1
 
     def add_to_device(self, nbytes: int):
         with self._mu:
             self.to_device_calls += 1
             self.to_device_bytes += int(nbytes)
+        self._tls.staged = getattr(self._tls, "staged", 0) + int(nbytes)
+
+    def staged_bytes(self) -> int:
+        """Bytes THIS thread has booked through :meth:`add_to_device`; a
+        ``transfer:stage`` span's bytes are the difference across it."""
+        return getattr(self._tls, "staged", 0)
+
+    def add_sync(self):
+        """The host blocked on a device value (:func:`wait_int`)."""
+        with self._mu:
+            self.sync_calls += 1
 
     def add_mapped(self, nbytes: int):
         """Bytes entering device arrays from MAPPED shuffle segments —
@@ -136,6 +158,7 @@ class DeviceStats:
                 "kernel_time_s": round(self.kernel_time_s, 6),
                 "mapped_calls": self.mapped_calls,
                 "mapped_bytes": self.mapped_bytes,
+                "sync_calls": self.sync_calls,
             }
 
 
@@ -155,6 +178,37 @@ class _KernelSpan:
 
 
 DEVICE_STATS = DeviceStats()
+
+
+def wait_int(x, what: str) -> int:
+    """``int(x)`` of a device scalar: the host blocks here until the device
+    has run everything ``x`` depends on, which drains the launch queue. Every
+    such per-batch sync goes through this one place: always counted
+    (``DEVICE_STATS.sync_calls``), and under full tracing a ``sync:<what>``
+    span — the time a task thread waited for the device, as opposed to the
+    operator's own Python (benchmark: ``device_wait_s``, ``*_host_s``)."""
+    DEVICE_STATS.add_sync()
+    with TRACER.detail(what, "sync"):
+        return int(x)
+
+
+@contextlib.contextmanager
+def _staging(rows: int):
+    bytes0 = DEVICE_STATS.staged_bytes()
+    with TRACER.detail("stage", "transfer", {"rows": rows}) as span:
+        yield
+        span.set(bytes=DEVICE_STATS.staged_bytes() - bytes0)
+
+
+_NOT_TRACED = contextlib.nullcontext()
+
+
+def stage_span(rows: int):
+    """``transfer:stage`` around one batch's upload: host seconds from Arrow
+    arrays or numpy planes to the ``device_put`` hand-off, with the bytes
+    ``DEVICE_STATS.add_to_device`` booked on this thread meanwhile (full
+    tracing only; benchmark: ``stage_h2d_s``)."""
+    return _staging(rows) if TRACER.enabled else _NOT_TRACED
 
 
 @functools.cache
@@ -244,8 +298,6 @@ def pull_columns(cols, n: int):
     else:
         to_pull = [a for i in dev_slots for a in (cols[i].data, cols[i].validity)]
     # start every transfer before blocking on any, so the copies overlap
-    from blaze_tpu.obs.tracer import TRACER
-
     t0_ns = time.perf_counter_ns() if TRACER.active else 0
     for a in to_pull:
         a.copy_to_host_async()

@@ -13,14 +13,35 @@ import threading
 _ctx = threading.local()
 
 
-def set_task_context(stage_id: int, partition_id: int):
+def set_task_context(stage_id: int, partition_id: int, query_id=None):
     _ctx.stage = stage_id
     _ctx.partition = partition_id
+    _ctx.query = query_id
 
 
 def clear_task_context():
     _ctx.stage = None
     _ctx.partition = None
+    _ctx.query = None
+
+
+def task_context():
+    """This thread's ``(stage, partition, query id)``, or None outside a
+    task. The tracer stamps it on every span; a helper thread a task starts
+    (the scan and shuffle prefetchers) passes its creator's to
+    :func:`adopt_task_context` so its spans carry the same identity."""
+    stage = getattr(_ctx, "stage", None)
+    if stage is None:
+        return None
+    return stage, _ctx.partition, getattr(_ctx, "query", None)
+
+
+def adopt_task_context(ctx):
+    """Take over another thread's :func:`task_context` (None clears)."""
+    if ctx is None:
+        clear_task_context()
+    else:
+        set_task_context(*ctx)
 
 
 class TaskContextFilter(logging.Filter):

@@ -29,3 +29,15 @@ def eight_devices():
     devs = jax.devices()
     assert len(devs) >= 8, devs
     return devs[:8]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_join_maps_left_behind():
+    """`ops/joins/bhj.py` keeps built join maps per process under the plan's
+    id, and several test files reuse `bench.py`'s ids over different data:
+    whichever ran second on a worker probed the first one's map. Test-only;
+    the engine's cache is as it was."""
+    yield
+    from blaze_tpu.ops.joins.bhj import clear_build_cache
+
+    clear_build_cache()

@@ -302,15 +302,29 @@ def test_tracing_disabled_overhead_under_5_percent():
         events = sess.metrics.total("output_batches")
 
     # microbench the per-batch instrumentation: the generator wrapper's
-    # stack push/pop + 2 metric adds + TRACER.enabled check + span() no-op
+    # stack push/pop + 2 metric adds + TRACER.enabled check + span() no-op,
+    # and per batch one pass through each site that records only under
+    # full tracing: the op-segment gate (inside execute), a counted sync,
+    # a decode wait, a staging span and a decoded batch
+    from blaze_tpu.ops.parquet import _decoded
+    from blaze_tpu.utils.device import stage_span, wait_int
+
     bsmall = ColumnarBatch.from_pydict({"a": list(range(64))})
     scan = MemoryScanExec(bsmall.schema, [[bsmall] * 256])
     op = RenameColumnsExec(RenameColumnsExec(scan, ["b"]), ["c"])
     ctx = ExecContext()
+    decoded = _decoded(iter(range(256)))
     t0 = time.perf_counter_ns()
     for _ in op.execute(0, ctx, MetricNode("root")):
         TRACER.span("x")
+        wait_int(7, "agg_partial")
+        with TRACER.detail("decode_wait", "scan"):
+            pass
+        with stage_span(64):
+            pass
+        next(decoded)
     bench_ns = time.perf_counter_ns() - t0
+    assert TRACER.snapshot() == []  # nothing was recorded on the way
     per_event_ns = bench_ns / (256 * 3)  # 3 operator levels x 256 batches
 
     overhead_ns = per_event_ns * max(events, 32)
