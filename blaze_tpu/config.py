@@ -321,13 +321,15 @@ class Config:
     fusion_min_saved_dispatches: int = 1
 
     # Dense-bucket grouped aggregation: when a partial agg's group keys are
-    # integers whose observed range fits a small table, the kernel scatters
-    # into range-sized segment tables instead of capacity-sized ones (the
+    # integers whose observed range fits a small table, the kernel reduces
+    # into range-sized slot tables instead of capacity-sized ones (the
     # TPU-friendly analogue of the reference's hash table, agg_hash_map.rs
-    # — one scatter-add pass, no sort, no 131k-wide tables for 400 groups).
-    # None = auto: ON when the process's backend is the CPU (the range probe
-    # costs one extra sync per stream) — chosen for a link that is gone; not
-    # measured on the chip. True/False force it.
+    # — one pass, no sort, no 131k-wide tables for 400 groups).
+    # None = on, on every backend: each stream decides from its own probed
+    # key range (one extra sync a stream): on the chip the sort kernel took
+    # 64-75 ms a batch into 6-10 groups, the slot table 0.03-0.04 ms
+    # (PERF.md section 6, PR 25).
+    # True is the same; False forces the sort kernel (tests compare the two).
     dense_agg: Optional[bool] = None
 
     # Upper bound on the dense-agg bucket-table size (product of per-key
@@ -341,8 +343,9 @@ class Config:
     # dense_agg_max_buckets, bounded by radix_agg_max_slots. Replaces the
     # O(n log n) sort segmentation for wide key ranges (q67-class ~570k
     # groups) on both the partial and the merge side. None = auto: ON when
-    # the process's backend is the CPU (same probe-sync tradeoff as
-    # dense_agg; likewise not measured on the chip). True/False force it.
+    # the process's backend is the CPU (a probe sync a stream; its kernel
+    # scatters a row at a time and was not measured on the chip: ROADMAP
+    # S4). True/False force it.
     radix_agg: Optional[bool] = None
 
     # Upper bound on the radix slot-table size (product of per-key rounded

@@ -31,7 +31,8 @@ from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn
 from blaze_tpu.exprs.compiler import ExprEvaluator, _broadcast
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
-from blaze_tpu.utils.device import is_device_dtype, wait_int
+from blaze_tpu.utils.device import (DEVICE_STATS, is_device_dtype,
+                                    wait_array, wait_int)
 
 _TM_RADIX = None
 
@@ -642,17 +643,14 @@ class DevicePartialAgger:
         return True
 
     def _dense_enabled(self) -> bool:
-        """Integer-keyed partial aggs may use the dense-bucket kernel; auto
-        mode gates on the CPU backend (the range probe costs one extra sync
-        per stream; chosen for a link that is gone, not measured on the
-        chip)."""
+        """Integer-keyed partial aggs may use the slot-table kernel, on every
+        backend unless ``dense_agg`` is forced off: whether a stream does is
+        decided from its probed key range (_plan_bucketed), at one extra
+        sync a stream (``sync:agg_probe``). Measured on the chip in PR 25
+        (PERF.md section 6)."""
         if self._dense_ok is None:
-            da = self.conf.dense_agg
-            if da is None:
-                from blaze_tpu.runtime import placement
-
-                da = placement.backend_is_cpu_hint()
-            self._dense_ok = bool(da) and self._int_keys()
+            self._dense_ok = self.conf.dense_agg is not False and \
+                self._int_keys()
         return self._dense_ok
 
     def _radix_enabled(self) -> bool:
@@ -671,22 +669,13 @@ class DevicePartialAgger:
     def _probe_eager(self, batch: ColumnarBatch):
         """Range probe for the unfused path: evaluates keys eagerly (the
         batch may carry HostColumns the jitted probe cannot flatten) and
-        reduces min/max/any on device."""
+        reduces min/max/any on device in one dispatch."""
         exists = batch.row_exists_mask()
         self.group_ev._reset_cse(batch)
-        info = np.iinfo(np.int64)
-        rows = []
-        for _, e in self.op.groupings:
-            d, val = _broadcast(
-                self.group_ev._to_dev(self.group_ev._eval(e, batch), batch),
-                batch)
-            val = val & exists
-            d64 = d.astype(jnp.int64)
-            rows.append(jnp.stack([
-                jnp.any(val).astype(jnp.int64),
-                jnp.min(jnp.where(val, d64, info.max)),
-                jnp.max(jnp.where(val, d64, info.min))]))
-        return jnp.stack(rows)
+        keys = [_broadcast(
+            self.group_ev._to_dev(self.group_ev._eval(e, batch), batch),
+            batch) for _, e in self.op.groupings]
+        return _key_ranges_jit(exists, keys)
 
     def _probe_fn(self, batch: ColumnarBatch):
         """Jitted range probe for the fused path (all columns device-
@@ -702,19 +691,10 @@ class DevicePartialAgger:
             def probe(num_rows, *flat):
                 tb, mask = agger._trace_tb_mask(num_rows, flat)
                 agger.group_ev._reset_cse(tb)
-                rows = []
-                for _, e in agger.op.groupings:
-                    d, val = _broadcast(
-                        agger.group_ev._to_dev(agger.group_ev._eval(e, tb),
-                                               tb), tb)
-                    val = val & mask
-                    d64 = d.astype(jnp.int64)
-                    info = jnp.iinfo(jnp.int64)
-                    rows.append(jnp.stack([
-                        jnp.any(val).astype(jnp.int64),
-                        jnp.min(jnp.where(val, d64, info.max)),
-                        jnp.max(jnp.where(val, d64, info.min))]))
-                return jnp.stack(rows)
+                return _key_ranges(mask, [
+                    _broadcast(agger.group_ev._to_dev(
+                        agger.group_ev._eval(e, tb), tb), tb)
+                    for _, e in agger.op.groupings])
 
             fn = jax.jit(probe)
             _FUSED_KERNELS[key] = fn
@@ -810,10 +790,11 @@ class DevicePartialAgger:
         for _ in range(2):
             if st is None:
                 if self._needs_trace():
-                    pr = np.asarray(self._probe_fn(batch)(
-                        jnp.int64(batch.num_rows), *self._jit_flat(batch)))
+                    pr = self._probe_fn(batch)(
+                        jnp.int64(batch.num_rows), *self._jit_flat(batch))
                 else:
-                    pr = np.asarray(self._probe_eager(batch))
+                    pr = self._probe_eager(batch)
+                pr = wait_array(pr, "agg_probe")
                 st = self._plan_bucketed(pr, batch.capacity, prev)
                 if st is _DEFER_PLAN:
                     # no valid keys in this batch to anchor a plan: sort
@@ -834,6 +815,7 @@ class DevicePartialAgger:
             # sync; -1 flags range overflow
             num_groups = wait_int(outs[0], "agg_partial")
             if num_groups >= 0:
+                DEVICE_STATS.add_agg_batch(dense=True)
                 if nbuck:
                     self._note_radix(outs, sizes, nbuck)
                     outs = outs[:-2]
@@ -862,9 +844,13 @@ class DevicePartialAgger:
                 args={"buckets": len(rows), "sizes": list(sizes),
                       "rows": rows.tolist(), "groups": groups.tolist()})
 
-    def process(self, batch: ColumnarBatch) -> Optional[ColumnarBatch]:
-        from blaze_tpu.utils.device import DEVICE_STATS
+    def _sort_groups(self, outs) -> int:
+        """The sort kernel's sync point (it completes here): the batch's
+        group count, and the batch counted as the sort path's."""
+        DEVICE_STATS.add_agg_batch(dense=False)
+        return wait_int(outs[0], "agg_partial")
 
+    def process(self, batch: ColumnarBatch) -> Optional[ColumnarBatch]:
         n = batch.num_rows
         if n == 0:
             return None
@@ -884,7 +870,7 @@ class DevicePartialAgger:
                         list(self.fused_predicates),
                         self.child_schema).evaluate_predicate(jb)
                 outs = self._flow(jb, exists)
-                num_groups = wait_int(outs[0], "agg_partial")
+                num_groups = self._sort_groups(outs)
             if num_groups == 0:
                 return None
             return self._assemble(outs, num_groups)
@@ -905,7 +891,7 @@ class DevicePartialAgger:
                             list(self.fused_predicates),
                             self.child_schema).evaluate_predicate(sb)
                     outs = self._flow(sb, exists)
-                    num_groups = wait_int(outs[0], "agg_partial")
+                    num_groups = self._sort_groups(outs)
                 if num_groups:
                     parts.append(self._assemble(outs, num_groups))
             if not parts:
@@ -922,8 +908,7 @@ class DevicePartialAgger:
                                                  *self._jit_flat(batch))
                 else:
                     outs = self._flow(batch, batch.row_exists_mask())
-                # the sync point: kernel completes here
-                num_groups = wait_int(outs[0], "agg_partial")
+                num_groups = self._sort_groups(outs)
         if num_groups == 0:
             return None
         return self._assemble(outs, num_groups)
@@ -942,8 +927,6 @@ class DevicePartialAgger:
         n = batch.num_rows
         if n == 0:
             return None
-        from blaze_tpu.utils.device import DEVICE_STATS
-
         with DEVICE_STATS.kernel_span():
             exists = batch.row_exists_mask()
             self.group_ev._reset_cse(batch)
@@ -1026,6 +1009,24 @@ class DevicePartialAgger:
                     out_valid_mask))
                 ci += 4
         return ColumnarBatch(schema, cols, num_groups)
+
+
+def _key_ranges(mask, keys):
+    """Traced: one (any_valid, min, max) int64 row per (data, valid) group
+    key over the rows ``mask`` keeps — what _plan_slot_table plans from."""
+    info = jnp.iinfo(jnp.int64)
+    rows = []
+    for d, val in keys:
+        val = val & mask
+        d64 = d.astype(jnp.int64)
+        rows.append(jnp.stack([
+            jnp.any(val).astype(jnp.int64),
+            jnp.min(jnp.where(val, d64, info.max)),
+            jnp.max(jnp.where(val, d64, info.min))]))
+    return jnp.stack(rows)
+
+
+_key_ranges_jit = jax.jit(_key_ranges)
 
 
 def _plan_slot_table(probe: np.ndarray, capacity: int, prev,
@@ -1135,30 +1136,102 @@ def _segmentation(exists, canon, key_valid, iota, capacity, key_dtypes):
     return jax.lax.cond(fits, direct_path, sort_path, None)
 
 
+# Largest segment table reduced without scatters (see _seg_reduce). A chip
+# measurement, not a knob: PERF.md section 6, PR 25, has the timings of the
+# slot-table kernel at 16..65,536 slots with the reduction forced each way.
+_MASKED_REDUCE_MAX_SLOTS = 16384
+
+
+def _masked_form(nseg: int, rows: int) -> bool:
+    return nseg <= _MASKED_REDUCE_MAX_SLOTS and nseg < rows
+
+
+def _slot_hits(seg, nseg: int):
+    """bool[nseg, rows]: row r routes to slot s. Never materialised: XLA
+    fuses it into the reduction that consumes it."""
+    return seg[None, :] == jnp.arange(nseg, dtype=seg.dtype)[:, None]
+
+
+def _seg_reduce(op: str, seg, values, nseg: int, init=None, where=None):
+    """Per-segment reduction: ``out[s] = op(init, values[seg == s])`` for s in
+    [0, nseg); rows whose ``seg`` is outside (the padding sentinel) drop.
+    ``op`` is "add", "min", "max", "any" (bool values) or "count" (bool
+    values, int64 counts); ``where`` masks rows out (they contribute
+    ``init``). Exact for integers in either form.
+
+    The form follows the static shapes. A table that is small (at most
+    ``_MASKED_REDUCE_MAX_SLOTS``) and smaller than the batch is reduced as a
+    masked vector reduction, ``seg == slot`` against an iota of slots,
+    select, reduce along the rows: ``nseg x rows`` lane operations that XLA
+    fuses into one pass. Any other keeps ``.at[seg].<op>(mode="drop")``: on
+    the TPU that is one serial update a row (66-88 ns each for an int64
+    plane), which is what ``jit(agg_partial)`` spends its time in. The sort
+    and passthrough kernels come here with a segment a row (``nseg`` is the
+    capacity, where the masked form would be quadratic in the batch), so
+    their programs are the same at every capacity."""
+    dtype = jnp.dtype(jnp.int64) if op == "count" else values.dtype
+    if op in ("add", "count", "any"):
+        init = dtype.type(0)
+    if _masked_form(nseg, seg.shape[0]):
+        hit = _slot_hits(seg, nseg)
+        if where is not None:
+            hit = hit & where[None, :]
+        if op == "any":
+            return jnp.any(hit & values[None, :], axis=1)
+        if op == "count":
+            # a batch has fewer than 2^31 rows: count in the native width
+            return jnp.sum(hit & values[None, :], axis=1,
+                           dtype=jnp.int32).astype(dtype)
+        fill = jnp.asarray(init, dtype)
+        picked = jnp.where(hit, values[None, :], fill)
+        if op == "add":
+            return jnp.sum(picked, axis=1, dtype=dtype)
+        # a row of `picked` with no lane left at `init` (every row of the
+        # batch in one segment) must still see it, as the scatter's table does
+        if op == "min":
+            return jnp.minimum(jnp.min(picked, axis=1), fill)
+        return jnp.maximum(jnp.max(picked, axis=1), fill)
+    if op in ("add", "count", "any"):
+        acc = jnp.zeros(nseg, dtype)
+    else:
+        acc = jnp.full(nseg, init, dtype)
+    if op == "count":
+        values = values.astype(dtype)
+    if where is not None:
+        values = jnp.where(where, values, dtype.type(init))
+    if op in ("add", "count"):
+        return acc.at[seg].add(values, mode="drop")
+    if op == "min":
+        return acc.at[seg].min(values, mode="drop")
+    return acc.at[seg].max(values, mode="drop")  # "max", and "any" of bools
+
+
+def _seg_take(table, seg):
+    """``table[seg]`` back onto the rows, in the form :func:`_seg_reduce`
+    uses for a table of this size: for a small one a select along the slots
+    and not a gather a row. Rows outside the table read anything."""
+    nseg = table.shape[0]
+    if _masked_form(nseg, seg.shape[0]):
+        return jnp.sum(jnp.where(_slot_hits(seg, nseg), table[:, None],
+                                 table.dtype.type(0)),
+                       axis=0, dtype=table.dtype)
+    return table[seg]
+
+
 def _segment_lex3(p0, p1, p2, m, seg, nseg, is_max: bool):
     """Per-segment lexicographic extreme of (p2, p1, p0) wide-decimal value
     limbs (p2 signed high word decides; p1/p0 nonnegative 32-bit chunks
     break ties). Returns (b0, b1, b2, has), zeros where empty."""
     info = jnp.iinfo(jnp.int64)
-    if is_max:
-        b2 = jnp.full(nseg, info.min, jnp.int64).at[seg].max(
-            jnp.where(m, p2, jnp.int64(info.min)), mode="drop")
-        t2 = m & (p2 == b2[seg])
-        b1 = jnp.full(nseg, -1, jnp.int64).at[seg].max(
-            jnp.where(t2, p1, jnp.int64(-1)), mode="drop")
-        t1 = t2 & (p1 == b1[seg])
-        b0 = jnp.full(nseg, -1, jnp.int64).at[seg].max(
-            jnp.where(t1, p0, jnp.int64(-1)), mode="drop")
-    else:
-        b2 = jnp.full(nseg, info.max, jnp.int64).at[seg].min(
-            jnp.where(m, p2, jnp.int64(info.max)), mode="drop")
-        t2 = m & (p2 == b2[seg])
-        b1 = jnp.full(nseg, info.max, jnp.int64).at[seg].min(
-            jnp.where(t2, p1, jnp.int64(info.max)), mode="drop")
-        t1 = t2 & (p1 == b1[seg])
-        b0 = jnp.full(nseg, info.max, jnp.int64).at[seg].min(
-            jnp.where(t1, p0, jnp.int64(info.max)), mode="drop")
-    shas = jnp.zeros(nseg, bool).at[seg].max(m, mode="drop")
+    # the low limbs are nonnegative, so -1 is below every candidate of a max
+    op, top, low = ("max", info.min, -1) if is_max else \
+        ("min", info.max, info.max)
+    b2 = _seg_reduce(op, seg, p2, nseg, top, where=m)
+    t2 = m & (p2 == _seg_take(b2, seg))
+    b1 = _seg_reduce(op, seg, p1, nseg, low, where=t2)
+    t1 = t2 & (p1 == _seg_take(b1, seg))
+    b0 = _seg_reduce(op, seg, p0, nseg, low, where=t1)
+    shas = _seg_reduce("any", seg, m, nseg)
     z = jnp.int64(0)
     return (jnp.where(shas, b0, z), jnp.where(shas, b1, z),
             jnp.where(shas, b2, z), shas)
@@ -1169,7 +1242,19 @@ def _reduce_aggs(specs, args, seg, nseg_total):
     dense-bucket partial kernels. ``args[i]`` is the i-th aggregate's
     already-masked (data, valid) pair aligned with ``specs``; rows route to
     ``seg`` (out-of-range segments drop). Returns one ("kind", arrays...)
-    tuple per aggregate, each array of length ``nseg_total``."""
+    tuple per aggregate, each array of length ``nseg_total``. Every
+    reduction goes through :func:`_seg_reduce`, which picks its form from
+    ``nseg_total``."""
+
+    def add(x, where=None):
+        return _seg_reduce("add", seg, x, nseg_total, where=where)
+
+    def count(sv):
+        return _seg_reduce("count", seg, sv, nseg_total)
+
+    def has(sv):
+        return _seg_reduce("any", seg, sv, nseg_total)
+
     outs = []
     for (kind, rescale, acc_dt), (sa, sv) in zip(specs, args):
         if kind in ("sum3", "avg3"):
@@ -1179,21 +1264,10 @@ def _reduce_aggs(specs, args, seg, nseg_total):
             p0, p1, p2 = sa
             from blaze_tpu.ops.aggfns import _limb3_renorm
 
-            s0 = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                jnp.where(sv, p0, jnp.int64(0)), mode="drop")
-            s1 = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                jnp.where(sv, p1, jnp.int64(0)), mode="drop")
-            s2 = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                jnp.where(sv, p2, jnp.int64(0)), mode="drop")
+            s0, s1, s2 = add(p0, sv), add(p1, sv), add(p2, sv)
             s0, s1, s2 = _limb3_renorm(s0, s1, s2)
-            if kind == "avg3":
-                scnt = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                    sv.astype(jnp.int64), mode="drop")
-                outs.append(("avg3", s0, s1, s2, scnt))
-            else:
-                shas = jnp.zeros(nseg_total, bool).at[seg].max(
-                    sv, mode="drop")
-                outs.append(("sum3", s0, s1, s2, shas))
+            outs.append((kind, s0, s1, s2,
+                         count(sv) if kind == "avg3" else has(sv)))
         elif kind in ("minw", "maxw"):
             p0, p1, p2 = sa
             b0, b1, b2, shas = _segment_lex3(p0, p1, p2, sv, seg,
@@ -1207,48 +1281,30 @@ def _reduce_aggs(specs, args, seg, nseg_total):
             x = sa.astype(jnp.int64)
             vlo = jnp.where(sv, x & jnp.int64(0xFFFFFFFF), jnp.int64(0))
             vhi = jnp.where(sv, x >> 32, jnp.int64(0))
-            slo = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                vlo, mode="drop")
-            shi = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                vhi, mode="drop")
+            slo = add(vlo)
+            shi = add(vhi)
             carry = slo >> 32
             slo, shi = slo & jnp.int64(0xFFFFFFFF), shi + carry
-            if kind == "avg2":
-                scnt = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                    sv.astype(jnp.int64), mode="drop")
-                outs.append(("avg2", slo, shi, scnt))
-            else:
-                shas = jnp.zeros(nseg_total, bool).at[seg].max(
-                    sv, mode="drop")
-                outs.append(("sum2", slo, shi, shas))
+            outs.append((kind, slo, shi,
+                         count(sv) if kind == "avg2" else has(sv)))
         elif kind in ("sum", "avg"):
             x = sa.astype(jnp.dtype(acc_dt))  # widen BEFORE accumulating
             if rescale:
                 x = x * jnp.array(10 ** rescale, x.dtype)
-            contrib = jnp.where(sv, x, jnp.zeros((), x.dtype))
-            ssum = jnp.zeros(nseg_total, contrib.dtype).at[seg].add(
-                contrib, mode="drop")
-            scnt = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                sv.astype(jnp.int64), mode="drop")
-            if kind == "sum":
-                outs.append(("sum", ssum, scnt > 0))
-            else:
-                outs.append(("avg", ssum, scnt))
+            ssum = add(jnp.where(sv, x, jnp.zeros((), x.dtype)))
+            scnt = count(sv)
+            outs.append((kind, ssum, scnt > 0 if kind == "sum" else scnt))
         elif kind == "count":
-            scnt = jnp.zeros(nseg_total, jnp.int64).at[seg].add(
-                sv.astype(jnp.int64), mode="drop")
-            outs.append(("count", scnt))
+            outs.append(("count", count(sv)))
         else:  # min / max
             if jnp.issubdtype(sa.dtype, jnp.floating):
                 sent = jnp.array(jnp.inf if kind == "min" else -jnp.inf, sa.dtype)
             else:
                 info = jnp.iinfo(sa.dtype)
                 sent = jnp.array(info.max if kind == "min" else info.min, sa.dtype)
-            x = jnp.where(sv, sa, sent)
-            acc = jnp.full(nseg_total, sent, sa.dtype)
-            acc = acc.at[seg].min(x, mode="drop") if kind == "min" else \
-                acc.at[seg].max(x, mode="drop")
-            shas = jnp.zeros(nseg_total, bool).at[seg].max(sv, mode="drop")
+            acc = _seg_reduce(kind, seg, jnp.where(sv, sa, sent), nseg_total,
+                              sent)
+            shas = has(sv)
             outs.append((kind, jnp.where(shas, acc, 0), shas))
     return outs
 
@@ -1260,8 +1316,9 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
                           sizes: Tuple[int, ...], out_cap: int,
                           nbuck: int = 0):
     """Dense-bucket partial kernel: integer group keys whose observed range
-    fits a small table scatter straight into ``prod(sizes)`` segment slots —
-    no sort, no capacity-sized tables (the TPU analogue of the reference's
+    fits a small table reduce straight into ``prod(sizes)`` segment slots —
+    no sort, no capacity-sized tables, and up to _MASKED_REDUCE_MAX_SLOTS
+    slots no row-sized scatter either (the TPU analogue of the reference's
     agg_hash_map.rs one-pass hash table, but with a static-shape range
     table). ``bases`` (traced, per key) anchor the ranges so one compiled
     kernel serves every batch of the stream; a key outside its range flips
@@ -1294,36 +1351,41 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
             else:
                 args.append((flat[pos], flat[pos + 1] & exists))
                 pos += 2
-        seg, fits = K.radix_pack(key_data, key_valid, exists, bases,
-                                 sizes, strides)
-        outs = _reduce_aggs(specs, args, seg, S)
-        present = jnp.zeros(S, bool).at[seg].max(exists, mode="drop")
-        num_groups = jnp.sum(present)
-        pos = jnp.cumsum(present) - 1
-        scat = jnp.where(present, pos, out_cap).astype(jnp.int32)
+        # the scopes split the program's device time in a trace
+        with jax.named_scope("pack"):
+            seg, fits = K.radix_pack(key_data, key_valid, exists, bases,
+                                     sizes, strides)
+        with jax.named_scope("reduce"):
+            outs = _reduce_aggs(specs, args, seg, S)
+            present = _seg_reduce("any", seg, exists, S)
+        with jax.named_scope("emit"):
+            num_groups = jnp.sum(present)
+            pos = jnp.cumsum(present) - 1
+            scat = jnp.where(present, pos, out_cap).astype(jnp.int32)
 
-        def compact(x):
-            return jnp.zeros((out_cap,), x.dtype).at[scat].set(x, mode="drop")
+            def compact(x):
+                return jnp.zeros((out_cap,), x.dtype).at[scat].set(
+                    x, mode="drop")
 
-        out_valid = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
-        results = [jnp.where(fits, num_groups.astype(jnp.int64),
-                             jnp.int64(-1)), out_valid]
-        # keys reconstruct arithmetically from the bucket index (exact for
-        # ints; no representative-row gathers needed)
-        iota_s = jnp.arange(S, dtype=jnp.int64)
-        for i, kdt in enumerate(key_dtypes):
-            code_b = (iota_s // strides[i]) % sizes[i]
-            kdata = (bases[i] + code_b - 1).astype(jnp.dtype(kdt))
-            results.append(jnp.where(out_valid, compact(kdata),
-                                     jnp.zeros((), jnp.dtype(kdt))))
-            results.append(compact(code_b > 0) & out_valid)
-        for entry in outs:
-            for a in entry[1:]:
-                results.append(compact(a))
-        if nbuck:
-            brows, bgroups = K.radix_histogram(seg, exists, present, S,
-                                               nbuck)
-            results += [brows, bgroups]
+            out_valid = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
+            results = [jnp.where(fits, num_groups.astype(jnp.int64),
+                                 jnp.int64(-1)), out_valid]
+            # keys reconstruct arithmetically from the bucket index (exact
+            # for ints; no representative-row gathers needed)
+            iota_s = jnp.arange(S, dtype=jnp.int64)
+            for i, kdt in enumerate(key_dtypes):
+                code_b = (iota_s // strides[i]) % sizes[i]
+                kdata = (bases[i] + code_b - 1).astype(jnp.dtype(kdt))
+                results.append(jnp.where(out_valid, compact(kdata),
+                                         jnp.zeros((), jnp.dtype(kdt))))
+                results.append(compact(code_b > 0) & out_valid)
+            for entry in outs:
+                for a in entry[1:]:
+                    results.append(compact(a))
+            if nbuck:
+                brows, bgroups = K.radix_histogram(seg, exists, present, S,
+                                                   nbuck)
+                results += [brows, bgroups]
         return tuple(results)
 
     return jax.jit(agg_dense_partial)

@@ -67,6 +67,8 @@ class DeviceStats:
             self.mapped_calls = 0
             self.mapped_bytes = 0
             self.sync_calls = 0
+            self.agg_dense_batches = 0
+            self.agg_sort_batches = 0
             self._active = 0
             self._active_t0 = 0.0
 
@@ -92,6 +94,16 @@ class DeviceStats:
         """The host blocked on a device value (:func:`wait_int`)."""
         with self._mu:
             self.sync_calls += 1
+
+    def add_agg_batch(self, dense: bool):
+        """One PARTIAL aggregation batch answered: by the slot-table kernel
+        (``jit(agg_dense_partial)``) or by the sort kernel
+        (``jit(agg_partial)``). Benchmark: ``agg_dense_batches``."""
+        with self._mu:
+            if dense:
+                self.agg_dense_batches += 1
+            else:
+                self.agg_sort_batches += 1
 
     def add_mapped(self, nbytes: int):
         """Bytes entering device arrays from MAPPED shuffle segments —
@@ -159,6 +171,8 @@ class DeviceStats:
                 "mapped_calls": self.mapped_calls,
                 "mapped_bytes": self.mapped_bytes,
                 "sync_calls": self.sync_calls,
+                "agg_dense_batches": self.agg_dense_batches,
+                "agg_sort_batches": self.agg_sort_batches,
             }
 
 
@@ -187,9 +201,16 @@ def wait_int(x, what: str) -> int:
     (``DEVICE_STATS.sync_calls``), and under full tracing a ``sync:<what>``
     span — the time a task thread waited for the device, as opposed to the
     operator's own Python (benchmark: ``device_wait_s``, ``*_host_s``)."""
+    return int(wait_array(x, what))
+
+
+def wait_array(x, what: str) -> np.ndarray:
+    """``np.asarray(x)`` of a few device values the host decides on (a key
+    range probe): the blocking wait behind :func:`wait_int`, counted and
+    named the same way."""
     DEVICE_STATS.add_sync()
     with TRACER.detail(what, "sync"):
-        return int(x)
+        return np.asarray(x)
 
 
 @contextlib.contextmanager

@@ -9,6 +9,10 @@ fallback beyond the bucket cap, and end-to-end equality with the sort
 kernel on nullable multi-key input.
 """
 
+import hashlib
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -17,9 +21,11 @@ from blaze_tpu.core.batch import ColumnarBatch
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import nodes as N
 from blaze_tpu.ir import types as T
+from blaze_tpu.ops import agg_device as A
 from blaze_tpu.ops.agg_device import DevicePartialAgger
 from blaze_tpu.runtime.executor import build_operator
 from blaze_tpu.runtime.session import Session
+from blaze_tpu.utils.device import DEVICE_STATS
 
 SCHEMA = pa.schema([("k1", pa.int64()), ("k2", pa.int64()), ("v", pa.int64())])
 
@@ -222,3 +228,250 @@ def test_int64_extreme_ranges_stay_exact():
     got = o2.to_arrow().to_pydict()
     assert got["k1"] == [lo]
     assert got["s#sum"] == [200]
+
+
+# -- the reduction's two forms (_seg_reduce) -----------------------------------
+
+CUT = A._MASKED_REDUCE_MAX_SLOTS
+ROWS = 384  # a batch of 300 rows and 84 padding rows
+# (kind, rescale, accumulator dtype), argument dtype
+KINDS = {
+    "sum": (("sum", 0, "int64"), "int64"),
+    "sum_rescaled": (("sum", 2, "int64"), "int64"),
+    "sum_widened": (("sum", 0, "int64"), "int32"),
+    "sum_f32": (("sum", 0, "float32"), "float32"),
+    "avg": (("avg", 0, "int64"), "int64"),
+    "count": (("count", 0, ""), "int64"),
+    "min": (("min", 0, ""), "int64"),
+    "max": (("max", 0, ""), "int64"),
+    "min_i32": (("min", 0, ""), "int32"),
+    "max_f32": (("max", 0, ""), "float32"),
+    "sum2": (("sum2", 0, ""), "int64"),
+    "avg2": (("avg2", 0, ""), "int64"),
+    "sum3": (("sum3", 0, ""), "wide3"),
+    "avg3": (("avg3", 0, ""), "wide3"),
+    "minw": (("minw", 0, ""), "wide3"),
+    "maxw": (("maxw", 0, ""), "wide3"),
+}
+I64 = np.iinfo(np.int64)
+
+
+def _values(rng, dtype, data):
+    """One argument plane (or the three limb planes of a decimal(38))."""
+    if dtype == "wide3":
+        # l0, l1: nonnegative 32-bit chunks; l2: the signed high word
+        if data == "extremes":
+            return tuple(jnp.asarray(rng.choice(np.array(c, np.int64), ROWS))
+                         for c in ([0, 2**32 - 1], [0, 2**32 - 1],
+                                   [I64.min, I64.max, 0, -1]))
+        return (jnp.asarray(rng.integers(0, 2**32, ROWS)),
+                jnp.asarray(rng.integers(0, 2**32, ROWS)),
+                jnp.asarray(rng.integers(-3, 3, ROWS)))  # ties in the high word
+    if dtype == "float32":
+        return jnp.asarray(rng.normal(0, 1e3, ROWS).astype(np.float32))
+    info = np.iinfo(dtype)
+    if data == "extremes":
+        return jnp.asarray(rng.choice(
+            np.array([info.min, info.max, info.min + 1, info.max - 1, 0, -1],
+                     dtype), ROWS))
+    return jnp.asarray(rng.integers(-10**6, 10**6, ROWS).astype(dtype))
+
+
+def _rows(nseg, data, seed):
+    """(seg, valid): 300 rows routed to slots below ``nseg`` and 84 padding
+    rows at the sentinel ``nseg``, which every reduction must drop."""
+    rng = np.random.default_rng(seed)
+    exists = np.arange(ROWS) < 300
+    if data == "one_segment":  # every row of the batch in one slot
+        slot = np.full(ROWS, nseg - 1)
+        exists[:] = True
+    else:
+        slot = rng.integers(0, min(nseg, 40), ROWS)  # crowded slots
+        slot[::7] = rng.integers(0, nseg, len(slot[::7]))  # and far ones
+    valid = exists & (rng.random(ROWS) > (1.0 if data == "all_null" else 0.1))
+    seg = jnp.asarray(np.where(exists, slot, nseg).astype(np.int32))
+    return rng, seg, jnp.asarray(valid)
+
+
+def _reduced(monkeypatch, masked, spec, arg, seg, nseg):
+    monkeypatch.setattr(A, "_masked_form", lambda nseg, rows: masked)
+    (out,) = A._reduce_aggs((spec,), [arg], seg, nseg)
+    monkeypatch.undo()
+    return [np.asarray(a) for a in out[1:]]
+
+
+@pytest.mark.parametrize("nseg", [16, 1024, CUT, 2 * CUT])
+@pytest.mark.parametrize("name", sorted(KINDS))
+def test_masked_reduction_equals_the_scatter(monkeypatch, name, nseg):
+    """Every aggregate kind, at slot counts on both sides of the cut: the
+    masked vector reduction gives the scatter's bits on random keys with
+    nulls and padding rows, an all-null batch, int64 extremes (sums wrap
+    alike, an extreme equals the sentinel) and a batch that is one segment."""
+    spec, dtype = KINDS[name]
+    for seed, data in enumerate(("random", "all_null", "extremes",
+                                 "one_segment")):
+        rng, seg, valid = _rows(nseg, data, 1000 * nseg + seed)
+        arg = (_values(rng, dtype, data), valid)
+        masked = _reduced(monkeypatch, True, spec, arg, seg, nseg)
+        scatter = _reduced(monkeypatch, False, spec, arg, seg, nseg)
+        for m, s in zip(masked, scatter):
+            assert m.dtype == s.dtype and m.shape == s.shape == (nseg,)
+            if name == "sum_f32":  # a float sum's order is the form's own
+                np.testing.assert_allclose(m, s, rtol=1e-5, atol=1e-2)
+            else:
+                assert np.array_equal(m, s), (name, nseg, data)
+
+
+@pytest.mark.parametrize("nseg,rows,masked", [
+    (16, 131072, True), (CUT, 131072, True), (2 * CUT, 131072, False),
+    (128, 128, False),  # a segment a row: the sort and passthrough kernels
+    (128, 256, True)])
+def test_form_follows_the_static_shapes(nseg, rows, masked):
+    closed = jax.make_jaxpr(
+        lambda seg, x: A._seg_reduce("add", seg, x, nseg))(
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int64))
+    scatters = [e for e in _eqns(closed.jaxpr)
+                if e.primitive.name.startswith("scatter")]
+    assert bool(scatters) != masked
+
+
+def _avals(key_dtypes, arg_dtypes, cap, bases=False):
+    """A partial kernel's arguments: exists, (the slot table's bases,) then a
+    (data, valid) pair a key and an aggregate argument."""
+    def plane(dt):
+        return jax.ShapeDtypeStruct((cap,), jnp.dtype(dt))
+
+    flat = [jax.ShapeDtypeStruct((len(key_dtypes),), jnp.int64)] * bases
+    for kd in key_dtypes:
+        flat += [plane(kd), plane(bool)]
+    for ad in arg_dtypes:
+        flat += [plane("int64")] * 3 if ad == "wide3" else [plane(ad)]
+        flat.append(plane(bool))
+    return [plane(bool)] + flat
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+NARROW = [k for k, (_s, d) in KINDS.items() if d != "wide3"]
+WIDE = [k for k, (_s, d) in KINDS.items() if d == "wide3"]
+CAPACITY = 131072  # a scan batch of the benchmark's cells
+
+
+@pytest.mark.parametrize("names", [["sum", "count"], NARROW, WIDE],
+                         ids=["q01", "narrow", "wide"])
+def test_dense_kernel_at_16_slots_has_no_row_sized_scatter(names):
+    """At 16 slots nothing in the slot-table kernel touches a row at a time:
+    no scatter, gather or sort with a batch-sized operand or update."""
+    specs = tuple(KINDS[n][0] for n in names)
+    adt = tuple(KINDS[n][1] for n in names)
+    kernel = A._dense_partial_kernel(("int64",), specs, adt, CAPACITY,
+                                     (16,), 128)
+    avals = _avals(("int64",), adt, CAPACITY, bases=True)
+    closed = jax.make_jaxpr(kernel)(*avals)
+    serial = [e for e in _eqns(closed.jaxpr)
+              if e.primitive.name.startswith(("scatter", "gather", "sort"))]
+    assert serial, "the emit step still compacts 16 slots by scatter"
+    for eqn in serial:
+        sizes = [v.aval.size for v in list(eqn.invars) + list(eqn.outvars)]
+        assert max(sizes) < CAPACITY, eqn
+    # and above the cut the same kernel keeps its row-sized scatter-adds
+    big = A._dense_partial_kernel(("int64",), specs, adt, CAPACITY,
+                                  (2 * CUT,), 2 * CUT)
+    closed = jax.make_jaxpr(big)(*avals)
+    assert any(e.primitive.name.startswith("scatter")
+               and max(v.aval.size for v in e.invars) >= CAPACITY
+               for e in _eqns(closed.jaxpr))
+
+
+# sha256 of str(make_jaxpr(_partial_kernel(...))) at the parent commit
+# (0f92de4, jax 0.9.0): the sort kernel, whose segment count is the batch
+# capacity, is not this PR's, so its program — and the compile-cache entries
+# q67 hits — must not move. The PR that rewrites the sort path replaces these.
+SORT_PATH = {
+    "sum_count_1key": (
+        ("int64",), ["sum", "count"],
+        "954dd6fce5740a96324f1fd6e8a5384a23692424d7039bb9bbb2147355ff9250"),
+    "narrow_2key": (
+        ("int64", "int32"), ["sum", "avg", "min", "max", "count", "sum_f32"],
+        "fbdd7acad6c17d74564bb645dbbc2825373939429832c016b18965133c9b6076"),
+    "wide_1key": (
+        ("int64",), ["sum2", "avg2", "sum3", "avg3", "minw", "maxw"],
+        "eb8eaa688c8210137fd2e8c348e5e7beed091aca397b6d68def6e1b287663229"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SORT_PATH))
+def test_sort_kernel_jaxpr_is_the_parents(case):
+    key_dtypes, names, digest = SORT_PATH[case]
+    specs = tuple(KINDS[n][0] for n in names)
+    adt = tuple(KINDS[n][1] for n in names)
+    kernel = A._partial_kernel(key_dtypes, specs, adt, CAPACITY)
+    text = str(jax.make_jaxpr(kernel)(*_avals(key_dtypes, adt, CAPACITY)))
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+# -- selection and counters ----------------------------------------------------
+
+
+@pytest.mark.parametrize("backend_is_cpu", [False, True])
+def test_auto_engages_whatever_the_backend_and_counts_what_ran(
+        monkeypatch, backend_is_cpu):
+    """``dense_agg=None`` selects from the probed key range alone; the radix
+    table keeps its backend gate, so off the CPU a wide range goes to the
+    sort kernel. Each answered batch is counted as what ran."""
+    from blaze_tpu.runtime import placement
+
+    monkeypatch.setattr(placement, "backend_is_cpu_hint",
+                        lambda: backend_is_cpu)
+    agger = _agger()
+    assert agger.conf.dense_agg is None
+    s0 = DEVICE_STATS.snapshot()
+    out = agger.process(_batch([3, 4, 5] * 100, [1] * 300))
+    s1 = DEVICE_STATS.snapshot()
+    assert agger._bucket_state[0] == "dense" and out.num_rows == 3
+    assert s1["agg_dense_batches"] - s0["agg_dense_batches"] == 1
+    assert s1["agg_sort_batches"] == s0["agg_sort_batches"]
+    # the probe and the kernel's group count: two named syncs
+    assert s1["sync_calls"] - s0["sync_calls"] == 2
+    out = agger.process(_batch([3, 4] * 100, [1] * 200))
+    s2 = DEVICE_STATS.snapshot()
+    assert s2["agg_dense_batches"] - s1["agg_dense_batches"] == 1
+    assert s2["sync_calls"] - s1["sync_calls"] == 1, "one probe a stream"
+    # a range past dense_agg_max_buckets: radix where its gate allows, else
+    # the sort kernel for the rest of the stream
+    wide = _agger()
+    out = wide.process(_batch([5, 900_005] * 100, [2] * 200))
+    s3 = DEVICE_STATS.snapshot()
+    assert sorted(out.to_arrow().to_pydict()["s#sum"]) == [200, 200]
+    if backend_is_cpu:
+        assert wide._bucket_state[0] == "radix"
+        assert s3["agg_dense_batches"] - s2["agg_dense_batches"] == 1
+    else:
+        assert wide._bucket_state is None and wide._dense_ok is False
+        assert s3["agg_sort_batches"] - s2["agg_sort_batches"] == 1
+        assert s3["agg_dense_batches"] == s2["agg_dense_batches"]
+
+
+def test_dense_agg_false_forces_the_sort_kernel():
+    from blaze_tpu.config import Config
+
+    schema = T.schema_from_arrow(SCHEMA)
+    node = N.Agg(_scan_stub(), E.AggExecMode.HASH_AGG,
+                 [("k1", E.Column("k1"))], [
+        N.AggColumn(E.AggExpr(E.AggFunction.SUM, [E.Column("v")]),
+                    E.AggMode.PARTIAL, "s")])
+    agger = DevicePartialAgger(build_operator(node), schema,
+                               conf=Config(dense_agg=False, radix_agg=False))
+    s0 = DEVICE_STATS.snapshot()
+    out = agger.process(_batch([3, 4, 5] * 100, [1] * 300))
+    s1 = DEVICE_STATS.snapshot()
+    assert out.num_rows == 3 and agger._bucket_state is None
+    assert s1["agg_sort_batches"] - s0["agg_sort_batches"] == 1
+    assert s1["agg_dense_batches"] == s0["agg_dense_batches"]
+    assert s1["sync_calls"] - s0["sync_calls"] == 1, "no probe"
