@@ -214,6 +214,29 @@ def gather_planes(datas: Sequence[jax.Array], valids: Sequence[jax.Array],
     return _dispatch(_gather, tuple(datas), tuple(valids), jnp.asarray(buf), jnp.asarray(lbuf))
 
 
+def take_planes_traced(datas, valids, idx, live):
+    """Traced: rows ``idx`` of every (data, validity) plane where ``live``,
+    else the padding contract (data 0, validity False). A gather costs the
+    chip as much for a bool plane as for an int32 one (PERF.md §6, PR 26),
+    so the validity planes travel as ONE bit-packed plane, 32 columns a
+    word. ``idx`` is clipped per plane: a batch's columns may differ in
+    capacity."""
+    out_d = tuple(
+        jnp.where(live, d[jnp.clip(idx, 0, d.shape[0] - 1)],
+                  jnp.zeros((), d.dtype)) for d in datas)
+    out_v = []
+    for at in range(0, len(valids), 32):
+        group = valids[at:at + 32]
+        cap = min(v.shape[0] for v in group)
+        word = jnp.zeros(cap, jnp.uint32)
+        for bit, v in enumerate(group):
+            word = word | (v[:cap].astype(jnp.uint32) << bit)
+        word = word[jnp.clip(idx, 0, cap - 1)]
+        out_v += [((word >> bit) & 1).astype(bool) & live
+                  for bit in range(len(group))]
+    return out_d, tuple(out_v)
+
+
 @jax.jit
 def _compact(datas, valids, mask):
     count = jnp.sum(mask)
@@ -295,6 +318,78 @@ def _key_ops_traced(datas, valids, exists, spec):
         ops.append(rank)
         ops.append(val)
     return tuple(ops)
+
+
+def _dense_ranks(words):
+    """(k, n) uint64 -> (k, n) int32: every line's values replaced by their
+    dense ranks (equal values, equal ranks; the order kept). One batched
+    two-operand sort, a prefix sum over the sorted values' changes, and a
+    second batched sort that carries the ranks back to the rows' places."""
+    k, n = words.shape
+    iota = lax.broadcasted_iota(jnp.int32, (k, n), 1)
+    sorted_words, perm = lax.sort((words, iota), dimension=1, num_keys=1,
+                                  is_stable=False)
+    new = jnp.concatenate(
+        [jnp.zeros((k, 1), bool), sorted_words[:, 1:] != sorted_words[:, :-1]],
+        axis=1)
+    rank = jnp.cumsum(new, axis=1, dtype=jnp.int32)
+    return lax.sort((perm, rank), dimension=1, num_keys=1, is_stable=False)[1]
+
+
+# classes a column of `lex_order_traced` may give its rows: -_LEX_CLASSES..-1
+# before the rows that compare by their word (class 0), 1.._LEX_CLASSES after
+_LEX_CLASSES = 4
+
+
+def lex_order_traced(columns):
+    """Traced: the order of ``n`` rows by several columns, lexicographically,
+    ties in row order — what ``lax.sort`` over all the columns at once gives,
+    built from two-operand sorts only. On the TPU a sort's compile time grows
+    faster than its operand count (a 64-bit word is two): a million rows on
+    three nullable int64 keys compile for 400 s as one ten-operand sort and
+    for half a minute this way, and run in about 22 ms for 8 (PERF.md §6,
+    PR 26).
+
+    A column is ``(word, cls)``: ``word`` a uint64 plane, ``cls`` a plane of
+    small integers or None. Rows of class 0 (all rows, for None) order by
+    their word; rows of a negative class stand before them and rows of a
+    positive class after, ordered by class alone (|cls| <= ``_LEX_CLASSES``).
+    Every word is replaced by its dense rank (`_dense_ranks`, all columns in
+    one batched sort), the ranks and classes are packed into uint64 words, as
+    many columns a word as fit, and while that is more than one word the
+    words are ranked and packed again. Returns ``(order, key)``: the row
+    standing at each position (int32), and the last packed word in that
+    order — equal exactly where all columns are."""
+    n = columns[0][0].shape[0]
+    assert n < 1 << 30  # two columns a word at least, so the packing ends
+    rank_bits = max(1, (n - 1).bit_length())
+    ranks = _dense_ranks(jnp.stack([w for w, _ in columns]))
+    codes = []
+    for line, (_, cls) in zip(ranks, columns):
+        if cls is None:
+            codes.append((line.astype(jnp.uint64), rank_bits))
+            continue
+        c = cls.astype(jnp.int32)
+        code = jnp.where(c < 0, c + _LEX_CLASSES, jnp.where(
+            c == 0, line + _LEX_CLASSES, c + (n + _LEX_CLASSES - 1)))
+        codes.append((code.astype(jnp.uint64),
+                      (n + 2 * _LEX_CLASSES - 1).bit_length()))
+    while True:
+        words, used = [], 64
+        for code, bits in codes:
+            if used + bits > 64:
+                words.append(code)
+                used = bits
+            else:
+                words[-1] = (words[-1] << bits) | code
+                used += bits
+        if len(words) == 1:
+            break
+        codes = [(line.astype(jnp.uint64), rank_bits)
+                 for line in _dense_ranks(jnp.stack(words))]
+    key, order = lax.sort((words[0], jnp.arange(n, dtype=jnp.int32)),
+                          num_keys=2, is_stable=False)
+    return order, key
 
 
 @functools.partial(jax.jit, static_argnames=("spec",))

@@ -222,6 +222,88 @@ def sorted_probe_traced(uniq, d, v, nk: int):
     return cidx, hit
 
 
+def merge_match_traced(lkeys, rkeys, nl, nr):
+    """Traceable exact match of two sides on several fixed-width keys — the
+    sort-merge join's probe (ops/joins/smj.py). ``lkeys`` / ``rkeys`` are
+    lists of (data, validity) planes, one pair a key, ``nl`` / ``nr`` the
+    sides' row counts. Every key goes through :func:`canon_word_traced`, so
+    the join compares the words the hash-join probes compare; several keys
+    are compared as a tuple of words, never hashed into one.
+
+    Both sides' rows are laid side by side (right rows first: row ``i`` of
+    the right side is joint row ``i``, row ``i`` of the left side joint row
+    ``cap_r + i``) and ordered ONCE, ties in row order, by (tag, word_1 ..
+    word_k) — ``core/kernels.lex_order_traced``: the order of one sort over
+    all of them, from two-operand sorts that compile in seconds; tag 0 is a
+    row whose keys are all valid, 1 a row with a null key (it matches
+    nothing: Spark's equi-join), 2 a padding row. Equal keys then form one
+    run, its right rows before its left rows, each in input order; neither
+    side needs to arrive sorted. Everything else is prefix scans over the
+    sorted order — no gather, no scatter. Returns, per joint POSITION of the
+    sorted order:
+
+    - ``row``: the joint row standing there (int32);
+    - ``run_start``: the position of its run's first row (the run's right
+      rows stand at ``run_start .. run_start + pairs - 1``);
+    - ``pairs``: at a left row, the right rows of its run; else 0;
+    - ``is_left`` / ``is_right``: an existing row of that side, null-keyed
+      ones included;
+    - ``r_matched``: a right row whose run holds a left row."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from blaze_tpu.core import kernels as K
+
+    def side(keys, n):
+        cap = keys[0][0].shape[0]
+        exists = jnp.arange(cap, dtype=jnp.int32) < n
+        valid = exists
+        for _d, v in keys:
+            valid = valid & v
+        tag = jnp.where(exists, jnp.where(valid, 0, 1), 2).astype(jnp.uint8)
+        words = [jnp.where(valid, canon_word_traced(d), jnp.int64(0))
+                 for d, _v in keys]
+        return tag, words
+
+    ltag, lwords = side(lkeys, nl)
+    rtag, rwords = side(rkeys, nr)
+    cap_r = rtag.shape[0]
+    total = cap_r + ltag.shape[0]
+    tag = jnp.concatenate([rtag, ltag])
+    # signed order of the words, as the sides' own sorts have it
+    words = [jnp.concatenate([r, l]).astype(jnp.uint64) ^ jnp.uint64(1 << 63)
+             for r, l in zip(rwords, lwords)]
+    row, key = K.lex_order_traced(
+        [(words[0], tag)] + [(w, None) for w in words[1:]])
+    at = jnp.arange(total, dtype=jnp.int32)
+    # the tag is the first column's class, so the sorted order is all of tag
+    # 0, then 1, then 2: the tag at a position follows from the counts
+    keyed = jnp.sum(tag == 0, dtype=jnp.int32)
+    tag = jnp.where(at < keyed, 0, jnp.where(
+        at < jnp.sum(tag < 2, dtype=jnp.int32), 1, 2))
+    new_run = jnp.concatenate([jnp.ones(1, bool), key[1:] != key[:-1]])
+    run_end = jnp.concatenate([new_run[1:], jnp.ones(1, bool)])
+    on_left = row >= cap_r
+    match_l = (tag == 0) & on_left
+    match_r = (tag == 0) & ~on_left
+    # counts are non-decreasing, so the count where a run starts (ends) is a
+    # running max (a reversed running min) over the run's boundary
+    seen_r = jnp.cumsum(match_r, dtype=jnp.int32)
+    seen_l = jnp.cumsum(match_l, dtype=jnp.int32)
+    r_before = lax.cummax(jnp.where(new_run, seen_r - match_r, 0), axis=0)
+    l_before = lax.cummax(jnp.where(new_run, seen_l - match_l, 0), axis=0)
+    l_through = lax.cummin(jnp.where(run_end, seen_l, total), axis=0,
+                           reverse=True)
+    return {
+        "row": row,
+        "run_start": lax.cummax(jnp.where(new_run, at, 0), axis=0),
+        "pairs": jnp.where(match_l, seen_r - r_before, 0),
+        "is_left": (tag < 2) & on_left,
+        "is_right": (tag < 2) & ~on_left,
+        "r_matched": match_r & (l_through > l_before),
+    }
+
+
 @functools.lru_cache(maxsize=None)
 def _probe_fn(dtype_str: str, nk: int):
     """Module-level cache: one jitted probe per (dtype, key count) — a

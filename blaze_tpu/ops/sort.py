@@ -4,15 +4,26 @@ Reference: ``sort_exec.rs:88-1608`` — in-memory row-key blocks, loser-tree
 k-way merge of squeezed spill blocks, key pruning, optional fetch limit
 (TopK), and the ``execute_with_key_rows`` fast path shared with SMJ.
 
-TPU design: per-run sorting happens on device via ``jax.lax.sort`` over
-normalized u64 key operands (ops/sort_keys.py) with an index payload; runs
-that exceed the memory budget spill as compressed batch streams with their
-key columns appended; the final pass k-way-merges runs on host. Sorts whose
-keys include var-width columns run on host via arrow sort_indices.
+TPU design: a partition that fits the memory budget is concatenated and
+sorted as ONE run on the device over normalized key operands
+(ops/sort_keys.py): one key by ``jax.lax.sort`` with an index payload
+(``jit(sort)``), several keys by ``jit(sort_order)`` — dense ranks of every
+key packed into one word, two-operand sorts only, because one sort over
+2k + 1 operands compiles for minutes on the chip (f64 keys, which do not
+pack, keep it). The permutation is applied to the batch's device planes by
+``jit(sort_take)`` — it never visits the host; only a batch that also
+carries host (var-width) columns pulls it for them (``sync:sort_indices``).
+A sort-merge join above takes that run whole (``whole_runs``); every other
+consumer gets it in batches of ``batch_size``. Runs that exceed the budget
+spill as compressed batch streams with their key columns appended (pulled
+for the file: ``sync:sort_spill_keys``), and the final pass k-way-merges the
+spilled runs on host. Sorts whose keys include var-width columns run on
+host via arrow sort_indices.
 """
 
 from __future__ import annotations
 
+import functools
 import heapq
 from typing import Iterator, List, Optional, Tuple
 
@@ -20,33 +31,80 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from blaze_tpu.config import get_config
+from blaze_tpu.core import kernels as K
 from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
 from blaze_tpu.ops import sort_keys as SK
 from blaze_tpu.ops.base import ExecContext, Operator
 from blaze_tpu.runtime.memmgr import MemConsumer, SpillFile
+from blaze_tpu.utils.device import wait_array
+
 
 def sort_batch(batch: ColumnarBatch, sort_orders: List[E.SortOrder],
                limit: Optional[int] = None) -> ColumnarBatch:
     """Sort one batch fully (device path when possible)."""
     if batch.num_rows <= 1:
         return batch
+    n_out = batch.num_rows if limit is None else min(limit, batch.num_rows)
     if SK.supports_device_sort(batch.schema, sort_orders):
-        operands = SK.key_operands(batch, sort_orders)
-        idx = _device_sort_indices(operands, batch.capacity)
-        indices = np.asarray(idx)[: batch.num_rows]
-    else:
-        indices = SK.host_sort_indices(batch, sort_orders)
-    if limit is not None:
-        indices = indices[:limit]
-    return batch.take(indices)
+        return _take_sorted(batch, SK.key_operands(batch, sort_orders),
+                            n_out)[0]
+    return batch.take(SK.host_sort_indices(batch, sort_orders)[:n_out])
+
+
+@jax.jit
+def sort_order(operands):
+    """The permutation that sorts by SEVERAL keys' operands [rank0, val0,
+    rank1, val1, ...]: ``lax.sort`` over all of them at once compiles for
+    minutes on the chip, `K.lex_order_traced` gives the same order from
+    two-operand sorts. A key's rank is its class (2, the rows ordered by
+    value, is class 0; every other rank's rows carry value 0)."""
+    columns = [(SK.orderable_word_traced(val), rank.astype(jnp.int8) - 2)
+               for rank, val in zip(operands[::2], operands[1::2])]
+    return K.lex_order_traced(columns)[0]
 
 
 def _device_sort_indices(operands: List[jnp.ndarray], capacity: int) -> jnp.ndarray:
+    if len(operands) > 2 and all(SK.packs_to_word(val.dtype)
+                                 for val in operands[1::2]):
+        return K._dispatch(sort_order, tuple(operands))
     iota = jnp.arange(capacity, dtype=jnp.int32)
     sorted_ops = jax.lax.sort(tuple(operands) + (iota,), num_keys=len(operands))
     return sorted_ops[-1]
+
+
+@functools.partial(jax.jit, static_argnames=("out_cap",))
+def sort_take(order, datas, valids, n_out, out_cap):
+    """The first ``n_out`` rows of every plane in the order of the device
+    permutation ``order``, at ``out_cap``: the gather that applies a sort."""
+    live = jnp.arange(out_cap, dtype=jnp.int32) < n_out
+    return K.take_planes_traced(datas, valids, order[:out_cap], live)
+
+
+def _take_sorted(batch: ColumnarBatch, operands, n_out: int):
+    """``batch``'s first ``n_out`` rows in the operands' order, and the
+    permutation, which stays on the device: ``jit(sort)`` or
+    ``jit(sort_order)``, then ``jit(sort_take)``. Host columns alone need it
+    on the host."""
+    slots = batch._device_slots()
+    out_cap = min(get_config().capacity_for(n_out), batch.capacity)
+    order = _device_sort_indices(operands, batch.capacity)
+    datas, valids = K._dispatch(
+        sort_take, order,
+        tuple(batch.columns[i].data for i in slots),
+        tuple(batch.columns[i].validity for i in slots),
+        np.int32(n_out), out_cap=out_cap)
+    cols = list(batch.columns)
+    for k, i in enumerate(slots):
+        cols[i] = DeviceColumn(cols[i].dtype, datas[k], valids[k])
+    if len(slots) < len(cols):
+        indices = wait_array(order, "sort_indices")[:n_out].astype(np.int64)
+        for i, c in enumerate(cols):
+            if not isinstance(c, DeviceColumn):
+                cols[i] = c.take_host(indices)
+    return ColumnarBatch(batch.schema, cols, n_out), order
 
 
 class SortExec(Operator):
@@ -54,6 +112,10 @@ class SortExec(Operator):
                  fetch_limit: Optional[int] = None):
         self.sort_orders = sort_orders
         self.fetch_limit = fetch_limit
+        # set by a consumer that works on the partition's whole sorted run
+        # (the sort-merge join): an unspilled run is handed over as ONE
+        # batch, not sliced into batches the consumer would concatenate
+        self.whole_runs = False
         super().__init__(child.schema, [child])
 
     def _execute(self, partition, ctx, metrics):
@@ -155,9 +217,9 @@ class _SortState(MemConsumer):
         if merged.num_rows <= 1:
             idx = np.arange(merged.num_rows, dtype=np.int64)
             return merged, SK.operands_merge_matrix(operands, idx)
-        idx = np.asarray(_device_sort_indices(operands, merged.capacity))
-        idx = idx[: merged.num_rows].astype(np.int64)
-        return merged.take(idx), SK.operands_merge_matrix(operands, idx)
+        run, order = _take_sorted(merged, operands, merged.num_rows)
+        idx = wait_array(order, "sort_spill_keys")[: merged.num_rows]
+        return run, SK.operands_merge_matrix(operands, idx.astype(np.int64))
 
     def output(self) -> Iterator[ColumnarBatch]:
         batch_size = self.ctx.conf.batch_size
@@ -165,6 +227,9 @@ class _SortState(MemConsumer):
             if not self.staged:
                 return
             merged = self._sorted_run()
+            if self.op.whole_runs:
+                yield merged
+                return
             for off in range(0, merged.num_rows, batch_size):
                 yield merged.slice(off, batch_size)
             return

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
@@ -66,6 +67,29 @@ def key_operands(batch: ColumnarBatch, sort_orders: List[E.SortOrder],
         valids.append(validity)
     return K.sort_key_operands(datas, valids, batch.row_exists_mask(),
                                key_spec(sort_orders))
+
+
+def packs_to_word(dtype) -> bool:
+    """Can :func:`orderable_word_traced` take an operand of this dtype? Not
+    f64: the v5e's X64 rewriting has no f64 -> s64 bitcast."""
+    dtype = jnp.dtype(dtype)
+    return (jnp.issubdtype(dtype, jnp.integer) or dtype == jnp.bool_
+            or dtype == jnp.float32)
+
+
+def orderable_word_traced(val):
+    """Traced: the uint64 image of an operand value plane (direction-adjusted,
+    NaN-free), whose unsigned order is ``lax.sort``'s order of the plane;
+    like its comparator, -0.0 is 0.0 here (`_orderable_bits_np`, the host's
+    twin for the spill merge, tells them apart)."""
+    if val.dtype == jnp.float32:
+        val = jnp.where(val == 0, jnp.float32(0), val)
+        bits = jax.lax.bitcast_convert_type(val, jnp.uint32)
+        top = jnp.uint32(1 << 31)
+        return jnp.where(bits >= top, ~bits, bits | top).astype(jnp.uint64)
+    if jnp.issubdtype(val.dtype, jnp.signedinteger):
+        return val.astype(jnp.int64).astype(jnp.uint64) ^ jnp.uint64(1 << 63)
+    return val.astype(jnp.uint64)
 
 
 # ---------------------------------------------------------------------------

@@ -172,6 +172,13 @@ class MetricNode:
 #   collective_bytes                 bytes moved by mesh all-to-all
 #                                    collectives in place of shuffle file
 #                                    writes (MeshBatchExchange wire bytes)
+#   smj_device_joins                 sort-merge join partitions joined by
+#                                    the device programs (ops/joins/smj.py:
+#                                    fixed-width device keys, no condition)
+#   smj_host_joins == 0              ... on plans whose join keys all live
+#                                    on the device: partitions that took the
+#                                    host's key interning instead (var-width
+#                                    or host-resident keys, a condition)
 TRIPWIRE_METRICS = (
     "split_batches",
     "split_gathers",
@@ -194,6 +201,8 @@ TRIPWIRE_METRICS = (
     "sharded_stages",
     "device_shuffle_bytes",
     "collective_bytes",
+    "smj_device_joins",
+    "smj_host_joins",
 )
 
 

@@ -1405,17 +1405,16 @@ class Session:
         self._register_resource(rrid, _SubsetBlockProvider(
             rindexes, parts, subset_applies=split_right))
         nparts = len(parts)
-        left: N.PlanNode = N.CoalesceBatches(
-            N.IpcReader(schema=lex.child.output_schema, resource_id=lrid,
-                        num_partitions=nparts), batch_size=0)
-        right: N.PlanNode = N.CoalesceBatches(
-            N.IpcReader(schema=rex.child.output_schema, resource_id=rrid,
-                        num_partitions=nparts), batch_size=0)
-        if lsort is not None:
-            left = dataclasses.replace(lsort, child=left)
-        if rsort is not None:
-            right = dataclasses.replace(rsort, child=right)
-        return dataclasses.replace(node, left=left, right=right)
+        def side(sort, ex, rid) -> N.PlanNode:
+            read = N.IpcReader(schema=ex.child.output_schema, resource_id=rid,
+                               num_partitions=nparts)
+            if sort is None:
+                return N.CoalesceBatches(read, batch_size=0)
+            # a Sort concatenates its whole input once itself (see _lower)
+            return dataclasses.replace(sort, child=read)
+
+        return dataclasses.replace(node, left=side(lsort, lex, lrid),
+                                   right=side(rsort, rex, rrid))
 
     def _coalesce_reducers(self, indexes, num_reducers: int):
         """Greedy adjacent merge of under-sized reducer partitions; returns
