@@ -237,6 +237,43 @@ def take_planes_traced(datas, valids, idx, live):
     return out_d, tuple(out_v)
 
 
+def take_rows_traced(datas, valids, idx, live):
+    """:func:`take_planes_traced`'s contract with ONE gather for all the
+    planes. On the TPU a gather costs by the index, not by what each index
+    fetches: at 131,072 rows six int64 planes gathered one by one (twelve
+    gathers, a 64-bit plane being two 32-bit ones) read 11.4 ms, and the
+    same planes laid side by side as one matrix of 32-bit words, a row an
+    index, 0.49 ms (PERF.md §6, PR 27). So every data plane is cut into
+    uint32 words (8 bytes: two, bit for bit; 4 bytes: one; narrower: widened
+    to int32), the validity planes are bit-packed 32 to a word, and the
+    words of a row travel together. Planes of different capacities are cut
+    to the shortest: ``idx`` names rows below the batch's row count, which
+    every plane holds."""
+    if not datas and not valids:
+        return (), ()
+    cap = min(p.shape[0] for p in (*datas, *valids))
+    # the dtype each data plane is bitcast from: itself, or int32 if narrower
+    wide = [jnp.int32 if d.dtype.itemsize < 4 else d.dtype for d in datas]
+    blocks = [lax.bitcast_convert_type(d[:cap].astype(w), jnp.uint32)
+              .reshape(cap, -1) for d, w in zip(datas, wide)]
+    for at in range(0, len(valids), 32):
+        word = jnp.zeros(cap, jnp.uint32)
+        for bit, v in enumerate(valids[at:at + 32]):
+            word = word | (v[:cap].astype(jnp.uint32) << bit)
+        blocks.append(word[:, None])
+    rows = jnp.concatenate(blocks, axis=1)[jnp.clip(idx, 0, cap - 1)]
+    rows = jnp.where(live[:, None], rows, jnp.uint32(0))
+    out_d, at = [], 0
+    for d, w, block in zip(datas, wide, blocks):
+        words = rows[:, at:at + block.shape[1]]
+        at += block.shape[1]
+        out_d.append(lax.bitcast_convert_type(
+            words if block.shape[1] == 2 else words[:, 0], w).astype(d.dtype))
+    out_v = [((rows[:, at + bit // 32] >> (bit % 32)) & 1).astype(bool)
+             for bit in range(len(valids))]
+    return tuple(out_d), tuple(out_v)
+
+
 @jax.jit
 def _compact(datas, valids, mask):
     count = jnp.sum(mask)

@@ -164,7 +164,7 @@ class FusedJoinSpec:
     kernel (the TPC-DS star-join shape: fact scan -> dim lookup -> group-by
     on dim attributes). Instead of materializing the joined batch (compact
     + re-gather of every column), the agg kernel probes the sorted dim keys
-    with ``searchsorted``, gathers ONLY the dim columns the group/agg
+    with ``sorted_probe_traced``, gathers ONLY the dim columns the group/agg
     expressions touch, and uses the hit mask as the row-exists mask — one
     dispatch, no intermediate rows (reference analogue: the probe loop of
     ``joins/bhj/full_join.rs`` feeding ``agg/agg_table.rs`` without an
@@ -264,7 +264,7 @@ class FusedJoinSpec:
         cap_p = ptb.capacity
         iota = jnp.arange(cap_p, dtype=jnp.int64)
         exists = iota < num_rows
-        # shared canonical-word + searchsorted membership (keymap is the
+        # shared canonical-word + sorted-key membership (keymap is the
         # single authority for the key encoding)
         cidx, hit = sorted_probe_traced(uniq, kd, kv & exists, self.nk)
         bcols = []

@@ -35,51 +35,53 @@ import functools
 
 
 @functools.lru_cache(maxsize=256)
-def _inner_fast_kernel(key_dtype: str, probe_dtypes, build_dtypes,
-                       cap_p: int, cap_b: int, nk: int):
+def _inner_fast_kernel(n_probe_cols: int, nk: int):
     """Fused device inner-join kernel for unique-single-key build maps (the
-    TPC-DS dimension join): searchsorted probe + matched-row compaction +
-    BOTH sides' gathers in ONE jitted dispatch, one scalar sync for the
-    surviving-row count. Replaces probe-dispatch -> 1MB code pull -> host
-    pair expansion -> two gather dispatches per batch, and with them a
-    per-batch host round trip (reference analogue: the probe+interleave loop
-    of joins/bhj/*.rs fused into one XLA program)."""
+    TPC-DS dimension join): probe, matched-row compaction and BOTH sides'
+    rows in ONE jitted dispatch, one scalar sync for the surviving-row
+    count (reference analogue: the probe+interleave loop of joins/bhj/*.rs
+    fused into one XLA program).
+
+    How the rows move is decided by what the TPU charges (PERF.md §6, PR
+    27; 131,072-row batches): a scatter runs one update at a time, 9.2 ms
+    for a row-sized int64 plane; a gather costs 0.94 ms for each 32-bit
+    plane it is asked for (an int64 plane is two), and no more when an index
+    fetches a row of many words; a two-operand 32-bit sort 0.1 ms. So
+    nothing is scattered. The row map ``src`` (output row -> probe row) is the payload
+    of a stable sort of the hit mask, and each side's planes move ONCE, as
+    one matrix of words, by one gather (``take_rows_traced``): the probe's by
+    ``src``, the match's rank riding along as one more plane, and the
+    build's by that rank, straight from the build table. Rows past ``count``
+    keep the padding contract (data 0, validity False)."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
+
+    from blaze_tpu.core.kernels import take_rows_traced
+    from blaze_tpu.ops.joins.keymap import sorted_probe_traced
 
     def bhj_inner_fast(uniq, num_rows, kd, kv, *flat):
-        from blaze_tpu.ops.joins.keymap import sorted_probe_traced
-
-        npr = len(probe_dtypes)
-        probe_planes = flat[:2 * npr]
-        build_planes = flat[2 * npr:]
-        iota = jnp.arange(cap_p, dtype=jnp.int64)
-        exists = iota < num_rows
-        # shared canonical-word + searchsorted membership (keymap is the
-        # single authority for the key encoding)
-        idx, hit = sorted_probe_traced(uniq, kd, kv & exists, nk)
-        count = jnp.sum(hit)
-        # order-preserving compaction by cumsum + scatter-drop: O(n), ~3x
-        # faster than the previous stable argsort over capacity on CPU and
-        # avoids a full sort on TPU as well. Dropped slots keep the padding
-        # contract (data 0, validity False) because the scatter target is
-        # zero-initialized.
-        pos = jnp.where(hit, jnp.cumsum(hit) - 1, cap_p).astype(jnp.int32)
-
-        def compact(x):
-            return jnp.zeros((cap_p,), x.dtype).at[pos].set(x, mode="drop")
-
-        # unique CSR: code c owns build row c exactly
-        bidx = jnp.clip(idx, 0, cap_b - 1)
+        probe_planes = flat[:2 * n_probe_cols]
+        build_planes = flat[2 * n_probe_cols:]
+        iota = jnp.arange(kd.shape[0], dtype=jnp.int32)
+        with jax.named_scope("probe"):
+            # keymap is the single authority for the key encoding
+            rank, hit = sorted_probe_traced(uniq, kd, kv & (iota < num_rows),
+                                            nk)
+        with jax.named_scope("rowmap"):
+            count = jnp.sum(hit)
+            _, src = lax.sort(((~hit).astype(jnp.uint8), iota), num_keys=1,
+                              is_stable=True)
+            live = iota < count
+        with jax.named_scope("gather"):
+            (*pd_, build_row), pv = take_rows_traced(
+                (*probe_planes[0::2], rank), probe_planes[1::2], src, live)
+            # unique CSR: code c owns build row c exactly
+            bd, bv = take_rows_traced(build_planes[0::2], build_planes[1::2],
+                                      build_row, live)
         outs = [count]
-        for i in range(npr):
-            pd_, pv = probe_planes[2 * i], probe_planes[2 * i + 1]
-            outs.append(compact(pd_))
-            outs.append(compact(pv))
-        for i in range(len(build_dtypes)):
-            bd, bv = build_planes[2 * i], build_planes[2 * i + 1]
-            outs.append(compact(bd[bidx]))
-            outs.append(compact(bv[bidx]))
+        for d, v in zip((*pd_, *bd), (*pv, *bv)):
+            outs += [d, v]
         return tuple(outs)
 
     return jax.jit(bhj_inner_fast)
@@ -248,11 +250,7 @@ class _HashJoinBase(Operator):
             bmap._dev_cell[0] = jnp.asarray(
                 bmap.sorted_keys if len(bmap.sorted_keys)
                 else np.zeros(1, np.int64))
-        kernel = _inner_fast_kernel(
-            str(cols[0].data.dtype),
-            tuple(str(c.data.dtype) for c in batch.columns),
-            tuple(str(c.data.dtype) for c in bb.columns),
-            batch.capacity, bb.capacity, len(bmap.sorted_keys))
+        kernel = _inner_fast_kernel(len(batch.columns), len(bmap.sorted_keys))
         flat = []
         for c in batch.columns:
             flat += [c.data, c.validity]

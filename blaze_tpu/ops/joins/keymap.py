@@ -212,14 +212,39 @@ def sorted_probe_traced(uniq, d, v, nk: int):
     """Traceable membership probe against sorted canonical keys: returns
     (rank clipped into [0, nk), hit mask). All device join probes MUST go
     through this so the key encoding can never desynchronize between the
-    build map and a probe path."""
+    build map and a probe path.
+
+    The search is a merge, not a binary search: on the TPU a binary search
+    costs what its gathers cost, one row-sized gather a step (15 steps for
+    18,000 keys: 30.1 ms for 131,072 rows against 0.48 ms this way; PERF.md
+    §6, PR 27). The build words and the probe words are ordered by ONE
+    stable two-operand sort (word, int32 row), build words first, so a build
+    word stands just ahead of the probe words equal to it; ``uniq`` holds no
+    word twice, so a probe word hits iff the last build word at or before it
+    opened its own run of equal words. That, and the build word's rank, are
+    prefix scans over the sorted order; a second two-operand sort, on the
+    row, brings the ranks back to the probe's row order (a third of what the
+    int32 scatter of that permutation costs). No gather, no scatter, and no
+    64-bit operand but the first sort's key."""
     import jax.numpy as jnp
+    from jax import lax
 
     w = canon_word_traced(d)
-    idx = jnp.searchsorted(uniq, w)
-    cidx = jnp.clip(idx, 0, max(nk - 1, 0))
-    hit = v & (idx < nk) & (uniq[cidx] == w)
-    return cidx, hit
+    m = uniq.shape[0]
+    words, row = lax.sort(
+        (jnp.concatenate([uniq, w]),
+         jnp.arange(m + w.shape[0], dtype=jnp.int32)),
+        num_keys=1, is_stable=True)
+    is_build = row < m
+    run = jnp.cumsum(jnp.concatenate(
+        [jnp.ones(1, bool), words[1:] != words[:-1]]), dtype=jnp.int32)
+    build_run = lax.cummax(jnp.where(is_build, run, 0), axis=0)
+    build_row = lax.cummax(jnp.where(is_build, row, -1), axis=0)
+    rank = jnp.where(~is_build & (build_run == run), build_row, -1)
+    _, rank = lax.sort((row, rank), num_keys=1, is_stable=False)
+    rank = rank[m:]
+    hit = v & (rank >= 0) & (rank < nk)
+    return jnp.clip(rank, 0, max(nk - 1, 0)), hit
 
 
 def merge_match_traced(lkeys, rkeys, nl, nr):
@@ -331,10 +356,11 @@ class JoinHashMap:
     Two code assignments share the CSR layout:
 
     - **device probe** (single fixed-width key): codes are ranks in the
-      SORTED unique-key array; the probe looks keys up with a jitted
-      ``searchsorted`` on device — no per-row host work (reference analogue:
-      the prefetched group-of-8 probe of ``joins/join_hash_map.rs:44-284``,
-      re-designed as binary search per SURVEY.md §7.2 L2').
+      SORTED unique-key array; the probe ranks keys in it on device
+      (``sorted_probe_traced``: a merge by sort, since a binary search is a
+      row-sized gather a step on the TPU) — no per-row host work (reference
+      analogue: the prefetched group-of-8 probe of
+      ``joins/join_hash_map.rs:44-284``, SURVEY.md §7.2 L2').
     - **host interning** (multi-column / var-width keys): vectorized
       ``np.unique`` dedup + dict lookups on per-batch distincts.
     """
@@ -396,8 +422,7 @@ class JoinHashMap:
     @staticmethod
     def _build_sorted(kept, key_cols, schema) -> "JoinHashMap":
         """Single fixed-width key: codes are ranks in the sorted unique-key
-        array (canonical int64 words), enabling the device searchsorted
-        probe."""
+        array (canonical int64 words), enabling the device probe."""
         from blaze_tpu.utils.device import pull_columns
 
         words = []

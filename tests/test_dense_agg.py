@@ -26,6 +26,7 @@ from blaze_tpu.ops.agg_device import DevicePartialAgger
 from blaze_tpu.runtime.executor import build_operator
 from blaze_tpu.runtime.session import Session
 from blaze_tpu.utils.device import DEVICE_STATS
+from tests.util import jaxpr_eqns
 
 SCHEMA = pa.schema([("k1", pa.int64()), ("k2", pa.int64()), ("v", pa.int64())])
 
@@ -331,7 +332,7 @@ def test_form_follows_the_static_shapes(nseg, rows, masked):
         lambda seg, x: A._seg_reduce("add", seg, x, nseg))(
         jax.ShapeDtypeStruct((rows,), jnp.int32),
         jax.ShapeDtypeStruct((rows,), jnp.int64))
-    scatters = [e for e in _eqns(closed.jaxpr)
+    scatters = [e for e in jaxpr_eqns(closed.jaxpr)
                 if e.primitive.name.startswith("scatter")]
     assert bool(scatters) != masked
 
@@ -351,13 +352,6 @@ def _avals(key_dtypes, arg_dtypes, cap, bases=False):
     return [plane(bool)] + flat
 
 
-def _eqns(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
-
-
 NARROW = [k for k, (_s, d) in KINDS.items() if d != "wide3"]
 WIDE = [k for k, (_s, d) in KINDS.items() if d == "wide3"]
 CAPACITY = 131072  # a scan batch of the benchmark's cells
@@ -374,7 +368,7 @@ def test_dense_kernel_at_16_slots_has_no_row_sized_scatter(names):
                                      (16,), 128)
     avals = _avals(("int64",), adt, CAPACITY, bases=True)
     closed = jax.make_jaxpr(kernel)(*avals)
-    serial = [e for e in _eqns(closed.jaxpr)
+    serial = [e for e in jaxpr_eqns(closed.jaxpr)
               if e.primitive.name.startswith(("scatter", "gather", "sort"))]
     assert serial, "the emit step still compacts 16 slots by scatter"
     for eqn in serial:
@@ -386,7 +380,7 @@ def test_dense_kernel_at_16_slots_has_no_row_sized_scatter(names):
     closed = jax.make_jaxpr(big)(*avals)
     assert any(e.primitive.name.startswith("scatter")
                and max(v.aval.size for v in e.invars) >= CAPACITY
-               for e in _eqns(closed.jaxpr))
+               for e in jaxpr_eqns(closed.jaxpr))
 
 
 # sha256 of str(make_jaxpr(_partial_kernel(...))) at the parent commit

@@ -25,6 +25,17 @@ def mem_scan(data_or_batches, schema=None, num_batches=1):
     return MemoryScanExec(schema, partitions)
 
 
+def jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs (pjit, cond,
+    while bodies) included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from jaxpr_eqns(sub)
+
+
 def run_op(op: Operator, partition=0, ctx=None):
     ctx = ctx or ExecContext()
     return list(op.execute(partition, ctx))
