@@ -1,5 +1,6 @@
-"""Benchmark: the five BASELINE.json query shapes over a generated TPC-DS-like
-star schema (the reference's headline workloads, driver `BASELINE.json`):
+"""The five BASELINE.json query shapes over a generated TPC-DS-like star
+schema, as data, plans and two references (pandas, pyarrow Acero) for the
+tests, ``chip_smoke.py`` and ``scripts/profile_query.py``:
 
   q01  scan -> decimal filter -> two-stage hash agg over an exchange -> top-k
   q06  group-by agg + broadcast hash join (BHJ)
@@ -7,29 +8,15 @@ star schema (the reference's headline workloads, driver `BASELINE.json`):
   q47  sort + window rank within partition (SMJ/window class)
   q67  window rank over MANY tiny partitions (segmented-window class)
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", "shapes"}.
-``value`` is the total engine wall-clock across the five shapes;
-``vs_baseline`` is speedup vs pandas doing the identical queries on the same
-parquet files (the round-1/2 denominator, kept for cross-round
-comparability); ``vs_arrow`` is speedup vs pyarrow Acero (multithreaded C++
-joins/group-bys — the strongest engine available in this image, standing in
-for Blaze-CPU). Per-shape wall-clocks and ratios are under
-"shapes". Every shape's engine output is cross-checked against the pandas
-oracle before any number is reported.
-
-Runs on whatever ``jax.devices()`` gives and says so in the record
-(``platform`` / ``device_kind`` / ``device_count``); a CPU run is one the
-caller asked for with ``JAX_PLATFORMS=cpu``, never one the script chose.
+Nothing here is timed: the benchmark is ``benchmark/run.py``
+(``BENCHMARK.json``), which has its own generator and copies of the plans.
 
 Env knobs: BENCH_ROWS (default 1_000_000 fact rows), BENCH_PARTITIONS
 (default 4).
 """
 
-import json
 import os
 import sys
-import tempfile
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -44,7 +31,6 @@ from blaze_tpu.ir import types as T
 
 ROWS = int(os.environ.get("BENCH_ROWS", 1_000_000))
 PARTS = int(os.environ.get("BENCH_PARTITIONS", 4))
-ARROW_THREADS = int(os.environ.get("BENCH_ARROW_THREADS", 8))
 N_ITEMS = 2000
 N_STORES = 400
 N_CUSTOMERS = 100_000
@@ -419,8 +405,8 @@ def _check(name: str):
 
 
 SHAPES = [
-    # (name, plan, pandas oracle, acero baseline, check, tables the query
-    #  touches — the acero timing reads exactly these, as the engine does)
+    # (name, plan, pandas oracle, acero reference, check, tables the query
+    #  touches — the acero reference reads exactly these, as the engine does)
     ("q01", plan_q01, pandas_q01, acero_q01, _check("q01"), ("store_returns",)),
     ("q06", plan_q06, pandas_q06, acero_q06, _check("q06"),
      ("store_sales", "item")),
@@ -437,189 +423,6 @@ def load_tables(paths, names):
             for n in names}
 
 
-def roofline_model(name: str) -> dict:
-    """Rough per-shape traffic/arithmetic model (round-4 verdict item 9) so
-    an MFU / roofline estimate is computable from the bench record:
-    ``model_bytes`` is the column data the query must move through the
-    compute (decoded device-resident columns actually read by the plan, one
-    pass), ``model_flops`` counts per-row kernel work (compares, hashes,
-    gathers, scatter-adds). Both are analytic — derived from the generator
-    shapes above, not measured — and deliberately conservative; divide by
-    ``kernel_time_s`` for effective GB/s / GFLOP/s, or by the chip's peak
-    for MFU."""
-    r = ROWS
-    per_row = {
-        # q01: 2 int64-plane cols scanned (store_sk + return_amt; the plan
-        # prunes sr_customer_sk); 1 cmp + hash(5) + 2 scatter-adds
-        "q01": (2 * 8, 8),
-        # q06: 3 fact cols + dim probe; hash-join probe ~10 + 2-sum agg ~8
-        "q06": (3 * 8, 18),
-        # q17: 3 narrow cols + 3-limb wide decimal (24B); 2 probes + limb agg
-        "q17": (3 * 8 + 24, 32),
-        # q47: 2 pruned fact cols; probe + agg + rank over tiny agg output
-        "q47": (2 * 8, 20),
-        # q67: 3 fact cols; 2-key hash agg + segmented rank over the
-        # (item, store) groups
-        "q67": (3 * 8, 14),
-    }[name]
-    return {"model_bytes": per_row[0] * r, "model_flops": per_row[1] * r,
-            "flops_per_byte": round(per_row[1] / per_row[0], 3)}
-
-
-# --------------------------------------------------------------------------
-# runners
-# --------------------------------------------------------------------------
-
-
-def run_engine(paths, plan_fn=plan_q01):
-    from blaze_tpu.runtime.session import Session
-
-    # BLAZE_TPU_PROFILE_DIR=<dir>: record spans during the engine run and
-    # dump trace+metrics artifacts there (Perfetto-loadable; obs/dump.py)
-    profile_dir = os.environ.get("BLAZE_TPU_PROFILE_DIR", "")
-    conf = None
-    if profile_dir:
-        import dataclasses as _dc
-
-        from blaze_tpu.config import get_config
-
-        conf = _dc.replace(get_config(), trace_enable=True)
-    from blaze_tpu.runtime.metrics import tripwire_totals
-
-    t0 = time.perf_counter()
-    with Session(conf=conf) as sess:
-        out = sess.execute_to_table(plan_fn(paths))
-        trips = tripwire_totals(sess.metrics)
-        profile = sess.profile()
-        if profile_dir:
-            from blaze_tpu.obs import TRACER, dump_profile
-
-            dump_profile(sess, profile_dir, plan_fn.__name__)
-            TRACER.reset()
-    return time.perf_counter() - t0, out, trips, profile
-
-
 def load_dfs(paths):
     return {name: tbl.to_pandas()
             for name, tbl in load_tables(paths, paths).items()}
-
-
-def run_baseline(paths):
-    """pandas over the same parquet files, all four shapes (read included,
-    matching what the engine pays). The timed results double as the
-    correctness oracles — computed ONCE per bench run."""
-    t0 = time.perf_counter()
-    dfs = load_dfs(paths)
-    oracles = {name: fn(dfs) for name, _p, fn, _a, _c, _t in SHAPES}
-    return time.perf_counter() - t0, oracles
-
-
-def run_arrow_baseline(paths):
-    """pyarrow Acero on the same files. The thread pool is PINNED (default
-    8, env BENCH_ARROW_THREADS) — Acero wall-clock otherwise swings >3x
-    with the machine's core count, making vs_arrow incomparable across
-    boxes (round-4 verdict weak #2); the pinned count is recorded in the
-    bench output."""
-    pa.set_cpu_count(ARROW_THREADS)
-    pa.set_io_thread_count(ARROW_THREADS)
-    per_shape = {}
-    total = 0.0
-    for name, _p, _o, acero_fn, _c, tables_used in SHAPES:
-        t0 = time.perf_counter()
-        # read exactly the tables this shape's query touches (the engine's
-        # scan reads the same ones)
-        acero_fn(load_tables(paths, tables_used))
-        per_shape[name] = time.perf_counter() - t0
-        total += per_shape[name]
-    return total, per_shape
-
-
-def main():
-    import jax
-
-    from blaze_tpu.utils import native
-    from blaze_tpu.utils.device import DEVICE_STATS
-
-    devices = jax.devices()  # this process opens the backend, and keeps it
-    backend = devices[0].platform
-    on_accel = backend != "cpu"
-    native.ensure_built()
-    with tempfile.TemporaryDirectory(prefix="blaze_bench_") as tmpdir:
-        paths = make_data(tmpdir)
-        baseline_s, oracles = run_baseline(paths)
-        shapes = {}
-        total = 0.0
-        for name, plan_fn, _oracle_fn, _acero_fn, check_fn, _t in SHAPES:
-            run_engine(paths, plan_fn)  # warmup compiles the shape's kernels
-            DEVICE_STATS.reset()
-            engine_s, out, trips, profile = run_engine(paths, plan_fn)
-            dev = DEVICE_STATS.snapshot()
-            check_fn(out, oracles[name])  # correctness gate before numbers
-            rl = roofline_model(name)
-            if dev["kernel_time_s"]:
-                rl["effective_gbps"] = round(
-                    rl["model_bytes"] / dev["kernel_time_s"] / 1e9, 2)
-                rl["effective_gflops"] = round(
-                    rl["model_flops"] / dev["kernel_time_s"] / 1e9, 2)
-            # invariant tripwires next to the timing (metrics.TRIPWIRE_METRICS):
-            # a silently-degraded fast path shows up as a counter diff here,
-            # not a slowdown hunt (window_group_loops must stay 0;
-            # window-bearing shapes must report window_segments > 0)
-            dev = dict(dev, **trips)
-            shapes[name] = {"value": round(engine_s, 3), "unit": "s",
-                            "backend": backend,
-                            "kernel_stats": dev,
-                            "roofline": rl,
-                            # device residency share; 0.0 on a CPU run,
-                            # where there is no device residency
-                            "device_time_fraction": round(
-                                min(dev["kernel_time_s"] / engine_s, 1.0), 3)
-                            if engine_s and on_accel else 0.0}
-            if profile is not None:
-                # compact stats-plane view (full profile lives in the store,
-                # GET /debug/profiles/<fingerprint>): per-stage partition
-                # shape + skew, per-operator est-vs-actual + device share
-                shapes[name]["profile"] = {
-                    "fingerprint": profile["fingerprint"],
-                    "device_time_fraction": profile["device_time_fraction"],
-                    "stages": [{k: s.get(k) for k in (
-                        "stage", "kind", "partitions", "total_bytes",
-                        "total_rows", "partition_skew_ratio", "skew",
-                        "device_time_fraction")} for s in profile["stages"]],
-                    "operators": [{k: o.get(k) for k in (
-                        "op", "est_rows", "actual_rows",
-                        "device_time_fraction")}
-                        for o in profile["operators"]],
-                }
-                # why-is-it-slow plane: per-category exclusive wall split
-                # (sum <= wall by construction), the critical path, and the
-                # fusion/placement decision audit for THIS shape's query
-                for k in ("attribution", "critical_path", "decision_audit"):
-                    if profile.get(k):
-                        shapes[name][k] = profile[k]
-            total += engine_s
-        arrow_total, arrow_shapes = run_arrow_baseline(paths)
-        for name, _p, _o, _a, _c, _t in SHAPES:
-            shapes[name]["vs_arrow"] = round(
-                arrow_shapes[name] / shapes[name]["value"], 3)
-        record = {
-            "metric": f"tpcds_5shape_{ROWS}rows_total_wallclock",
-            "value": round(total, 3),
-            "unit": "s",
-            # vs pandas on the identical queries
-            "vs_baseline": round(baseline_s / total, 3),
-            "vs_arrow": round(arrow_total / total, 3),
-            "arrow_threads": ARROW_THREADS,
-            "platform": backend,
-            "device_kind": devices[0].device_kind,
-            "device_count": len(devices),
-            "shapes": shapes,
-        }
-        from blaze_tpu.obs.attribution import artifact_section
-
-        record.update(artifact_section())
-        print(json.dumps(record))
-
-
-if __name__ == "__main__":
-    main()

@@ -25,7 +25,6 @@ class Config:
     # Suggested in-memory bytes per batch (reference: suggested_batch_mem_size,
     # datafusion-ext-commons/src/lib.rs:74-118).
     suggested_batch_mem_size: int = 8 << 20
-    suggested_batch_mem_size_kway_merge: int = 1 << 20
 
     # Fraction of the process memory budget handed to the memory manager
     # (reference: MEMORY_FRACTION=0.6, MemManager::init(total * fraction)).
@@ -149,9 +148,6 @@ class Config:
     # chaos/serve policy, not a batch default).
     task_timeout_s: float = 0.0
 
-    # Device HBM budget for resident batch data (bytes). None = ask the device.
-    hbm_budget: Optional[int] = None
-
     # Compression codec for shuffle/spill streams: "zstd" | "lz4" | "none".
     # (reference: spark.auron.shuffle.compression.codec, default lz4; we default
     # to zstd level 1 since the python lz4 binding is absent and libzstd is fast)
@@ -240,12 +236,6 @@ class Config:
     # events (full trace or the flight-recorder ring); one attribute check
     # per query when off.
     attribution_enabled: bool = True
-    # regression-watch thresholds (scripts/regression_watch.py and
-    # bench_diff --attribution): a category regresses when its new exclusive
-    # time exceeds ratio x baseline AND the growth clears the noise floor.
-    attribution_regress_ratio: float = 2.0
-    attribution_regress_jit_ratio: float = 3.0
-    attribution_regress_min_ms: float = 50.0
 
     profile_store_dir: str = dataclasses.field(
         default_factory=lambda: os.environ.get(

@@ -50,18 +50,20 @@ def _telemetry():
 
 
 def _dispatch(fn, *args, **kw):
-    """Run one jitted kernel dispatch; its "kernel" span and
-    ``kernel_time_s`` time the ENQUEUE on the chip, not the execution — the
-    wait for the result is the ``sync:*`` span of ``utils/device.wait_int``
-    (benchmark: ``device_wait_s``).
+    """Run one jitted kernel dispatch. What is timed here is the call of the
+    jitted function: on an asynchronous backend that is the ENQUEUE (trace,
+    compile where the jit cache grows, and the hand-off to the launch
+    queue), not the kernel's run — the wait for the result is the ``sync:*``
+    span of ``utils/device.wait_int`` (benchmark: ``device_wait_s``).
 
-    The clock is utils/device.DEVICE_STATS (on an async backend it times
-    dispatch, on the CPU backend it approximates execution). With tracing
-    enabled each dispatch is a "kernel" span; one that grew the jit cache (a
-    fresh trace+compile) is labelled jit_compile instead — compile storms
-    show up as wide blocks in the Perfetto timeline. The registry always
-    gets the dispatch-time histogram and compile counters (kernel spans
-    would flood the flight-recorder ring, so those stay trace-gated)."""
+    Two readers take that time: with tracing enabled each dispatch is a
+    ``kernel:<fn>`` span (the benchmark's ``idle_gaps`` names gaps by it);
+    one that grew the jit cache (a fresh trace+compile) is labelled
+    jit_compile instead — compile storms show up as wide blocks in the
+    Perfetto timeline. The registry always gets
+    ``blaze_kernel_dispatch_seconds`` and the compile counters (kernel spans
+    would flood the flight-recorder ring, so those stay trace-gated).
+    ``DEVICE_STATS.kernel_calls`` counts the dispatch."""
     from blaze_tpu.obs.tracer import TRACER
     from blaze_tpu.utils.device import DEVICE_STATS
 
@@ -74,13 +76,10 @@ def _dispatch(fn, *args, **kw):
             cache0 = fn._cache_size()
         except Exception:
             cache0 = -1
-    DEVICE_STATS.kernel_begin()
+    DEVICE_STATS.add_kernel_call()
     t0 = time.perf_counter()
-    try:
-        out = fn(*args, **kw)
-    finally:
-        dt = time.perf_counter() - t0
-        DEVICE_STATS.kernel_end()
+    out = fn(*args, **kw)
+    dt = time.perf_counter() - t0
     if trace or track:
         compiled = False
         if cache0 >= 0:
@@ -104,13 +103,13 @@ def _dispatch(fn, *args, **kw):
 
 
 def fused_dispatch(fn, *args):
-    """Dispatch one fused-stage closure (its "kernel" span and
-    ``kernel_time_s`` time the ENQUEUE on the chip; the wait is ``sync:*`` /
-    ``device_wait_s``, see :func:`_dispatch`) and report whether it hit the jit
-    cache. Unlike :func:`_dispatch`, the cache-size sample is unconditional:
-    the fused-stage hit/miss counters are a fast-path tripwire (recompile
-    storms must be visible in every BENCH/SOAK artifact, not only under
-    tracing). Returns ``(out, compiled)``."""
+    """Dispatch one fused-stage closure (its ``kernel:fused_stage`` span and
+    its ``blaze_kernel_dispatch_seconds`` reading time the ENQUEUE on the
+    chip; the wait is ``sync:*`` / ``device_wait_s``, see :func:`_dispatch`)
+    and report whether it hit the jit cache. Unlike :func:`_dispatch`, the
+    cache-size sample is unconditional: the fused-stage hit/miss counters are
+    a fast-path tripwire (a recompile storm must be visible without tracing).
+    Returns ``(out, compiled)``."""
     from blaze_tpu.obs.tracer import TRACER
     from blaze_tpu.utils.device import DEVICE_STATS
 
@@ -119,13 +118,10 @@ def fused_dispatch(fn, *args):
         cache0 = fn._cache_size()
     except Exception:
         cache0 = -1
-    DEVICE_STATS.kernel_begin()
+    DEVICE_STATS.add_kernel_call()
     t0 = time.perf_counter()
-    try:
-        out = fn(*args)
-    finally:
-        dt = time.perf_counter() - t0
-        DEVICE_STATS.kernel_end()
+    out = fn(*args)
+    dt = time.perf_counter() - t0
     compiled = False
     if cache0 >= 0:
         try:

@@ -892,9 +892,8 @@ def _arrow_to_devcol(arr: pa.Array, dt: T.DataType, capacity: int) -> DeviceColu
 # Device scalars for literals, keyed by (value, dtype repr, default device).
 # Without this every evaluation of every literal re-staged a fresh host
 # scalar onto the device per batch: a host->device hop per constant per
-# batch (the "transfers outnumber kernels" finding in BENCH_r06). DevVals
-# are immutable so
-# sharing one array across expressions and batches is safe.
+# batch. DevVals are immutable so sharing one array across expressions and
+# batches is safe.
 _LITERAL_CACHE: dict = {}
 _LITERAL_CACHE_MAX = 4096
 

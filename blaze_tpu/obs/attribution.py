@@ -12,8 +12,7 @@ module closes that gap in three layers:
   instant of the query window to exactly ONE category — the most specific
   span active at that instant (a kernel dispatch inside a task inside an
   operator counts as kernel time, not three times). By construction
-  ``sum(categories) <= wall``: the same union-of-intervals argument the
-  PR 11 depth-guarded device timer makes for ``kernel_time_s <= wall``.
+  ``sum(categories) <= wall`` (a union of intervals).
   Like DEVICE_STATS deltas, the per-query binding is by time window —
   exact for a query running alone (bench/tests), an upper bound under
   concurrency. Worker spans participate because they were already absorbed
@@ -312,30 +311,6 @@ def note_queue_wait(seconds: float) -> None:
     the process totals directly (and emits the queue span for traces)."""
     if seconds > 0:
         _ATTR_SECONDS.labels(category="queue_wait").inc(float(seconds))
-
-
-def artifact_section() -> dict:
-    """The observability block every BENCH/SOAK/SERVE/CHAOS/MULTICHIP
-    artifact embeds: process-lifetime category exclusive-seconds totals,
-    the fusion/placement decision audit, and the tracer drop counter."""
-    return {
-        "attribution_totals": category_totals(),
-        "decision_audit": decision_audit(),
-        "tracer_events_dropped": get_registry().counter(
-            "blaze_obs_tracer_events_dropped_total").total(),
-    }
-
-
-def category_totals() -> Dict[str, float]:
-    """Process-lifetime exclusive seconds per category (the soak/serve
-    artifact section; zero-filled so the schema is stable)."""
-    out = {c: 0.0 for c in CATEGORIES}
-    for key, v in _ATTR_SECONDS.series().items():
-        labels = dict(key)
-        c = labels.get("category")
-        if c in out:
-            out[c] = round(float(v), 6)
-    return out
 
 
 # -- decision audit ------------------------------------------------------------

@@ -1,9 +1,7 @@
 """Profile artifact dumping: trace JSON + metrics snapshot (+ explain text)
 and incident forensic bundles (the flight-recorder dump path).
 
-One helper shared by ``scripts/profile_query.py``, ``scripts/scale_soak.py``
-and ``bench.py`` (env-gated there) so every entry point writes the same
-artifact layout:
+The helper ``scripts/profile_query.py`` writes its artifacts with:
 
 - ``<tag>_trace.json``    — Chrome trace events; load in https://ui.perfetto.dev
 - ``<tag>_metrics.json``  — the session metric tree with humanized durations

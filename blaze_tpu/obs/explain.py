@@ -223,11 +223,9 @@ def render_explain_analyze(query: dict, session_metrics: MetricNode) -> str:
         # the AQE signal: ordered estimate-vs-observed cardinalities
         lines.append("-- Cardinality (estimated vs actual) --")
         for o in paired:
-            frac = o.get("device_time_fraction", 0.0)
             lines.append(
                 f"   {o['op']}: est={o['est_rows']}"
-                f" actual={o['actual_rows']}"
-                f" device_frac={frac:.2f}")
+                f" actual={o['actual_rows']}")
     attr = stats.get("attribution")
     if attr:
         from blaze_tpu.obs.attribution import CATEGORIES

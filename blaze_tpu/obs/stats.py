@@ -15,9 +15,8 @@ and a completed query folds into one compact ``QueryProfile`` dict
 - per-operator estimated-vs-actual cardinalities (estimates from
   ``ir/estimates.py`` on the logical plan, actuals from executor
   ``output_rows``),
-- per-operator and per-stage ``device_time_fraction`` (the depth-guarded
-  union timer in utils/device.py attributes each thread-outermost kernel
-  span to the operator on the self-time stack),
+- per-operator and per-stage self time (``compute_time_ns``: the host
+  clock of ``elapsed_compute_time_ns``, the wait for the device included),
 - residency (device/mapped/host byte deltas + the zero-copy tripwires) and
   spill/recovery events.
 
@@ -48,25 +47,22 @@ from typing import Dict, List, Optional
 # -- field-name schema ---------------------------------------------------------
 # Every key a QueryProfile may contain, by section. scripts/
 # check_metrics_names.py lints these against the snake_case convention so
-# artifact keys stay greppable across BENCH/SOAK/SERVE rounds.
+# profile keys stay greppable.
 
 PROFILE_FIELDS = (
     "fingerprint", "query_id", "label", "state", "unix_time", "wall_s",
-    "rows", "nparts", "device_time_fraction", "operators", "stages",
+    "rows", "nparts", "operators", "stages",
     "residency", "spills", "recovery", "truncated",
-    "attribution", "critical_path", "decision_audit", "attribution_baseline",
-    "cache",
+    "attribution", "critical_path", "decision_audit", "cache",
 )
 STAGE_FIELDS = (
     "stage", "kind", "num_tasks", "partitions", "partition_bytes",
     "partition_rows", "total_bytes", "total_rows", "max_partition_bytes",
     "median_partition_bytes", "partition_skew_ratio", "truncated", "skew",
-    "device_time_ns", "compute_time_ns", "device_time_fraction",
-    "recovered_tasks",
+    "compute_time_ns", "recovered_tasks",
 )
 OPERATOR_FIELDS = (
-    "op", "est_rows", "actual_rows", "compute_time_ns", "device_time_ns",
-    "device_time_fraction",
+    "op", "est_rows", "actual_rows", "compute_time_ns",
 )
 SKEW_FIELDS = (
     "buckets", "min_bucket_rows", "p50_bucket_rows", "max_bucket_rows",
@@ -94,7 +90,6 @@ AUDIT_FIELDS = (
     "ops_fused", "ops_eligible", "fused_op_fraction", "fusion_break_reasons",
     "placement_decisions", "placement_decline_reasons",
 )
-BASELINE_FIELDS = _CATEGORY_FIELDS + ("wall_ns", "samples")
 
 # result/subplan cache plane (blaze_tpu/cache/): the ``cache`` profile
 # section (subplan hits noted during execution) plus the cache_* tripwire
@@ -109,8 +104,7 @@ CACHE_FIELDS = (
 ALL_PROFILE_FIELDS = (PROFILE_FIELDS + STAGE_FIELDS + OPERATOR_FIELDS +
                       SKEW_FIELDS + RESIDENCY_FIELDS + SPILL_FIELDS +
                       RECOVERY_FIELDS + ATTRIBUTION_FIELDS +
-                      CRITICAL_PATH_FIELDS + AUDIT_FIELDS + BASELINE_FIELDS +
-                      CACHE_FIELDS)
+                      CRITICAL_PATH_FIELDS + AUDIT_FIELDS + CACHE_FIELDS)
 
 _SAFE_ID = re.compile(r"[^A-Za-z0-9_.-]+")
 
@@ -121,7 +115,6 @@ MAX_OPERATORS_RECORDED = 128
 MAX_RECOVERY_EVENTS = 64
 
 SELF_TIME_METRIC = "elapsed_compute_time_ns"
-DEVICE_TIME_METRIC = "device_time_ns"
 
 
 # -- plan fingerprint ----------------------------------------------------------
@@ -423,10 +416,6 @@ class StatsPlane:
 
     # -- finalize -------------------------------------------------------------
 
-    @staticmethod
-    def _fraction(dev: int, comp: int) -> float:
-        return round(min(dev / comp, 1.0), 4) if comp > 0 else 0.0
-
     def finalize_into(self, query: dict, session_metrics, state: str):
         """Build the QueryProfile and attach it as ``query["stats"]``.
         Called by ``finish_query`` before the record enters the query log;
@@ -472,9 +461,6 @@ class StatsPlane:
                            "num_tasks": query.get("nparts") or 0,
                            "partitions": query.get("nparts") or 0,
                            "truncated": False, "skew": result_skew})
-
-        total_dev = sum(o["device_time_ns"] for o in operators)
-        total_comp = sum(o["compute_time_ns"] for o in operators)
 
         def tree_total(metric: str) -> int:
             return sum(t.total(metric) for _, t in trees if t is not None)
@@ -542,7 +528,6 @@ class StatsPlane:
             "wall_s": round(float(query.get("wall_s") or 0.0), 6),
             "rows": query.get("rows"),
             "nparts": query.get("nparts"),
-            "device_time_fraction": self._fraction(total_dev, total_comp),
             "operators": operators,
             "stages": stages,
             "residency": residency,
@@ -566,16 +551,12 @@ class StatsPlane:
             name, children = shape
             if not name.startswith("+ "):  # fused pseudo-children: no metrics
                 vals = dict(node.values) if node is not None else {}
-                comp = int(vals.get(SELF_TIME_METRIC, 0))
-                dev = int(vals.get(DEVICE_TIME_METRIC, 0))
                 q = est_queue.get(normalize_op_name(name))
                 operators.append({
                     "op": name,
                     "est_rows": q.popleft() if q else None,
                     "actual_rows": int(vals.get("output_rows", 0)),
-                    "compute_time_ns": comp,
-                    "device_time_ns": dev,
-                    "device_time_fraction": self._fraction(dev, comp),
+                    "compute_time_ns": int(vals.get(SELF_TIME_METRIC, 0)),
                 })
             for i, c in enumerate(children):
                 cn = None
@@ -612,11 +593,7 @@ class StatsPlane:
                     rec["partition_rows"] = rows
                     rec["total_rows"] = sum(
                         node.total(f"part_rows_{r}") for r in range(nparts))
-                dev = node.total(DEVICE_TIME_METRIC)
-                comp = node.total(SELF_TIME_METRIC)
-                rec["device_time_ns"] = dev
-                rec["compute_time_ns"] = comp
-                rec["device_time_fraction"] = self._fraction(dev, comp)
+                rec["compute_time_ns"] = node.total(SELF_TIME_METRIC)
             if sid in recovered:
                 rec["recovered_tasks"] = recovered[sid]
             out.append(rec)
@@ -644,8 +621,6 @@ def stage_summary_line(stage_rec: dict) -> str:
         parts.append(
             f"radix[p50={skew['p50_bucket_rows']} max={skew['max_bucket_rows']}"
             f" hot={skew['hot_bucket_ids']}]")
-    if stage_rec.get("device_time_fraction"):
-        parts.append(f"device={stage_rec['device_time_fraction']}")
     if stage_rec.get("recovered_tasks"):
         parts.append(f"recovered={stage_rec['recovered_tasks']}")
     return " ".join(parts)
@@ -662,43 +637,11 @@ def _conf(conf):
     return get_config()
 
 
-_BASELINE_WINDOW = 8  # capped-window running mean
-
-
-def _merge_baseline(profile: dict, path: str) -> dict:
-    """Fold this run's attribution into the previously stored per-category
-    baseline (capped-window running mean over the fingerprint's recent
-    runs) — the history ``scripts/regression_watch.py`` compares a single
-    run against. Stored profiles without attribution pass through."""
-    attr = profile.get("attribution") or {}
-    if not attr:
-        return profile
-    try:
-        with open(path) as f:
-            prev = json.load(f).get("attribution_baseline") or {}
-    except (OSError, ValueError):
-        prev = {}
-    from blaze_tpu.obs.attribution import CATEGORY_FIELDS
-
-    n = int(prev.get("samples") or 0)
-    weight = min(n + 1, _BASELINE_WINDOW)
-    base = {"samples": n + 1}
-    for k in CATEGORY_FIELDS + ("wall_ns",):
-        x = float(attr.get(k) or 0.0)
-        old = float(prev.get(k) or 0.0) if n else x
-        base[k] = int(old + (x - old) / weight)
-    profile = dict(profile)
-    profile["attribution_baseline"] = base
-    return profile
-
-
 def save_profile(profile: dict, conf=None) -> Optional[str]:
     """Persist one QueryProfile under ``<fingerprint>.json`` (the latest
     run of a plan shape overwrites: the store answers "last observed stats
     for this fingerprint"). Atomic write, mtime-GC'd to
-    ``conf.profile_store_max``; never raises. Profiles carrying an
-    ``attribution`` section also fold into the fingerprint's rolling
-    per-category baseline (the regression-watch history)."""
+    ``conf.profile_store_max``; never raises."""
     try:
         conf = _conf(conf)
         out_dir = getattr(conf, "profile_store_dir", "") or ""
@@ -710,7 +653,6 @@ def save_profile(profile: dict, conf=None) -> Optional[str]:
             return None
         os.makedirs(out_dir, exist_ok=True)
         path = os.path.join(out_dir, fp + ".json")
-        profile = _merge_baseline(profile, path)
         tmp = f"{path}.tmp{os.getpid()}"
         with open(tmp, "w") as f:
             json.dump(profile, f, default=str)
@@ -762,8 +704,7 @@ def list_profiles(conf=None) -> List[dict]:
                         "wall_s": p.get("wall_s"),
                         "rows": p.get("rows"),
                         "unix_time": p.get("unix_time"),
-                        "stages": len(p.get("stages") or []),
-                        "device_time_fraction": p.get("device_time_fraction")})
+                        "stages": len(p.get("stages") or [])})
         except (OSError, ValueError):
             continue
     return out

@@ -261,26 +261,13 @@ class Histogram(_Instrument):
 
     def snapshot_delta(self, prev: Optional[dict], **kw) -> Optional[dict]:
         """Interval view since ``prev`` (a previous :meth:`snapshot` of the
-        SAME label set): bucket-vector subtraction for windowed quantiles.
-        The current snapshot is taken under the instrument lock, so a
-        concurrent ``observe()`` either lands fully in it or not at all —
-        buckets only grow, which makes every delta non-negative. A shrunk
-        count (``reset_values`` between samples) returns the current
-        snapshot whole instead of a negative delta."""
+        SAME label set): :func:`delta_snapshot` of a snapshot taken now. A
+        caller that CHAINS intervals keeps the snapshot it took and calls
+        :func:`delta_snapshot` itself: a second ``snapshot()`` for the next
+        ``prev`` would be a second read, and what lands between the two is
+        counted twice."""
         cur = self.snapshot(**kw)
-        if cur is None:
-            return None
-        if not prev or cur["count"] < prev["count"]:
-            return cur
-        buckets = {}
-        prev_buckets = prev["buckets"]
-        for i, c in cur["buckets"].items():
-            d = c - prev_buckets.get(i, 0)
-            if d > 0:
-                buckets[i] = d
-        return {"buckets": buckets,
-                "sum": cur["sum"] - prev["sum"],
-                "count": cur["count"] - prev["count"]}
+        return None if cur is None else delta_snapshot(cur, prev)
 
     def quantile(self, q: float, **kw) -> Optional[float]:
         st = self.snapshot(**kw)
@@ -294,6 +281,27 @@ class Histogram(_Instrument):
             run += c
             cum.append((le, run))
         return quantile_from_le_buckets(cum, q)
+
+
+def delta_snapshot(cur: dict, prev: Optional[dict]) -> dict:
+    """``cur`` less ``prev``, two :meth:`Histogram.snapshot` dicts of one
+    series: bucket-vector subtraction for windowed quantiles. A snapshot is
+    taken under the instrument lock, so a concurrent ``observe()`` either
+    lands fully in it or not at all — its buckets sum to its count, and
+    buckets only grow, which makes every delta non-negative. A shrunk count
+    (``reset_values`` between samples) returns ``cur`` whole instead of a
+    negative delta."""
+    if not prev or cur["count"] < prev["count"]:
+        return cur
+    buckets = {}
+    prev_buckets = prev["buckets"]
+    for i, c in cur["buckets"].items():
+        d = c - prev_buckets.get(i, 0)
+        if d > 0:
+            buckets[i] = d
+    return {"buckets": buckets,
+            "sum": cur["sum"] - prev["sum"],
+            "count": cur["count"] - prev["count"]}
 
 
 def quantile_from_snapshot(snap: Optional[dict],
@@ -559,7 +567,7 @@ def _fmt_val(v) -> str:
     return f"{f:.9g}"
 
 
-# -- scrape-side helpers (soak scripts, tests) --------------------------------
+# -- scrape-side helper (tests) -----------------------------------------------
 
 
 _SAMPLE_RE = re.compile(
@@ -596,22 +604,6 @@ def parse_prometheus_text(text: str) -> Dict[str, dict]:
         out.setdefault(name, {"type": None, "samples": []})
         out[name]["samples"].append((labels, value))
     return out
-
-
-def histogram_quantiles_from_text(parsed: Dict[str, dict], name: str,
-                                  match_labels: Dict[str, str],
-                                  qs: List[float]) -> Dict[float, Optional[float]]:
-    """Quantile estimates for one scraped histogram series: collects the
-    ``<name>_bucket`` samples whose labels include ``match_labels``."""
-    pairs = []
-    for labels, value in parsed.get(name + "_bucket", {}).get("samples", []):
-        if any(labels.get(k) != v for k, v in match_labels.items()):
-            continue
-        le = labels.get("le")
-        if le is None:
-            continue
-        pairs.append((math.inf if le == "+Inf" else float(le), int(value)))
-    return {q: quantile_from_le_buckets(pairs, q) for q in qs}
 
 
 # -- process-global registry ---------------------------------------------------

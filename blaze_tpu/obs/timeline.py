@@ -10,7 +10,7 @@ continuously:
   ``timeline_interval_s`` into fixed-size ring buffers. Counters become
   windowed per-second rates (``<name>:rate``), gauges become samples
   (``<name>``), histograms become interval p50/p95/p99 via bucket-snapshot
-  deltas (``<name>:p99`` — ``Histogram.snapshot_delta``). On top of the
+  deltas (``<name>:p99`` — ``telemetry.delta_snapshot``). On top of the
   generic pass, derived serve/cache/ingest series: ingest lag in versions
   (appended version minus the newest version any fresh cache entry
   covers), refresh backlog, admission queue depth, per-tenant
@@ -27,10 +27,8 @@ continuously:
   history, closes the previous state's interval, and writes exactly one
   incident bundle through ``obs/dump.record_incident`` (kind ``health``).
   Served live at ``GET /debug/health`` and
-  ``GET /debug/timeseries?name=&since=`` (runtime/http.py), embedded in
-  soak artifacts via :func:`timeline_artifact_section` so gates can judge
-  health *history* (no critical interval, bounded degraded time), not just
-  end state.
+  ``GET /debug/timeseries?name=&since=`` (runtime/http.py): the health
+  *history* (critical intervals, degraded time), not just the end state.
 
 The sampler binds to the newest driver :class:`Session` (weakly) and
 stops when that session closes — no thread outlives its session. When
@@ -47,7 +45,8 @@ import time
 import weakref
 from typing import Dict, List, Optional, Tuple
 
-from blaze_tpu.obs.telemetry import (Counter, Gauge, Histogram, get_registry,
+from blaze_tpu.obs.telemetry import (Counter, Gauge, Histogram,
+                                     delta_snapshot, get_registry,
                                      quantile_from_snapshot)
 
 _reg = get_registry()
@@ -97,15 +96,10 @@ DERIVED_SERIES = (
 COUNTER_TRACK_SERIES = ("serve_inflight_count", "ingest_lag_versions",
                         "memmgr_used_bytes")
 
-# top-level keys of health_report() — the artifact "health" section schema
+# top-level keys of health_report() — what GET /debug/health serves
 HEALTH_FIELDS = ("enabled", "interval_s", "wall_s", "samples", "subsystems",
                  "slo", "transitions", "intervals", "degraded_s",
                  "critical_s", "critical_intervals", "degraded_ratio")
-
-# series embedded whole in soak artifacts (the gate-relevant curves)
-ARTIFACT_SERIES = ("ingest_lag_versions", "cache_stale_served_rate",
-                   "serve_inflight_count", "serve_queue_depth_count",
-                   "memmgr_used_bytes")
 
 
 class Ring:
@@ -396,7 +390,7 @@ class Timeline:
                     continue
                 prev = self._prev_hists.get(name)
                 self._prev_hists[name] = merged
-                delta = _delta_snapshot(merged, prev)
+                delta = delta_snapshot(merged, prev)
                 if delta["count"] > 0:
                     for q, suffix in ((0.50, ":p50"), (0.95, ":p95"),
                                       (0.99, ":p99")):
@@ -656,18 +650,6 @@ def _merged_snapshot(inst: Histogram) -> Optional[dict]:
     return merged
 
 
-def _delta_snapshot(cur: dict, prev: Optional[dict]) -> dict:
-    if not prev or cur["count"] < prev["count"]:
-        return cur
-    buckets = {}
-    for i, c in cur["buckets"].items():
-        d = c - prev["buckets"].get(i, 0)
-        if d > 0:
-            buckets[i] = d
-    return {"buckets": buckets, "sum": cur["sum"] - prev["sum"],
-            "count": cur["count"] - prev["count"]}
-
-
 TIMELINE = Timeline()
 
 
@@ -696,11 +678,3 @@ def configure_from(conf, session=None) -> Timeline:
     elif session is not None:
         TIMELINE.start(session)
     return TIMELINE
-
-
-def timeline_artifact_section(series=ARTIFACT_SERIES) -> dict:
-    """The ``health`` + ``timeline`` sections soak artifacts embed (and
-    bench_diff --health compares)."""
-    tl = get_timeline()
-    return {"health": tl.health_report(),
-            "timeline": {n: tl.series_since(n, 0.0) or [] for n in series}}

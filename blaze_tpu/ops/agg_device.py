@@ -863,14 +863,13 @@ class DevicePartialAgger:
                 jb = spec.materialize(jb, spec.metrics)
                 if jb is None or jb.num_rows == 0:
                     return None
-            with DEVICE_STATS.kernel_span():
-                exists = jb.row_exists_mask()
-                if self.fused_predicates:
-                    exists = ExprEvaluator(
-                        list(self.fused_predicates),
-                        self.child_schema).evaluate_predicate(jb)
-                outs = self._flow(jb, exists)
-                num_groups = self._sort_groups(outs)
+            exists = jb.row_exists_mask()
+            if self.fused_predicates:
+                exists = ExprEvaluator(
+                    list(self.fused_predicates),
+                    self.child_schema).evaluate_predicate(jb)
+            outs = self._flow(jb, exists)
+            num_groups = self._sort_groups(outs)
             if num_groups == 0:
                 return None
             return self._assemble(outs, num_groups)
@@ -884,31 +883,29 @@ class DevicePartialAgger:
                                   batch):
                 if sb.num_rows == 0:
                     continue
-                with DEVICE_STATS.kernel_span():
-                    exists = sb.row_exists_mask()
-                    if self.fused_predicates:
-                        exists = exists & ExprEvaluator(
-                            list(self.fused_predicates),
-                            self.child_schema).evaluate_predicate(sb)
-                    outs = self._flow(sb, exists)
-                    num_groups = self._sort_groups(outs)
+                exists = sb.row_exists_mask()
+                if self.fused_predicates:
+                    exists = exists & ExprEvaluator(
+                        list(self.fused_predicates),
+                        self.child_schema).evaluate_predicate(sb)
+                outs = self._flow(sb, exists)
+                num_groups = self._sort_groups(outs)
                 if num_groups:
                     parts.append(self._assemble(outs, num_groups))
             if not parts:
                 return None
             return parts[0] if len(parts) == 1 else \
                 ColumnarBatch.concat(parts, self.op.schema)
-        with DEVICE_STATS.kernel_span():
-            dense = self._try_dense(batch)
-            if dense is not None:
-                outs, num_groups = dense
+        dense = self._try_dense(batch)
+        if dense is not None:
+            outs, num_groups = dense
+        else:
+            if self._needs_trace():
+                outs = self._fused_fn(batch)(jnp.int64(n),
+                                             *self._jit_flat(batch))
             else:
-                if self._needs_trace():
-                    outs = self._fused_fn(batch)(jnp.int64(n),
-                                                 *self._jit_flat(batch))
-                else:
-                    outs = self._flow(batch, batch.row_exists_mask())
-                num_groups = self._sort_groups(outs)
+                outs = self._flow(batch, batch.row_exists_mask())
+            num_groups = self._sort_groups(outs)
         if num_groups == 0:
             return None
         return self._assemble(outs, num_groups)
@@ -927,31 +924,30 @@ class DevicePartialAgger:
         n = batch.num_rows
         if n == 0:
             return None
-        with DEVICE_STATS.kernel_span():
-            exists = batch.row_exists_mask()
-            self.group_ev._reset_cse(batch)
-            for ev in self.agg_evs:
-                if ev is not None:
-                    ev._reset_cse(batch)
-            key_data, key_valid = [], []
-            for _, e in self.op.groupings:
-                d, val = _broadcast(
-                    self.group_ev._to_dev(self.group_ev._eval(e, batch),
-                                          batch),
-                    batch)
-                key_data.append(d)
-                key_valid.append(val & exists)
-            args = self._eval_args(batch, exists)
-            kernel = _passthrough_kernel(
-                tuple(str(d.dtype) for d in key_data), tuple(self.specs),
-                tuple("wide3" if isinstance(a[0], tuple) else str(a[0].dtype)
-                      for a in args), batch.capacity)
-            flat = []
-            for d, v in zip(key_data, key_valid):
-                flat += [d, v]
-            for d, v in args:
-                flat += ([*d, v] if isinstance(d, tuple) else [d, v])
-            outs = kernel(exists, *flat)
+        exists = batch.row_exists_mask()
+        self.group_ev._reset_cse(batch)
+        for ev in self.agg_evs:
+            if ev is not None:
+                ev._reset_cse(batch)
+        key_data, key_valid = [], []
+        for _, e in self.op.groupings:
+            d, val = _broadcast(
+                self.group_ev._to_dev(self.group_ev._eval(e, batch),
+                                      batch),
+                batch)
+            key_data.append(d)
+            key_valid.append(val & exists)
+        args = self._eval_args(batch, exists)
+        kernel = _passthrough_kernel(
+            tuple(str(d.dtype) for d in key_data), tuple(self.specs),
+            tuple("wide3" if isinstance(a[0], tuple) else str(a[0].dtype)
+                  for a in args), batch.capacity)
+        flat = []
+        for d, v in zip(key_data, key_valid):
+            flat += [d, v]
+        for d, v in args:
+            flat += ([*d, v] if isinstance(d, tuple) else [d, v])
+        outs = kernel(exists, *flat)
         # rows stay in place (exists is a prefix mask), so the group count
         # is the batch's row count — no device sync at all
         return self._assemble(outs, n)

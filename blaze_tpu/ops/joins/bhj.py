@@ -244,7 +244,7 @@ class _HashJoinBase(Operator):
             return NotImplemented
         import jax.numpy as jnp
 
-        from blaze_tpu.utils.device import DEVICE_STATS, wait_int
+        from blaze_tpu.utils.device import wait_int
 
         if bmap._dev_cell[0] is None:
             bmap._dev_cell[0] = jnp.asarray(
@@ -256,10 +256,9 @@ class _HashJoinBase(Operator):
             flat += [c.data, c.validity]
         for c in bb.columns:
             flat += [c.data, c.validity]
-        with DEVICE_STATS.kernel_span():
-            outs = kernel(bmap._dev_cell[0], jnp.int64(batch.num_rows),
-                          cols[0].data, cols[0].validity, *flat)
-            count = wait_int(outs[0], "bhj_probe")  # sync point
+        outs = kernel(bmap._dev_cell[0], jnp.int64(batch.num_rows),
+                      cols[0].data, cols[0].validity, *flat)
+        count = wait_int(outs[0], "bhj_probe")  # sync point
         metrics.add("device_inner_batches", 1)
         # The probe itself ran on device inside the fused kernel; count it
         # under device_probe_batches too so the metric stays meaningful for

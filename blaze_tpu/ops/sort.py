@@ -242,7 +242,7 @@ class _SortState(MemConsumer):
         The vectorized chunk merge over squeezed (n, 2k) i64 key matrices is
         THE merge path for device-sortable keys (numpy lexsort over
         safe-to-emit prefixes; the per-row heap walk it replaced was ~1000x
-        slower at 10M-row volume, SOAK_r05). Only var-width (host-compared)
+        slower at 10M-row volume on the CPU). Only var-width (host-compared)
         keys fall back to the row heap."""
         if self.device:
             yield from self._merge_runs_vectorized(batch_size)

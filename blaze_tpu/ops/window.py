@@ -168,7 +168,7 @@ class WindowExec(Operator):
                 return
             # tripwire: the segmented path never takes this per-group loop —
             # a nonzero count on a default-frame plan means a fast-path
-            # regression (scale_soak records it next to window_segments)
+            # regression
             metrics.add("window_group_loops", 1)
             part = ColumnarBatch.concat(pending.drain(), child_schema)
             out = self._process_one_partition(part)

@@ -497,15 +497,12 @@ class MeshBatchExchange:
                           for s, p in enumerate(ps)]
                 gplanes.append(jax.make_array_from_single_device_arrays(
                     (n * seg_len,), sharding, shards))
-            # the collective is device work: the union-interval kernel clock
-            # must see it or mesh-run stages report device_time_fraction ~0
             import time as _time
 
             from blaze_tpu.obs.tracer import TRACER
-            from blaze_tpu.utils.device import DEVICE_STATS
 
             t0_ns = _time.perf_counter_ns() if TRACER.active else 0
-            with DEVICE_STATS.kernel_span(), self.mesh:
+            with self.mesh:
                 outs = _exchange_compact_step(self.mesh, self.axis,
                                               len(gplanes), chunk, *gplanes)
             if t0_ns:

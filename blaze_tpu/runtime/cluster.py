@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import os
 import queue
-import random
 import socket
 import subprocess
 import sys
@@ -54,9 +53,6 @@ _TM_WORKER_DEATHS = get_registry().counter(
 _TM_TASKS_RETRIED = get_registry().counter(
     "blaze_cluster_tasks_retried_total",
     "pool tasks re-queued after a failure or worker loss")
-_TM_CHAOS_KILLS = get_registry().counter(
-    "blaze_chaos_kills_total",
-    "worker processes hard-killed by chaos injection")
 _TM_TASKS_TIMED_OUT = get_registry().counter(
     "blaze_cluster_tasks_timed_out_total",
     "in-flight task attempts hard-cancelled after exceeding task_timeout_s")
@@ -685,41 +681,3 @@ class WorkerPool:
             os.rmdir(self._sockdir)
         except OSError:
             pass
-
-
-class ChaosMonkey:
-    """Kills a random live worker every ``kill_every_s`` seconds — the soak
-    scripts' ``--chaos-kill-every`` flag. Deterministic given the seed (the
-    victim sequence, not the interleaving)."""
-
-    def __init__(self, pool: WorkerPool, kill_every_s: float, seed: int = 0):
-        self.pool = pool
-        self.kill_every_s = float(kill_every_s)
-        self._rng = random.Random(seed)
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-        self.kills: List[dict] = []
-
-    def start(self):
-        self._thread = threading.Thread(target=self._loop, daemon=True,
-                                        name="chaos-monkey")
-        self._thread.start()
-        return self
-
-    def _loop(self):
-        while not self._stop.wait(self.kill_every_s):
-            live = [w.wid for w in self.pool.workers
-                    if w.proc is not None and w.proc.poll() is None]
-            if not live:
-                continue
-            wid = self._rng.choice(live)
-            pid = self.pool.kill_worker(wid)
-            _TM_CHAOS_KILLS.inc()
-            self.kills.append({"wid": wid, "pid": pid,
-                               "at_monotonic": time.monotonic()})
-            log.warning("chaos: killed worker %d (pid %s)", wid, pid)
-
-    def stop(self):
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=5)

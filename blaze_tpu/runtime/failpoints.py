@@ -7,7 +7,7 @@ failure mode a handle: a ``failpoint("site", payload)`` call compiled into
 the hot path is a single dict lookup when nothing is armed, and an armed
 site fires a configured *action* on a deterministic seeded *trigger* —
 exactly reproducible run to run, which is what makes chaos results
-diffable (scripts/bench_diff.py --chaos). Probability triggers draw from
+diffable. Probability triggers draw from
 a stream keyed by (seed, site, worker slot): slot salting keeps symmetric
 workers — which otherwise draw identical streams — from firing in
 lockstep, without giving up determinism.

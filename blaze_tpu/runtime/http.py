@@ -19,8 +19,8 @@ bound to a free port exposes:
 - ``/debug/memory``            — process RSS + memory-manager accounting
   (spill count/bytes/time and per-consumer usage)
 - ``/debug/config``            — the active engine config
-- ``/debug/device``            — device residency: transfer bytes/calls +
-  jitted-kernel dispatch counts/time (utils/device.DEVICE_STATS)
+- ``/debug/device``            — device residency: transfer bytes/calls,
+  syncs and jitted-kernel dispatch counts (utils/device.DEVICE_STATS)
 - ``/debug/trace``             — Chrome-trace-event JSON of recorded spans
   (query/stage/task/operator/spill/shuffle-fetch/kernel); load the payload
   in Perfetto or chrome://tracing. Requires ``Config.trace_enable`` (or

@@ -116,8 +116,8 @@ class MetricNode:
 # Invariant "tripwire" counters: cheap global counts whose expected
 # relationship flags a silently-degraded fast path — a plan can produce
 # correct results at 10x the cost and no test notices, but a diffed counter
-# does. bench/scale_soak record these next to timings so a regression shows
-# up as a number, not a slowdown hunt. Current invariants:
+# does. chip_smoke.py and the benchmark's ``counters_must`` read these, so a
+# regression shows up as a number, not a slowdown hunt. Current invariants:
 #   split_gathers == split_batches   range split gathers ONCE per batch
 #   window_group_loops == 0          segmentable windows (counters +
 #                                    default-frame aggs) never take the
@@ -208,7 +208,7 @@ TRIPWIRE_METRICS = (
 
 def tripwire_totals(node: "MetricNode") -> Dict[str, int]:
     """Totals of the tripwire counters for a metric tree (session root or a
-    single query) — the shape bench/SOAK records embed."""
+    single query)."""
     return node.totals(TRIPWIRE_METRICS)
 
 

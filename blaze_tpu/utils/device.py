@@ -26,30 +26,12 @@ from blaze_tpu.obs.tracer import TRACER
 
 
 class DeviceStats:
-    """Process-wide device counters; ``kernel_time_s`` (and the operators'
-    ``device_time_ns``) time the ENQUEUE of each dispatch on the chip, not
-    its execution — the wait for the device is the ``sync:*`` spans of
-    :func:`wait_int` (the benchmark's ``device_wait_s``), counted here as
-    ``sync_calls``.
-
-    Device-residency accounting (round-1 verdict item 9: the
-    TPU-first analogue of the reference's pervasive ``elapsed_compute``
-    discipline, execution_context.rs:705-730). Tracks device<->host transfer
-    bytes/calls, blocking syncs and jitted-kernel dispatches; surfaced at
-    /debug/device and in the bench output.
-
-    ``kernel_time_s`` is the UNION of all kernel-active intervals, not the
-    sum of per-dispatch durations: timed phases nest (agg_device wraps a
-    whole device pass that itself goes through ``kernels._dispatch``) and
-    parallel task threads overlap, so a plain sum exceeds wall-clock
-    (BENCH_r09 q01: 0.543s kernel vs 0.336s wall). ``kernel_begin``/
-    ``kernel_end`` keep a process-wide active count under the lock and add
-    elapsed time only when the count drops back to zero — nested and
-    overlapping spans count wall time once, so kernel_time_s <= wall by
-    construction. A per-thread depth additionally attributes each thread's
-    OUTERMOST span to the operator currently on the self-time stack
-    (``device_time_ns`` on its MetricNode — the per-operator device-time
-    signal the stats plane reports)."""
+    """Process-wide device counters, integers all: device<->host transfer
+    bytes and calls, blocking syncs (``sync_calls``: the wait itself is the
+    ``sync:*`` span of :func:`wait_int`, the benchmark's ``device_wait_s``),
+    jitted-kernel dispatches and which PARTIAL aggregation kernel answered a
+    batch. Surfaced at /debug/device; the benchmark reads the deltas a
+    query (``h2d_mb``, ``d2h_mb``, ``sync_points``, ``agg_dense_batches``)."""
 
     def __init__(self):
         self._mu = threading.Lock()
@@ -63,14 +45,11 @@ class DeviceStats:
             self.to_device_calls = 0
             self.to_device_bytes = 0
             self.kernel_calls = 0
-            self.kernel_time_s = 0.0
             self.mapped_calls = 0
             self.mapped_bytes = 0
             self.sync_calls = 0
             self.agg_dense_batches = 0
             self.agg_sort_batches = 0
-            self._active = 0
-            self._active_t0 = 0.0
 
     def add_to_host(self, nbytes: int):
         """One blocking pull of ``nbytes``: a transfer and a sync."""
@@ -114,50 +93,11 @@ class DeviceStats:
             self.mapped_calls += 1
             self.mapped_bytes += int(nbytes)
 
-    def kernel_begin(self):
-        import time
-
-        depth = getattr(self._tls, "depth", 0)
-        self._tls.depth = depth + 1
-        if depth == 0:
-            self._tls.t0 = time.perf_counter()
+    def add_kernel_call(self):
+        """One jitted dispatch through ``core/kernels._dispatch`` or
+        ``fused_dispatch``."""
         with self._mu:
             self.kernel_calls += 1
-            if self._active == 0:
-                self._active_t0 = time.perf_counter()
-            self._active += 1
-
-    def kernel_end(self):
-        import time
-
-        now = time.perf_counter()
-        with self._mu:
-            # reset() between begin/end (bench resets between shapes) drops
-            # the open span rather than booking a negative/garbage interval
-            if self._active > 0:
-                self._active -= 1
-                if self._active == 0:
-                    self.kernel_time_s += now - self._active_t0
-        depth = getattr(self._tls, "depth", 1) - 1
-        self._tls.depth = depth
-        if depth == 0:
-            self._attribute(now - self._tls.t0)
-
-    def _attribute(self, seconds: float):
-        """Charge one thread-outermost kernel span to the operator currently
-        computing on this thread (ops/base._SELF_TIME stack top)."""
-        try:
-            from blaze_tpu.ops import base as _ops_base
-        except Exception:
-            return
-        stack = getattr(_ops_base._SELF_TIME, "stack", None)
-        if stack:
-            stack[-1][0].add("device_time_ns", int(seconds * 1e9))
-
-    def kernel_span(self) -> "_KernelSpan":
-        """Context manager form of kernel_begin/kernel_end for call sites
-        that time a whole device phase (agg flows, fused join probes)."""
-        return _KernelSpan(self)
 
     def snapshot(self) -> dict:
         with self._mu:
@@ -167,28 +107,12 @@ class DeviceStats:
                 "to_device_calls": self.to_device_calls,
                 "to_device_bytes": self.to_device_bytes,
                 "kernel_calls": self.kernel_calls,
-                "kernel_time_s": round(self.kernel_time_s, 6),
                 "mapped_calls": self.mapped_calls,
                 "mapped_bytes": self.mapped_bytes,
                 "sync_calls": self.sync_calls,
                 "agg_dense_batches": self.agg_dense_batches,
                 "agg_sort_batches": self.agg_sort_batches,
             }
-
-
-class _KernelSpan:
-    __slots__ = ("_stats",)
-
-    def __init__(self, stats: DeviceStats):
-        self._stats = stats
-
-    def __enter__(self):
-        self._stats.kernel_begin()
-        return self
-
-    def __exit__(self, *exc):
-        self._stats.kernel_end()
-        return False
 
 
 DEVICE_STATS = DeviceStats()

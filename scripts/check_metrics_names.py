@@ -164,12 +164,6 @@ def check_timeline_taxonomy(seen: dict):
                 f"obs/timeline.py: COUNTER_TRACK_SERIES entry {s!r} not "
                 f"in DERIVED_SERIES — the Chrome counter track would "
                 f"sample a series the timeline never produces")
-    for s in tl.ARTIFACT_SERIES:
-        if s not in tl.DERIVED_SERIES:
-            violations.append(
-                f"obs/timeline.py: ARTIFACT_SERIES entry {s!r} not in "
-                f"DERIVED_SERIES — soak artifacts would carry an empty "
-                f"series")
     for hs in ("healthy", "degraded", "critical"):
         if hs not in tl.HEALTH_STATES:
             violations.append(
@@ -208,7 +202,6 @@ def check_profile_fields():
         ("ATTRIBUTION_FIELDS", stats.ATTRIBUTION_FIELDS),
         ("CRITICAL_PATH_FIELDS", stats.CRITICAL_PATH_FIELDS),
         ("AUDIT_FIELDS", stats.AUDIT_FIELDS),
-        ("BASELINE_FIELDS", stats.BASELINE_FIELDS),
         ("CACHE_FIELDS", stats.CACHE_FIELDS),
     ]
     for schema_name, fields in schemas:

@@ -16,7 +16,7 @@ bucket) alongside the raw instants Perfetto renders on the timeline.
 
 Run: ``python scripts/profile_query.py [q01|q06|q17|q47|q67] [-o OUTDIR]``
 Env: BENCH_ROWS (default 200_000 here — profiling wants fast iterations),
-BENCH_PARTITIONS (4), SOAK-style knobs via the usual bench envs.
+BENCH_PARTITIONS (4).
 """
 
 import argparse

@@ -1,6 +1,5 @@
 """The why-is-it-slow plane (ISSUE 17): exclusive wall-time attribution,
-critical-path extraction, the fusion/placement decision audit, and the
-per-fingerprint regression watch.
+critical-path extraction, and the fusion/placement decision audit.
 
 Covers the acceptance surface: the priority interval sweep's exclusivity
 invariant ``sum(categories) <= wall`` (unit + real queries + all five
@@ -8,14 +7,10 @@ bench shapes over a real 2-worker pool), worker-span merge onto the
 driver timeline, critical-path structural stability on a fixed plan,
 fusion-break-reason goldens (pyudf / cost_below_min_saved / blocking_op
 and the ``fused_op_fraction`` tripwire), the disabled-path overhead
-guard, humanized duration rendering above one hour, Chrome-trace cname/
-flow export, ``bench_diff --attribution`` gating (pre-attribution
-BENCH_r10 self-diffs clean), and the regression watch's incident bundle
-on a category breach."""
+guard, humanized duration rendering above one hour, and Chrome-trace
+cname/flow export."""
 
-import json
 import os
-import sys
 import time
 
 import numpy as np
@@ -36,8 +31,6 @@ from blaze_tpu.obs.attribution import (CATEGORIES, CATEGORY_CNAME,
 from blaze_tpu.obs.explain import fmt_ns
 from blaze_tpu.obs.tracer import TRACER
 from blaze_tpu.runtime.session import Session
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 F = E.AggFunction
 M = E.AggMode
@@ -358,124 +351,6 @@ def test_attribution_disabled_overhead_under_5_percent(tmp_path):
         f"disabled attribution {overhead_ns / 1e6:.2f}ms vs query "
         f"{wall_ns / 1e6:.1f}ms: disabled-path overhead exceeds 5%")
     assert per_check_ns < 2_000, f"active check {per_check_ns:.0f}ns"
-
-
-# -- bench_diff --attribution gates --------------------------------------------
-
-
-def _bench_diff():
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    try:
-        import bench_diff
-    finally:
-        sys.path.pop(0)
-    return bench_diff
-
-
-@pytest.mark.quick
-def test_bench_diff_attribution_r10_self_diff_clean():
-    """Pre-attribution artifacts carry no sections: the gate must skip
-    them clean, so BENCH_r10 -> BENCH_r10 (and r10 -> any successor with
-    sections) exits 0."""
-    bd = _bench_diff()
-    art = os.path.join(REPO, "BENCH_r10.json")
-    assert os.path.exists(art)
-    assert bd.main(["--attribution", art, art]) == 0
-
-
-@pytest.mark.quick
-def test_bench_diff_attribution_category_gate():
-    bd = _bench_diff()
-
-    def art(jit_ms, kern_ms, frac=0.5):
-        return {"shapes": {"q": {
-            "attribution": {"jit_compile_time_ns": int(jit_ms * 1e6),
-                            "kernel_compute_time_ns": int(kern_ms * 1e6)},
-            "decision_audit": {"fused_op_fraction": frac}}}}
-
-    # jit tripled-plus over a >=floor base: breach even with other cats flat
-    r = bd.diff_attribution(art(100, 400), art(400, 400))
-    assert any("jit_compile_time_ns" in s for s in r)
-    # 2.5x jit is under the 3.0 jit ratio; 2.5x kernel is over its 2.0
-    assert bd.diff_attribution(art(100, 400), art(250, 400)) == []
-    assert any("kernel_compute_time_ns" in s
-               for s in bd.diff_attribution(art(100, 400), art(100, 1000)))
-    # sub-floor noise never trips (5ms -> 40ms is under 2x the 50ms floor)
-    assert bd.diff_attribution(art(100, 5), art(100, 40)) == []
-    # fusion coverage tripwire: a 0.3 drop fails, 0.1 passes
-    assert any("fused_op_fraction" in s for s in bd.diff_attribution(
-        art(100, 100, frac=0.8), art(100, 100, frac=0.5)))
-    assert bd.diff_attribution(art(100, 100, frac=0.8),
-                               art(100, 100, frac=0.7)) == []
-    # missing sections skip clean in either direction
-    assert bd.diff_attribution({"shapes": {"q": {}}}, art(1, 1)) == []
-    assert bd.diff_attribution(art(1, 1), {"shapes": {"q": {}}}) == []
-
-
-# -- the regression watch ------------------------------------------------------
-
-
-def _profile(fp, samples, cur_jit_ms, base_jit_ms):
-    return {"fingerprint": fp, "label": fp,
-            "attribution": {"jit_compile_time_ns": int(cur_jit_ms * 1e6),
-                            "wall_ns": int(1e9)},
-            "attribution_baseline": {"samples": samples,
-                                     "jit_compile_time_ns":
-                                         int(base_jit_ms * 1e6)}}
-
-
-@pytest.mark.quick
-def test_regression_watch_breach_writes_incident(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    try:
-        import regression_watch as rw
-    finally:
-        sys.path.pop(0)
-    store = tmp_path / "profiles"
-    inc = tmp_path / "incidents"
-    store.mkdir(), inc.mkdir()
-    for prof in (_profile("ok", 5, 100, 100),       # within baseline
-                 _profile("bad", 5, 400, 100),      # jit 4x: breach
-                 _profile("fresh", 1, 400, 400)):   # no history: skipped
-        with open(store / (prof["fingerprint"] + ".json"), "w") as f:
-            json.dump(prof, f)
-    report = rw.watch(str(store), 2.0, 3.0, 50.0, str(inc))
-    assert report["checked"] == 2
-    assert report["skipped_no_history"] == 1
-    assert [b["fingerprint"] for b in report["breaches"]] == ["bad"]
-    breach = report["breaches"][0]["breaches"][0]
-    assert breach["category"] == "jit_compile_time_ns"
-    assert breach["ratio"] == pytest.approx(4.0)
-    # the incident bundle landed with the offending categories
-    bundles = os.listdir(inc)
-    assert len(bundles) == 1 and "attribution_regression" in bundles[0]
-    with open(inc / bundles[0]) as f:
-        bundle = json.load(f)
-    assert bundle["kind"] == "attribution_regression"
-    assert bundle["extra"]["breaches"][0]["category"] == "jit_compile_time_ns"
-    # CLI contract: breach -> exit 1, clean store -> exit 0
-    assert rw.main(["--store", str(store), "--incident-dir", ""]) == 1
-    os.unlink(store / "bad.json")
-    assert rw.main(["--store", str(store), "--incident-dir", ""]) == 0
-
-
-@pytest.mark.quick
-def test_attribution_baseline_rolls_in_store(tmp_path):
-    """save_profile folds each run into the capped-window mean the watch
-    compares against."""
-    from blaze_tpu.obs.stats import save_profile
-
-    conf = Config(profile_store_dir=str(tmp_path / "p"), profile_store_max=8)
-    attr1 = {f: 0 for f in CATEGORY_FIELDS}
-    attr1.update({"jit_compile_time_ns": 100, "wall_ns": 1000})
-    save_profile({"fingerprint": "fp", "attribution": attr1}, conf)
-    attr2 = dict(attr1, jit_compile_time_ns=300)
-    save_profile({"fingerprint": "fp", "attribution": attr2}, conf)
-    with open(tmp_path / "p" / "fp.json") as f:
-        stored = json.load(f)
-    base = stored["attribution_baseline"]
-    assert base["samples"] == 2
-    assert base["jit_compile_time_ns"] == 200  # mean of 100 and 300
 
 
 # -- the five bench shapes over a real 2-worker pool (slow) --------------------

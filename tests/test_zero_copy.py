@@ -294,12 +294,24 @@ def test_reader_outlives_unlinked_segment(tmp_path):
     assert got.equals(pa.Table.from_batches([b.to_arrow()]))
 
 
-def test_tier_fallback_without_dev_shm():
+def test_tier_fallback_without_dev_shm(tmp_path, monkeypatch):
     """When /dev/shm is unusable (here: an impossibly high free-space
     floor) segments fall back to the session work dir — mmap still works,
-    results are unchanged, nothing lands in /dev/shm."""
+    results are unchanged, and THIS process makes no shm root (other xdist
+    workers make theirs under /dev/shm meanwhile, so no glob of it)."""
+    import tempfile
+
+    from blaze_tpu.io.shm_segments import SHM_ROOT_PREFIX, is_shm_path
+
+    made = []
+    real_mkdtemp = tempfile.mkdtemp
+
+    def mkdtemp(*a, **kw):
+        made.append(real_mkdtemp(*a, **kw))
+        return made[-1]
+
+    monkeypatch.setattr(tempfile, "mkdtemp", mkdtemp)
     parts = _make_parts(seed=15)
-    shm_before = set(glob.glob("/dev/shm/blaze_tpu_shm_*"))
     with config_override(zero_copy_tier="shm",
                          shm_min_free_bytes=1 << 62):
         with Session() as sess:
@@ -308,13 +320,15 @@ def test_tier_fallback_without_dev_shm():
             out = sess.execute_to_table(_two_stage_plan(parts))
     ref, _ = _run(parts, zero_copy_shuffle=False)
     assert out.equals(ref)
-    assert set(glob.glob("/dev/shm/blaze_tpu_shm_*")) == shm_before
+    assert made and not [d for d in made if is_shm_path(d)]
 
     # explicit shm_dir wins over the probe
-    with config_override(zero_copy_tier="shm", shm_dir="/dev/shm",
+    with config_override(zero_copy_tier="shm", shm_dir=str(tmp_path),
                          shm_min_free_bytes=1 << 62):
         with Session() as sess:
-            assert sess.shuffle_root.startswith("/dev/shm/blaze_tpu_shm_")
+            assert sess.shuffle_root.startswith(
+                os.path.join(str(tmp_path), SHM_ROOT_PREFIX))
+            assert sess.shuffle_root in made
 
 
 # -- the five bench shapes on a real worker pool ------------------------------
