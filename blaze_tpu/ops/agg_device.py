@@ -4,13 +4,16 @@ The general AggTable (ops/agg.py) interns group keys on host — exact for any
 type, but it pulls every input batch's key columns across the device
 boundary. On this backend transfers cost ~25-90ms each, so for the hot
 TPC-DS shape (grouped sum/count/avg/min/max over fixed-width keys) this
-module keeps the whole partial stage on device (SURVEY.md §7.2 L2':
-sort-based grouped aggregation over ``lax.sort`` + segment ops — the same
-kernel the ICI mesh path uses, parallel/mesh.py):
+module keeps the whole partial stage on device (SURVEY.md §7.2 L2'). Keys
+whose observed range fits a slot table reduce straight into it
+(``_dense_partial_kernel``); any other keys go the sort path
+(``_aggregate_sorted``, the body of ``jit(agg_partial)`` and
+``jit(agg_merge)``):
 
-    sort rows by (key validity, key value)* -> segment boundaries ->
-    segment_sum/min/max per aggregate -> compact -> partial batch whose key
-    and state columns are still device arrays.
+    order the rows by their keys (two-operand sorts) -> one gather of every
+    plane into that order -> contiguous segments reduced by prefix scans ->
+    one gather of each segment's last row -> a partial batch whose key and
+    state columns are still device arrays, the groups in key order.
 
 One jitted call per batch; the only host sync is the group-count scalar.
 Per-batch partials are NOT consolidated across batches — they merge at the
@@ -31,6 +34,7 @@ from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn
 from blaze_tpu.exprs.compiler import ExprEvaluator, _broadcast
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
+from blaze_tpu.ops.sort_keys import orderable_word_traced
 from blaze_tpu.utils.device import (DEVICE_STATS, is_device_dtype,
                                     wait_array, wait_int)
 
@@ -340,7 +344,8 @@ def supports_fused_filter(filter_op, grandchild_schema: T.Schema) -> bool:
 
 
 class DevicePartialAgger:
-    """Streams batches through the jitted sort-segment partial kernel.
+    """Streams batches through the jitted partial kernels: the slot table
+    where the probed key range allows it, else the sort path.
 
     With ``fused_predicates`` set, the upstream FilterExec's predicate is
     traced INTO the kernel (reference: filter-project fusion): the filter
@@ -1078,58 +1083,262 @@ def _canonical_keys(key_data, key_valid):
     return canon
 
 
-def _segmentation(exists, canon, key_valid, iota, capacity, key_dtypes):
-    """(seg, order): rows -> segment ids < capacity (padding rows drop to
-    capacity). Single int keys in range use direct indexing (no sort),
-    decided on device by lax.cond; otherwise lax.sort groups equal keys."""
-    nk = len(canon)
+def _order_word(d):
+    """The uint64 word whose unsigned order is a canonical key plane's
+    ascending order (NaN last): ``sort_keys.orderable_word_traced``, and for
+    a float of any width its bits, the sign bit flipped and a negative
+    value's other bits too."""
+    if not jnp.issubdtype(d.dtype, jnp.floating):
+        return orderable_word_traced(d)
+    width = 8 * d.dtype.itemsize
+    bits = jax.lax.bitcast_convert_type(d, jnp.dtype(f"uint{width}"))
+    top = bits.dtype.type(1 << (width - 1))
+    return jnp.where(bits >= top, ~bits, bits | top).astype(jnp.uint64)
 
-    def sort_path(_):
-        # sort rows so equal keys are adjacent; padding rows last
-        operands = [(~exists).astype(jnp.uint8)]
+
+def _segmented_scan(combine, new, planes):
+    """Inclusive scan of a tuple of row planes that restarts wherever ``new``
+    is set: every row reads ``combine`` (associative, over such tuples) of
+    its segment's rows up to itself, so a segment's last row reads the
+    segment's."""
+
+    def step(a, b):
+        merged = combine(a[1:], b[1:])
+        return (a[0] | b[0],
+                *(jnp.where(b[0], y, m) for y, m in zip(b[1:], merged)))
+
+    return jax.lax.associative_scan(step, (new, *planes))[1:]
+
+
+def _lex3_pick(is_max: bool):
+    """``combine`` of the (has, p2, p1, p0) scan behind minw / maxw: the
+    lexicographic extreme of two wide-decimal values (:func:`_segment_lex3`'s
+    answer in one pass; the planes are zero where ``has`` is not set)."""
+
+    def pick(a, b):
+        ha, a2, a1, a0 = a
+        hb, b2, b1, b0 = b
+        lo, hi = ((a2, a1, a0), (b2, b1, b0)) if is_max else \
+            ((b2, b1, b0), (a2, a1, a0))
+        b_wins = (lo[0] < hi[0]) | ((lo[0] == hi[0]) & (
+            (lo[1] < hi[1]) | ((lo[1] == hi[1]) & (lo[2] < hi[2]))))
+        take_b = hb & (~ha | b_wins)
+        return (ha | hb, *(jnp.where(take_b, y, x)
+                           for x, y in ((a2, b2), (a1, b1), (a0, b0))))
+
+    return pick
+
+
+def _is_running_total(op: str, dtype) -> bool:
+    """Does :func:`_reduce_sorted` answer ``op`` over planes of ``dtype`` with
+    a running total over ALL the rows? A segment's value is then its last
+    row's less the last row's of the segment before. Integer sums and counts
+    do (an integer sum wraps mod 2^64 either way, so the difference is the
+    scatter-add's sum bit for bit); a float sum never."""
+    return op in ("count", "any") or (
+        op == "add" and jnp.issubdtype(dtype, jnp.integer))
+
+
+def _reduce_sorted(op: str, new, planes):
+    """One request's row planes over rows sorted into contiguous segments
+    (``new`` marks each segment's first row): at a segment's last row stands
+    its reduction, or its running total (:func:`_is_running_total`). On the
+    TPU a prefix scan of 131,072 rows is a fraction of a millisecond where
+    the scatter it replaces runs an update at a time (PERF.md section 6,
+    PR 29). Everything but the running totals is a scan that restarts at
+    ``new``; a float sum adds in that scan's fixed tree order."""
+    x = planes[0]
+    if op in ("count", "any"):
+        # a batch has fewer than 2^31 rows: count in the native width
+        return [jnp.cumsum(x, dtype=jnp.int32)]
+    if _is_running_total(op, x.dtype):
+        return [jnp.cumsum(x, dtype=x.dtype)]
+    if op in ("lexmin", "lexmax"):
+        p0, p1, p2, m = planes
+        has, b2, b1, b0 = _segmented_scan(_lex3_pick(op == "lexmax"), new,
+                                          (m, p2, p1, p0))
+        return [b0, b1, b2, has]
+    fn = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[op]
+    return list(_segmented_scan(lambda a, b: (fn(a[0], b[0]),), new, (x,)))
+
+
+def _extreme_sentinel(kind: str, dtype):
+    """What a row that does not count reads in a min / max reduction."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.array(jnp.inf if kind == "min" else -jnp.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.array(info.max if kind == "min" else info.min, dtype)
+
+
+def _partial_requests(spec, arg, exists):
+    """One aggregate's reductions over raw rows, for :func:`_aggregate_sorted`:
+    a list of ``(op, plane...)``, every plane already neutral on the rows
+    that do not count. What :func:`_reduce_aggs` asks of ``_seg_reduce``."""
+    kind, rescale, acc_dt = spec
+    sa, sv = arg
+    sv = sv & exists
+    tail = ("count" if kind.startswith("avg") else "any", sv)
+    z = jnp.int64(0)
+    if kind in ("sum3", "avg3"):
+        return [("add", jnp.where(sv, p, z)) for p in sa] + [tail]
+    if kind in ("minw", "maxw"):
+        return [("lex" + kind[:3], *(jnp.where(sv, p, z) for p in sa), sv)]
+    if kind in ("sum2", "avg2"):
+        x = sa.astype(jnp.int64)
+        return [("add", jnp.where(sv, x & jnp.int64(0xFFFFFFFF), z)),
+                ("add", jnp.where(sv, x >> 32, z)), tail]
+    if kind in ("sum", "avg"):
+        x = sa.astype(jnp.dtype(acc_dt))  # widen BEFORE accumulating
+        if rescale:
+            x = x * jnp.array(10 ** rescale, x.dtype)
+        return [("add", jnp.where(sv, x, jnp.zeros((), x.dtype))), tail]
+    if kind == "count":
+        return [("count", sv)]
+    return [(kind, jnp.where(sv, sa, _extreme_sentinel(kind, sa.dtype))),
+            ("any", sv)]
+
+
+def _merge_requests(kind: str, scols, exists):
+    """One aggregate's reductions over partial STATE rows (its (data, valid)
+    state columns), for :func:`_aggregate_sorted`: what :func:`_merge_reduce`
+    scatters."""
+    cols = [(d, v & exists) for d, v in scols]
+
+    def add(d, m):
+        return ("add", jnp.where(m, d, jnp.zeros((), d.dtype)))
+
+    if kind in ("count", "avg"):  # (count) / (sum, count): each its own mask
+        return [add(d, v) for d, v in cols]
+    # every other state ends in a column that says whether (sum, min, max,
+    # the wide kinds) or how many (avg2, avg3) rows it holds
+    *vals, (last, last_valid) = cols
+    m = vals[0][1] & last.astype(bool) & last_valid
+    tail = add(last, m) if kind in ("avg2", "avg3") else ("any", m)
+    if kind in ("minw", "maxw"):
+        return [("lex" + kind[:3],
+                 *(jnp.where(m, d, jnp.int64(0)) for d, _ in vals), m)]
+    if kind in ("min", "max"):
+        (vd, _), = vals
+        return [(kind, jnp.where(m, vd, _extreme_sentinel(kind, vd.dtype))),
+                tail]
+    return [add(d, m) for d, _ in vals] + [tail]
+
+
+def _finish_state(kind: str, reduced):
+    """Elementwise: one aggregate's reduced planes -> its state planes
+    (the limb carries; an empty min / max reads 0, not the sentinel)."""
+    if kind in ("sum2", "avg2"):
+        slo, shi, last = reduced
+        return [slo & jnp.int64(0xFFFFFFFF), shi + (slo >> 32), last]
+    if kind in ("sum3", "avg3"):
+        from blaze_tpu.ops.aggfns import _limb3_renorm
+
+        s0, s1, s2, last = reduced
+        return [*_limb3_renorm(s0, s1, s2), last]
+    if kind in ("min", "max"):
+        acc, has = reduced
+        return [jnp.where(has, acc, jnp.zeros((), acc.dtype)), has]
+    return list(reduced)
+
+
+def _take_rows(planes, idx, live):
+    """``kernels.take_rows_traced`` over data and bool planes in any order:
+    the bool ones travel bit-packed, as its validity planes."""
+    is_bool = [p.dtype == jnp.bool_ for p in planes]
+    datas, bools = K.take_rows_traced(
+        [p for p, b in zip(planes, is_bool) if not b],
+        [p for p, b in zip(planes, is_bool) if b], idx, live)
+    datas, bools = iter(datas), iter(bools)
+    return [next(bools) if b else next(datas) for b in is_bool]
+
+
+def _segment_value(op: str, x, out_valid):
+    """A reduction's plane once the segments' last rows stand at the front:
+    a running total less its neighbour's (the first group's neighbour is
+    0), a count as int64, an ``any`` as a flag; a scan's value as it is."""
+    if not _is_running_total(op, x.dtype):
+        return x
+    x = x - jnp.concatenate([jnp.zeros(1, x.dtype), x[:-1]])
+    x = jnp.where(out_valid, x, jnp.zeros((), x.dtype))
+    if op == "add":
+        return x
+    return x > 0 if op == "any" else x.astype(jnp.int64)
+
+
+def _aggregate_sorted(exists, key_data, key_valid, kinds, requests):
+    """The body ``jit(agg_partial)`` and ``jit(agg_merge)`` share: group the
+    rows ``exists`` keeps by their keys and answer every aggregate's
+    ``requests`` (:func:`_partial_requests` / :func:`_merge_requests`) a
+    group. Returns the kernels' outputs: ``[num_groups, out_valid, (key
+    data, key validity)..., state planes...]``, the groups at the front in
+    key order (nulls first, then ascending; a null or padding key reads 0,
+    a float key its canonical value), zeros past ``num_groups``.
+
+    Shaped by what the TPU charges (PERF.md section 6, PR 29; 131,072 rows):
+    a row-sized int64 scatter 9 ms, a gather 0.9 ms a 32-bit plane it is
+    asked for and no more for a row of many words, a two-operand sort or a
+    prefix scan a few tenths. So nothing is scattered and every plane moves
+    twice, each time with all the others as one matrix of words:
+
+    order   ``lex_order_traced`` over the canonical key words (two-operand
+            sorts only; the 2k + 2-operand sort this replaces compiled for
+            five minutes). Its packed word is equal exactly where all the
+            keys are, so a segment starts where the sorted word changes.
+            ONE ``take_rows_traced`` brings the keys and every request's
+            planes into that order.
+    reduce  contiguous segments reduce by prefix scans
+            (:func:`_reduce_sorted`); a segment's last row holds its value.
+    emit    the groups' last rows go to the front by the stable sort of
+            their flags (PR 27's row map) and ONE ``take_rows_traced``; the
+            running totals then subtract their neighbour's."""
+    capacity = exists.shape[0]
+    nk = len(key_data)
+    iota = jnp.arange(capacity, dtype=jnp.int32)
+    key_valid = [v & exists for v in key_valid]
+    canon = _canonical_keys(key_data, key_valid)
+    with jax.named_scope("order"):
+        columns = []
         for d, v in zip(canon, key_valid):
-            operands.append(v.astype(jnp.uint8))
-            operands.append(d)
-        sorted_ops = jax.lax.sort(tuple(operands) + (iota,),
-                                  num_keys=len(operands))
-        order = sorted_ops[-1]
-        s_exists = exists[order]
-        # segment boundaries: any key field differs from previous row
-        new = jnp.zeros(capacity, dtype=bool).at[0].set(True)
-        for d, v in zip(canon, key_valid):
-            sd, sv = d[order], v[order]
-            new = new | jnp.concatenate([jnp.ones(1, bool), sd[1:] != sd[:-1]])
-            new = new | jnp.concatenate([jnp.ones(1, bool), sv[1:] != sv[:-1]])
-        new = new & s_exists
-        seg = (jnp.cumsum(new) - 1).astype(jnp.int32)
-        seg = jnp.where(s_exists, seg, capacity)
-        return seg, order
-
-    single_int_key = nk == 1 and jnp.issubdtype(
-        jnp.dtype(key_dtypes[0]), jnp.integer)
-    if not single_int_key:
-        return sort_path(None)
-    # direct segmentation: when every valid key lies in [0, capacity-1) the
-    # key IS the segment id — no sort at all (the common TPC-DS
-    # dimension-key group-by). Decided on device by lax.cond: no host sync,
-    # both branches compiled once.
-    v0 = key_valid[0]
-    # range-check and build seg in int64/int32, NOT the key dtype: int8/16
-    # would wrap the capacity sentinels (32768 -> -32768, and negative
-    # scatter indices wrap instead of drop), and comparing in a narrowed
-    # dtype could false-positive the fits test
-    d064 = canon[0].astype(jnp.int64)
-    fits = jnp.all(jnp.where(exists & v0,
-                             (d064 >= 0) & (d064 < capacity - 1), True))
-
-    def direct_path(_):
-        seg = jnp.where(
-            exists,
-            jnp.where(v0, d064.astype(jnp.int32), jnp.int32(capacity - 1)),
-            jnp.int32(capacity))
-        return seg, iota
-
-    return jax.lax.cond(fits, direct_path, sort_path, None)
+            # class -1: a null key, before the values; 1: padding, after all
+            cls = jnp.where(v, 0, -1)
+            if not columns:
+                cls = jnp.where(exists, cls, 1)
+            columns.append((_order_word(d), cls.astype(jnp.int8)))
+        order, word = K.lex_order_traced(columns)
+        live = iota < jnp.sum(exists)  # padding sorts last
+        new = live & jnp.concatenate(
+            [jnp.ones(1, bool), word[1:] != word[:-1]])
+        s_rows = _take_rows(
+            [*canon, *key_valid,
+             *(p for reqs in requests for _op, *planes in reqs for p in planes)],
+            order, live)
+    with jax.named_scope("reduce"):
+        s_planes = iter(s_rows[2 * nk:])
+        # per aggregate, (op, row plane) for every plane its requests give
+        reduced = [[(op, plane) for op, *planes in reqs
+                    for plane in _reduce_sorted(
+                        op, new, [next(s_planes) for _ in planes])]
+                   for reqs in requests]
+    with jax.named_scope("emit"):
+        is_last = live & jnp.concatenate([new[1:] | ~live[1:],
+                                          jnp.ones(1, bool)])
+        num_groups = jnp.sum(is_last)
+        out_valid = iota < num_groups
+        _, last_row = jax.lax.sort(((~is_last).astype(jnp.uint8), iota),
+                                   num_keys=1, is_stable=True)
+        g_rows = _take_rows(
+            [*s_rows[:2 * nk],
+             *(plane for agg in reduced for _op, plane in agg)],
+            last_row, out_valid)
+        outs = [num_groups, out_valid]
+        for d, v in zip(g_rows[:nk], g_rows[nk:2 * nk]):
+            outs += [d, v]
+        g_planes = iter(g_rows[2 * nk:])
+        for kind, agg in zip(kinds, reduced):
+            outs += _finish_state(kind, [
+                _segment_value(op, next(g_planes), out_valid)
+                for op, _plane in agg])
+    return tuple(outs)
 
 
 # Largest segment table reduced without scatters (see _seg_reduce). A chip
@@ -1161,10 +1370,10 @@ def _seg_reduce(op: str, seg, values, nseg: int, init=None, where=None):
     select, reduce along the rows: ``nseg x rows`` lane operations that XLA
     fuses into one pass. Any other keeps ``.at[seg].<op>(mode="drop")``: on
     the TPU that is one serial update a row (66-88 ns each for an int64
-    plane), which is what ``jit(agg_partial)`` spends its time in. The sort
-    and passthrough kernels come here with a segment a row (``nseg`` is the
-    capacity, where the masked form would be quadratic in the batch), so
-    their programs are the same at every capacity."""
+    plane). The passthrough kernel comes here with a segment a row (``nseg``
+    is the capacity, where the masked form would be quadratic in the batch),
+    so its program is the same at every capacity. Rows that a sort has made
+    contiguous segments of need neither form: :func:`_reduce_sorted`."""
     dtype = jnp.dtype(jnp.int64) if op == "count" else values.dtype
     if op in ("add", "count", "any"):
         init = dtype.type(0)
@@ -1234,8 +1443,9 @@ def _segment_lex3(p0, p1, p2, m, seg, nseg, is_max: bool):
 
 
 def _reduce_aggs(specs, args, seg, nseg_total):
-    """Per-aggregate segment reductions shared by the sort-path and
-    dense-bucket partial kernels. ``args[i]`` is the i-th aggregate's
+    """Per-aggregate segment reductions of the partial kernels whose ``seg``
+    is in no order (slot table, radix, passthrough; sorted rows take
+    :func:`_partial_requests`). ``args[i]`` is the i-th aggregate's
     already-masked (data, valid) pair aligned with ``specs``; rows route to
     ``seg`` (out-of-range segments drop). Returns one ("kind", arrays...)
     tuple per aggregate, each array of length ``nseg_total``. Every
@@ -1293,11 +1503,7 @@ def _reduce_aggs(specs, args, seg, nseg_total):
         elif kind == "count":
             outs.append(("count", count(sv)))
         else:  # min / max
-            if jnp.issubdtype(sa.dtype, jnp.floating):
-                sent = jnp.array(jnp.inf if kind == "min" else -jnp.inf, sa.dtype)
-            else:
-                info = jnp.iinfo(sa.dtype)
-                sent = jnp.array(info.max if kind == "min" else info.min, sa.dtype)
+            sent = _extreme_sentinel(kind, sa.dtype)
             acc = _seg_reduce(kind, seg, jnp.where(sv, sa, sent), nseg_total,
                               sent)
             shas = has(sv)
@@ -1388,12 +1594,12 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
 
 
 def _merge_reduce(kinds, states, seg, CAP):
-    """Per-aggregate partial-STATE merges shared by the sort-path and radix
-    merge kernels. ``states[i]`` is aggregate i's list of already-masked
-    (data, valid) state-column pairs aligned with ``kinds``; rows route to
-    ``seg`` (out-of-range segments drop), so it works for ANY seg mapping —
-    sorted segment ids or direct radix slot codes. One output tuple of
-    merged state arrays (length ``CAP``) per aggregate."""
+    """Per-aggregate partial-STATE merges of the radix merge kernel (sorted
+    rows take :func:`_merge_requests`). ``states[i]`` is aggregate i's list
+    of already-masked (data, valid) state-column pairs aligned with
+    ``kinds``; rows route to ``seg`` (out-of-range segments drop), so it
+    works for ANY seg mapping. One output tuple of merged state arrays
+    (length ``CAP``) per aggregate."""
     outs = []
     for kind, scols in zip(kinds, states):
         if kind in ("sum2", "avg2"):
@@ -1461,13 +1667,7 @@ def _merge_reduce(kinds, states, seg, CAP):
         else:  # min / max
             (vd, vv), (hd, hv) = scols
             m = vv & hd.astype(bool) & hv
-            if jnp.issubdtype(vd.dtype, jnp.floating):
-                sent = jnp.array(jnp.inf if kind == "min" else -jnp.inf,
-                                 vd.dtype)
-            else:
-                info = jnp.iinfo(vd.dtype)
-                sent = jnp.array(info.max if kind == "min" else info.min,
-                                 vd.dtype)
+            sent = _extreme_sentinel(kind, vd.dtype)
             x = jnp.where(m, vd, sent)
             acc = jnp.full(CAP, sent, vd.dtype)
             acc = acc.at[seg].min(x, mode="drop") if kind == "min" else \
@@ -1539,8 +1739,9 @@ def _merge_kernel(key_dtypes: Tuple[str, ...], kinds: Tuple[str, ...],
     """FINAL/PARTIAL_MERGE device kernel: group partial STATE columns by key
     and merge them with each aggregate's merge semantics (round-1 verdict
     weak #4 — the merge stage previously always landed in the host intern
-    table). Same segmentation as the partial kernel; state reductions:
-    sum (sum,has), count (count), avg (sum,count), min/max (val,has)."""
+    table). The partial kernel's body (:func:`_aggregate_sorted`) over
+    state reductions (:func:`_merge_requests`): sum (sum,has), count (count),
+    avg (sum,count), min/max (val,has), the wide kinds their limbs."""
     nk = len(key_dtypes)
 
     def agg_merge(exists, *flat):
@@ -1554,39 +1755,10 @@ def _merge_kernel(key_dtypes: Tuple[str, ...], kinds: Tuple[str, ...],
                 cols.append((flat[pos], flat[pos + 1]))
                 pos += 2
             states.append(cols)
-        iota = jnp.arange(capacity, dtype=jnp.int32)
-        canon = _canonical_keys(key_data, key_valid)
-        seg, order = _segmentation(exists, canon, key_valid, iota, capacity,
-                                   key_dtypes)
-        s_exists = exists[order]
-        s_keys = [(d[order], v[order]) for d, v in zip(key_data, key_valid)]
-        CAP = capacity
-        outs = _merge_reduce(
-            kinds,
-            [[(d[order], v[order] & s_exists) for d, v in cols]
-             for cols in states],
-            seg, CAP)
-        # compact present segments to the front (cumsum+scatter, no 2nd sort)
-        first_idx = jnp.full(CAP, capacity - 1, jnp.int32).at[seg].min(
-            iota, mode="drop")
-        seg_present = jnp.zeros(CAP, bool).at[seg].max(s_exists, mode="drop")
-        num_groups = jnp.sum(seg_present)
-        pos2 = jnp.cumsum(seg_present) - 1
-        scat = jnp.where(seg_present, pos2, CAP).astype(jnp.int32)
-
-        def compact(x):
-            return jnp.zeros((CAP,), x.dtype).at[scat].set(x, mode="drop")
-
-        out_valid = iota < num_groups
-        results = [num_groups, out_valid]
-        for d, v in s_keys:
-            results.append(jnp.where(out_valid, compact(d[first_idx]),
-                                     jnp.zeros((), d.dtype)))
-            results.append(compact(v[first_idx]) & out_valid)
-        for group in outs:
-            for a in group:
-                results.append(compact(a))
-        return tuple(results)
+        return _aggregate_sorted(
+            exists, key_data, key_valid, kinds,
+            [_merge_requests(kind, cols, exists)
+             for kind, cols in zip(kinds, states)])
 
     return jax.jit(agg_merge)
 
@@ -1808,7 +1980,8 @@ def _passthrough_kernel(key_dtypes: Tuple[str, ...],
 @functools.lru_cache(maxsize=256)
 def _partial_kernel(key_dtypes: Tuple[str, ...], specs: Tuple[Tuple[str, int], ...],
                     arg_dtypes: Tuple[str, ...], capacity: int):
-    """Build + jit the per-batch partial kernel for one (schema, capacity)."""
+    """Build + jit the per-batch sort-path partial kernel for one (schema,
+    capacity): :func:`_aggregate_sorted` over :func:`_partial_requests`."""
     nk = len(key_dtypes)
 
     def agg_partial(exists, *flat):
@@ -1824,43 +1997,9 @@ def _partial_kernel(key_dtypes: Tuple[str, ...], specs: Tuple[Tuple[str, int], .
             else:
                 args.append((flat[pos], flat[pos + 1]))
                 pos += 2
-        iota = jnp.arange(capacity, dtype=jnp.int32)
-        canon = _canonical_keys(key_data, key_valid)
-        seg, order = _segmentation(exists, canon, key_valid, iota, capacity,
-                                   key_dtypes)
-
-        s_exists = exists[order]
-        s_keys = [(d[order], v[order]) for d, v in zip(key_data, key_valid)]
-        nseg_total = capacity
-        # --- per-aggregate segment reductions
-        outs = _reduce_aggs(
-            specs,
-            [(tuple(p[order] for p in ad) if isinstance(ad, tuple)
-              else ad[order], av[order] & s_exists) for ad, av in args],
-            seg, nseg_total)
-        # --- representative row (first of each segment) for key values
-        first_idx = jnp.full(nseg_total, capacity - 1, jnp.int32).at[seg].min(
-            iota, mode="drop")
-        seg_present = jnp.zeros(nseg_total, bool).at[seg].max(
-            s_exists, mode="drop")
-        num_groups = jnp.sum(seg_present)
-        # compact present segments to the front by cumsum+scatter (O(n); an
-        # argsort here would cost a second full lax.sort)
-        pos = jnp.cumsum(seg_present) - 1
-        scat = jnp.where(seg_present, pos, nseg_total).astype(jnp.int32)
-
-        def compact(x):
-            return jnp.zeros((nseg_total,), x.dtype).at[scat].set(x, mode="drop")
-
-        out_valid = iota < num_groups
-        results = [num_groups, out_valid]
-        for d, v in s_keys:
-            results.append(jnp.where(out_valid, compact(d[first_idx]),
-                                     jnp.zeros((), d.dtype)))
-            results.append(compact(v[first_idx]) & out_valid)
-        for entry in outs:
-            for a in entry[1:]:
-                results.append(compact(a))
-        return tuple(results)
+        return _aggregate_sorted(
+            exists, key_data, key_valid, [kind for kind, _r, _d in specs],
+            [_partial_requests(spec, arg, exists)
+             for spec, arg in zip(specs, args)])
 
     return jax.jit(agg_partial)

@@ -325,7 +325,7 @@ def test_masked_reduction_equals_the_scatter(monkeypatch, name, nseg):
 
 @pytest.mark.parametrize("nseg,rows,masked", [
     (16, 131072, True), (CUT, 131072, True), (2 * CUT, 131072, False),
-    (128, 128, False),  # a segment a row: the sort and passthrough kernels
+    (128, 128, False),  # a segment a row: the passthrough kernel
     (128, 256, True)])
 def test_form_follows_the_static_shapes(nseg, rows, masked):
     closed = jax.make_jaxpr(
@@ -383,31 +383,92 @@ def test_dense_kernel_at_16_slots_has_no_row_sized_scatter(names):
                for e in jaxpr_eqns(closed.jaxpr))
 
 
-# sha256 of str(make_jaxpr(_partial_kernel(...))) at the parent commit
-# (0f92de4, jax 0.9.0): the sort kernel, whose segment count is the batch
-# capacity, is not this PR's, so its program — and the compile-cache entries
-# q67 hits — must not move. The PR that rewrites the sort path replaces these.
-SORT_PATH = {
+# how many state planes a kind's merge takes and every kernel gives
+NSTATE = {"sum": 2, "count": 1, "avg": 2, "min": 2, "max": 2, "sum2": 3,
+          "avg2": 3, "sum3": 4, "avg3": 4, "minw": 4, "maxw": 4}
+
+
+def _state_dtypes(name):
+    """The dtypes of the partial-state columns ``jit(agg_merge)`` takes."""
+    (kind, _rescale, acc), adt = KINDS[name]
+    last = "int64" if kind.startswith("avg") else "bool"
+    if kind == "count":
+        return ("int64",)
+    if kind in ("sum", "avg"):
+        return (acc, last)
+    if kind in ("min", "max"):
+        return (adt, "bool")
+    return ("int64",) * (NSTATE[kind] - 1) + (last,)
+
+
+# key dtypes, aggregates, and sha256 of str(make_jaxpr(_dense_partial_kernel))
+# at 16 and at 2 x CUT slots at PR 28's commit (03958eb, jax 0.9.0): PR 29
+# rewrote the sort path beside it, and the slot-table kernel, its program and
+# the compile-cache entries q01, q06 and q47 hit, must not move.
+SCHEMAS = {
     "sum_count_1key": (
         ("int64",), ["sum", "count"],
-        "954dd6fce5740a96324f1fd6e8a5384a23692424d7039bb9bbb2147355ff9250"),
+        "e93110f768a05d8b5ee57927301a266c2baa358f3998343c0315047c38931927",
+        "9eeb4b89a0917718a8eaffaead9f9d02c57edf9321b1e6d1bf81c078fb8e2666"),
     "narrow_2key": (
         ("int64", "int32"), ["sum", "avg", "min", "max", "count", "sum_f32"],
-        "fbdd7acad6c17d74564bb645dbbc2825373939429832c016b18965133c9b6076"),
+        "3ae10d49a207373a94025b56d1d04d0faad1fe64fc7ad1b84a12d05b7b159f09",
+        "c1df3eb7eac6195bf0f2cb062948361387bda4e5a6bbefe5413b95771d220806"),
     "wide_1key": (
         ("int64",), ["sum2", "avg2", "sum3", "avg3", "minw", "maxw"],
-        "eb8eaa688c8210137fd2e8c348e5e7beed091aca397b6d68def6e1b287663229"),
+        "4257a9db6e65ad6b05887834f3b593892b8538d1e359de3751b579c50a68dcf8",
+        "59f9404fba7e47a35e0753cb3b7ce6fe288c6e3066b8031c1075916a1a92f5d6"),
 }
 
 
-@pytest.mark.parametrize("case", sorted(SORT_PATH))
-def test_sort_kernel_jaxpr_is_the_parents(case):
-    key_dtypes, names, digest = SORT_PATH[case]
+@pytest.mark.parametrize("case", sorted(SCHEMAS))
+def test_dense_kernel_jaxpr_is_the_parents(case):
+    key_dtypes, names, *digests = SCHEMAS[case]
     specs = tuple(KINDS[n][0] for n in names)
     adt = tuple(KINDS[n][1] for n in names)
-    kernel = A._partial_kernel(key_dtypes, specs, adt, CAPACITY)
-    text = str(jax.make_jaxpr(kernel)(*_avals(key_dtypes, adt, CAPACITY)))
-    assert hashlib.sha256(text.encode()).hexdigest() == digest
+    avals = _avals(key_dtypes, adt, CAPACITY, bases=True)
+    for slots, digest in zip((16, 2 * CUT), digests):
+        sizes = (slots,) if len(key_dtypes) == 1 else (slots // 4, 4)
+        kernel = A._dense_partial_kernel(key_dtypes, specs, adt, CAPACITY,
+                                         sizes, max(128, slots))
+        text = str(jax.make_jaxpr(kernel)(*avals))
+        assert hashlib.sha256(text.encode()).hexdigest() == digest, slots
+
+
+@pytest.mark.parametrize("which", ["partial", "merge"])
+@pytest.mark.parametrize("case", sorted(SCHEMAS))
+def test_sort_path_kernels_touch_no_row_at_a_time(case, which):
+    """``jit(agg_partial)`` and ``jit(agg_merge)`` order their rows by
+    two-operand sorts, reduce by scans and move their planes by one gather in
+    and one out: no scatter with a batch-sized operand or update, at most two
+    gathers with batch-sized indices, no sort of more than two operands, and
+    no ``cond`` (one path, whatever the keys)."""
+    key_dtypes, names, *_ = SCHEMAS[case]
+    if which == "partial":
+        adt = tuple(KINDS[n][1] for n in names)
+        kernel = A._partial_kernel(
+            key_dtypes, tuple(KINDS[n][0] for n in names), adt, CAPACITY)
+        avals = _avals(key_dtypes, adt, CAPACITY)
+    else:
+        states = tuple(_state_dtypes(n) for n in names)
+        kernel = A._merge_kernel(
+            key_dtypes, tuple(KINDS[n][0][0] for n in names), states, CAPACITY)
+        avals = _avals(key_dtypes, [dt for dts in states for dt in dts],
+                       CAPACITY)
+    eqns = list(jaxpr_eqns(jax.make_jaxpr(kernel)(*avals).jaxpr))
+    names_seen = {e.primitive.name for e in eqns}
+    assert "cond" not in names_seen and "while" not in names_seen
+    gathers = 0
+    for eqn in eqns:
+        prim = eqn.primitive.name
+        if prim.startswith("scatter"):
+            assert max(v.aval.size for v in eqn.invars) < CAPACITY, eqn
+        elif prim == "sort":
+            assert len(eqn.invars) <= 2, eqn
+        elif prim == "gather":
+            gathers += eqn.invars[1].aval.shape[0] >= CAPACITY
+    assert 1 <= gathers <= 2
+    assert "sort" in names_seen and "cumsum" in names_seen
 
 
 # -- selection and counters ----------------------------------------------------

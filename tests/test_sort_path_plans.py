@@ -1,0 +1,69 @@
+"""q67's and q29's plans as the chip runs them, end to end on the CPU.
+
+On the CPU ``radix_agg`` defaults on and the aggregation goes through the
+radix slot table, so tier-1 otherwise never runs ``jit(agg_partial)`` /
+``jit(agg_merge)`` under a whole query (ROADMAP.md D12). Here it is off, as on
+the chip, over a cut-down ``tpcds_star`` data set whose (item, store) range
+refuses the slot table as SF1's does: every PARTIAL batch takes the sort
+path, the FINAL side merges on the device, and the answer is Acero's."""
+
+import dataclasses
+
+import pytest
+
+from tests.benchmark import helpers
+
+helpers.load_run()  # puts the benchmark's directory on sys.path
+from benchlib import plans  # noqa: E402
+from benchlib.registry import Registry  # noqa: E402
+
+# item x store -> 2,048 x 16 slots, more than a batch's capacity holds
+ROWS = {"store_sales": 20_000, "store_returns": 3_000, "item": 2_000,
+        "store": 12, "customer": 1_000}
+# the configuration each cell runs under (benchmark/configs/)
+CONFIGS = {"q67": "tpcds_sf1_chip1", "q29": "tpcds_sf1_smj_chip1"}
+
+
+@pytest.fixture(scope="module")
+def registry():
+    return Registry([helpers.BENCH_DIR])
+
+
+@pytest.fixture(scope="module")
+def star(registry, tmp_path_factory):
+    config = registry.data("configs", "tpcds_sf1_chip1")
+    config["generator_params"]["table_rows"] = ROWS
+    generator = registry.module("generators", config["generator"])
+    paths = generator.generate(str(tmp_path_factory.mktemp("star")), 7, config)
+    return plans.Dataset(paths, config["scan_partitions"],
+                         config["shuffle_partitions"])
+
+
+@pytest.mark.parametrize("query", sorted(CONFIGS))
+def test_the_chips_plan_takes_the_sort_path_and_answers_as_acero(
+        query, registry, star):
+    from blaze_tpu.config import get_config
+    from blaze_tpu.ops.joins.bhj import clear_build_cache
+    from blaze_tpu.runtime.session import Session
+    from blaze_tpu.utils.device import DEVICE_STATS
+
+    cls = registry.module("queries", query)
+    overrides = registry.data("configs", CONFIGS[query])["session"]["conf"]
+    conf = dataclasses.replace(get_config(), radix_agg=False,
+                               fused_filter_agg=False, **overrides)
+    want = plans.rows_of(cls.reference({t: star.table(t) for t in cls.TABLES}),
+                         cls.REFERENCE_COLUMNS, cls.ORDERED)
+    session = Session(conf=conf)
+    try:
+        before = DEVICE_STATS.snapshot()
+        got = session.execute_to_table(cls.plan(star))
+        after = DEVICE_STATS.snapshot()
+        merged = session.metrics.totals(("device_merge_batches",))
+    finally:
+        session.close()
+        clear_build_cache()
+    assert plans.rows_of(got, cls.ENGINE_COLUMNS, cls.ORDERED) == want
+    assert len(want) > 100 or query == "q29"  # q29 keeps the first 100 groups
+    assert after["agg_sort_batches"] - before["agg_sort_batches"] > 0
+    assert after["agg_dense_batches"] == before["agg_dense_batches"]
+    assert merged["device_merge_batches"] >= 1
