@@ -666,6 +666,20 @@ class ColumnarBatch:
 
     # --- host boundary -------------------------------------------------------
 
+    def by_type(self) -> "ColumnarBatch":
+        """The batch with every column where its TYPE says it lives. The one
+        column that is ever elsewhere is a decimal wider than int64 that a
+        window left as one int64 device plane, every value of it proved to
+        fit (ops/window_device.py); it becomes the type's host column here."""
+        stray = [i for i, c in enumerate(self.columns)
+                 if isinstance(c, DeviceColumn) and T.is_wide_decimal(c.dtype)]
+        if not stray:
+            return self
+        cols = list(self.columns)
+        for i in stray:
+            cols[i] = HostColumn(cols[i].dtype, cols[i].to_arrow(self.num_rows))
+        return ColumnarBatch(self.schema, cols, self.num_rows)
+
     def to_arrow(self) -> pa.RecordBatch:
         from blaze_tpu.utils.device import pull_columns
 

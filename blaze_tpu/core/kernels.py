@@ -645,6 +645,139 @@ def segment_scan_planes(data: jax.Array, validity: jax.Array,
     return np.asarray(out_s)[:n], np.asarray(out_c)[:n]
 
 
+# -- traced twins of the segmented scans --------------------------------------
+#
+# The same contracts inside a jitted program (``ops/window.py``'s
+# ``jit(window_scan)``): masks and planes are device arrays of one capacity,
+# carries are device scalars, and a segment restarts by the scan's own
+# operator — no ``cs[prev]`` gather a plane (PERF.md section 6, PR 27), no
+# scatter. A carry SEEDS row 0 when row 0 continues the segment the previous
+# batch left open (``seg_start[0]`` unset), which is all "head rows continue
+# the carried accumulators" needs: the scan carries the seed forward.
+
+
+def segmented_scan_traced(combine, new, planes):
+    """Inclusive scan of a tuple of row planes that restarts wherever ``new``
+    is set: every row reads ``combine`` (associative, over such tuples) of
+    its segment's rows up to itself, so a segment's last row reads the
+    segment's."""
+
+    def step(a, b):
+        merged = combine(a[1:], b[1:])
+        return (a[0] | b[0],
+                *(jnp.where(b[0], y, m) for y, m in zip(b[1:], merged)))
+
+    return lax.associative_scan(step, (new, *planes))[1:]
+
+
+def _continues(seg_start):
+    """Row 0, where it continues the segment carried in."""
+    first = jnp.arange(seg_start.shape[0], dtype=jnp.int32) == 0
+    return first & ~seg_start
+
+
+def _add(a, b):
+    return tuple(x + y for x, y in zip(a, b))
+
+
+def restarting_counters_traced(part_start, new_peer, carry_rn, carry_rank,
+                               carry_dense):
+    """:func:`restarting_counters` traced: int64 (row_number, rank,
+    dense_rank) planes from the two boundary masks and the three carried
+    scalars."""
+    cont = _continues(part_start)
+    zero = jnp.int64(0)
+    rn, dense = segmented_scan_traced(_add, part_start, (
+        jnp.where(cont, carry_rn + 1, jnp.int64(1)),
+        new_peer.astype(jnp.int64) + jnp.where(cont, carry_dense, zero)))
+    # rank: the row number at the row's peer-group start; row numbers rise
+    # within a partition, so that is a running maximum
+    at_peer = jnp.where(new_peer, rn, jnp.where(cont, carry_rank, zero))
+    (rank,) = segmented_scan_traced(
+        lambda a, b: (jnp.maximum(a[0], b[0]),), part_start, (at_peer,))
+    return rn, rank, dense
+
+
+def segment_cumsum_traced(vals, valid, seg_start, carry_sum, carry_cnt):
+    """:func:`segment_cumsum` traced, for an integer plane: int64 (sum,
+    count) planes, wrapping as int64 does."""
+    cont = _continues(seg_start)
+    zero = jnp.int64(0)
+    vals = jnp.where(valid, vals.astype(jnp.int64), zero)
+    return segmented_scan_traced(_add, seg_start, (
+        vals + jnp.where(cont, carry_sum, zero),
+        valid.astype(jnp.int64) + jnp.where(cont, carry_cnt, zero)))
+
+
+_LOW32 = 0xFFFFFFFF
+
+
+def segment_cumsum_wide_traced(lo, hi, valid, seg_start, carry_lo, carry_hi,
+                               carry_cnt):
+    """:func:`segment_cumsum` traced and exact for sums wider than int64
+    (Spark types SUM(decimal(p, s)) as decimal(p + 10, s)). A value is two
+    int64 words, ``hi * 2^64 + uint64(lo)`` (decimal128's own layout; an
+    int64 value is ``(v, v >> 63)``). The scan adds the low word's two
+    32-bit chunks and the high word apart: below 2^31 rows no chunk's sum can
+    wrap, and the carries move up once, at the end. Returns the (lo, hi,
+    count) planes; a sum fits int64 where ``hi == lo >> 63``."""
+    cont = _continues(seg_start)
+    zero, low = jnp.int64(0), jnp.int64(_LOW32)
+
+    def chunks(lo, hi, keep):
+        return tuple(jnp.where(keep, x, zero)
+                     for x in (lo & low, (lo >> 32) & low, hi))
+
+    seed = chunks(carry_lo, carry_hi, cont)
+    l0, l1, l2, cnt = segmented_scan_traced(_add, seg_start, (
+        *_add(chunks(lo, hi, valid), seed),
+        valid.astype(jnp.int64) + jnp.where(cont, carry_cnt, zero)))
+    l1 = l1 + (l0 >> 32)
+    return ((l1 & low) << 32) | (l0 & low), l2 + (l1 >> 32), cnt
+
+
+def _lex_before(a, b):
+    """Is ``a`` before ``b``? Planes compared in turn, the first signed and
+    those after it unsigned (the words of one wide value, highest first)."""
+    flip = jnp.int64(-1 << 63)
+    before, tied = a[0] < b[0], a[0] == b[0]
+    for x, y in zip(a[1:], b[1:]):
+        before = before | (tied & ((x ^ flip) < (y ^ flip)))
+        tied = tied & (x == y)
+    return before
+
+
+def segment_running_reduce_traced(vals, valid, seg_start, is_min: bool,
+                                  carry_vals, carry_has):
+    """:func:`segment_running_reduce` traced: the running extremum and a
+    plane that says whether the row's segment has held a valid value yet
+    (where it has not, the extremum reads 0). ``vals`` and ``carry_vals``
+    are tuples of planes and of scalars: one plane for a value the device
+    orders itself, (hi, lo) for a wide value in two int64 words."""
+    cont = _continues(seg_start)
+    seeded = cont & carry_has
+
+    def pick(a, b):
+        take_b = _lex_before(b, a) if is_min else _lex_before(a, b)
+        return tuple(jnp.where(take_b, y, x) for x, y in zip(a, b))
+
+    vals = tuple(jnp.where(valid, v, jnp.zeros((), v.dtype)) for v in vals)
+    seeds = tuple(jnp.broadcast_to(c, v.shape).astype(v.dtype)
+                  for c, v in zip(carry_vals, vals))
+    vals = tuple(jnp.where(seeded, jnp.where(valid, p, c), v) for v, c, p in
+                 zip(vals, seeds, pick(seeds, vals)))
+
+    def combine(a, b):
+        (ha, *va), (hb, *vb) = a, b
+        both = pick(va, vb)
+        return (ha | hb, *(jnp.where(ha & hb, m, jnp.where(hb, y, x))
+                           for x, y, m in zip(va, vb, both)))
+
+    has, *ext = segmented_scan_traced(combine, seg_start,
+                                      (valid | seeded, *vals))
+    return tuple(ext), has
+
+
 def concat_planes(per_field_datas: List[Tuple[jax.Array, ...]],
                   per_field_valids: List[Tuple[jax.Array, ...]],
                   num_rows: Sequence[int], out_cap: int):

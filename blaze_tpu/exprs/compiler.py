@@ -267,6 +267,11 @@ class ExprEvaluator:
         col = batch.columns[expr.index]
         dt = batch.schema[expr.index].dtype
         if isinstance(col, DeviceColumn):
+            if T.is_wide_decimal(dt):
+                # a wide decimal that a window left as a proved int64 plane
+                # (ops/window_device.py): expressions read the type's form,
+                # decimal128 on the host, exact at every digit
+                return HostVal(dt, col.to_arrow(batch.num_rows))
             return DevVal(dt, col.data, col.validity)
         return HostVal(dt, col.array)
 

@@ -133,6 +133,12 @@ class DecimalType(DataType):
         return self.precision <= self.MAX_INT64_PRECISION
 
 
+def is_wide_decimal(dt: DataType) -> bool:
+    """A decimal whose unscaled values may pass int64: by type a host column
+    of decimal128."""
+    return isinstance(dt, DecimalType) and not dt.fits_int64
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class ArrayType(DataType):
     element_type: DataType = None

@@ -64,6 +64,14 @@ class AggExec(Operator):
         super().__init__(schema, [child])
 
     @property
+    def takes_wide_planes(self) -> bool:
+        """Raw rows' wide-decimal arguments may arrive as a window's proved
+        int64 plane: the device aggers cut their limbs from it
+        (agg_device._proved_limbs), everything else reads it through the
+        evaluator, as its type."""
+        return not self.input_is_partial
+
+    @property
     def is_partial_output(self) -> bool:
         return bool(self.aggs) and all(
             a.mode in (E.AggMode.PARTIAL, E.AggMode.PARTIAL_MERGE) for a in self.aggs

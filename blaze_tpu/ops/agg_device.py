@@ -133,16 +133,27 @@ def _host_wide_planes(col, capacity: int):
             jnp.asarray(valid))
 
 
+def _proved_limbs(data):
+    """The three limb planes of a wide decimal that rides as ONE int64 plane
+    (a window's result whose every value the device proved to fit,
+    ops/window_device): the low word's two 32-bit chunks and the sign."""
+    low = jnp.int64(0xFFFFFFFF)
+    return data & low, (data >> 32) & low, data >> 63
+
+
 def _flatten_cols(batch: ColumnarBatch):
     """jit-argument planes for a batch: 2 per device column, 4 (limbs +
-    validity) per wide-decimal host column. The schema determines the
-    layout, so kernels cache correctly on (schema, capacity) keys."""
+    validity) per wide-decimal column, whether it is a host column or a
+    proved int64 plane. The schema determines the layout, so kernels cache
+    correctly on (schema, capacity) keys."""
     flat = []
     for c, f in zip(batch.columns, batch.schema.fields):
-        if isinstance(c, DeviceColumn):
+        if _is_wide_dec(f.dtype):
+            flat += [*_proved_limbs(c.data), c.validity] \
+                if isinstance(c, DeviceColumn) \
+                else list(_host_wide_planes(c, batch.capacity))
+        elif isinstance(c, DeviceColumn):
             flat += [c.data, c.validity]
-        elif _is_wide_dec(f.dtype):
-            flat += list(_host_wide_planes(c, batch.capacity))
         else:
             raise TypeError(
                 f"column {f.name} ({f.dtype}) is not jit-flattenable")
@@ -478,12 +489,13 @@ class DevicePartialAgger:
             elif kind in _WIDE_KINDS:
                 arg = a.agg.args[0]
                 planes = valid = None
-                if isinstance(arg, E.Column):
+                if isinstance(arg, (E.Column, E.BoundReference)):
                     # bare-column wide args read the batch's limb planes
                     # directly — works in BOTH eager and traced contexts
                     # (_WideLimbCol in a virtual batch, HostColumn eagerly)
                     try:
-                        idx = batch.schema.index_of(arg.name)
+                        idx = arg.index if isinstance(arg, E.BoundReference) \
+                            else batch.schema.index_of(arg.name)
                     except (KeyError, ValueError):
                         idx = None
                     if idx is not None:
@@ -491,7 +503,10 @@ class DevicePartialAgger:
                         if isinstance(col, _WideLimbCol):
                             planes = (col.l0, col.l1, col.l2)
                             valid = col.validity
-                        elif not isinstance(col, DeviceColumn):
+                        elif isinstance(col, DeviceColumn):
+                            planes, valid = _proved_limbs(col.data), \
+                                col.validity
+                        else:
                             p4 = _host_wide_planes(col, batch.capacity)
                             planes, valid = p4[:3], p4[3]
                 if planes is None:
@@ -1096,18 +1111,7 @@ def _order_word(d):
     return jnp.where(bits >= top, ~bits, bits | top).astype(jnp.uint64)
 
 
-def _segmented_scan(combine, new, planes):
-    """Inclusive scan of a tuple of row planes that restarts wherever ``new``
-    is set: every row reads ``combine`` (associative, over such tuples) of
-    its segment's rows up to itself, so a segment's last row reads the
-    segment's."""
-
-    def step(a, b):
-        merged = combine(a[1:], b[1:])
-        return (a[0] | b[0],
-                *(jnp.where(b[0], y, m) for y, m in zip(b[1:], merged)))
-
-    return jax.lax.associative_scan(step, (new, *planes))[1:]
+_segmented_scan = K.segmented_scan_traced
 
 
 def _lex3_pick(is_max: bool):

@@ -217,9 +217,19 @@ class Operator:
                  ) -> Iterator[ColumnarBatch]:
         raise NotImplementedError
 
+    # Does ``_execute`` know a wide decimal that arrives as one int64 device
+    # plane (a window's result whose values fit, ops/window_device.py)? Every
+    # other operator is handed such a column as its type's host column.
+    takes_wide_planes = False
+
     def execute_child(self, i: int, partition: int, ctx: ExecContext,
                       metrics: MetricNode) -> Iterator[ColumnarBatch]:
-        return self.children[i].execute(partition, ctx, metrics.child(i))
+        batches = self.children[i].execute(partition, ctx, metrics.child(i))
+        if self.takes_wide_planes or not any(
+                T.is_wide_decimal(f.dtype)
+                for f in self.children[i].schema.fields):
+            return batches
+        return (b.by_type() for b in batches)
 
     def __repr__(self):
         return f"{self.name}({', '.join(repr(c) for c in self.children)})"

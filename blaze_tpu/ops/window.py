@@ -29,7 +29,8 @@ from typing import Iterator, List, Optional
 import numpy as np
 import pyarrow as pa
 
-from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn, HostColumn
+from blaze_tpu.core.batch import (ColumnarBatch, DeviceColumn, HostColumn,
+                                  _arrow_to_column)
 from blaze_tpu.exprs.compiler import ExprEvaluator
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
@@ -128,9 +129,12 @@ class WindowExec(Operator):
         schema = self._output_schema(child.schema)
         super().__init__(schema, [child])
 
-    def _output_schema(self, child_schema: T.Schema) -> T.Schema:
-        if not self.output_window_cols:
-            return child_schema
+    @property
+    def takes_wide_planes(self) -> bool:
+        return self._device_spec() is not None
+
+    def _window_fields(self, child_schema: T.Schema) -> List[T.StructField]:
+        """The window columns, typed, whether or not they are put out."""
         extra = []
         for w in self.window_exprs:
             if w.kind == "agg":
@@ -141,7 +145,13 @@ class WindowExec(Operator):
             else:
                 dt = w.return_type or (T.I32 if w.kind in ("rank", "dense_rank") else T.I64)
             extra.append(T.StructField(w.name, dt))
-        return T.Schema(child_schema.fields + tuple(extra))
+        return extra
+
+    def _output_schema(self, child_schema: T.Schema) -> T.Schema:
+        if not self.output_window_cols:
+            return child_schema
+        return T.Schema(child_schema.fields
+                        + tuple(self._window_fields(child_schema)))
 
     def _segmentable(self) -> bool:
         """Rank-family counters and default-frame aggregates compute as
@@ -152,7 +162,34 @@ class WindowExec(Operator):
                    or (w.kind == "agg" and w.frame is None)
                    for w in self.window_exprs)
 
+    def _device_spec(self):
+        """The device programs' spec (ops/window_device), or None: this
+        window takes the host paths below, and its batches count as
+        ``window_host_batches``."""
+        from blaze_tpu.ops import window_device as WD
+
+        child_schema = self.children[0].schema
+        spec = WD.plan(self.window_exprs, self.partition_spec,
+                       self.order_spec, child_schema,
+                       [f.dtype for f in self._window_fields(child_schema)])
+        if spec is not None and self.group_limit is not None and \
+                self._limit_expr() is None:
+            return None  # the plane the limit filters on is not computed
+        return spec
+
+    def _limit_expr(self) -> Optional[int]:
+        """Which window expression's plane ``group_limit`` filters on
+        (:meth:`_limit_vals`), if one of them is that plane."""
+        kinds = [w.kind for w in self.window_exprs]
+        want = "rank" if set(kinds) == {"rank"} else \
+            "dense_rank" if set(kinds) == {"dense_rank"} else "row_number"
+        return kinds.index(want) if want in kinds else None
+
     def _execute(self, partition, ctx, metrics):
+        spec = self._device_spec()
+        if spec is not None:
+            yield from self._execute_device(spec, partition, ctx, metrics)
+            return
         if self._segmentable():
             yield from self._execute_segmented(partition, ctx, metrics)
             return
@@ -195,6 +232,8 @@ class WindowExec(Operator):
             n = batch.num_rows
             if n == 0:
                 continue
+            metrics.add("window_host_batches", 1)
+            metrics.add("window_rows", n)
             # self-time lands in elapsed_compute_time_ns via Operator.execute
             if part_ev is None:
                 ch = np.zeros(n, dtype=bool)
@@ -215,6 +254,78 @@ class WindowExec(Operator):
                     yield from process_partition()
                 pending.append(batch.slice(s, e - s))
         yield from process_partition()
+
+    # -- device execution (counters + running-frame aggregates) ---------------
+
+    def _execute_device(self, spec, partition, ctx, metrics):
+        """One ``jit(window_scan)`` a batch (ops/window_device): the sorted
+        batch's key and argument planes in, the window columns' planes out,
+        the carry a device value from batch to batch, exact at any width.
+        The host waits only where a result is typed wider than int64
+        (``sync:window_carry``, one flag a batch): where every value of the
+        batch fits, the column goes on as one int64 device plane; where one
+        does not, as the type's host column (``wide_host_batches``)."""
+        from blaze_tpu.core import kernels as K
+        from blaze_tpu.ops import window_device as WD
+        from blaze_tpu.utils.device import wait_int
+
+        child_schema = self.children[0].schema
+        result_types = [f.dtype for f in self._window_fields(child_schema)]
+        key_exprs = list(self.partition_spec) + \
+            [so.child for so in self.order_spec]
+        arg_exprs = [w.agg.args[0] if w.kind == "agg" and w.agg.args else None
+                     for w in self.window_exprs]
+        wide = [WD.is_wide_result(e) for e in spec.exprs]
+        limit_at = self._limit_expr() if self.group_limit is not None else None
+
+        def finish(batch, planes, fits):
+            n = batch.num_rows
+            fits = not any(wide) or bool(wait_int(fits, "window_carry"))
+            if not fits:
+                metrics.add("wide_host_batches", 1)
+            cols = [
+                WD.wide_host_column(dt, *p, n) if w and not fits else
+                DeviceColumn(dt, p[0], p[-1])
+                for dt, p, w in zip(result_types, planes, wide)]
+            out = ColumnarBatch(self.schema, list(batch.columns) + cols, n) \
+                if self.output_window_cols else batch
+            if limit_at is not None:
+                out = _keep_at_most(out, cols[limit_at], self.group_limit)
+            if out is not None:
+                yield out
+
+        # where there is a flag to wait for, a batch leaves once the NEXT
+        # one's program is enqueued: the carry is exact either way and needs
+        # no flag, so the wait for batch k's falls behind batch k + 1's work
+        # and the device is not left idle for it (2.6% of q51's query_s on
+        # the chip, PERF.md section 6, PR 31)
+        carry = ahead = None
+        for batch in self.execute_child(0, partition, ctx, metrics):
+            n = batch.num_rows
+            if n == 0:
+                continue
+            metrics.add("window_rows", n)
+            metrics.add("window_device_batches", 1)
+            cap = batch.capacity
+            keys = [_planes(_argument(e, batch), cap) for e in key_exprs]
+            args = [None if e is None else _planes(_argument(e, batch), cap)
+                    for e in arg_exprs]
+            if carry is None:
+                carry = WD.initial_carry(
+                    spec, [k[0].dtype for k in keys],
+                    [None if a is None else a[0].dtype for a in args])
+            planes, carry, fits = K._dispatch(
+                WD.window_scan, np.int32(n), tuple(keys), tuple(args), carry,
+                spec=spec, cap=cap)
+            done, ahead = (ahead, (batch, planes, fits)) if any(wide) else \
+                ((batch, planes, fits), None)
+            if done is not None:
+                yield from finish(*done)
+        if ahead is not None:
+            yield from finish(*ahead)
+        if carry is not None:
+            metrics.add("window_segments",
+                        wait_int(carry["segments"], "window_carry"))
 
     # -- segmented execution (counters + default-frame aggregates) ------------
 
@@ -276,6 +387,8 @@ class WindowExec(Operator):
                 n = batch.num_rows
                 if n == 0:
                     continue
+                metrics.add("window_host_batches", 1)
+                metrics.add("window_rows", n)
                 if part_ev is None:
                     part_start = np.zeros(n, dtype=bool)
                     part_start[0] = not started
@@ -728,6 +841,52 @@ class WindowExec(Operator):
         fvals = fval.tolist() if agg.fn in (F.MIN, F.MAX) else [None] * n
         return self._agg_result_col(w, child_schema, fsum.tolist(),
                                     fcnt.tolist(), fvals)
+
+
+def _argument(expr: E.Expr, batch: ColumnarBatch):
+    """A key or an aggregate's argument as a column. A bare column is taken
+    as the batch holds it: the evaluator pays two eager dispatches a column
+    reference (PERF.md section 7), and a wide decimal that an earlier window
+    proved into an int64 plane is wanted as that plane."""
+    if isinstance(expr, E.Column):
+        return batch.columns[batch.schema.index_of(expr.name)]
+    if isinstance(expr, E.BoundReference):
+        return batch.columns[expr.index]
+    return ExprEvaluator([expr], batch.schema).evaluate(batch)[0]
+
+
+def _planes(col, capacity: int):
+    """A key's or an argument's column as ``jit(window_scan)`` takes it:
+    (data, validity), or a wide decimal's host column as its two words."""
+    from blaze_tpu.ops import window_device as WD
+
+    if isinstance(col, DeviceColumn):
+        return col.data, col.validity
+    if WD.is_wide_decimal(col.dtype):
+        return WD.wide_words(col, capacity)
+    col = _arrow_to_column(col.array, col.dtype, capacity)
+    return col.data, col.validity
+
+
+def _keep_at_most(out: ColumnarBatch, limit_col, k: int):
+    """``out``'s rows whose ``limit_col`` (a device plane: rank, dense_rank
+    or row_number) is at most ``k``: one compaction, or None if no row is."""
+    from blaze_tpu.core import kernels as K
+    from blaze_tpu.utils.device import wait_array
+
+    mask = limit_col.validity & (limit_col.data <= k)
+    if len(out._device_slots()) < len(out.columns):  # host payload columns
+        keep = np.nonzero(wait_array(mask, "window_limit")[:out.num_rows])[0]
+        return out.take(keep) if len(keep) else None
+    count, datas, valids = K.compact_planes(
+        [c.data for c in out.columns], [c.validity for c in out.columns], mask)
+    if count == 0:
+        return None
+    if count == out.num_rows:
+        return out
+    return ColumnarBatch(out.schema, [
+        DeviceColumn(c.dtype, d, v)
+        for c, d, v in zip(out.columns, datas, valids)], count)
 
 
 def _offset(keys: np.ndarray, off) -> np.ndarray:

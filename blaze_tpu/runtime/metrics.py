@@ -179,6 +179,22 @@ class MetricNode:
 #                                    on the device: partitions that took the
 #                                    host's key interning instead (var-width
 #                                    or host-resident keys, a condition)
+#   window_device_batches            window batches computed by the device
+#                                    program (ops/window_device.py: the rank
+#                                    family and running-frame SUM / COUNT /
+#                                    MIN / MAX over fixed-width keys)
+#   window_host_batches == 0         ... on plans of such windows: batches
+#                                    that took a host path (default or
+#                                    offset frames, var-width or float keys,
+#                                    AVG, float arguments)
+#   wide_host_batches == 0           ... on plans over money: batches of the
+#                                    device program with a wide-decimal
+#                                    result past int64, which left as the
+#                                    type's host decimal128 column and not
+#                                    as one int64 device plane
+#   window_rows                      rows every window operator saw, either
+#                                    path (what the benchmark's
+#                                    window_roofline_share counts bytes from)
 TRIPWIRE_METRICS = (
     "split_batches",
     "split_gathers",
@@ -203,6 +219,10 @@ TRIPWIRE_METRICS = (
     "collective_bytes",
     "smj_device_joins",
     "smj_host_joins",
+    "window_device_batches",
+    "window_host_batches",
+    "wide_host_batches",
+    "window_rows",
 )
 
 
