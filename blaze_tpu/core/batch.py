@@ -515,7 +515,8 @@ class ColumnarBatch:
 
     def take(self, indices: np.ndarray) -> "ColumnarBatch":
         """Host-driven row gather (indices must be < num_rows). All device
-        columns move in ONE jitted dispatch (core/kernels.py)."""
+        columns move in ONE jitted dispatch and one device gather, side by
+        side as a matrix of words (core/kernels.take_rows_traced)."""
         from blaze_tpu.core import kernels
 
         indices = np.asarray(indices, dtype=np.int64)
@@ -537,7 +538,7 @@ class ColumnarBatch:
 
     def take_nullable(self, indices: np.ndarray) -> "ColumnarBatch":
         """Row gather where index -1 yields an all-null row (outer-join null
-        extension)."""
+        extension); the device columns as in :meth:`take`."""
         from blaze_tpu.core import kernels
 
         indices = np.asarray(indices, dtype=np.int64)
@@ -567,8 +568,9 @@ class ColumnarBatch:
         return ColumnarBatch(schema, cols, n)
 
     def slice(self, offset: int, length: int) -> "ColumnarBatch":
-        """Contiguous row window: one jitted dynamic-slice dispatch for all
-        device columns, zero-copy arrow slices for host columns."""
+        """Contiguous row window: ONE jitted dispatch for all device columns,
+        a slice copy a plane (no index is built, nothing gathered); zero-copy
+        arrow slices for host columns."""
         from blaze_tpu.core import kernels
 
         length = max(0, min(length, self.num_rows - offset))
@@ -576,8 +578,6 @@ class ColumnarBatch:
         slots = self._device_slots()
         cols: List[Column] = list(self.columns)
         if slots:
-            if cap > self.capacity:
-                return self.take(np.arange(offset, offset + length))
             datas, valids = kernels.slice_planes(
                 [self.columns[i].data for i in slots],
                 [self.columns[i].validity for i in slots],
@@ -592,9 +592,11 @@ class ColumnarBatch:
     @staticmethod
     def concat(batches: List["ColumnarBatch"], schema: Optional[T.Schema] = None) -> "ColumnarBatch":
         """Coalesce small batches (reference: coalesce_batches_unchecked).
-        Device planes concatenate+compact in one jitted dispatch; host arrays
-        via arrow concat — no arrow round trip for device data (the round-1
-        profiler's top fixed cost)."""
+        Device planes concatenate+compact in ONE jitted dispatch of slice
+        copies — each batch's planes written whole at its row offset, over the
+        padding of the one before, nothing gathered; host arrays via arrow
+        concat — no arrow round trip for device data (the round-1 profiler's
+        top fixed cost)."""
         from blaze_tpu.core import kernels
 
         if not batches:
@@ -621,13 +623,6 @@ class ColumnarBatch:
         ncols = len(batches[0].columns)
         cols: List[Column] = [None] * ncols
         if slots:
-            # concat_planes assumes each batch's device columns share one
-            # capacity (one index space per batch) — normalize stragglers
-            batches = [
-                b if len({b.columns[i].capacity for i in slots}) == 1
-                else b.with_capacity(max(b.columns[i].capacity for i in slots))
-                for b in batches
-            ]
             # multichip sessions feed batches committed to DIFFERENT mesh
             # devices (sharded fused outputs, device-tier shuffle segments);
             # one dispatch over mixed commitments raises, so align stragglers

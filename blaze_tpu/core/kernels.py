@@ -8,6 +8,15 @@ actual work at batch sizes. These kernels take ALL of a batch's device
 columns at once as a pytree, so one ``jax.jit`` dispatch moves the whole
 batch; jit's cache is keyed by (pytree structure, shapes, dtypes), and the
 capacity-bucket discipline (config.capacity_for) makes those recur.
+
+Rows move in one of two ways, chosen by what the caller knows of its
+indexes: a contiguous move (``concat_planes``, ``slice_planes``) copies
+slices and gathers nothing; a permutation move (``gather_planes``,
+``compact_planes``, ``ops/sort.sort_take``, the joins' and the aggregation's
+kernels) is ONE gather of the planes laid side by side as a matrix of 32-bit
+words (``take_rows_traced``). No mover gathers a plane at a time: on the TPU
+a gather costs by the index, 0.94 ms for each 32-bit plane at 131,072
+indexes (PERF.md §6, PR 27 and PR 32).
 """
 
 from __future__ import annotations
@@ -168,83 +177,64 @@ def align_planes(datas: Sequence[jax.Array], valids: Sequence[jax.Array],
 
 @jax.jit
 def _gather(datas, valids, idx, live):
-    # per-field clip: columns of one batch may carry different capacities
-    # (e.g. agg state columns assembled at another bucket); live rows index
-    # only [0, num_rows) which is within every column's capacity
-    out_d = tuple(
-        jnp.where(live, d[jnp.clip(idx, 0, d.shape[0] - 1)],
-                  jnp.zeros((), d.dtype))
-        for d in datas)
-    out_v = tuple(v[jnp.clip(idx, 0, v.shape[0] - 1)] & live for v in valids)
-    return out_d, out_v
+    with jax.named_scope("move"):
+        return take_rows_traced(datas, valids, idx, live)
 
 
 @jax.jit
 def _gather_n(datas, valids, idx, n_out):
-    live = jnp.arange(idx.shape[0]) < n_out
-    out_d = tuple(
-        jnp.where(live, d[jnp.clip(idx, 0, d.shape[0] - 1)],
-                  jnp.zeros((), d.dtype))
-        for d in datas)
-    out_v = tuple(v[jnp.clip(idx, 0, v.shape[0] - 1)] & live for v in valids)
-    return out_d, out_v
+    live = jnp.arange(idx.shape[0], dtype=jnp.int32) < n_out
+    with jax.named_scope("move"):
+        return take_rows_traced(datas, valids, idx, live)
 
 
 def gather_planes(datas: Sequence[jax.Array], valids: Sequence[jax.Array],
                   idx: np.ndarray, out_cap: int, n_out: int,
                   null_mask: np.ndarray = None):
-    """Gather rows from every (data, validity) plane in ONE jitted dispatch.
+    """Rows ``idx`` of every (data, validity) plane in ONE jitted dispatch and
+    ONE device gather: the planes travel side by side as a matrix of 32-bit
+    words (:func:`take_rows_traced`).
 
-    ``idx`` is host int64 of length n_out (already < num_rows); rows where
-    ``null_mask`` is True come out null (outer-join extension). The common
-    no-null-mask case computes the live prefix mask ON DEVICE from the
-    traced count — uploading it was a capacity-sized host->device transfer
-    per call carrying information already present in one scalar."""
-    buf = np.zeros(out_cap, dtype=np.int64)
+    ``idx`` is a host array of length n_out (already < num_rows, so it goes
+    up as int32); rows where ``null_mask`` is True come out null (outer-join
+    extension). The common no-null-mask case computes the live prefix mask
+    ON DEVICE from the traced count — uploading it was a capacity-sized
+    host->device transfer per call carrying information already present in
+    one scalar."""
+    buf = np.zeros(out_cap, dtype=np.int32)
     buf[:n_out] = idx
     if null_mask is None:
         return _dispatch(_gather_n, tuple(datas), tuple(valids),
-                         jnp.asarray(buf), jnp.int64(n_out))
+                         jnp.asarray(buf), jnp.int32(n_out))
     lbuf = np.zeros(out_cap, dtype=bool)
     lbuf[:n_out] = ~null_mask
     return _dispatch(_gather, tuple(datas), tuple(valids), jnp.asarray(buf), jnp.asarray(lbuf))
 
 
-def take_planes_traced(datas, valids, idx, live):
-    """Traced: rows ``idx`` of every (data, validity) plane where ``live``,
-    else the padding contract (data 0, validity False). A gather costs the
-    chip as much for a bool plane as for an int32 one (PERF.md §6, PR 26),
-    so the validity planes travel as ONE bit-packed plane, 32 columns a
-    word. ``idx`` is clipped per plane: a batch's columns may differ in
-    capacity."""
-    out_d = tuple(
-        jnp.where(live, d[jnp.clip(idx, 0, d.shape[0] - 1)],
-                  jnp.zeros((), d.dtype)) for d in datas)
-    out_v = []
-    for at in range(0, len(valids), 32):
-        group = valids[at:at + 32]
-        cap = min(v.shape[0] for v in group)
-        word = jnp.zeros(cap, jnp.uint32)
-        for bit, v in enumerate(group):
-            word = word | (v[:cap].astype(jnp.uint32) << bit)
-        word = word[jnp.clip(idx, 0, cap - 1)]
-        out_v += [((word >> bit) & 1).astype(bool) & live
-                  for bit in range(len(group))]
-    return out_d, tuple(out_v)
+# The word matrix of `take_rows_traced` at most this large (a row of it laid to
+# sublanes of 8 words): at 1,048,576 rows 13 words (64 MiB) gather in 7.5 ms
+# and 25 words (128 MiB) in 42; in two matrices the 25 take what two gathers
+# take (PERF.md section 6, PR 32). The width alone costs nothing: 143 and 263
+# words at 131,072 rows read 8.7 and 16.3 ms, 0.06 ms a word as at 25.
+_WORD_MATRIX_BYTES = 64 << 20
 
 
 def take_rows_traced(datas, valids, idx, live):
-    """:func:`take_planes_traced`'s contract with ONE gather for all the
-    planes. On the TPU a gather costs by the index, not by what each index
-    fetches: at 131,072 rows six int64 planes gathered one by one (twelve
-    gathers, a 64-bit plane being two 32-bit ones) read 11.4 ms, and the
-    same planes laid side by side as one matrix of 32-bit words, a row an
-    index, 0.49 ms (PERF.md §6, PR 27). So every data plane is cut into
-    uint32 words (8 bytes: two, bit for bit; 4 bytes: one; narrower: widened
-    to int32), the validity planes are bit-packed 32 to a word, and the
-    words of a row travel together. Planes of different capacities are cut
-    to the shortest: ``idx`` names rows below the batch's row count, which
-    every plane holds."""
+    """Traced: rows ``idx`` of every (data, validity) plane where ``live``,
+    else the padding contract (data 0, validity False), with ONE gather for
+    all the planes — how every mover of this module and every operator's
+    kernel applies a permutation. On the TPU a gather costs by the index,
+    not by what each index fetches: at 131,072 rows six int64 planes
+    gathered one by one (twelve gathers, a 64-bit plane being two 32-bit
+    ones) read 11.4 ms, and the same planes laid side by side as one matrix
+    of 32-bit words, a row an index, 0.49 ms (PERF.md §6, PR 27). So every
+    data plane is cut into uint32 words (8 bytes: two, bit for bit, so NaN
+    payloads and -0.0 survive; 4 bytes: one; narrower: widened to int32),
+    the validity planes are bit-packed 32 to a word, and the words of a row
+    travel together — in one matrix up to ``_WORD_MATRIX_BYTES``, past it
+    in as few as stay under it, by a rule on the static shapes alone.
+    Planes of different capacities are cut to the shortest: ``idx`` names
+    rows below the batch's row count, which every plane holds."""
     if not datas and not valids:
         return (), ()
     cap = min(p.shape[0] for p in (*datas, *valids))
@@ -257,29 +247,49 @@ def take_rows_traced(datas, valids, idx, live):
         for bit, v in enumerate(valids[at:at + 32]):
             word = word | (v[:cap].astype(jnp.uint32) << bit)
         blocks.append(word[:, None])
-    rows = jnp.concatenate(blocks, axis=1)[jnp.clip(idx, 0, cap - 1)]
-    rows = jnp.where(live[:, None], rows, jnp.uint32(0))
-    out_d, at = [], 0
-    for d, w, block in zip(datas, wide, blocks):
-        words = rows[:, at:at + block.shape[1]]
-        at += block.shape[1]
+    # one matrix while it stays small: the words go in groups, a gather each
+    width = max(8, _WORD_MATRIX_BYTES // (4 * cap) // 8 * 8)
+    place, groups, used = [], [[]], 0  # place: a block's (matrix, first word)
+    for block in blocks:
+        if used and used + block.shape[1] > width:
+            groups.append([])
+            used = 0
+        place.append((len(groups) - 1, used))
+        groups[-1].append(block)
+        used += block.shape[1]
+    matrices = [jnp.concatenate(group, axis=1) for group in groups]
+    idx = jnp.clip(idx, 0, cap - 1)
+    if idx.dtype.itemsize > 4:  # a capacity fits int32; the gather follows it
+        idx = idx.astype(jnp.int32)
+    rows = []
+    for matrix in matrices:
+        got = matrix[idx]
+        rows.append(jnp.where(live[:, None], got, jnp.uint32(0)))
+    out_d = []
+    for d, w, block, (g, at) in zip(datas, wide, blocks, place):
+        words = rows[g][:, at:at + block.shape[1]]
         out_d.append(lax.bitcast_convert_type(
             words if block.shape[1] == 2 else words[:, 0], w).astype(d.dtype))
-    out_v = [((rows[:, at + bit // 32] >> (bit % 32)) & 1).astype(bool)
-             for bit in range(len(valids))]
+    out_v = []
+    for bit in range(len(valids)):
+        g, at = place[len(datas) + bit // 32]
+        out_v.append(((rows[g][:, at] >> (bit % 32)) & 1).astype(bool))
     return tuple(out_d), tuple(out_v)
 
 
 @jax.jit
 def _compact(datas, valids, mask):
-    count = jnp.sum(mask)
-    order = jnp.argsort(~mask, stable=True)
-    live = jnp.arange(order.shape[0]) < count
-    out_d = tuple(
-        jnp.where(live, d[jnp.clip(order, 0, d.shape[0] - 1)],
-                  jnp.zeros((), d.dtype))
-        for d in datas)
-    out_v = tuple(v[jnp.clip(order, 0, v.shape[0] - 1)] & live for v in valids)
+    iota = jnp.arange(mask.shape[0], dtype=jnp.int32)
+    with jax.named_scope("order"):
+        # the kept rows' places, in row order: the payload of a stable
+        # two-operand sort of the mask (PERF.md §6, PR 27: 0.4 ms at 131,072
+        # rows, where a scatter of the prefix sums reads 0.7)
+        count = jnp.sum(mask)
+        _, order = lax.sort(((~mask).astype(jnp.uint8), iota), num_keys=1,
+                            is_stable=True)
+        live = iota < count
+    with jax.named_scope("move"):
+        out_d, out_v = take_rows_traced(datas, valids, order, live)
     return count, out_d, out_v
 
 
@@ -293,27 +303,39 @@ def compact_planes(datas: Sequence[jax.Array], valids: Sequence[jax.Array],
     return wait_int(count, "compact"), out_d, out_v
 
 
+def _mask_planes(datas, valids, live):
+    """The padding contract over planes already in place: data 0 and
+    validity False where ``live`` is not set."""
+    return (tuple(jnp.where(live, d, jnp.zeros((), d.dtype)) for d in datas),
+            tuple(v & live for v in valids))
+
+
 @functools.partial(jax.jit, static_argnames=("out_cap",))
 def _dyn_slice(datas, valids, offset, length, out_cap):
-    # gather with a traced offset rather than lax.dynamic_slice: dynamic_slice
-    # CLAMPS its start index whenever offset + out_cap > capacity, silently
-    # returning the wrong window
-    live = jnp.arange(out_cap) < length
-    idx = offset + jnp.arange(out_cap)
-    out_d = tuple(
-        jnp.where(live, d[jnp.clip(idx, 0, d.shape[0] - 1)],
-                  jnp.zeros((), d.dtype))
-        for d in datas)
-    out_v = tuple(v[jnp.clip(idx, 0, v.shape[0] - 1)] & live for v in valids)
-    return out_d, out_v
+    # lax.dynamic_slice CLAMPS its start whenever offset + out_cap passes the
+    # plane's end, silently returning another window; over a plane padded by
+    # out_cap rows it cannot (offset <= capacity), and the pad fuses into the
+    # slice. A copy, not a gather: no index is built or followed.
+    def window(plane):
+        padded = jnp.concatenate(
+            [plane, jnp.zeros((out_cap,), plane.dtype)])
+        return lax.dynamic_slice(padded, (offset,), (out_cap,))
+
+    with jax.named_scope("copy"):
+        datas = tuple(window(d) for d in datas)
+        valids = tuple(window(v) for v in valids)
+    with jax.named_scope("mask"):
+        live = jnp.arange(out_cap, dtype=jnp.int32) < length
+        return _mask_planes(datas, valids, live)
 
 
 def slice_planes(datas: Sequence[jax.Array], valids: Sequence[jax.Array],
                  offset: int, length: int, out_cap: int):
-    """Contiguous row window in ONE jitted dispatch; offset/length are traced
-    so every slice of the same shapes reuses one compiled program."""
+    """Contiguous row window in ONE jitted dispatch, a slice copy a plane;
+    offset/length are traced so every slice of the same shapes reuses one
+    compiled program."""
     return _dispatch(_dyn_slice, tuple(datas), tuple(valids),
-                     jnp.int64(offset), jnp.int64(length), out_cap=out_cap)
+                     jnp.int32(offset), jnp.int32(length), out_cap=out_cap)
 
 
 def _key_ops_traced(datas, valids, exists, spec):
@@ -486,16 +508,26 @@ def range_partition_order(datas, valids, exists, bound_ops, spec):
                      tuple(bound_ops), spec)
 
 
-@jax.jit
-def _concat_gather(datas, valids, idx, total):
-    # live prefix mask derived on device from the traced row total — the
-    # former host-built bool plane was a capacity-sized upload per concat
-    live = jnp.arange(idx.shape[0]) < total
-    big_d = tuple(jnp.concatenate(parts) for parts in datas)
-    big_v = tuple(jnp.concatenate(parts) for parts in valids)
-    out_d = tuple(jnp.where(live, d[idx], jnp.zeros((), d.dtype)) for d in big_d)
-    out_v = tuple(v[idx] & live for v in big_v)
-    return out_d, out_v
+@functools.partial(jax.jit, static_argnames=("out_cap",))
+def _concat_gather(datas, valids, offsets, out_cap):
+    # (the name is the trace's: the benchmark's breakdown reads it; nothing
+    # is gathered.) Part j's live rows are a prefix of it and land at
+    # offsets[j], so the parts are written whole, in order, each over the
+    # padding tail of the one before: offsets[j] + cap_j never passes the sum
+    # of the capacities, so no update clamps. offsets[-1] is the row total.
+    def lay(parts):
+        rows = max(out_cap, sum(p.shape[0] for p in parts))
+        buf = jnp.zeros((rows,), parts[0].dtype)
+        for j, part in enumerate(parts):
+            buf = lax.dynamic_update_slice(buf, part, (offsets[j],))
+        return buf[:out_cap]
+
+    with jax.named_scope("copy"):
+        datas = tuple(lay(parts) for parts in datas)
+        valids = tuple(lay(parts) for parts in valids)
+    with jax.named_scope("mask"):
+        live = jnp.arange(out_cap, dtype=jnp.int32) < offsets[-1]
+        return _mask_planes(datas, valids, live)
 
 
 # -- segmented scans ---------------------------------------------------------
@@ -782,23 +814,18 @@ def concat_planes(per_field_datas: List[Tuple[jax.Array, ...]],
                   per_field_valids: List[Tuple[jax.Array, ...]],
                   num_rows: Sequence[int], out_cap: int):
     """Concatenate k batches' planes field-wise and compact live rows, in ONE
-    jitted dispatch (replaces the arrow round trip the profiler flagged in
-    ColumnarBatch.concat). ``per_field_datas[f]`` is the f-th field's array
-    from each input batch; ``num_rows[j]`` is batch j's live row count."""
-    caps = [d.shape[0] for d in per_field_datas[0]]
-    total = int(sum(num_rows))
-    idx = np.zeros(out_cap, dtype=np.int64)
-    pos = 0
-    base = 0
-    for cap_j, n_j in zip(caps, num_rows):
-        idx[pos:pos + n_j] = np.arange(base, base + n_j)
-        pos += n_j
-        base += cap_j
+    jitted dispatch of slice copies (replaces the arrow round trip the
+    profiler flagged in ColumnarBatch.concat). ``per_field_datas[f]`` is the
+    f-th field's array from each input batch; ``num_rows[j]`` is batch j's
+    live row count. The host sends the k + 1 running row totals, nothing
+    capacity-sized."""
+    offsets = np.zeros(len(num_rows) + 1, dtype=np.int32)
+    np.cumsum(num_rows, out=offsets[1:])
     return _dispatch(
         _concat_gather,
         tuple(tuple(p) for p in per_field_datas),
         tuple(tuple(p) for p in per_field_valids),
-        jnp.asarray(idx), jnp.int64(total))
+        jnp.asarray(offsets), out_cap=out_cap)
 
 
 # -- radix key partitioning ----------------------------------------------------

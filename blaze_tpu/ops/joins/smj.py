@@ -19,7 +19,8 @@ sums of the wanted rows (the pairs, the unmatched or matched rows of a side)
 give each output slot its source. One wait (``sync:smj_count``) tells the
 host how many rows each kind has; ``jit(smj_pairs)`` / ``jit(smj_rows)``
 then fill one output batch a dispatch: a binary search of the slot numbers
-in the prefix sums, then gathers — no row-sized scatter. Unmatched and
+in the prefix sums, then ONE gather a side, its planes side by side
+(``kernels.take_rows_traced``) — no row-sized scatter. Unmatched and
 semi/anti rows come out in key order (the input's order, when it arrives
 sorted). The operator's metric node counts ``smj_device_joins``.
 
@@ -131,8 +132,8 @@ def smj_pairs(row, run_start, pairs, sums, lplanes, rplanes, offset, count,
         li = row[pos] - cap_r
         ri = row[jnp.clip(run_start[pos] + nth, 0, row.shape[0] - 1)]
     with jax.named_scope("gather"):
-        return li, ri, K.take_planes_traced(*lplanes, li, live), \
-            K.take_planes_traced(*rplanes, ri, live)
+        return li, ri, K.take_rows_traced(*lplanes, li, live), \
+            K.take_rows_traced(*rplanes, ri, live)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "base"))
@@ -144,7 +145,7 @@ def smj_rows(row, pairs, sums, planes, offset, count, cap, base):
         idx = row[pos] - base
     with jax.named_scope("gather"):
         exists = ((pairs[pos] > 0) & live, live)
-        return idx, exists, K.take_planes_traced(*planes, idx, live)
+        return idx, exists, K.take_rows_traced(*planes, idx, live)
 
 
 def _key_columns(exprs: List[E.Expr], batch: ColumnarBatch):

@@ -78,9 +78,11 @@ def _device_sort_indices(operands: List[jnp.ndarray], capacity: int) -> jnp.ndar
 @functools.partial(jax.jit, static_argnames=("out_cap",))
 def sort_take(order, datas, valids, n_out, out_cap):
     """The first ``n_out`` rows of every plane in the order of the device
-    permutation ``order``, at ``out_cap``: the gather that applies a sort."""
+    permutation ``order``, at ``out_cap``: the ONE gather that applies a
+    sort, all planes side by side (`K.take_rows_traced`)."""
     live = jnp.arange(out_cap, dtype=jnp.int32) < n_out
-    return K.take_planes_traced(datas, valids, order[:out_cap], live)
+    with jax.named_scope("move"):
+        return K.take_rows_traced(datas, valids, order[:out_cap], live)
 
 
 def _take_sorted(batch: ColumnarBatch, operands, n_out: int):
