@@ -14,8 +14,10 @@ with the reference and the program's counters must say the chip did the work.
 `--trace 1` profiles a short steady stretch with `jax.profiler` and reports
 the per-layer metrics, each through its reader, plus `breakdown`.
 
-The last line of stdout is the result object; the line before it,
-`readings: {...}`, has the window's order statistics. A run that finds no
+The last line of stdout is the result object (its last key, `compared`, has
+every number `correct` was decided by beside its limit, and the last line of
+stderr says the same); the line before it, `readings: {...}`, has the
+window's order statistics and every query's time in order. A run that finds no
 TPU, or fewer chips than the cell asks for, exits non-zero and prints no
 result (`--allow-cpu` is for the rehearsal tests and marks the output).
 """
@@ -73,6 +75,7 @@ class QueryRecord:
     device_stats: Dict[str, int] = dataclasses.field(default_factory=dict)
     self_ns: Dict[str, int] = dataclasses.field(default_factory=dict)
     wrong: Optional[str] = None  # why the answer does not count, if it does not
+    wrong_counter: bool = False  # ... because a counter left `counters_must`
 
 
 @dataclasses.dataclass
@@ -175,6 +178,7 @@ def check_record(record: QueryRecord, expected: dict, must: dict):
         n = record.counters[counter]
         if (lo is not None and n < lo) or (hi is not None and n > hi):
             record.wrong = f"{counter} = {n}, outside [{lo}, {hi}]"
+            record.wrong_counter = True
             return
     record.table = None  # compared; let it go
 
@@ -215,6 +219,38 @@ def build_session(overrides: dict, traced: bool):
     if traced:
         overrides = dict(overrides, trace_enable=True)
     return Session(conf=dataclasses.replace(conf, **overrides))
+
+
+def usage() -> dict:
+    """This process's CPU seconds and context switches so far: taken around
+    the window, they tell a process that worked more from one that waited
+    more (involuntary switches: somebody else wanted its cores)."""
+    import resource
+
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return {"user_s": ru.ru_utime, "sys_s": ru.ru_stime,
+            "voluntary_switches": ru.ru_nvcsw,
+            "involuntary_switches": ru.ru_nivcsw,
+            "minor_faults": ru.ru_minflt}
+
+
+def host_facts(usage_before) -> dict:
+    """What differs from one process or machine to the next and is not the
+    program's: what the window cost in CPU time and switches, the cores this
+    process may use, the hash seed, Arrow's pools, the interpreter's switch
+    interval and the collector's runs. (The load average reads 0.00 on the
+    chip tool's machine whatever runs there, so it is not among them.)"""
+    import gc
+
+    import pyarrow as pa
+
+    return {"window_usage": _delta(usage(), usage_before),
+            "cpus": os.cpu_count(), "affinity": len(os.sched_getaffinity(0)),
+            "hashseed": os.environ.get("PYTHONHASHSEED"),
+            "arrow_cpu_threads": pa.cpu_count(),
+            "arrow_io_threads": pa.io_thread_count(),
+            "switch_interval": sys.getswitchinterval(),
+            "gc_collections": [g["collections"] for g in gc.get_stats()]}
 
 
 def cache_files(path) -> int:
@@ -325,6 +361,7 @@ def run(args, t_start: float) -> int:
         setup_hits = hits - compiles_at_start[1]
         mark = requests
 
+        usage_before = usage()
         if traced:
             from blaze_tpu.obs.tracer import TRACER
 
@@ -357,6 +394,12 @@ def run(args, t_start: float) -> int:
                                "reference's answer")
         say("readings: " + json.dumps({
             "query_s": stats.summary([r.seconds for r in good]),
+            # every query of the window in order, so that a drift inside it,
+            # or what a shorter window would have read, can be told afterwards
+            "query_seconds": [r.seconds for r in good],
+            "query_starts": [r.t0 - records[0].t0 for r in good],
+            "host": host_facts(usage_before),
+            "device_stats_last_query": good[-1].device_stats,
             "setup_s": setup_s, "window_compiles": window_compiles,
             "setup_compiled": setup_compiles, "setup_cache_hits": setup_hits,
             "cache_files": [files_before, cache_files(cache_dir)],
@@ -402,7 +445,16 @@ def run(args, t_start: float) -> int:
                 "device_ops": [list(x) for x in reduction.device_ops],
                 "idle_gaps": [list(x) for x in reduction.idle_gaps]}
         result["device"] = described
+        # every number `correct` was decided by, beside its limit: the rows
+        # compare exactly with the reference's, so each limit is 0
+        result["compared"] = compared = {
+            "queries_compared": {"value": len(records), "limit": ">=1"},
+            "answers_wrong": {"value": sum(
+                1 for r in records if r.wrong and not r.wrong_counter), "limit": 0},
+            "counters_outside": {"value": sum(
+                1 for r in records if r.wrong_counter), "limit": 0}}
         print(json.dumps(result), flush=True)
+        print("compared: " + json.dumps(compared), file=sys.stderr, flush=True)
         return 0
     finally:
         if session is not None:

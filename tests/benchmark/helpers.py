@@ -14,7 +14,7 @@ MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
 TINY_ROWS = {"store_sales": 6000, "store_returns": 900, "item": 300,
              "store": 12, "customer": 1000}
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 

@@ -17,7 +17,11 @@ from benchlib import spans as sp  # noqa: E402
 from benchlib.registry import Registry  # noqa: E402
 
 with open(helpers.MANIFEST) as _f:
-    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+    _MANIFEST = json.load(_f)
+CELLS = [w["name"] for w in _MANIFEST["workloads"]]
+# the cells whose plan has a hash join: `join_host_s`'s own list
+(JOIN_CELLS,) = [m["workloads"] for m in _MANIFEST["per_layer"]
+                 if m["name"] == "join_host_s"]
 NEW = {"decode_wait_s", "decode_mrows_s", "stage_h2d_s", "d2h_s",
        "device_wait_s", "sync_points", "agg_host_s", "exchange_host_s"}
 
@@ -37,9 +41,9 @@ def test_traced_rehearsal_reports_the_span_metrics(cell, tmp_path, capsys):
     assert rc == 0, lines
     result = json.loads(lines[-1])
     metrics = result["metrics"]
-    want = NEW | ({"join_host_s"} if cell == "q06_bhj_agg" else set())
+    want = NEW | ({"join_host_s"} if cell in JOIN_CELLS else set())
     assert want <= set(metrics), sorted(want - set(metrics))
-    assert ("join_host_s" in metrics) == (cell == "q06_bhj_agg")
+    assert ("join_host_s" in metrics) == (cell in JOIN_CELLS)
     for name in want:
         assert metrics[name]["value"] >= 0, name
     assert metrics["decode_mrows_s"]["value"] > 0
@@ -50,7 +54,7 @@ def test_traced_rehearsal_reports_the_span_metrics(cell, tmp_path, capsys):
     assert metrics["agg_host_s"]["value"] <= metrics["agg_self_s"]["value"] + eps
     assert metrics["exchange_host_s"]["value"] <= \
         metrics["exchange_self_s"]["value"] + eps
-    if cell == "q06_bhj_agg":
+    if cell in JOIN_CELLS:
         assert metrics["join_host_s"]["value"] <= \
             metrics["join_self_s"]["value"] + eps
     # an idle gap is named by what a thread was doing in it (the shortest

@@ -140,7 +140,7 @@ def test_traced_rehearsal_of_q29_joins_on_the_device_path(tmp_path, capsys):
     assert metrics["sortwin_self_s"]["value"] > 0
     # read from a device trace: none on the CPU
     assert not {"smj_device_s", "smj_roofline_share", "sort_device_s"} & set(metrics)
-    assert "join_self_s" not in metrics  # lists q06_bhj_agg alone
+    assert "join_self_s" not in metrics  # lists the two cells with a hash join
 
 
 def test_traced_rehearsal_of_q47_ranks_after_the_slot_table(tmp_path, capsys):

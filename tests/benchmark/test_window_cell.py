@@ -231,10 +231,12 @@ def test_traced_rehearsal_of_q51_windows_on_the_device_path(tmp_path, capsys):
     assert 0 <= metrics["window_host_s"]["value"] <= \
         metrics["window_self_s"]["value"]
     assert metrics["sortwin_self_s"]["value"] >= metrics["window_self_s"]["value"]
-    # read from a device trace: none on the CPU
-    assert not {"window_device_s", "window_roofline_share"} & set(metrics)
+    # read from a device trace: none on the CPU (`sort_device_s` lists the
+    # cell since PR 34; `test_gained_cells.py` reads it over stand-ins)
+    assert not {"window_device_s", "window_roofline_share",
+                "sort_device_s"} & set(metrics)
     # the other cells' listed metrics stay theirs
-    assert not {"smj_device_joins", "join_self_s", "sort_device_s"} & set(metrics)
+    assert not {"smj_device_joins", "join_self_s"} & set(metrics)
 
 
 def test_the_other_cells_do_not_report_the_windows_metrics(tmp_path, capsys):
