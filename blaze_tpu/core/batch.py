@@ -4,9 +4,11 @@ The reference streams Arrow ``RecordBatch``es of ~``batch_size`` rows between
 DataFusion operators. On TPU the equivalent is a struct-of-arrays batch whose
 fixed-width columns are dense jax arrays padded to a *capacity bucket* (static
 shapes for XLA) with an explicit ``num_rows`` and per-column validity masks.
-Variable-width columns (string/binary) and nested types stay host-resident as
-Arrow arrays, with on-demand per-batch dictionary codes pushed to the device
-for filtering/grouping (SURVEY.md §7.2 L0').
+A variable-width column (string/binary) that arrives dictionary-encoded is a
+``CodedColumn``: int32 codes and validity on the device under the same
+padding contract, its values ONE Arrow dictionary on the host, held by
+reference (core/dictionary.py). Var-width values without a dictionary
+(computed strings, nested types) stay host-resident as Arrow arrays.
 
 Padding discipline: rows in ``[num_rows, capacity)`` have ``validity == False``
 and ``data == 0`` so that hashes/sorts over padded tails are deterministic.
@@ -103,14 +105,22 @@ def _int64_to_decimal128(values: np.ndarray, validity: np.ndarray, dt: T.Decimal
 
 
 class Column:
-    """Abstract column. Concrete: DeviceColumn (fixed-width, on device) and
-    HostColumn (var-width/nested, Arrow on host)."""
+    """Abstract column. Concrete: DeviceColumn (fixed-width, on device),
+    CodedColumn (var-width: codes on device, one dictionary on the host) and
+    HostColumn (var-width without a dictionary, nested: Arrow on host)."""
 
     dtype: T.DataType
 
     @property
     def is_device(self) -> bool:
         return isinstance(self, DeviceColumn)
+
+
+def has_planes(col) -> bool:
+    """Does the column keep its rows as (data, validity) device planes that
+    the movers of core/kernels.py carry: a DeviceColumn's values or a
+    CodedColumn's codes?"""
+    return isinstance(col, (DeviceColumn, CodedColumn))
 
 
 @dataclasses.dataclass
@@ -131,18 +141,20 @@ class DeviceColumn(Column):
     def nbytes(self) -> int:
         return self.data.nbytes + self.validity.nbytes
 
+    def like(self, data: jax.Array, validity: jax.Array) -> "DeviceColumn":
+        """A column of this kind and type over other planes (a mover's
+        result)."""
+        return DeviceColumn(self.dtype, data, validity)
+
     def with_capacity(self, capacity: int) -> "DeviceColumn":
         cap = self.capacity
         if capacity == cap:
             return self
         if capacity > cap:
             pad = capacity - cap
-            return DeviceColumn(
-                self.dtype,
-                jnp.pad(self.data, (0, pad)),
-                jnp.pad(self.validity, (0, pad)),
-            )
-        return DeviceColumn(self.dtype, self.data[:capacity], self.validity[:capacity])
+            return self.like(jnp.pad(self.data, (0, pad)),
+                             jnp.pad(self.validity, (0, pad)))
+        return self.like(self.data[:capacity], self.validity[:capacity])
 
     def take_device(self, indices: jax.Array, valid_mask: jax.Array) -> "DeviceColumn":
         """Gather rows by device indices; valid_mask marks live output rows."""
@@ -203,6 +215,109 @@ def _devcol_to_arrow(dt: T.DataType, data: np.ndarray, validity: np.ndarray,
 
 
 @dataclasses.dataclass
+class CodedColumn(Column):
+    """Var-width column whose rows are int32 codes into ONE dictionary: the
+    code and validity planes live on the device, padded to capacity under
+    the padding contract (code 0, validity False past ``num_rows`` and at
+    NULLs), and move with the movers of core/kernels.py like any plane; the
+    dictionary is a host Arrow array (large_utf8 / large_binary, no NULL
+    entry needed) held by reference and never copied a batch. Two coded
+    columns with different dictionaries meet only through one remap table
+    (core/dictionary.unify). A value is decoded only where one is needed:
+    ``to_arrow``. ``null_literal`` says the column was made as a typed NULL
+    (`nulls_like`): no row of it is valid. A mover does not carry the mark
+    on (`like`), so a column without it may hold no valid row either."""
+
+    dtype: T.DataType
+    data: jax.Array        # shape (capacity,), int32 codes
+    validity: jax.Array    # shape (capacity,), bool
+    dictionary: pa.Array
+    null_literal: bool = False
+
+    def __post_init__(self):
+        from blaze_tpu.core import dictionary as D
+
+        self.dictionary = D.large(self.dictionary)
+
+    @property
+    def capacity(self) -> int:
+        return self.data.shape[0]
+
+    def nbytes(self) -> int:
+        return self.data.nbytes + self.validity.nbytes
+
+    def like(self, data: jax.Array, validity: jax.Array) -> "CodedColumn":
+        return CodedColumn(self.dtype, data, validity, self.dictionary)
+
+    with_capacity = DeviceColumn.with_capacity
+
+    def nulls_like(self) -> "CodedColumn":
+        """The typed NULL in this column's place: an all-invalid plane over
+        the same dictionary (a ROLLUP's nulled key), marked as one."""
+        return CodedColumn(self.dtype, _zero_plane(self.capacity, "int32"),
+                           _zero_plane(self.capacity, "bool"),
+                           self.dictionary, null_literal=True)
+
+    def remapped(self, dictionary: pa.Array,
+                 table: Optional[np.ndarray]) -> "CodedColumn":
+        """The same rows as codes of ``dictionary``: through ``table`` (its
+        codes for this column's; the caller counts the rows as
+        ``dict_remap_rows``), or as they stand where it is None."""
+        if table is None:
+            return CodedColumn(self.dtype, self.data, self.validity, dictionary)
+        lookup = jnp.asarray(table if len(table) else np.zeros(1, np.int32))
+        return CodedColumn(self.dtype, lookup[self.data], self.validity,
+                           dictionary)
+
+    def to_host(self, num_rows: int, pulled=None) -> "HostColumn":
+        """The column as a host column over the same dictionary (Arrow's
+        dictionary array): the codes are pulled, no value is touched."""
+        from blaze_tpu.core import dictionary as D
+
+        codes, valid = pulled if pulled is not None else (
+            np.asarray(self.data[:num_rows]),
+            np.asarray(self.validity[:num_rows]))
+        return HostColumn(self.dtype,
+                          D.dictionary_array(self.dictionary, codes, valid))
+
+    def to_arrow(self, num_rows: int, pulled=None) -> pa.Array:
+        from blaze_tpu.core import dictionary as D
+
+        codes, valid = pulled if pulled is not None else (
+            np.asarray(self.data[:num_rows]),
+            np.asarray(self.validity[:num_rows]))
+        return D.decode(self.dictionary, codes, valid)
+
+    @staticmethod
+    def from_arrow(arr: pa.Array, dt: T.DataType, capacity: int,
+                   dictionary: Optional[pa.Array] = None,
+                   table: Optional[np.ndarray] = None) -> "CodedColumn":
+        """Upload a dictionary array's indices; ``dictionary`` / ``table``
+        place them in another dictionary on the way (a scan's unified
+        one)."""
+        codes = arr.indices
+        validity = ~np.asarray(codes.is_null()) if codes.null_count else None
+        codes = codes.fill_null(0).to_numpy(zero_copy_only=False)
+        if table is not None:
+            codes = table[codes]
+        planes = DeviceColumn.from_numpy(T.I32, codes.astype(np.int32, copy=False),
+                                         validity, capacity)
+        return CodedColumn(dt, planes.data, planes.validity,
+                           arr.dictionary if dictionary is None else dictionary)
+
+
+@functools.lru_cache(maxsize=64)
+def _zero_plane_on(capacity: int, dtype: str, device) -> jax.Array:
+    return jnp.zeros(capacity, dtype=dtype)
+
+
+def _zero_plane(capacity: int, dtype: str) -> jax.Array:
+    """Device-resident zeros per capacity bucket (as `_iota`): the planes of
+    a typed NULL."""
+    return _zero_plane_on(capacity, dtype, jax.config.jax_default_device)
+
+
+@dataclasses.dataclass
 class HostColumn(Column):
     """Host-resident column (string/binary/nested/decimal>18) as an Arrow array
     of exactly ``num_rows`` values (no padding on host)."""
@@ -223,18 +338,6 @@ class HostColumn(Column):
     def to_arrow(self, num_rows: int) -> pa.Array:
         assert len(self.array) == num_rows, (len(self.array), num_rows)
         return self.array
-
-    def dict_encode(self, capacity: int):
-        """Per-batch dictionary encoding: returns (codes DeviceColumn[int32],
-        dictionary pa.Array). Null -> validity False, code 0."""
-        arr = self.array
-        if not pa.types.is_dictionary(arr.type):
-            arr = arr.dictionary_encode()
-        codes = arr.indices
-        validity = ~np.asarray(codes.is_null())
-        codes_np = codes.fill_null(0).to_numpy(zero_copy_only=False).astype(np.int32)
-        col = DeviceColumn.from_numpy(T.I32, codes_np, validity, capacity)
-        return col, arr.dictionary
 
 
 def decode_dictionary(arr: pa.Array, dt: T.DataType) -> pa.Array:
@@ -384,10 +487,10 @@ def _arrow_to_column(arr: pa.Array, dt: T.DataType, capacity: int) -> Column:
                                                       T.BinaryType)):
             arr = arr.cast(arr.type.value_type)
         else:
-            # keep strings/binary dictionary-encoded: predicates then run
-            # on the device int32 CODES (exprs/compiler._dict_fast) and
-            # exchanges reuse the codes instead of re-encoding
-            return HostColumn(dt, arr)
+            # a dictionary-read string/binary column is coded, always: the
+            # codes go up once, predicates, joins, grouping and exchanges
+            # work on them, and the dictionary stays one host array
+            return CodedColumn.from_arrow(arr, dt, capacity)
     if is_device_dtype(dt):
         values, validity = arrow_fixed_planes(arr, dt)
         return DeviceColumn.from_numpy(dt, values, validity, capacity)
@@ -397,6 +500,40 @@ def _arrow_to_column(arr: pa.Array, dt: T.DataType, capacity: int) -> Column:
     if isinstance(dt, T.BinaryType) and not pa.types.is_large_binary(arr.type):
         arr = arr.cast(pa.large_binary())
     return HostColumn(dt, arr)
+
+
+def _one_dictionary_a_column(batches):
+    """Before a concat: every var-width column either coded in ALL the
+    batches, over one dictionary (the others' codes remapped through
+    core/dictionary.unify's tables), or coded in none (the coded ones
+    handed over as host columns: a batch built without a dictionary, such
+    as `ColumnarBatch.empty`, is among them). Returns the batches and the
+    rows whose codes were remapped."""
+    ncols = len(batches[0].columns)
+    coded = [i for i in range(ncols)
+             if any(isinstance(b.columns[i], CodedColumn) for b in batches)]
+    if not coded:
+        return batches, 0
+    from blaze_tpu.core import dictionary as D
+
+    out = [list(b.columns) for b in batches]
+    remapped = 0
+    for i in coded:
+        col_of = [b.columns[i] for b in batches]
+        if not all(isinstance(c, CodedColumn) for c in col_of):
+            for cols, b in zip(out, batches):
+                if isinstance(cols[i], CodedColumn):
+                    cols[i] = cols[i].to_host(b.num_rows)
+            continue
+        first = col_of[0].dictionary
+        if all(c.dictionary is first for c in col_of):
+            continue
+        unified, tables = D.unify([c.dictionary for c in col_of])
+        for cols, b, table in zip(out, batches, tables):
+            cols[i] = cols[i].remapped(unified, table)
+            remapped += b.num_rows if table is not None else 0
+    return [ColumnarBatch(b.schema, cols, b.num_rows)
+            for b, cols in zip(batches, out)], remapped
 
 
 @dataclasses.dataclass
@@ -476,7 +613,7 @@ class ColumnarBatch:
     @property
     def capacity(self) -> int:
         for c in self.columns:
-            if isinstance(c, DeviceColumn):
+            if has_planes(c):
                 return c.capacity
         return get_config().capacity_for(self.num_rows)
 
@@ -505,13 +642,15 @@ class ColumnarBatch:
             f"cannot shrink capacity {capacity} below num_rows {self.num_rows}"
         )
         cols = [
-            c.with_capacity(capacity) if isinstance(c, DeviceColumn) else c
+            c.with_capacity(capacity) if has_planes(c) else c
             for c in self.columns
         ]
         return ColumnarBatch(self.schema, cols, self.num_rows)
 
     def _device_slots(self):
-        return [i for i, c in enumerate(self.columns) if isinstance(c, DeviceColumn)]
+        """The columns whose rows are device planes: fixed-width values and
+        var-width codes alike."""
+        return [i for i, c in enumerate(self.columns) if has_planes(c)]
 
     def take(self, indices: np.ndarray) -> "ColumnarBatch":
         """Host-driven row gather (indices must be < num_rows). All device
@@ -530,9 +669,9 @@ class ColumnarBatch:
                 [self.columns[i].validity for i in slots],
                 indices, cap, n)
             for k, i in enumerate(slots):
-                cols[i] = DeviceColumn(self.columns[i].dtype, datas[k], valids[k])
+                cols[i] = self.columns[i].like(datas[k], valids[k])
         for i, c in enumerate(self.columns):
-            if not isinstance(c, DeviceColumn):
+            if not has_planes(c):
                 cols[i] = c.take_host(indices)
         return ColumnarBatch(self.schema, cols, n)
 
@@ -553,10 +692,10 @@ class ColumnarBatch:
                 [self.columns[i].validity for i in slots],
                 np.where(null_mask, 0, indices), cap, n, null_mask=null_mask)
             for k, i in enumerate(slots):
-                cols[i] = DeviceColumn(self.columns[i].dtype, datas[k], valids[k])
+                cols[i] = self.columns[i].like(datas[k], valids[k])
         pa_idx = None
         for i, c in enumerate(self.columns):
-            if not isinstance(c, DeviceColumn):
+            if not has_planes(c):
                 if pa_idx is None:
                     pa_idx = pa.Array.from_pandas(
                         np.where(null_mask, 0, indices), mask=null_mask,
@@ -583,20 +722,23 @@ class ColumnarBatch:
                 [self.columns[i].validity for i in slots],
                 offset, length, cap)
             for k, i in enumerate(slots):
-                cols[i] = DeviceColumn(self.columns[i].dtype, datas[k], valids[k])
+                cols[i] = self.columns[i].like(datas[k], valids[k])
         for i, c in enumerate(self.columns):
-            if not isinstance(c, DeviceColumn):
+            if not has_planes(c):
                 cols[i] = HostColumn(c.dtype, c.array.slice(offset, length))
         return ColumnarBatch(self.schema, cols, length)
 
     @staticmethod
-    def concat(batches: List["ColumnarBatch"], schema: Optional[T.Schema] = None) -> "ColumnarBatch":
+    def concat(batches: List["ColumnarBatch"], schema: Optional[T.Schema] = None,
+               metrics=None) -> "ColumnarBatch":
         """Coalesce small batches (reference: coalesce_batches_unchecked).
         Device planes concatenate+compact in ONE jitted dispatch of slice
         copies — each batch's planes written whole at its row offset, over the
         padding of the one before, nothing gathered; host arrays via arrow
         concat — no arrow round trip for device data (the round-1 profiler's
-        top fixed cost)."""
+        top fixed cost). Coded columns over different dictionaries meet
+        through one remap table; the rows remapped are added to the calling
+        operator's ``metrics`` node as ``dict_remap_rows``."""
         from blaze_tpu.core import kernels
 
         if not batches:
@@ -614,11 +756,15 @@ class ColumnarBatch:
         # and compile once per (fan-in, capacities) shape.
         while len(batches) > _CONCAT_FANIN:
             batches = [
-                ColumnarBatch.concat(batches[i:i + _CONCAT_FANIN], schema)
+                ColumnarBatch.concat(batches[i:i + _CONCAT_FANIN], schema,
+                                     metrics)
                 for i in range(0, len(batches), _CONCAT_FANIN)
             ]
         total = sum(b.num_rows for b in batches)
         cap = get_config().capacity_for(total)
+        batches, remapped = _one_dictionary_a_column(batches)
+        if metrics is not None and remapped:
+            metrics.add("dict_remap_rows", remapped)
         slots = batches[0]._device_slots()
         ncols = len(batches[0].columns)
         cols: List[Column] = [None] * ncols
@@ -638,9 +784,8 @@ class ColumnarBatch:
                     cols = list(b.columns)
                     for i in slots:
                         c = cols[i]
-                        cols[i] = DeviceColumn(
-                            c.dtype, jax.device_put(c.data, target),
-                            jax.device_put(c.validity, target))
+                        cols[i] = c.like(jax.device_put(c.data, target),
+                                         jax.device_put(c.validity, target))
                     aligned.append(ColumnarBatch(b.schema, cols, b.num_rows))
                 batches = aligned
             datas, valids = kernels.concat_planes(
@@ -648,7 +793,7 @@ class ColumnarBatch:
                 [tuple(b.columns[i].validity for b in batches) for i in slots],
                 [b.num_rows for b in batches], cap)
             for k, i in enumerate(slots):
-                cols[i] = DeviceColumn(batches[0].columns[i].dtype, datas[k], valids[k])
+                cols[i] = batches[0].columns[i].like(datas[k], valids[k])
         for i in range(ncols):
             if cols[i] is None:
                 c0 = batches[0].columns[i]
@@ -675,12 +820,33 @@ class ColumnarBatch:
             cols[i] = HostColumn(cols[i].dtype, cols[i].to_arrow(self.num_rows))
         return ColumnarBatch(self.schema, cols, self.num_rows)
 
+    def coded_to_host(self, metrics) -> "ColumnarBatch":
+        """The batch with every coded column as a host column over the same
+        dictionary (Arrow's dictionary array): what an operator that does
+        not take coded columns is handed (ops/base.Operator.takes_coded),
+        and what one that does falls back to. One pull of the code planes;
+        no value is touched. Counted as ``host_key_batches`` on ``metrics``,
+        the node of the operator that asked."""
+        coded = [i for i, c in enumerate(self.columns)
+                 if isinstance(c, CodedColumn)]
+        if not coded:
+            return self
+        from blaze_tpu.utils.device import pull_columns
+
+        metrics.add("host_key_batches", 1)
+        cols = list(self.columns)
+        pulled = pull_columns([cols[i] for i in coded], self.num_rows)
+        for i, p in zip(coded, pulled):
+            cols[i] = cols[i].to_host(self.num_rows, p)
+        return ColumnarBatch(self.schema, cols, self.num_rows)
+
     def to_arrow(self) -> pa.RecordBatch:
         from blaze_tpu.utils.device import pull_columns
 
         pulled = pull_columns(self.columns, self.num_rows)
         arrays = [
             c.to_arrow(self.num_rows) if p is None
+            else c.to_arrow(self.num_rows, p) if isinstance(c, CodedColumn)
             else _devcol_to_arrow(c.dtype, p[0], p[1], self.num_rows)
             for c, p in zip(self.columns, pulled)
         ]
@@ -717,8 +883,13 @@ class HostBatch:
 
         n = batch.num_rows
         pulled = pull_columns(batch.columns, n)
+        # a coded column stages as Arrow's dictionary array over the same
+        # dictionary: routing takes and slices its indices, the serializer
+        # ships codes and one dictionary, and nothing decodes
         items = [
-            (p[0], p[1]) if p is not None else c.to_arrow(n)
+            c.to_arrow(n) if p is None
+            else c.to_host(n, p).array if isinstance(c, CodedColumn)
+            else (p[0], p[1])
             for c, p in zip(batch.columns, pulled)
         ]
         return HostBatch(batch.schema, items, n)
@@ -751,7 +922,7 @@ class HostBatch:
         with stage_span(self.num_rows):
             cols: List[Column] = [
                 DeviceColumn.from_numpy(f.dtype, it[0], it[1], cap)
-                if isinstance(it, tuple) else HostColumn(f.dtype, it)
+                if isinstance(it, tuple) else _arrow_to_column(it, f.dtype, cap)
                 for f, it in zip(self.schema.fields, self.items)
             ]
         return ColumnarBatch(self.schema, cols, self.num_rows)

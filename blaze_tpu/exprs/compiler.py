@@ -29,7 +29,8 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
-from blaze_tpu.core.batch import Column, ColumnarBatch, DeviceColumn, HostColumn
+from blaze_tpu.core.batch import (CodedColumn, Column, ColumnarBatch,
+                                  DeviceColumn, HostColumn)
 from blaze_tpu.exprs import decimal as dec
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
@@ -50,7 +51,47 @@ class HostVal:
     arr: pa.Array
 
 
+class CodedVal(HostVal):
+    """A reference to a coded column (core/batch.CodedColumn): the column
+    itself where the expression is only that reference (a projection, a
+    ROLLUP's Expand, a grouping key: no pull, no value touched), predicates
+    over its dictionary through `_dict_fast`, and for everything else a host
+    value over the same dictionary, made when first asked for (one pull of
+    the codes, counted as ``host_key_batches`` on ``metrics``, the node the
+    evaluator's operator gave it)."""
+
+    def __init__(self, dtype: T.DataType, col: CodedColumn, num_rows: int,
+                 metrics=None):
+        self.dtype = dtype
+        self.col = col
+        self.num_rows = num_rows
+        self.metrics = metrics
+        self._arr = None
+
+    @property
+    def arr(self) -> pa.Array:
+        if self._arr is None:
+            if self.metrics is not None:
+                self.metrics.add("host_key_batches", 1)
+            self._arr = self.col.to_host(self.num_rows).array
+        return self._arr
+
+    def __repr__(self):
+        return f"CodedVal({self.dtype}, {self.num_rows} rows)"
+
+
 Val = Union[DevVal, HostVal]
+
+
+def reference_index(expr: E.Expr, schema: T.Schema) -> Optional[int]:
+    """The position of the column a BARE reference names, else None (an
+    expression over columns, a name the schema does not have): where an
+    operator asks what kind of column a key or an argument arrives as."""
+    if isinstance(expr, E.BoundReference):
+        return expr.index
+    if isinstance(expr, E.Column) and expr.name in schema.names:
+        return schema.index_of(expr.name)
+    return None
 
 
 class ExprError(Exception):
@@ -74,9 +115,13 @@ class ExprEvaluator:
     subgraphs keyed by batch capacity.
     """
 
-    def __init__(self, exprs: List[E.Expr], input_schema: T.Schema):
+    def __init__(self, exprs: List[E.Expr], input_schema: T.Schema,
+                 metrics=None):
         self.exprs = exprs
         self.input_schema = input_schema
+        # the operator's metric node: where an expression reads a coded
+        # column's VALUES the pull is counted there (`CodedVal.arr`)
+        self.metrics = metrics
         self.row_num_offset = 0
         # common-subexpression cache, valid for ONE batch only (reference:
         # CachedExprsEvaluator's cached_exprs — shared subtrees evaluate once)
@@ -101,40 +146,46 @@ class ExprEvaluator:
         """String predicates on dictionary CODES (round-2 verdict item 5,
         reference: the dictionary fast paths of ``spark_strings.rs``): when
         a host value wraps a dictionary-encoded arrow array spanning the
-        batch, evaluate the predicate over the K dictionary VALUES once
+        batch (or the column is coded: its codes are on the device
+        already), evaluate the predicate over the K dictionary VALUES once
         (tiny host compute), then map per-row results through the device
         int32 codes — the O(rows) work becomes a device gather instead of a
         host string scan. Returns a BOOL DevVal, or None when not
         applicable. ``value_fn(dictionary) -> arrow bool array`` computes
         the per-dictionary-entry result; its nulls propagate as invalid."""
-        orig = getattr(hv, "arr", None)
-        if not isinstance(hv, HostVal) or orig is None:
-            return None
-        arr = orig.combine_chunks() if isinstance(orig, pa.ChunkedArray) \
-            else orig
-        if not pa.types.is_dictionary(arr.type) or \
-                len(arr) != batch.num_rows or batch.num_rows == 0:
-            return None
-        K = len(arr.dictionary)
+        if isinstance(hv, CodedVal):
+            codes, dictionary = hv.col, hv.col.dictionary
+            if batch.num_rows == 0:
+                return None
+        else:
+            orig = getattr(hv, "arr", None)
+            if not isinstance(hv, HostVal) or orig is None:
+                return None
+            arr = orig.combine_chunks() if isinstance(orig, pa.ChunkedArray) \
+                else orig
+            if not pa.types.is_dictionary(arr.type) or \
+                    len(arr) != batch.num_rows or batch.num_rows == 0:
+                return None
+            dictionary = arr.dictionary
+            # keyed by the ORIGINAL array object and identity-checked: id()
+            # of a freshly combined temporary could be recycled within the
+            # batch and hand back another column's codes. The cached entry
+            # holds the array reference, pinning the id.
+            entry = self._dict_codes.get(id(orig))
+            if entry is not None and entry[0] is orig:
+                codes = entry[1]
+            else:
+                codes = CodedColumn.from_arrow(arr, hv.dtype, batch.capacity)
+                self._dict_codes[id(orig)] = (orig, codes)
+        K = len(dictionary)
         if K == 0:
             # every row is null: invalid everywhere
             z = jnp.zeros(batch.capacity, bool)
             return DevVal(T.BOOL, z, z)
-        res = value_fn(arr.dictionary)
+        res = value_fn(dictionary)
         rd = np.asarray(pc.fill_null(res, False)
                         .to_numpy(zero_copy_only=False)).astype(bool)
         rv = ~np.asarray(pc.is_null(res).to_numpy(zero_copy_only=False))
-        # keyed by the ORIGINAL array object and identity-checked: id() of
-        # a freshly combined temporary could be recycled within the batch
-        # and hand back another column's codes. The cached entry holds the
-        # array reference, pinning the id.
-        entry = self._dict_codes.get(id(orig))
-        if entry is not None and entry[0] is orig:
-            codes = entry[1]
-        else:
-            col = HostColumn(hv.dtype, arr)
-            codes = col.dict_encode(batch.capacity)[0]
-            self._dict_codes[id(orig)] = (orig, codes)
         cidx = jnp.clip(codes.data, 0, K - 1)
         lk_d = jnp.asarray(rd)
         lk_v = jnp.asarray(rv)
@@ -189,6 +240,8 @@ class ExprEvaluator:
             else:
                 validity = val.validity & batch.row_exists_mask()
             return DeviceColumn(val.dtype, data, validity)
+        if isinstance(val, CodedVal):
+            return val.col  # the reference itself: codes and dictionary
         arr = val.arr
         if len(arr) != batch.num_rows:  # scalar host literal
             assert len(arr) == 1
@@ -198,6 +251,10 @@ class ExprEvaluator:
     def _to_dev(self, val: Val, batch: ColumnarBatch) -> DevVal:
         if isinstance(val, DevVal):
             return val
+        if isinstance(val, CodedVal):
+            # a coded column's device value is its code plane (a grouping
+            # key to the device aggregation: equal codes, equal values)
+            return DevVal(T.I32, val.col.data, val.col.validity)
         col = _arrow_to_devcol(val.arr, val.dtype, batch.capacity)
         return DevVal(val.dtype, col.data, col.validity)
 
@@ -273,6 +330,8 @@ class ExprEvaluator:
                 # decimal128 on the host, exact at every digit
                 return HostVal(dt, col.to_arrow(batch.num_rows))
             return DevVal(dt, col.data, col.validity)
+        if isinstance(col, CodedColumn):
+            return CodedVal(dt, col, batch.num_rows, self.metrics)
         return HostVal(dt, col.array)
 
     def _eval_Literal(self, expr: E.Literal, batch: ColumnarBatch) -> Val:
@@ -310,6 +369,8 @@ class ExprEvaluator:
                    B.GT: B.LT, B.GTEQ: B.LTEQ}
 
         def scalar_of(v):
+            if isinstance(v, CodedVal):
+                return None  # a column, and asking for `.arr` would pull it
             if isinstance(v, HostVal) and len(v.arr) == 1:
                 return v.arr[0]
             if isinstance(v, DevVal) and v.data.ndim == 0:

@@ -314,7 +314,10 @@ def hash_batch(columns, num_rows: int, capacity: int, seed: int = 42,
     for xxhash64. Device columns are hashed on device; host (string/binary)
     columns force a host pass over the running hashes.
     """
-    from blaze_tpu.core.batch import DeviceColumn, HostColumn
+    import pyarrow as pa
+
+    from blaze_tpu.core.batch import (CodedColumn, DeviceColumn, HostColumn,
+                                      decode_dictionary)
 
     is64 = algo == "xxhash64"
     h_dev: Optional[jnp.ndarray] = None
@@ -359,11 +362,23 @@ def hash_batch(columns, num_rows: int, capacity: int, seed: int = 42,
                 is64)
             continue
         i += 1
+        if isinstance(col, CodedColumn) and not is64:
+            # Spark's murmur3 of the VALUE by code: the dictionary's entry
+            # bytes hashed in place with the row's running hash as seed
+            from blaze_tpu.core import dictionary as D
+
+            h = to_host()
+            h_host = D.murmur3_by_code(
+                col.dictionary, np.asarray(col.data[:num_rows]),
+                np.asarray(col.validity[:num_rows]), h)
+            continue
+        if isinstance(col, CodedColumn):
+            col = col.to_host(num_rows)
         if isinstance(col, HostColumn):
             h = to_host()
             arr = col.array
-            import pyarrow as pa
-
+            if pa.types.is_dictionary(arr.type):
+                arr = decode_dictionary(arr, col.dtype)
             from blaze_tpu.ir import types as T
 
             if pa.types.is_decimal(arr.type):

@@ -57,9 +57,9 @@ def dict_identity(dictionary: pa.Array) -> tuple:
     (per-partition sub-batches of one bucketized batch); buffer addresses
     don't. Safe only while a reference to some wrapper is held (the
     registry entry holds one), which pins the buffers against reuse."""
-    return tuple(
-        (b.address, b.size) for b in dictionary.buffers() if b is not None
-    ) + (len(dictionary), str(dictionary.type))
+    from blaze_tpu.core.dictionary import dict_key
+
+    return dict_key(dictionary)
 
 
 class DictEncodeContext:
@@ -128,6 +128,30 @@ def _maybe_dict_ref(arr, meta: dict, ctx: DictEncodeContext, new_dicts,
     return arr.indices, meta
 
 
+def _staged_if_coded(batch):
+    """A batch with coded columns serializes from its staged form (one pull;
+    the codes ship as Arrow's dictionary arrays over the same dictionary)."""
+    from blaze_tpu.core.batch import CodedColumn, HostBatch
+
+    if isinstance(batch, ColumnarBatch) and any(
+            isinstance(c, CodedColumn) for c in batch.columns):
+        return HostBatch.from_batch(batch)
+    return batch
+
+
+def _host_or_coded(arr, dt, capacity: int):
+    """A decoded host array as its column: coded where it arrived as codes
+    and a dictionary (the codes go back up; the dictionary is held by
+    reference), else the host column."""
+    from blaze_tpu.core.batch import _arrow_to_column
+
+    if isinstance(arr, pa.ChunkedArray):
+        arr = arr.combine_chunks()
+    if pa.types.is_dictionary(arr.type):
+        return _arrow_to_column(arr, dt, capacity)
+    return HostColumn(dt, arr)
+
+
 def serialize_batch(batch, transpose: Optional[bool] = None,
                     dict_ctx: Optional[DictEncodeContext] = None) -> bytes:
     """One batch (ColumnarBatch or HostBatch) -> uncompressed payload bytes.
@@ -140,6 +164,7 @@ def serialize_batch(batch, transpose: Optional[bool] = None,
         transpose = cfg.serde_transpose
 
     n = batch.num_rows
+    batch = _staged_if_coded(batch)
     if isinstance(batch, HostBatch):
         pulled = [it if isinstance(it, tuple) else None for it in batch.items]
         host_arrays = {i: it for i, it in enumerate(batch.items)
@@ -292,7 +317,7 @@ def deserialize_batch(payload,
                 if isinstance(arr, pa.ChunkedArray):
                     arr = arr.combine_chunks()
                 arr = pa.DictionaryArray.from_arrays(arr, d)
-            cols[i] = HostColumn(f.dtype, arr)
+            cols[i] = _host_or_coded(arr, f.dtype, cap)
     # all device planes of the batch ride one batched device_put
     with stage_span(n):
         for slot, col in zip(dev_slots, device_columns(dev_items, cap)):
@@ -319,6 +344,7 @@ def serialize_batch_raw(batch,
 
     n = batch.num_rows
     cap = get_config().capacity_for(n)
+    batch = _staged_if_coded(batch)
     if isinstance(batch, HostBatch):
         pulled = [it if isinstance(it, tuple) else None for it in batch.items]
         host_arrays = {i: it for i, it in enumerate(batch.items)
@@ -477,7 +503,7 @@ def deserialize_batch_raw(payload,
                 if isinstance(arr, pa.ChunkedArray):
                     arr = arr.combine_chunks()
                 arr = pa.DictionaryArray.from_arrays(arr, d)
-            cols[i] = HostColumn(f.dtype, arr)
+            cols[i] = _host_or_coded(arr, f.dtype, cap)
     with stage_span(n):
         for slot, col in zip(dev_slots,
                              device_columns_mapped(dev_items, cap, n,

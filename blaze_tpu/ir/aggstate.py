@@ -15,8 +15,17 @@ from blaze_tpu.ir import types as T
 
 
 def avg_sum_type(arg_t: T.DataType) -> T.DataType:
+    """The type AVG carries its sum in. A decimal's is Spark's (p + 10). An
+    INTEGRAL argument's is int64: Spark sums it as a double, which is exact
+    while the sum stays under 2^53 and rounds past it; the int64 sum is
+    exact to 2^63, lives on a device without float64 arithmetic, and the one
+    division to DOUBLE happens at the end (AvgAgg.final_column), where both
+    operands convert exactly under 2^53 — below that bound, bit for bit
+    what Spark computes. Floats sum as double."""
     if isinstance(arg_t, T.DecimalType):
         return T.DecimalType(min(arg_t.precision + 10, 38), arg_t.scale)
+    if isinstance(arg_t, (T.Int8Type, T.Int16Type, T.Int32Type, T.Int64Type)):
+        return T.I64
     return T.F64
 
 
@@ -259,6 +268,8 @@ def _arg_type_from_state(agg: E.AggExpr, child_schema: T.Schema, pos: int) -> T.
         return T.DecimalType(max(dt.precision - 10, 1), dt.scale)
     if agg.fn == E.AggFunction.AVG and isinstance(dt, T.Float64Type):
         return T.F64
+    if agg.fn == E.AggFunction.AVG and isinstance(dt, T.Int64Type):
+        return T.I64  # an integral argument's sum (avg_sum_type)
     if isinstance(dt, T.ArrayType):
         return dt.element_type
     return dt

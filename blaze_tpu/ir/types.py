@@ -139,6 +139,12 @@ def is_wide_decimal(dt: DataType) -> bool:
     return isinstance(dt, DecimalType) and not dt.fits_int64
 
 
+def is_var_width(dt: DataType) -> bool:
+    """A string or binary type: the columns that may arrive coded
+    (core/batch.CodedColumn)."""
+    return isinstance(dt, (StringType, BinaryType))
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class ArrayType(DataType):
     element_type: DataType = None

@@ -63,6 +63,11 @@ class AggExec(Operator):
         schema = self._output_schema(child.schema)
         super().__init__(schema, [child])
 
+    # var-width grouping keys may arrive coded (core/batch.CodedColumn): the
+    # device aggers group by the int32 code planes; the host table is handed
+    # the same dictionary as a host column (AggTable.process_batch)
+    takes_coded = True
+
     @property
     def takes_wide_planes(self) -> bool:
         """Raw rows' wide-decimal arguments may arrive as a window's proved
@@ -171,10 +176,25 @@ class AggExec(Operator):
 
     def _execute(self, partition, ctx, metrics):
         child_schema = self.children[0].schema
-        from blaze_tpu.ops.agg_device import DevicePartialAgger, supports_device_partial
+        from blaze_tpu.ops.agg_device import (DevicePartialAgger, coded_keys,
+                                              has_var_width_keys,
+                                              supports_device_partial)
 
-        if self.exec_mode == E.AggExecMode.HASH_AGG and \
-                supports_device_partial(self, child_schema):
+        device_partial = self.exec_mode == E.AggExecMode.HASH_AGG and \
+            supports_device_partial(self, child_schema)
+        first = None
+        if device_partial and has_var_width_keys(self, child_schema):
+            # a var-width key is a device key only where it arrives coded:
+            # the stream's first batch says which (a scan's dictionary-read
+            # column, a coded join payload, a ROLLUP's Expand over them)
+            first = _Peeked(self.execute_child(0, partition, ctx, metrics))
+            device_partial = first.head is not None and \
+                coded_keys(self, first.head)
+            if not device_partial:
+                yield from self._execute_table(partition, ctx, metrics,
+                                               child_schema, iter(first))
+                return
+        if device_partial:
             # TPU fast path: per-batch device partials, no host interning.
             # When the child is a fusable FilterExec, its predicate traces
             # into the same jitted kernel (one device call per batch).
@@ -286,10 +306,13 @@ class AggExec(Operator):
                 metrics=metrics)
             if join_src is not None:
                 src_iter = join_src
+            elif first is not None:
+                src_iter = iter(first)  # var-width keys fuse nothing below
             else:
                 src_iter = (source.execute(partition, ctx, src_metrics)
                             if source is not child_op else
                             self.execute_child(0, partition, ctx, metrics))
+            count_coded = first is not None
             # Per-task consolidation: per-batch partials merge into ONE
             # state batch at stream end (reference parity: AggTable
             # accumulates across the whole partition, agg_table.rs:77-305).
@@ -327,6 +350,12 @@ class AggExec(Operator):
                         yield out
                     continue
                 # self-time lands in elapsed_compute_time_ns via Operator.execute
+                if count_coded:
+                    if not coded_keys(self, batch):
+                        # a later batch without its dictionary (none is
+                        # built upstream today): encode it on the host
+                        batch = _encode_var_width_keys(self, batch, metrics)
+                    metrics.add("coded_key_batches", 1)
                 out = agger.process(batch)
                 if skipper is not None:
                     if agger.last_bucket_stats is not None:
@@ -369,7 +398,8 @@ class AggExec(Operator):
                 # device merge: all state batches concat on device, one
                 # kernel call merges + finalizes — no host key interning
                 # (round-1 verdict weak #4). Falls back to the host table
-                # when the buffered states outgrow the fallback threshold.
+                # when the buffered states outgrow the fallback threshold,
+                # or where a var-width key arrives without its dictionary.
                 staged = []
                 staged_bytes = 0
                 src = self.execute_child(0, partition, ctx, metrics)
@@ -377,7 +407,8 @@ class AggExec(Operator):
                 for b in src:
                     staged.append(b)
                     staged_bytes += b.nbytes()
-                    if staged_bytes > ctx.conf.device_merge_max_bytes:
+                    if staged_bytes > ctx.conf.device_merge_max_bytes or \
+                            not coded_keys(self, b):
                         too_big = True
                         break
                 if not too_big:
@@ -433,6 +464,38 @@ class AggExec(Operator):
         finally:
             ctx.mem.unregister(table)
             table.release()
+
+
+class _Peeked:
+    """A batch stream with its first non-empty batch looked at (``head``;
+    None for a stream without one); iterating gives the whole stream."""
+
+    def __init__(self, batches):
+        self._batches = iter(batches)
+        self.head = next((b for b in self._batches if b.num_rows), None)
+
+    def __iter__(self):
+        if self.head is not None:
+            yield self.head
+        yield from self._batches
+
+
+def _encode_var_width_keys(op: "AggExec", batch: ColumnarBatch, metrics):
+    """``batch`` with every var-width grouping key that is not coded made
+    so: dictionary-encoded on the host, the codes sent up (counted as
+    ``host_key_batches``)."""
+    from blaze_tpu.core.batch import CodedColumn, decode_dictionary
+    from blaze_tpu.exprs.compiler import reference_index
+
+    cols = list(batch.columns)
+    for _, e in op.groupings:
+        idx = reference_index(e, batch.schema)
+        c = cols[idx] if idx is not None else None
+        if isinstance(c, HostColumn) and T.is_var_width(c.dtype):
+            arr = decode_dictionary(c.array, c.dtype).dictionary_encode()
+            cols[idx] = CodedColumn.from_arrow(arr, c.dtype, batch.capacity)
+    metrics.add("host_key_batches", 1)
+    return ColumnarBatch(batch.schema, cols, batch.num_rows)
 
 
 def _execute_sorted_impl(op: "AggExec", partition, ctx, metrics):
@@ -840,6 +903,9 @@ class AggTable(MemConsumer):
         n = batch.num_rows
         if n == 0:
             return
+        # the host table groups a coded key through its dictionary as it
+        # groups any dictionary-encoded host column (_host_key_plane)
+        batch = batch.coded_to_host(self.metrics)
         self.rows_processed += n
         cols = self._grouping_columns(batch)
         slots_np = self._intern_keys(batch, cols)

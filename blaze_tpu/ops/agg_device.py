@@ -310,6 +310,62 @@ class FusedJoinSpec:
             self.probe_on_left, JoinType.INNER)
 
 
+def _device_key(e: E.Expr, schema: T.Schema) -> bool:
+    """May this grouping key be a device plane: a fixed-width type, or a bare
+    reference to a var-width column — which is one where the column arrives
+    CODED (core/batch.CodedColumn: int32 codes and validity on the device).
+    Whether it does is the stream's to say (`coded_keys`)."""
+    dt = E.infer_type(e, schema)
+    return is_device_dtype(dt) or (
+        T.is_var_width(dt) and isinstance(e, (E.Column, E.BoundReference)))
+
+
+def coded_keys(op, batch: ColumnarBatch) -> bool:
+    """Does ``batch`` carry every var-width grouping key of ``op`` as a coded
+    column? (A stream whose first batch does not goes to the host table.)"""
+    from blaze_tpu.core.batch import CodedColumn
+    from blaze_tpu.exprs.compiler import reference_index
+
+    for _, e in op.groupings:
+        idx = reference_index(e, batch.schema)
+        if idx is not None and T.is_var_width(batch.schema[idx].dtype) and \
+                not isinstance(batch.columns[idx], CodedColumn):
+            return False
+    return True
+
+
+def has_var_width_keys(op, child_schema: T.Schema) -> bool:
+    return any(T.is_var_width(E.infer_type(e, child_schema))
+               for _, e in op.groupings)
+
+
+def _key_planes(ev: ExprEvaluator, groupings, batch: ColumnarBatch, exists):
+    """The grouping keys of ``batch`` as device planes: (data, validity &
+    exists) a key (the validity as it stands where ``exists`` is None), and
+    beside them the dictionary of each key that is a
+    coded column (its plane is the int32 codes; None for the others)."""
+    from blaze_tpu.exprs.compiler import CodedVal
+
+    key_data, key_valid, dicts = [], [], []
+    for _, e in groupings:
+        val = ev._eval(e, batch)
+        dicts.append(val.col.dictionary if isinstance(val, CodedVal) else None)
+        d, v = _broadcast(ev._to_dev(val, batch), batch)
+        key_data.append(d)
+        key_valid.append(v if exists is None else v & exists)
+    return key_data, key_valid, dicts
+
+
+def _key_column(dt: T.DataType, dictionary, data, validity):
+    """An output key column: coded over ``dictionary`` where the input key
+    was, else the type's device column."""
+    from blaze_tpu.core.batch import CodedColumn
+
+    if dictionary is not None:
+        return CodedColumn(dt, data, validity, dictionary)
+    return DeviceColumn(dt, data, validity)
+
+
 def supports_device_partial(op, child_schema: T.Schema) -> bool:
     """Partial-mode hash agg over device keys and device-mode aggregates."""
     if not op.is_partial_output or op.input_is_partial or not op.groupings:
@@ -317,7 +373,7 @@ def supports_device_partial(op, child_schema: T.Schema) -> bool:
     from blaze_tpu.ops import aggfns
 
     for _, e in op.groupings:
-        if not is_device_dtype(E.infer_type(e, child_schema)):
+        if not _device_key(e, child_schema):
             return False
     for a in op.aggs:
         if a.agg.fn not in _DEVICE_AGG_FNS:
@@ -352,6 +408,17 @@ def supports_fused_filter(filter_op, grandchild_schema: T.Schema) -> bool:
            for p in filter_op.predicates):
         return False
     return not any(_contains_stateful(p) for p in filter_op.predicates)
+
+
+class _TableState:
+    """A stream's slot-table state (for one null signature of its keys):
+    ``dense_ok`` / ``radix_ok`` None = eligibility undecided, False =
+    ineligible or refused; ``bucket_state`` the active plan."""
+
+    __slots__ = ("dense_ok", "radix_ok", "bucket_state")
+
+    def __init__(self):
+        self.dense_ok = self.radix_ok = self.bucket_state = None
 
 
 class DevicePartialAgger:
@@ -391,18 +458,25 @@ class DevicePartialAgger:
         self.metrics = metrics
         self.conf = conf or get_config()
         self._fused_cache = {}
-        # dense/radix bucket path state: _dense_ok/_radix_ok None =
-        # eligibility undecided, False = ineligible/disabled; _bucket_state
-        # is the active plan ("dense"|"radix", bases, sizes, out_cap)
-        self._dense_ok = None
-        self._radix_ok = None
-        self._bucket_state = None
+        # dense/radix bucket path: _dense_ok/_radix_ok None = eligibility
+        # undecided, False = ineligible/disabled; a bucket state is the
+        # active plan ("dense"|"radix", bases, sizes, out_cap)
+        # ... kept a NULL SIGNATURE of the stream (which grouping keys
+        # arrive as a ROLLUP's typed NULL, `_null_signature`): each grouping
+        # set of an Expand has its own key space and plans for itself. A
+        # stream without such keys has one signature: one state, one probe.
+        self._table_states = {(): _TableState()}
+        self._table_state = self._table_states[()]
+        # the dictionaries of the batch in hand's coded keys (_key_planes)
+        self._key_dicts = [None] * len(op.groupings)
         # per-radix-pass (rows, groups) numpy histograms, consumed by the
         # partial-skipping heuristic between process() calls
         self.last_bucket_stats = None
-        self.group_ev = ExprEvaluator([e for _, e in op.groupings], child_schema)
+        self.group_ev = ExprEvaluator([e for _, e in op.groupings], child_schema,
+                                      metrics)
         self.agg_evs = [
-            ExprEvaluator(list(a.agg.args), child_schema) if a.agg.args else None
+            ExprEvaluator(list(a.agg.args), child_schema, metrics)
+            if a.agg.args else None
             for a in op.aggs
         ]
         from blaze_tpu.ops import aggfns
@@ -445,6 +519,15 @@ class DevicePartialAgger:
                 acc_dt = ""
             self.specs.append((kind, rescale, acc_dt))
 
+    # the slot-table state of the signature in hand
+    _dense_ok = property(lambda self: self._table_state.dense_ok,
+                         lambda self, v: setattr(self._table_state, "dense_ok", v))
+    _radix_ok = property(lambda self: self._table_state.radix_ok,
+                         lambda self, v: setattr(self._table_state, "radix_ok", v))
+    _bucket_state = property(
+        lambda self: self._table_state.bucket_state,
+        lambda self, v: setattr(self._table_state, "bucket_state", v))
+
     def _flow(self, batch: ColumnarBatch, exists):
         """Traceable per-batch flow: evaluate keys/args, run the segment
         kernel body. Works on real arrays (eager) and tracers (fused jit)."""
@@ -454,13 +537,8 @@ class DevicePartialAgger:
         for ev in self.agg_evs:
             if ev is not None:
                 ev._reset_cse(batch)
-        gcols = [self.group_ev._to_dev(self.group_ev._eval(e, batch), batch)
-                 for _, e in self.op.groupings]
-        key_data, key_valid = [], []
-        for v in gcols:
-            d, val = _broadcast(v, batch)
-            key_data.append(d)
-            key_valid.append(val & exists)
+        key_data, key_valid, self._key_dicts = _key_planes(
+            self.group_ev, self.op.groupings, batch, exists)
         args = self._eval_args(batch, exists)
         kernel = _partial_kernel(
             tuple(str(d.dtype) for d in key_data),
@@ -657,7 +735,10 @@ class DevicePartialAgger:
 
     def _int_keys(self) -> bool:
         for _, e in self.op.groupings:
-            ndt = E.infer_type(e, self.child_schema).np_dtype
+            dt = E.infer_type(e, self.child_schema)
+            if T.is_var_width(dt):
+                continue  # a coded key: its plane is int32 codes
+            ndt = dt.np_dtype
             if ndt is None or not np.issubdtype(np.dtype(ndt), np.integer):
                 return False
         return True
@@ -692,10 +773,9 @@ class DevicePartialAgger:
         reduces min/max/any on device in one dispatch."""
         exists = batch.row_exists_mask()
         self.group_ev._reset_cse(batch)
-        keys = [_broadcast(
-            self.group_ev._to_dev(self.group_ev._eval(e, batch), batch),
-            batch) for _, e in self.op.groupings]
-        return _key_ranges_jit(exists, keys)
+        key_data, key_valid, _ = _key_planes(
+            self.group_ev, self.op.groupings, batch, None)  # masked in the jit
+        return _key_ranges_jit(exists, list(zip(key_data, key_valid)))
 
     def _probe_fn(self, batch: ColumnarBatch):
         """Jitted range probe for the fused path (all columns device-
@@ -776,13 +856,8 @@ class DevicePartialAgger:
         for ev in self.agg_evs:
             if ev is not None:
                 ev._reset_cse(batch)
-        key_data, key_valid = [], []
-        for _, e in self.op.groupings:
-            d, val = _broadcast(
-                self.group_ev._to_dev(self.group_ev._eval(e, batch), batch),
-                batch)
-            key_data.append(d)
-            key_valid.append(val & exists)
+        key_data, key_valid, self._key_dicts = _key_planes(
+            self.group_ev, self.op.groupings, batch, exists)
         args = self._eval_args(batch, exists)
         kernel = _dense_partial_kernel(
             tuple(str(d.dtype) for d in key_data), tuple(self.specs),
@@ -803,8 +878,10 @@ class DevicePartialAgger:
         kernel. Radix passes additionally publish the per-bucket (rows,
         groups) histogram through ``last_bucket_stats``."""
         self.last_bucket_stats = None
+        nulled = self._null_signature(batch)
+        self._table_state = self._table_states.setdefault(nulled, _TableState())
         if not (self._dense_enabled() or self._radix_enabled()):
-            return None
+            return None  # not eligible, or this signature's range refused
         st = self._bucket_state
         prev = None
         for _ in range(2):
@@ -815,6 +892,11 @@ class DevicePartialAgger:
                 else:
                     pr = self._probe_eager(batch)
                 pr = wait_array(pr, "agg_probe")
+                if nulled:
+                    # a nulled key has no valid row to anchor its range, and
+                    # never will: one slot beside the NULL's
+                    pr = np.array(pr)
+                    pr[list(nulled)] = (1, 0, 0)
                 st = self._plan_bucketed(pr, batch.capacity, prev)
                 if st is _DEFER_PLAN:
                     # no valid keys in this batch to anchor a plan: sort
@@ -823,7 +905,8 @@ class DevicePartialAgger:
                     return None
                 if st is None:
                     # observed range too wide for even the radix cap: stop
-                    # probing for the rest of this stream
+                    # probing for the rest of this stream (of its batches
+                    # with these keys nulled)
                     self._dense_ok = False
                     self._radix_ok = False
                     self._bucket_state = None
@@ -843,6 +926,23 @@ class DevicePartialAgger:
             prev, st = (bases, sizes), None
         self._bucket_state = None
         return None
+
+    def _null_signature(self, batch: ColumnarBatch) -> tuple:
+        """The grouping keys that arrive as a ROLLUP's typed NULL (a coded
+        column marked ``null_literal``, `CodedColumn.nulls_like`). Each grouping
+        set of an Expand has its own key space — the grand total's is one
+        slot, the finest set's every name — so each signature plans its
+        slot table for itself, from a probe of its own."""
+        from blaze_tpu.core.batch import CodedColumn
+        from blaze_tpu.exprs.compiler import reference_index
+
+        nulled = []
+        for i, (_, e) in enumerate(self.op.groupings):
+            idx = reference_index(e, batch.schema)
+            col = batch.columns[idx] if idx is not None else None
+            if isinstance(col, CodedColumn) and col.null_literal:
+                nulled.append(i)
+        return tuple(nulled)
 
     def _note_radix(self, outs, sizes, nbuck: int):
         """Publish one radix pass's bucket histogram: skipper input,
@@ -915,7 +1015,7 @@ class DevicePartialAgger:
             if not parts:
                 return None
             return parts[0] if len(parts) == 1 else \
-                ColumnarBatch.concat(parts, self.op.schema)
+                ColumnarBatch.concat(parts, self.op.schema, self.metrics)
         dense = self._try_dense(batch)
         if dense is not None:
             outs, num_groups = dense
@@ -949,14 +1049,8 @@ class DevicePartialAgger:
         for ev in self.agg_evs:
             if ev is not None:
                 ev._reset_cse(batch)
-        key_data, key_valid = [], []
-        for _, e in self.op.groupings:
-            d, val = _broadcast(
-                self.group_ev._to_dev(self.group_ev._eval(e, batch),
-                                      batch),
-                batch)
-            key_data.append(d)
-            key_valid.append(val & exists)
+        key_data, key_valid, self._key_dicts = _key_planes(
+            self.group_ev, self.op.groupings, batch, exists)
         args = self._eval_args(batch, exists)
         kernel = _passthrough_kernel(
             tuple(str(d.dtype) for d in key_data), tuple(self.specs),
@@ -980,7 +1074,8 @@ class DevicePartialAgger:
         ci = 0
         for gi, (gname, e) in enumerate(self.op.groupings):
             dt = schema[ci].dtype
-            cols.append(DeviceColumn(dt, outs[pos], outs[pos + 1] & out_valid_mask))
+            cols.append(_key_column(dt, self._key_dicts[gi], outs[pos],
+                                    outs[pos + 1] & out_valid_mask))
             pos += 2
             ci += 1
         for a, fn, (kind, _, _) in zip(self.op.aggs, self.fns, self.specs):
@@ -1773,7 +1868,7 @@ def supports_device_merge(op, child_schema: T.Schema) -> bool:
     if not op.input_is_partial or not op.groupings:
         return False
     for _, e in op.groupings:
-        if not is_device_dtype(E.infer_type(e, child_schema)):
+        if not _device_key(e, child_schema):
             return False
     try:
         fns = op._make_fns(child_schema)
@@ -1830,17 +1925,20 @@ class DeviceMergeAgger:
         batches = [b for b in batches if b.num_rows]
         if not batches:
             return []
-        big = ColumnarBatch.concat(batches, self.child_schema)
-        ev = ExprEvaluator([e for _, e in op.groupings], big.schema)
+        big = ColumnarBatch.concat(batches, self.child_schema, self.metrics)
+        ev = ExprEvaluator([e for _, e in op.groupings], big.schema,
+                           self.metrics)
         ev._reset_cse(big)
         exists = big.row_exists_mask()
         flat = []
-        key_dtypes = []
-        for _, e in op.groupings:
-            dv = ev._to_dev(ev._eval(e, big), big)
-            d, v = _broadcast(dv, big)
-            flat += [d, v & exists]
-            key_dtypes.append(str(d.dtype))
+        key_data, key_valid, key_dicts = _key_planes(ev, op.groupings, big,
+                                                     exists)
+        for d, v in zip(key_data, key_valid):
+            flat += [d, v]
+        key_dtypes = [str(d.dtype) for d in key_data]
+        if self.metrics is not None and any(
+                d is not None for d in key_dicts):
+            self.metrics.add("coded_key_batches", 1)
         state_dtypes = []
         pos = len(op.groupings)
         for fn in self.fns:
@@ -1881,8 +1979,8 @@ class DeviceMergeAgger:
         p = 2
         out_schema = op.schema
         for gi, _ in enumerate(op.groupings):
-            cols.append(DeviceColumn(out_schema[gi].dtype, outs[p],
-                                     outs[p + 1] & out_valid))
+            cols.append(_key_column(out_schema[gi].dtype, key_dicts[gi],
+                                    outs[p], outs[p + 1] & out_valid))
             p += 2
         final = not op.is_partial_output
         for a, fn, kind in zip(op.aggs, self.fns, self.kinds):

@@ -482,12 +482,10 @@ class AvgAgg(AggFunction):
 
     def __init__(self, agg, arg_type, result_type, limbs=None):
         super().__init__(agg, arg_type, result_type)
-        from blaze_tpu.ir.aggstate import limb3_tag, limb_tag, state_mode
+        from blaze_tpu.ir.aggstate import (avg_sum_type, limb3_tag, limb_tag,
+                                           state_mode)
 
-        if isinstance(arg_type, T.DecimalType):
-            self.sum_type = T.DecimalType(min(arg_type.precision + 10, 38), arg_type.scale)
-        else:
-            self.sum_type = T.F64
+        self.sum_type = avg_sum_type(arg_type)
         if limbs is None:
             self.limbs = state_mode(E.AggFunction.AVG, arg_type,
                                     self.result_type)
@@ -661,6 +659,15 @@ class AvgAgg(AggFunction):
             out, validity = dec.div(s, has, cnz, has, scale_adjust)
             out, validity = dec.check_overflow(out, validity, self.result_type.precision)
             return DeviceColumn(self.result_type, out, validity)
+        if not is_device_dtype(T.F64):
+            # no float64 arithmetic on this device: the sum and the count
+            # stayed int64 on it, and the one division happens here, on the
+            # host, in IEEE double (exact operands under 2^53)
+            packed = np.asarray(jnp.stack([s[:num_slots], c[:num_slots]]))
+            counted = packed[1] > 0
+            out = packed[0].astype(np.float64) / \
+                np.where(counted, packed[1], 1).astype(np.float64)
+            return _host_col_out(T.F64, out, counted)
         out = s.astype(jnp.float64) / cnz.astype(jnp.float64)
         return DeviceColumn(T.F64, out, has)
 

@@ -222,14 +222,25 @@ class Operator:
     # other operator is handed such a column as its type's host column.
     takes_wide_planes = False
 
+    # Does ``_execute`` know a var-width column that arrives CODED (int32
+    # codes and validity on the device, one dictionary on the host:
+    # core/batch.CodedColumn)? Every other operator is handed such a column
+    # as a host column over the same dictionary (Arrow's dictionary array;
+    # the codes are pulled, no value is touched), counted as
+    # ``host_key_batches``.
+    takes_coded = False
+
     def execute_child(self, i: int, partition: int, ctx: ExecContext,
                       metrics: MetricNode) -> Iterator[ColumnarBatch]:
         batches = self.children[i].execute(partition, ctx, metrics.child(i))
-        if self.takes_wide_planes or not any(
-                T.is_wide_decimal(f.dtype)
-                for f in self.children[i].schema.fields):
-            return batches
-        return (b.by_type() for b in batches)
+        fields = self.children[i].schema.fields
+        if not self.takes_wide_planes and any(
+                T.is_wide_decimal(f.dtype) for f in fields):
+            batches = (b.by_type() for b in batches)
+        if not self.takes_coded and any(
+                T.is_var_width(f.dtype) for f in fields):
+            batches = (b.coded_to_host(metrics) for b in batches)
+        return batches
 
     def __repr__(self):
         return f"{self.name}({', '.join(repr(c) for c in self.children)})"

@@ -9,14 +9,17 @@ fusion via CachedExprsEvaluator), ``limit_exec.rs``, ``coalesce_batches``,
 
 from __future__ import annotations
 
+import functools
 import logging
 from typing import List, Optional, Tuple
 
+import jax
 import numpy as np
 
-from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn
+from blaze_tpu.core.batch import (CodedColumn, ColumnarBatch, DeviceColumn,
+                                  has_planes)
 import jax.numpy as jnp
-from blaze_tpu.exprs.compiler import ExprEvaluator
+from blaze_tpu.exprs.compiler import ExprEvaluator, reference_index
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
 from blaze_tpu.ops.base import ExecContext, Operator
@@ -25,6 +28,8 @@ log = logging.getLogger(__name__)
 
 
 class ProjectExec(Operator):
+    takes_coded = True  # a reference to a coded column is that column
+
     def __init__(self, child: Operator, exprs: List[E.Expr], names: List[str],
                  schema: Optional[T.Schema] = None):
         self.exprs = exprs
@@ -39,7 +44,7 @@ class ProjectExec(Operator):
         super().__init__(schema, [child])
 
     def _execute(self, partition, ctx, metrics):
-        ev = ExprEvaluator(self.exprs, self.children[0].schema)
+        ev = ExprEvaluator(self.exprs, self.children[0].schema, metrics)
         for batch in self.execute_child(0, partition, ctx, metrics):
             # self-time lands in elapsed_compute_time_ns via Operator.execute
             cols = ev.evaluate(batch)
@@ -49,6 +54,8 @@ class ProjectExec(Operator):
 class FilterExec(Operator):
     """Filter with optional fused projection (reference: filter-project
     fusion in filter_exec.rs/cached_exprs_evaluator.rs)."""
+
+    takes_coded = True  # code planes compact like any plane
 
     def __init__(self, child: Operator, predicates: List[E.Expr],
                  projection: Optional[Tuple[List[E.Expr], List[str]]] = None):
@@ -68,13 +75,14 @@ class FilterExec(Operator):
 
     def _execute(self, partition, ctx, metrics):
         child_schema = self.children[0].schema
-        pred_ev = ExprEvaluator(self.predicates, child_schema)
+        pred_ev = ExprEvaluator(self.predicates, child_schema, metrics)
         proj_ev = (
-            ExprEvaluator(self.projection[0], child_schema) if self.projection else None
+            ExprEvaluator(self.projection[0], child_schema, metrics)
+            if self.projection else None
         )
         for batch in self.execute_child(0, partition, ctx, metrics):
             mask = pred_ev.evaluate_predicate(batch)
-            all_device = all(isinstance(c, DeviceColumn) for c in batch.columns)
+            all_device = all(has_planes(c) for c in batch.columns)
             if all_device:
                 # device-side stable compaction: one jitted dispatch and
                 # one scalar pull (core/kernels.py)
@@ -88,10 +96,8 @@ class FilterExec(Operator):
                 if count == batch.num_rows:
                     out = batch
                 else:
-                    cols = [
-                        DeviceColumn(c.dtype, d, v) for c, d, v in
-                        zip(batch.columns, datas, valids)
-                    ]
+                    cols = [c.like(d, v) for c, d, v in
+                            zip(batch.columns, datas, valids)]
                     out = ColumnarBatch(batch.schema, cols, count)
             else:
                 indices = np.nonzero(np.asarray(mask))[0]
@@ -107,6 +113,8 @@ class FilterExec(Operator):
 class LimitExec(Operator):
     """Per-partition limit (reference: limit_exec.rs; global limit is this
     after a single-partition exchange)."""
+
+    takes_coded = True
 
     def __init__(self, child: Operator, limit: int):
         self.limit = limit
@@ -128,6 +136,8 @@ class CoalesceBatchesExec(Operator):
     """Merge small batches up to the configured batch size (reference:
     coalesce_batches_unchecked / ExecutionContext.coalesce)."""
 
+    takes_coded = True
+
     def __init__(self, child: Operator, batch_size: Optional[int] = None):
         self.batch_size = batch_size
         super().__init__(child.schema, [child])
@@ -145,15 +155,17 @@ class CoalesceBatchesExec(Operator):
             staged.append(batch)
             staged_rows += batch.num_rows
             if staged_rows >= target:
-                out = ColumnarBatch.concat(staged, self.schema)
+                out = ColumnarBatch.concat(staged, self.schema, metrics)
                 staged, staged_rows = [], 0
                 yield out
         if staged:
-            yield ColumnarBatch.concat(staged, self.schema)
+            yield ColumnarBatch.concat(staged, self.schema, metrics)
 
 
 class RenameColumnsExec(Operator):
     """Zero-copy schema rename (reference: rename_columns_exec.rs)."""
+
+    takes_coded = True
 
     def __init__(self, child: Operator, names: List[str]):
         self.names = names
@@ -166,6 +178,8 @@ class RenameColumnsExec(Operator):
 
 class UnionExec(Operator):
     """Union with partition mapping (reference: union_exec.rs)."""
+
+    takes_coded = True
 
     def __init__(self, inputs: List[Operator],
                  num_partitions: Optional[int] = None,
@@ -239,17 +253,92 @@ class MemoryScanExec(Operator):
 
 class ExpandExec(Operator):
     """Grouping-sets expansion: each input batch emits one output batch per
-    projection list (reference: expand_exec.rs)."""
+    projection list (reference: expand_exec.rs).
+
+    A projection that is a reference to a coded column yields that column
+    (codes and dictionary, nothing computed); a typed NULL literal standing
+    where another projection has such a reference yields an all-invalid
+    plane over the SAME dictionary (`CodedColumn.nulls_like`: a ROLLUP's
+    nulled key stays a coded key, so the aggregation above sees one kind of
+    column and one dictionary a key); `spark_grouping_id` is a literal, a
+    device constant. No pull and no per-row Python."""
+
+    takes_coded = True
 
     def __init__(self, child: Operator, projections: List[List[E.Expr]],
                  schema: T.Schema):
         self.projections = projections
         super().__init__(schema, [child])
 
+    def _coded_sources(self, child_schema: T.Schema):
+        """Per output position: the child column a coded NULL may borrow its
+        dictionary from (the first projection that references one there)."""
+        sources = [None] * len(self.schema)
+        for pos, f in enumerate(self.schema.fields):
+            if not T.is_var_width(f.dtype):
+                continue
+            sources[pos] = next(
+                (idx for idx in (reference_index(proj[pos], child_schema)
+                                 for proj in self.projections)
+                 if idx is not None), None)
+        return sources
+
     def _execute(self, partition, ctx, metrics):
+        from blaze_tpu.core import kernels
+        from blaze_tpu.exprs.compiler import make_literal
+        from blaze_tpu.utils.device import is_device_dtype
+
         child_schema = self.children[0].schema
-        evs = [ExprEvaluator(p, child_schema) for p in self.projections]
+        sources = self._coded_sources(child_schema)
+        # per projection: the whole evaluator (the fallback), the places of
+        # the NULLs that stand for a coded column, the places and device
+        # scalars of the constants, and an evaluator of the rest
+        plans = []
+        for p in self.projections:
+            nulls = [pos for pos, e in enumerate(p)
+                     if sources[pos] is not None and isinstance(e, E.Literal)
+                     and e.value is None]
+            consts = [pos for pos, e in enumerate(p)
+                      if isinstance(e, E.Literal) and e.value is not None
+                      and is_device_dtype(e.dtype)]
+            rest = [pos for pos in range(len(p))
+                    if pos not in nulls and pos not in consts]
+            plans.append((ExprEvaluator(p, child_schema, metrics), nulls, consts,
+                          tuple(make_literal(p[pos].value, p[pos].dtype).data
+                                for pos in consts),
+                          rest, ExprEvaluator([p[pos] for pos in rest],
+                                              child_schema, metrics)))
         for batch in self.execute_child(0, partition, ctx, metrics):
-            for ev in evs:
-                cols = ev.evaluate(batch)
+            coded = any(isinstance(c, CodedColumn) for c in batch.columns)
+            for ev, nulls, consts, scalars, rest, ev_rest in plans:
+                if all(isinstance(batch.columns[sources[pos]], CodedColumn)
+                       for pos in nulls):
+                    cols = [None] * len(self.schema)
+                    for pos, col in zip(rest, ev_rest.evaluate(batch)):
+                        cols[pos] = col
+                    for pos in nulls:
+                        cols[pos] = batch.columns[sources[pos]].nulls_like()
+                    if consts:
+                        planes, live = kernels._dispatch(
+                            expand_literal, scalars,
+                            jnp.int32(batch.num_rows), capacity=batch.capacity)
+                        for pos, plane in zip(consts, planes):
+                            cols[pos] = DeviceColumn(self.schema[pos].dtype,
+                                                     plane, live)
+                else:
+                    cols = ev.evaluate(batch)
+                metrics.add("rollup_rows", batch.num_rows)
+                if coded:
+                    metrics.add("coded_key_batches", 1)
                 yield ColumnarBatch(self.schema, cols, batch.num_rows)
+
+
+@functools.partial(jax.jit, static_argnames=("capacity",))
+def expand_literal(scalars, num_rows, capacity):
+    """A projection's constants (`spark_grouping_id` among them) as planes
+    under the padding contract, and the validity they share, in ONE launch a
+    projection and batch."""
+    with jax.named_scope("constant"):
+        live = jnp.arange(capacity, dtype=jnp.int32) < num_rows
+        return tuple(jnp.where(live, v, jnp.zeros((), v.dtype))
+                     for v in scalars), live

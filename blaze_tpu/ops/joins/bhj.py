@@ -95,6 +95,10 @@ def clear_build_cache():
 class _HashJoinBase(Operator):
     """Common probe logic; subclasses define how the build side loads."""
 
+    # coded var-width columns pass on either side: the fused kernel and the
+    # row movers of the generic probe carry their code planes
+    takes_coded = True
+
     def __init__(self, left: Operator, right: Operator,
                  on: List[Tuple[E.Expr, E.Expr]], join_type: JoinType,
                  build_side: JoinSide, condition: Optional[E.Expr] = None):
@@ -158,7 +162,7 @@ class _HashJoinBase(Operator):
         with metrics.timer("build_time_ns"):
             batches = list(self.execute_child(child, partition, ctx, metrics))
             return JoinHashMap.build(batches, self._key_exprs(for_build=True),
-                                     self.children[child].schema)
+                                     self.children[child].schema, metrics)
 
     # -- probe ----------------------------------------------------------------
 
@@ -191,8 +195,8 @@ class _HashJoinBase(Operator):
         track_build_matched = emit_unmatched_build or (
             semi_anti_exist and not self._semi_side_is_probe())
 
-        key_ev = ExprEvaluator(key_exprs, probe_schema)
-        cond_ev = ExprEvaluator([self.condition], self._pair_schema) \
+        key_ev = ExprEvaluator(key_exprs, probe_schema, metrics)
+        cond_ev = ExprEvaluator([self.condition], self._pair_schema, metrics) \
             if self.condition is not None else None
         inner_fast_ok = (
             jt == JoinType.INNER and cond_ev is None
@@ -207,6 +211,7 @@ class _HashJoinBase(Operator):
                         if out is not None and out.num_rows:
                             yield out
                         continue
+                metrics.add("join_generic_batches", 1)
                 codes, on_device = bmap.probe_codes(batch, cols)
                 if on_device:
                     metrics.add("device_probe_batches", 1)
@@ -232,15 +237,18 @@ class _HashJoinBase(Operator):
     def _inner_fast(self, batch, bmap, cols, probe_on_left, metrics):
         """Fused one-dispatch device inner join (unique-single-key build
         map). NotImplemented = not eligible for THIS batch (host columns):
-        caller falls through to the generic probe."""
-        from blaze_tpu.core.batch import DeviceColumn
+        caller falls through to the generic probe. A coded var-width column
+        on either side is eligible: its int32 code plane and its validity
+        ride through the one matrix gather like any payload plane, and the
+        output column keeps the side's dictionary by reference."""
+        from blaze_tpu.core.batch import CodedColumn, DeviceColumn, has_planes
 
         if not (len(cols) == 1 and isinstance(cols[0], DeviceColumn)):
             return NotImplemented
-        if not all(isinstance(c, DeviceColumn) for c in batch.columns):
+        if not all(has_planes(c) for c in batch.columns):
             return NotImplemented
         bb = bmap.batch
-        if not all(isinstance(c, DeviceColumn) for c in bb.columns):
+        if not all(has_planes(c) for c in bb.columns):
             return NotImplemented
         import jax.numpy as jnp
 
@@ -264,14 +272,16 @@ class _HashJoinBase(Operator):
         # under device_probe_batches too so the metric stays meaningful for
         # callers that only check whether probing happened on device.
         metrics.add("device_probe_batches", 1)
+        if any(isinstance(c, CodedColumn)
+               for c in (*batch.columns, *bb.columns)):
+            metrics.add("coded_key_batches", 1)
         if count == 0:
             return None
-        probe_cols = [DeviceColumn(f.dtype, outs[1 + 2 * i], outs[2 + 2 * i])
-                      for i, f in enumerate(batch.schema.fields)]
+        probe_cols = [c.like(outs[1 + 2 * i], outs[2 + 2 * i])
+                      for i, c in enumerate(batch.columns)]
         off = 1 + 2 * len(batch.columns)
-        build_cols = [DeviceColumn(f.dtype, outs[off + 2 * i],
-                                   outs[off + 1 + 2 * i])
-                      for i, f in enumerate(bb.schema.fields)]
+        build_cols = [c.like(outs[off + 2 * i], outs[off + 1 + 2 * i])
+                      for i, c in enumerate(bb.columns)]
         left, right = ((probe_cols, build_cols) if probe_on_left
                        else (build_cols, probe_cols))
         return ColumnarBatch(self.schema, left + right, count)
@@ -390,7 +400,7 @@ class HashJoinExec(_HashJoinBase):
                                               batches, it)
                 return
             bmap = JoinHashMap.build(batches, self._key_exprs(for_build=True),
-                                     build_child.schema)
+                                     build_child.schema, metrics)
             yield from self._probe_with_map(bmap, partition, ctx, metrics)
             return
         yield from super()._execute(partition, ctx, metrics)
@@ -477,6 +487,7 @@ class BroadcastJoinBuildHashMapExec(Operator):
     def _execute(self, partition, ctx, metrics):
         batches = list(self.execute_child(0, partition, ctx, metrics))
         with metrics.timer("build_time_ns"):
-            m = JoinHashMap.build(batches, self.keys, self.children[0].schema)
+            m = JoinHashMap.build(batches, self.keys, self.children[0].schema,
+                                  metrics)
             blob = m.serialize()
         yield ColumnarBatch.from_pydict({"hash_map": [blob]}, self.SCHEMA)

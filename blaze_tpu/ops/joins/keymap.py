@@ -396,13 +396,13 @@ class JoinHashMap:
 
     @staticmethod
     def build(batches: List[ColumnarBatch], key_exprs: List[E.Expr],
-              schema) -> "JoinHashMap":
+              schema, metrics=None) -> "JoinHashMap":
         key_cols = []
         kept = []
         for b in batches:
             if b.num_rows == 0:
                 continue
-            ev = ExprEvaluator(key_exprs, b.schema)
+            ev = ExprEvaluator(key_exprs, b.schema, metrics)
             key_cols.append(ev.evaluate(b))
             kept.append(b)
         if not kept:
@@ -410,17 +410,17 @@ class JoinHashMap:
             return JoinHashMap(empty, {}, np.zeros(1, np.int64), schema)
         if len(key_exprs) == 1 and all(
                 isinstance(cols[0], DeviceColumn) for cols in key_cols):
-            return JoinHashMap._build_sorted(kept, key_cols, schema)
+            return JoinHashMap._build_sorted(kept, key_cols, schema, metrics)
         key_map: Dict = {}
         code_arrays = [key_codes(b, cols, key_map, insert=True)
                        for b, cols in zip(kept, key_cols)]
-        big = ColumnarBatch.concat(kept, schema)
+        big = ColumnarBatch.concat(kept, schema, metrics)
         codes = np.concatenate(code_arrays)
         ncodes = len(key_map)
         return JoinHashMap._from_codes(big, codes, ncodes, key_map, None, schema)
 
     @staticmethod
-    def _build_sorted(kept, key_cols, schema) -> "JoinHashMap":
+    def _build_sorted(kept, key_cols, schema, metrics) -> "JoinHashMap":
         """Single fixed-width key: codes are ranks in the sorted unique-key
         array (canonical int64 words), enabling the device probe."""
         from blaze_tpu.utils.device import pull_columns
@@ -431,7 +431,7 @@ class JoinHashMap:
             (data, valid), = pull_columns(cols, b.num_rows)
             words.append(_canon_words(data))
             valids.append(valid)
-        big = ColumnarBatch.concat(kept, schema)
+        big = ColumnarBatch.concat(kept, schema, metrics)
         w = np.concatenate(words)
         v = np.concatenate(valids)
         uniq = np.unique(w[v])

@@ -173,10 +173,10 @@ class ParquetScanExec(Operator):
     def _execute(self, partition, ctx, metrics):
         group = self.conf.file_groups[partition]
         proj_names = [self.conf.file_schema[i].name for i in self.conf.projection]
-        # read string/binary columns dictionary-encoded: scans stay
-        # byte-identical logically, but downstream predicates run on the
-        # device int32 CODES (exprs/compiler._dict_fast) instead of host
-        # string scans, and the codes upload once per batch
+        # read string/binary columns dictionary-encoded: they go on as coded
+        # columns (core/batch.CodedColumn) — the int32 CODES upload once per
+        # batch and predicates, joins, grouping and exchanges run on them;
+        # the values stay one host dictionary a column and scan
         dict_cols = [self.conf.file_schema[i].name
                      for i in self.conf.projection
                      if isinstance(self.conf.file_schema[i].dtype,
@@ -246,6 +246,12 @@ class ParquetScanExec(Operator):
         t = threading.Thread(target=produce, daemon=True, name="parquet-prefetch")
         t.start()
         proj_schema = self.conf.file_schema.select(self.conf.projection)
+        # one dictionary a coded column and scan task: a row group's that
+        # differs is unified with the ones before it, its codes remapped
+        from blaze_tpu.core.batch import CodedColumn
+        from blaze_tpu.core.dictionary import OneDictionary
+
+        dictionaries = OneDictionary()
         try:
             while True:
                 with TRACER.detail("decode_wait", "scan"):
@@ -258,6 +264,13 @@ class ParquetScanExec(Operator):
                 if rb.num_rows == 0:
                     continue
                 batch = ColumnarBatch.from_arrow(rb, proj_schema)
+                for i, c in enumerate(batch.columns):
+                    if isinstance(c, CodedColumn):
+                        batch.columns[i], grown, remapped = dictionaries.keep(
+                            i, c, batch.num_rows)
+                        metrics.add("dict_entries", grown)
+                        if remapped:
+                            metrics.add("dict_remap_rows", remapped)
                 if len(self.conf.partition_schema):
                     batch = _attach_partition_values(batch, pfile, self.conf, self.schema)
                 yield batch

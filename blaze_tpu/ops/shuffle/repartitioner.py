@@ -132,10 +132,17 @@ class HashPartitioner(Repartitioner):
     def partition_ids_host(self, host):
         """Numpy murmur3 over plain-column integer keys of an already
         pulled batch (the shuffle-write staging path): bit-exact with the
-        device kernel, no dispatch + pull round trip. Non-column exprs,
-        arrow-resident columns, and float keys (NaN/-0.0 normalization
-        lives in the device kernel) decline."""
+        device kernel, no dispatch + pull round trip. A coded var-width key
+        (staged as Arrow's dictionary array) hashes BY CODE: Spark's murmur3
+        of the value, read from the dictionary in place with the row's
+        running hash as seed (core/dictionary.murmur3_by_code) — no string
+        is built. Non-column exprs, other arrow-resident columns, and float
+        keys (NaN/-0.0 normalization lives in the device kernel) decline."""
+        import pyarrow as pa
+
+        from blaze_tpu.core import dictionary as D
         from blaze_tpu.exprs import spark_hash as SH
+        from blaze_tpu.ir import types as T
 
         names = [f.name for f in host.schema.fields]
         h = np.full(host.num_rows, 42, dtype=np.uint32)
@@ -145,7 +152,17 @@ class HashPartitioner(Repartitioner):
             idx = names.index(e.name)
             it = host.items[idx]
             if not isinstance(it, tuple):
-                return None
+                if not (T.is_var_width(host.schema[idx].dtype)
+                        and isinstance(it, pa.DictionaryArray)):
+                    return None
+                codes = it.indices
+                valid = ~np.asarray(codes.is_null()) \
+                    if codes.null_count else None
+                h = D.murmur3_by_code(
+                    it.dictionary,
+                    codes.fill_null(0).to_numpy(zero_copy_only=False),
+                    valid, h)
+                continue
             kind = SH._dtype_kind(host.schema[idx].dtype)
             if kind not in ("i32", "i64"):
                 return None

@@ -110,6 +110,10 @@ class ShuffleWriterExec(Operator):
     input, device.put failure) and from there exactly like the process
     tier (spill / budget / pool → frames → shm or files)."""
 
+    # a coded var-width column stages as Arrow's dictionary array over its
+    # dictionary: codes and one dictionary cross the exchange, not strings
+    takes_coded = True
+
     def __init__(self, child: Operator, partitioning, output_data_file: str,
                  output_index_file: str, mem_sink=None, device_sink=False):
         self.partitioning = partitioning
@@ -560,12 +564,12 @@ class RssShuffleWriterExec(Operator):
             pending_rows += batch.num_rows
             if pending_rows >= coalesce_min:
                 _push(pending[0] if len(pending) == 1 else
-                      ColumnarBatch.concat(pending))
+                      ColumnarBatch.concat(pending, metrics=metrics))
                 pending = []
                 pending_rows = 0
         if pending:
             _push(pending[0] if len(pending) == 1 else
-                  ColumnarBatch.concat(pending))
+                  ColumnarBatch.concat(pending, metrics=metrics))
         writer.flush()
         return
         yield  # pragma: no cover

@@ -48,7 +48,7 @@ def sort_batch(batch: ColumnarBatch, sort_orders: List[E.SortOrder],
     if batch.num_rows <= 1:
         return batch
     n_out = batch.num_rows if limit is None else min(limit, batch.num_rows)
-    if SK.supports_device_sort(batch.schema, sort_orders):
+    if SK.device_sortable(batch, sort_orders):
         return _take_sorted(batch, SK.key_operands(batch, sort_orders),
                             n_out)[0]
     return batch.take(SK.host_sort_indices(batch, sort_orders)[:n_out])
@@ -100,16 +100,20 @@ def _take_sorted(batch: ColumnarBatch, operands, n_out: int):
         np.int32(n_out), out_cap=out_cap)
     cols = list(batch.columns)
     for k, i in enumerate(slots):
-        cols[i] = DeviceColumn(cols[i].dtype, datas[k], valids[k])
+        cols[i] = cols[i].like(datas[k], valids[k])
     if len(slots) < len(cols):
         indices = wait_array(order, "sort_indices")[:n_out].astype(np.int64)
         for i, c in enumerate(cols):
-            if not isinstance(c, DeviceColumn):
+            if i not in slots:
                 cols[i] = c.take_host(indices)
     return ColumnarBatch(batch.schema, cols, n_out), order
 
 
 class SortExec(Operator):
+    # a coded var-width column moves with the device planes, and as a KEY it
+    # orders by its dictionary's rank plane (ops/sort_keys.coded_rank_plane)
+    takes_coded = True
+
     def __init__(self, child: Operator, sort_orders: List[E.SortOrder],
                  fetch_limit: Optional[int] = None):
         self.sort_orders = sort_orders
@@ -147,7 +151,7 @@ class SortExec(Operator):
     def _merge_topk(self, current, staged, k, metrics):
         # self-time lands in elapsed_compute_time_ns via Operator.execute
         parts = ([current] if current is not None else []) + staged
-        merged = ColumnarBatch.concat(parts, self.schema)
+        merged = ColumnarBatch.concat(parts, self.schema, metrics)
         return sort_batch(merged, self.sort_orders, limit=k)
 
     # -- full sort with spill -------------------------------------------------
@@ -208,13 +212,15 @@ class _SortState(MemConsumer):
         return freed
 
     def _sorted_run(self) -> ColumnarBatch:
-        merged = ColumnarBatch.concat(self.staged, self.op.schema)
+        merged = ColumnarBatch.concat(self.staged, self.op.schema,
+                                      self.metrics)
         return sort_batch(merged, self.op.sort_orders)
 
     def _sorted_run_with_keys(self) -> Tuple[ColumnarBatch, np.ndarray]:
         """Sorted run + its (n, 2k) uint64 merge-key matrix, computed from
         one operand kernel dispatch (device key path only)."""
-        merged = ColumnarBatch.concat(self.staged, self.op.schema)
+        merged = ColumnarBatch.concat(self.staged, self.op.schema,
+                                      self.metrics)
         operands = SK.key_operands(merged, self.op.sort_orders)
         if merged.num_rows <= 1:
             idx = np.arange(merged.num_rows, dtype=np.int64)

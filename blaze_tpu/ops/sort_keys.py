@@ -38,6 +38,35 @@ def supports_device_sort(schema: T.Schema, sort_orders: List[E.SortOrder]) -> bo
     return all(is_device_dtype(E.infer_type(so.child, schema)) for so in sort_orders)
 
 
+def device_sortable(batch: ColumnarBatch, sort_orders: List[E.SortOrder]) -> bool:
+    """`supports_device_sort` for the batch in hand: a var-width key that is
+    a reference to a CODED column sorts on the device too, by its rank
+    plane (`coded_rank_plane`)."""
+    from blaze_tpu.core.batch import CodedColumn
+    from blaze_tpu.exprs.compiler import reference_index
+    from blaze_tpu.utils.device import is_device_dtype
+
+    for so in sort_orders:
+        if is_device_dtype(E.infer_type(so.child, batch.schema)):
+            continue
+        idx = reference_index(so.child, batch.schema)
+        if idx is None or not isinstance(batch.columns[idx], CodedColumn):
+            return False
+    return True
+
+
+def coded_rank_plane(col):
+    """A coded column's ORDER as a device plane: the rank of each row's
+    entry in value order (core/dictionary.rank: one sort of the dictionary,
+    bytes order as Spark's), gathered by code. Ordering a coded column is by
+    value, never by code."""
+    from blaze_tpu.core import dictionary as D
+
+    ranks = D.rank(col.dictionary)
+    table = jnp.asarray(ranks if len(ranks) else np.zeros(1, np.int32))
+    return table[col.data]
+
+
 # ---------------------------------------------------------------------------
 # device operands (native dtypes, no 64-bit bitcasts)
 # ---------------------------------------------------------------------------
@@ -58,11 +87,17 @@ def key_operands(batch: ColumnarBatch, sort_orders: List[E.SortOrder],
     range-partition kernel reuses them)."""
     from blaze_tpu.core import kernels as K
 
+    from blaze_tpu.exprs.compiler import CodedVal
+
     ev = evaluator or ExprEvaluator([so.child for so in sort_orders], batch.schema)
-    cols = [ev._to_dev(ev._eval(so.child, batch), batch) for so in sort_orders]
     datas, valids = [], []
-    for v in cols:
-        data, validity = _broadcast(v, batch)
+    for so in sort_orders:
+        v = ev._eval(so.child, batch)
+        if isinstance(v, CodedVal):
+            datas.append(coded_rank_plane(v.col))
+            valids.append(v.col.validity)
+            continue
+        data, validity = _broadcast(ev._to_dev(v, batch), batch)
         datas.append(data)
         valids.append(validity)
     return K.sort_key_operands(datas, valids, batch.row_exists_mask(),
@@ -209,11 +244,23 @@ def host_sort_indices(batch: ColumnarBatch, sort_orders: List[E.SortOrder],
     """Multi-key sort on host via arrow (var-width keys)."""
     ev = evaluator or ExprEvaluator([so.child for so in sort_orders], batch.schema)
     cols = ev.evaluate(batch)
-    from blaze_tpu.core.batch import decode_dictionary
+    from blaze_tpu.core import dictionary as D
+    from blaze_tpu.core.batch import CodedColumn, decode_dictionary
+    from blaze_tpu.utils.device import pull_columns
 
+    n = batch.num_rows
+    # a coded key orders by its entries' ranks (one sort of the dictionary,
+    # core/dictionary.rank): an int32 key beside the others, nothing decoded
+    coded = [c for c in cols if isinstance(c, CodedColumn)]
+    ranked = {}
+    for c, (codes, valid) in zip(coded, pull_columns(coded, n) if coded else ()):
+        ranks = D.rank(c.dictionary)
+        ranked[id(c)] = pa.array(
+            ranks[codes] if len(ranks) else np.zeros(n, np.int32),
+            mask=None if valid.all() else ~valid)
     # pc.sort_indices has no dictionary kernel: decode code-encoded strings
-    arrays = [decode_dictionary(c.to_arrow(batch.num_rows),
-                                c.dtype) for c in cols]
+    arrays = [ranked[id(c)] if id(c) in ranked
+              else decode_dictionary(c.to_arrow(n), c.dtype) for c in cols]
     placements = {so.nulls_first for so in sort_orders}
     if len(placements) > 1:
         # arrow's sort has one global null placement; mixed per-key

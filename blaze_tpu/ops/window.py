@@ -29,8 +29,8 @@ from typing import Iterator, List, Optional
 import numpy as np
 import pyarrow as pa
 
-from blaze_tpu.core.batch import (ColumnarBatch, DeviceColumn, HostColumn,
-                                  _arrow_to_column)
+from blaze_tpu.core.batch import (CodedColumn, ColumnarBatch, DeviceColumn,
+                                  HostColumn, _arrow_to_column, has_planes)
 from blaze_tpu.exprs.compiler import ExprEvaluator
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
@@ -185,13 +185,51 @@ class WindowExec(Operator):
             "dense_rank" if set(kinds) == {"dense_rank"} else "row_number"
         return kinds.index(want) if want in kinds else None
 
+    @property
+    def takes_coded(self) -> bool:
+        """The device program takes a name among its keys as the column's
+        int32 codes; the host paths are handed host columns (`_input`)."""
+        return self._device_spec() is not None
+
+    def _var_width_keys(self) -> List[E.Expr]:
+        child_schema = self.children[0].schema
+        return [e for e in list(self.partition_spec)
+                + [so.child for so in self.order_spec]
+                if T.is_var_width(E.infer_type(e, child_schema))]
+
+    def _input(self, partition, ctx, metrics, batches=None):
+        """The child's batches for a HOST path: coded columns as host
+        columns over their dictionaries (what `execute_child` hands an
+        operator that does not take them)."""
+        if batches is None:
+            batches = self.execute_child(0, partition, ctx, metrics)
+        if not self.takes_coded:
+            return batches
+        return (b.coded_to_host(metrics) for b in batches)
+
     def _execute(self, partition, ctx, metrics):
         spec = self._device_spec()
+        batches = None
+        names = self._var_width_keys() if spec is not None else ()
+        if names:
+            # a name is a device key only where it arrives coded: the
+            # stream's first batch says which (as ops/agg.py does)
+            from blaze_tpu.ops.agg import _Peeked
+
+            first = _Peeked(self.execute_child(0, partition, ctx, metrics))
+            if first.head is None:
+                return
+            if not all(isinstance(_argument(e, first.head), CodedColumn)
+                       for e in names):
+                spec = None
+            batches = iter(first)
         if spec is not None:
-            yield from self._execute_device(spec, partition, ctx, metrics)
+            yield from self._execute_device(spec, partition, ctx, metrics,
+                                            batches)
             return
         if self._segmentable():
-            yield from self._execute_segmented(partition, ctx, metrics)
+            yield from self._execute_segmented(partition, ctx, metrics,
+                                               batches)
             return
         child_schema = self.children[0].schema
         # buffered partition slices are memmgr-watched: accumulation spills
@@ -207,20 +245,21 @@ class WindowExec(Operator):
             # a nonzero count on a default-frame plan means a fast-path
             # regression
             metrics.add("window_group_loops", 1)
-            part = ColumnarBatch.concat(pending.drain(), child_schema)
+            part = ColumnarBatch.concat(pending.drain(), child_schema, metrics)
             out = self._process_one_partition(part)
             for off in range(0, out.num_rows, bs):
                 yield out.slice(off, bs)
 
         try:
             yield from self._execute_buffered(partition, ctx, metrics,
-                                              pending, process_partition)
+                                              pending, process_partition,
+                                              batches)
         finally:
             ctx.mem.unregister(pending)
             pending.release()
 
     def _execute_buffered(self, partition, ctx, metrics, pending,
-                          process_partition):
+                          process_partition, batches=None):
         from blaze_tpu.ops.joins.keymap import RunningKeyCodes
 
         part_ev = ExprEvaluator(self.partition_spec,
@@ -228,7 +267,7 @@ class WindowExec(Operator):
             if self.partition_spec else None
         part_keys = RunningKeyCodes()
         started = False
-        for batch in self.execute_child(0, partition, ctx, metrics):
+        for batch in self._input(partition, ctx, metrics, batches):
             n = batch.num_rows
             if n == 0:
                 continue
@@ -257,7 +296,7 @@ class WindowExec(Operator):
 
     # -- device execution (counters + running-frame aggregates) ---------------
 
-    def _execute_device(self, spec, partition, ctx, metrics):
+    def _execute_device(self, spec, partition, ctx, metrics, batches=None):
         """One ``jit(window_scan)`` a batch (ops/window_device): the sorted
         batch's key and argument planes in, the window columns' planes out,
         the carry a device value from batch to batch, exact at any width.
@@ -300,14 +339,26 @@ class WindowExec(Operator):
         # and the device is not left idle for it (2.6% of q51's query_s on
         # the chip, PERF.md section 6, PR 31)
         carry = ahead = None
-        for batch in self.execute_child(0, partition, ctx, metrics):
+        # the carry holds the last row's codes: one dictionary a stream
+        from blaze_tpu.core.dictionary import OneDictionary
+
+        one_dictionary = OneDictionary()
+        if batches is None:
+            batches = self.execute_child(0, partition, ctx, metrics)
+        for batch in batches:
             n = batch.num_rows
             if n == 0:
                 continue
             metrics.add("window_rows", n)
             metrics.add("window_device_batches", 1)
             cap = batch.capacity
-            keys = [_planes(_argument(e, batch), cap) for e in key_exprs]
+            keys = [_argument(e, batch) for e in key_exprs]
+            for i, k in enumerate(keys):
+                if isinstance(k, CodedColumn):
+                    keys[i], _grown, remapped = one_dictionary.keep(i, k, n)
+                    if remapped:
+                        metrics.add("dict_remap_rows", remapped)
+            keys = [_planes(k, cap) for k in keys]
             args = [None if e is None else _planes(_argument(e, batch), cap)
                     for e in arg_exprs]
             if carry is None:
@@ -329,7 +380,7 @@ class WindowExec(Operator):
 
     # -- segmented execution (counters + default-frame aggregates) ------------
 
-    def _execute_segmented(self, partition, ctx, metrics):
+    def _execute_segmented(self, partition, ctx, metrics, batches=None):
         """One pass, one shot per batch: boundary masks + restart-at-segment
         scans (core/kernels) replace the per-group loop entirely. The carry
         across batches is O(1): counter bases, per-aggregate (sum, count,
@@ -383,7 +434,7 @@ class WindowExec(Operator):
             hold.discard()
 
         try:
-            for batch in self.execute_child(0, partition, ctx, metrics):
+            for batch in self._input(partition, ctx, metrics, batches):
                 n = batch.num_rows
                 if n == 0:
                     continue
@@ -860,7 +911,7 @@ def _planes(col, capacity: int):
     (data, validity), or a wide decimal's host column as its two words."""
     from blaze_tpu.ops import window_device as WD
 
-    if isinstance(col, DeviceColumn):
+    if has_planes(col):  # a coded key's plane is its int32 codes
         return col.data, col.validity
     if WD.is_wide_decimal(col.dtype):
         return WD.wide_words(col, capacity)
@@ -885,8 +936,7 @@ def _keep_at_most(out: ColumnarBatch, limit_col, k: int):
     if count == out.num_rows:
         return out
     return ColumnarBatch(out.schema, [
-        DeviceColumn(c.dtype, d, v)
-        for c, d, v in zip(out.columns, datas, valids)], count)
+        c.like(d, v) for c, d, v in zip(out.columns, datas, valids)], count)
 
 
 def _offset(keys: np.ndarray, off) -> np.ndarray:

@@ -83,6 +83,12 @@ def plan(window_exprs, partition_spec, order_spec, child_schema,
     programs compute (it then takes the host paths, counted)."""
     for e in list(partition_spec) + [so.child for so in order_spec]:
         dt = E.infer_type(e, child_schema)
+        if T.is_var_width(dt) and isinstance(e, (E.Column, E.BoundReference)):
+            # a name that arrives CODED (core/batch.CodedColumn) is an int32
+            # plane here: the program only asks whether a row's key differs
+            # from the row's before it, and within one dictionary equal
+            # codes are equal values (ops/window.py keeps a stream to one)
+            continue
         if not (_integral(dt) or isinstance(dt, T.BooleanType)) or \
                 is_wide_decimal(dt):
             return None

@@ -195,6 +195,40 @@ class MetricNode:
 #   window_rows                      rows every window operator saw, either
 #                                    path (what the benchmark's
 #                                    window_roofline_share counts bytes from)
+#   coded_key_batches                batches a join, an Expand or an
+#                                    aggregation stage worked through with
+#                                    a var-width column as CODES (int32
+#                                    codes and validity on the device, one
+#                                    host dictionary: core/batch.CodedColumn)
+#   host_key_batches == 0            ... on plans whose names are read
+#                                    dictionary-encoded: batches whose coded
+#                                    column was turned into a host column
+#                                    for an operator to work on (one that
+#                                    does not take coded columns, an
+#                                    expression over the values, the host
+#                                    aggregation table, a key encoded on
+#                                    the host), counted on the node the
+#                                    asking operator passes in
+#                                    (ColumnarBatch.coded_to_host, the
+#                                    operator's ExprEvaluator)
+#   rollup_rows                      rows out of ExpandExec (what the
+#                                    benchmark's rollup_roofline_share
+#                                    counts a rollup's bytes from)
+#   dict_entries                     entries of the dictionaries scans
+#                                    adopted (one a coded column and scan
+#                                    task, grown where row groups differ)
+#   dict_remap_rows                  rows whose codes went through a remap
+#                                    table because two dictionaries met
+#                                    (core/dictionary.unify: a scan's row
+#                                    groups, a window's batches, a concat
+#                                    that was handed the operator's node);
+#                                    0 where one dictionary serves a
+#                                    column throughout
+#   join_generic_batches == 0        ... on unique-single-key inner joins
+#                                    over device and coded columns: probe
+#                                    batches that left jit(bhj_inner_fast)
+#                                    (device_inner_batches counts its own)
+#                                    for the generic probe
 TRIPWIRE_METRICS = (
     "split_batches",
     "split_gathers",
@@ -223,6 +257,12 @@ TRIPWIRE_METRICS = (
     "window_host_batches",
     "wide_host_batches",
     "window_rows",
+    "coded_key_batches",
+    "host_key_batches",
+    "rollup_rows",
+    "dict_entries",
+    "dict_remap_rows",
+    "join_generic_batches",
 )
 
 

@@ -219,13 +219,14 @@ def pull_columns(cols, n: int):
     400-group agg output in a 131k-row bucket) we first compact all planes
     to the small capacity bucket on device in ONE dispatch, then pull only
     those bytes (chosen for a link that is gone; not measured on the chip).
-    Host columns pass through as None placeholders.
+    Host columns pass through as None placeholders; a coded column's planes
+    are its codes.
 
     Returns a list aligned with ``cols``: (np_data, np_validity) for device
-    columns, None for host columns."""
-    from blaze_tpu.core.batch import DeviceColumn
+    and coded columns, None for host columns."""
+    from blaze_tpu.core.batch import has_planes
 
-    dev_slots = [i for i, c in enumerate(cols) if isinstance(c, DeviceColumn)]
+    dev_slots = [i for i, c in enumerate(cols) if has_planes(c)]
     if not dev_slots:
         return [None] * len(cols)
     from blaze_tpu.config import get_config

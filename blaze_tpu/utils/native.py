@@ -33,6 +33,7 @@ def _configure(lib: ctypes.CDLL):
                                  ctypes.c_size_t, ctypes.c_size_t, ctypes.c_int]
     lib.bt_murmur3_bytes.argtypes = [ctypes.c_void_p] * 4 + [ctypes.c_size_t]
     lib.bt_xxh64_bytes.argtypes = [ctypes.c_void_p] * 4 + [ctypes.c_size_t]
+    lib.bt_murmur3_codes.argtypes = [ctypes.c_void_p] * 6 + [ctypes.c_size_t]
     lib.bt_zstd_compress_bound.restype = ctypes.c_int64
     lib.bt_zstd_compress_bound.argtypes = [ctypes.c_int64]
     lib.bt_zstd_compress.restype = ctypes.c_int64
@@ -181,6 +182,27 @@ def murmur3_bytes(offsets: np.ndarray, data: np.ndarray, seeds: np.ndarray
     seeds = np.ascontiguousarray(seeds, dtype=np.uint32)
     out = np.empty(n, dtype=np.uint32)
     l.bt_murmur3_bytes(offsets.ctypes.data, data.ctypes.data,
+                       seeds.ctypes.data, out.ctypes.data, n)
+    return out
+
+
+def murmur3_codes(codes: np.ndarray, valid, offsets: np.ndarray,
+                  data: np.ndarray, seeds: np.ndarray) -> np.ndarray:
+    """`murmur3_bytes` of dictionary entries by code: row i hashes entry
+    ``codes[i]``'s bytes with ``seeds[i]``; rows where ``valid`` (None: all)
+    is False keep their seed."""
+    l = lib()
+    n = len(codes)
+    codes = np.ascontiguousarray(codes, dtype=np.int32)
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    data = np.ascontiguousarray(data, dtype=np.uint8)
+    seeds = np.ascontiguousarray(seeds, dtype=np.uint32)
+    if valid is not None:
+        valid = np.ascontiguousarray(valid, dtype=np.uint8)
+    out = np.empty(n, dtype=np.uint32)
+    l.bt_murmur3_codes(codes.ctypes.data,
+                       valid.ctypes.data if valid is not None else None,
+                       offsets.ctypes.data, data.ctypes.data,
                        seeds.ctypes.data, out.ctypes.data, n)
     return out
 

@@ -99,6 +99,35 @@ EXPORT void bt_murmur3_bytes(const int64_t* offsets, const uint8_t* data,
   }
 }
 
+// the same hash of dictionary entries BY CODE: row i hashes the bytes of
+// entry codes[i], seeded by seeds[i]; a row that is not valid (valid may be
+// null: all are) keeps its seed. No string is materialized a row.
+EXPORT void bt_murmur3_codes(const int32_t* codes, const uint8_t* valid,
+                             const int64_t* offsets, const uint8_t* data,
+                             const uint32_t* seeds, uint32_t* out, size_t n) {
+  for (size_t i = 0; i < n; ++i) {
+    if (valid && !valid[i]) {
+      out[i] = seeds[i];
+      continue;
+    }
+    int64_t at = offsets[codes[i]];
+    const uint8_t* p = data + at;
+    int64_t len = offsets[codes[i] + 1] - at;
+    int64_t aligned = len & ~int64_t(3);
+    uint32_t h1 = seeds[i];
+    for (int64_t j = 0; j < aligned; j += 4) {
+      uint32_t k;
+      std::memcpy(&k, p + j, 4);
+      h1 = mmh3_mix_h1(h1, mmh3_mix_k1(k));
+    }
+    for (int64_t j = aligned; j < len; ++j) {
+      int32_t b = static_cast<int8_t>(p[j]);
+      h1 = mmh3_mix_h1(h1, mmh3_mix_k1(static_cast<uint32_t>(b)));
+    }
+    out[i] = mmh3_fmix(h1, static_cast<uint32_t>(len));
+  }
+}
+
 // ---------------------------------------------------------------------------
 // xxhash64 over variable-length byte strings (spark XXH64)
 // ---------------------------------------------------------------------------

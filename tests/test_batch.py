@@ -82,14 +82,26 @@ def test_padding_is_zero_and_invalid():
     assert (~validity[3:]).all()
 
 
-def test_dict_encode():
-    b = ColumnarBatch.from_pydict({"s": ["x", "y", "x", None]})
-    col, dictionary = b.columns[0].dict_encode(b.capacity)
-    codes = np.asarray(col.data)[:4]
-    validity = np.asarray(col.validity)[:4]
-    assert validity.tolist() == [True, True, True, False]
-    vals = dictionary.to_pylist()
+def test_dictionary_read_strings_are_coded():
+    """A dictionary-encoded string column arrives CODED: int32 codes and
+    validity on the device under the padding contract, one dictionary on
+    the host (`HostColumn.dict_encode`, the per-batch encoding this
+    replaced, is gone)."""
+    import pyarrow as pa
+
+    from blaze_tpu.core.batch import CodedColumn
+
+    arr = pa.array(["x", "y", "x", None]).dictionary_encode()
+    b = ColumnarBatch.from_arrow(pa.table({"s": arr}))
+    col = b.columns[0]
+    assert isinstance(col, CodedColumn) and col.data.dtype == np.int32
+    codes = np.asarray(col.data)
+    validity = np.asarray(col.validity)
+    assert validity[:4].tolist() == [True, True, True, False]
+    assert (codes[3:] == 0).all() and (~validity[4:]).all()
+    vals = col.dictionary.to_pylist()
     assert vals[codes[0]] == "x" and vals[codes[1]] == "y" and codes[0] == codes[2]
+    assert b.to_arrow().column(0).to_pylist() == ["x", "y", "x", None]
 
 
 def test_empty():

@@ -60,11 +60,13 @@ def test_dict_def_frame_sequencing():
     got = list(BatchReader(buf))
     tbl = pa.Table.from_batches([b.to_arrow() for b in got])
     assert tbl.to_pydict() == big.to_arrow().to_pydict()
-    # the wire columns (before to_arrow() normalizes to the schema type)
-    # stay dictionary-encoded over one shared dictionary
-    arrs = [b.column(0).array for b in got]
-    assert all(pa.types.is_dictionary(a.type) for a in arrs)
-    assert dict_identity(arrs[0].dictionary) == dict_identity(arrs[1].dictionary)
+    # the wire columns (before to_arrow() decodes to the schema type) come
+    # back CODED over one shared dictionary
+    from blaze_tpu.core.batch import CodedColumn
+
+    cols = [b.column(0) for b in got]
+    assert all(isinstance(c, CodedColumn) for c in cols)
+    assert dict_identity(cols[0].dictionary) == dict_identity(cols[1].dictionary)
 
 
 @pytest.mark.quick

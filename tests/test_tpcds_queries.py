@@ -83,3 +83,40 @@ def test_tpcds_query(name, dataset):
     assert _rows_equal(got, want, flags), (
         f"{name}: {len(got)} rows vs oracle {len(want)};"
         f" first diff: {next(((g, w) for g, w in zip(got, want) if g != w), None)}")
+
+
+def _by_operator(node, metric, into=None):
+    """A metric's totals by operator name over a `MetricNode.to_dict()`."""
+    into = {} if into is None else into
+    if node["values"].get(metric):
+        into[node["name"]] = into.get(node["name"], 0) + node["values"][metric]
+    for child in node["children"]:
+        _by_operator(child, metric, into)
+    return into
+
+
+@pytest.mark.parametrize("name", ["q22", "q67"])
+def test_rollup_names_stay_on_the_device_as_codes(name, dataset):
+    """The two ROLLUPs over item names: the scan reads the names coded, and
+    join payload, Expand, both aggregation stages and the exchange work on
+    the codes; no batch's names are turned into a host column — in q67 but
+    for the rank window's one batch: it orders by a decimal wider than
+    int64, which keeps it off the device program (a window over a coded
+    partition key alone runs there: tests/test_coded_column.py)."""
+    tables, dfs = dataset
+    plan_json, oracle, extract, flags = QUERIES[name]()
+    result = SparkPlanConverter(tables=tables).convert(json.dumps(plan_json))
+    with Session() as sess:
+        out = sess.execute_to_table(result.plan)
+        tree = sess.metrics.to_dict()
+    rows = extract(out) if extract is not None else \
+        list(zip(*out.to_pydict().values()))
+    assert _rows_equal(_sorted_if_tied(rows, flags),
+                       _sorted_if_tied(oracle(dfs), flags), flags)
+    coded = _by_operator(tree, "coded_key_batches")
+    assert {"BroadcastJoinExec", "ExpandExec", "AggExec"} <= set(coded), coded
+    assert _by_operator(tree, "rollup_rows")["ExpandExec"] > 0
+    assert not _by_operator(tree, "agg_reintern_rows")
+    assert not _by_operator(tree, "join_generic_batches")
+    assert _by_operator(tree, "host_key_batches") == (
+        {} if name == "q22" else {"WindowExec": 1})
