@@ -84,12 +84,16 @@ class Config:
     # sharing a box); 1 still builds a mesh so the code path is identical.
     multichip_devices: int = 0
 
-    # The "device" shuffle tier: pool-less sessions with a mesh (or
-    # multichip enabled) commit device-resident sub-batch references into
-    # the MemSegmentRegistry — no host pull between fused stages. False
-    # pins such sessions back to the host "process" tier (escape hatch);
-    # the tier also degrades per map output past the HBM budget or when
-    # the ``device.put`` failpoint fires.
+    # The "device" shuffle tier: a pool-less session whose stages run on an
+    # accelerator (mesh or no mesh; also any session with a mesh) routes
+    # each batch of a hash, range or round-robin exchange on the chip and
+    # commits the device-resident sub-batch references into the
+    # MemSegmentRegistry — the rows are never pulled and never uploaded
+    # again. On the CPU backend the session negotiates the host "process"
+    # tier instead (one memory: numpy routing is the cheaper one). False
+    # pins every session back to "process" (escape hatch); the tier also
+    # degrades per batch (host-backed input) or per map output (past the
+    # byte budget, or when the ``device.put`` failpoint fires).
     device_shuffle_tier: bool = True
 
     # AQE small-partition coalescing (Spark's coalescePartitions): adjacent
@@ -367,9 +371,9 @@ class Config:
     zero_copy_shuffle: bool = True
 
     # Force one tier for tests: None = negotiate from placement
-    # (pool-less -> "device" under a mesh / multichip, else "process";
-    # local pool -> "shm"); "device" | "process" | "shm" | "ipc" pin the
-    # tier. "process"/"device" with a worker pool degrade to "shm" (batch
+    # (pool-less -> "device" on an accelerator or under a mesh, "process"
+    # on the CPU backend; local pool -> "shm"); "device" | "process" |
+    # "shm" | "ipc" pin the tier. "process"/"device" with a worker pool degrade to "shm" (batch
     # references cannot cross process boundaries).
     zero_copy_tier: Optional[str] = None
 

@@ -502,13 +502,14 @@ def _arrow_to_column(arr: pa.Array, dt: T.DataType, capacity: int) -> Column:
     return HostColumn(dt, arr)
 
 
-def _one_dictionary_a_column(batches):
+def _one_dictionary_a_column(batches, rows):
     """Before a concat: every var-width column either coded in ALL the
     batches, over one dictionary (the others' codes remapped through
     core/dictionary.unify's tables), or coded in none (the coded ones
     handed over as host columns: a batch built without a dictionary, such
-    as `ColumnarBatch.empty`, is among them). Returns the batches and the
-    rows whose codes were remapped."""
+    as `ColumnarBatch.empty`, is among them). ``rows[j]`` is what the
+    concat takes of batch j. Returns the batches and the rows whose codes
+    were remapped."""
     ncols = len(batches[0].columns)
     coded = [i for i in range(ncols)
              if any(isinstance(b.columns[i], CodedColumn) for b in batches)]
@@ -529,9 +530,9 @@ def _one_dictionary_a_column(batches):
         if all(c.dictionary is first for c in col_of):
             continue
         unified, tables = D.unify([c.dictionary for c in col_of])
-        for cols, b, table in zip(out, batches, tables):
+        for cols, n, table in zip(out, rows, tables):
             cols[i] = cols[i].remapped(unified, table)
-            remapped += b.num_rows if table is not None else 0
+            remapped += n if table is not None else 0
     return [ColumnarBatch(b.schema, cols, b.num_rows)
             for b, cols in zip(batches, out)], remapped
 
@@ -747,7 +748,8 @@ class ColumnarBatch:
             return ColumnarBatch.empty(schema)
         batches = [b for b in batches if b.num_rows > 0] or batches[:1]
         if len(batches) == 1:
-            return batches[0]
+            only = batches[0]
+            return only.to_columnar() if isinstance(only, RowWindow) else only
         schema = schema or batches[0].schema
         # bound the jit fan-in: concatenating thousands of tiny batches in one
         # traced call unrolls into an HLO whose compile time is quadratic-ish
@@ -760,9 +762,19 @@ class ColumnarBatch:
                                      metrics)
                 for i in range(0, len(batches), _CONCAT_FANIN)
             ]
-        total = sum(b.num_rows for b in batches)
+        # a part is a batch's first rows, or a window of one (RowWindow):
+        # the copy then starts at the window's first row
+        rows = [b.num_rows for b in batches]
+        starts = None
+        if all(isinstance(b, RowWindow) for b in batches):
+            starts = [b.start for b in batches]
+            batches = [b.batch for b in batches]
+        else:  # windows among whole batches: rare, so they are cut first
+            batches = [b.to_columnar() if isinstance(b, RowWindow) else b
+                       for b in batches]
+        total = sum(rows)
         cap = get_config().capacity_for(total)
-        batches, remapped = _one_dictionary_a_column(batches)
+        batches, remapped = _one_dictionary_a_column(batches, rows)
         if metrics is not None and remapped:
             metrics.add("dict_remap_rows", remapped)
         slots = batches[0]._device_slots()
@@ -788,10 +800,11 @@ class ColumnarBatch:
                                          jax.device_put(c.validity, target))
                     aligned.append(ColumnarBatch(b.schema, cols, b.num_rows))
                 batches = aligned
+                cols = [None] * ncols
             datas, valids = kernels.concat_planes(
                 [tuple(b.columns[i].data for b in batches) for i in slots],
                 [tuple(b.columns[i].validity for b in batches) for i in slots],
-                [b.num_rows for b in batches], cap)
+                rows, cap, starts)
             for k, i in enumerate(slots):
                 cols[i] = batches[0].columns[i].like(datas[k], valids[k])
         for i in range(ncols):
@@ -863,6 +876,29 @@ class ColumnarBatch:
 
     def __repr__(self):
         return f"ColumnarBatch({self.num_rows} rows, schema={self.schema.names})"
+
+
+@dataclasses.dataclass
+class RowWindow:
+    """Rows ``[start, start + num_rows)`` of a batch whose columns are all
+    device planes, not copied yet: what the device shuffle tier stages for
+    a partition. The exchange's program moves a batch's rows into partition
+    order once, every partition's rows are then a window of that one batch,
+    and the reduce side's concat copies the windows straight into its
+    output (``ColumnarBatch.concat``) — no slice a partition on the map
+    side, no padding of each slice to its own capacity bucket."""
+
+    batch: ColumnarBatch
+    start: int
+    num_rows: int
+
+    @property
+    def schema(self) -> T.Schema:
+        return self.batch.schema
+
+    def to_columnar(self) -> ColumnarBatch:
+        """The window as a batch of its own (a slice copy a plane)."""
+        return self.batch.slice(self.start, self.num_rows)
 
 
 @dataclasses.dataclass

@@ -484,14 +484,6 @@ def _range_pids(datas, valids, exists, bound_ops, spec):
     return jnp.where(exists, pid, jnp.int32(bound_ops[0].shape[0] + 1))
 
 
-@functools.partial(jax.jit, static_argnames=("spec",))
-def _range_order(datas, valids, exists, bound_ops, spec):
-    pid = _range_pids(datas, valids, exists, bound_ops, spec)
-    iota = jnp.arange(pid.shape[0], dtype=jnp.int32)
-    sorted_pid, order = lax.sort((pid, iota), num_keys=1, is_stable=True)
-    return sorted_pid, order
-
-
 def range_partition_ids(datas, valids, exists, bound_ops, spec):
     """Row-order partition ids for range partitioning, ONE jitted dispatch:
     key normalization + device searchsorted against resident bounds."""
@@ -499,26 +491,26 @@ def range_partition_ids(datas, valids, exists, bound_ops, spec):
                      tuple(bound_ops), spec)
 
 
-def range_partition_order(datas, valids, exists, bound_ops, spec):
-    """Fused range-exchange split: normalize keys, compute partition ids,
-    and stable-sort rows by pid — all in ONE dispatch. Returns
-    (sorted_pids, order); the caller does one gather by ``order`` and
-    slices contiguous pid runs."""
-    return _dispatch(_range_order, tuple(datas), tuple(valids), exists,
-                     tuple(bound_ops), spec)
-
-
 @functools.partial(jax.jit, static_argnames=("out_cap",))
-def _concat_gather(datas, valids, offsets, out_cap):
+def _concat_gather(datas, valids, offsets, out_cap, starts=None):
     # (the name is the trace's: the benchmark's breakdown reads it; nothing
     # is gathered.) Part j's live rows are a prefix of it and land at
     # offsets[j], so the parts are written whole, in order, each over the
     # padding tail of the one before: offsets[j] + cap_j never passes the sum
     # of the capacities, so no update clamps. offsets[-1] is the row total.
+    # With ``starts`` (else None: another program) part j's live rows begin
+    # at its row starts[j] — a window of a batch the exchange routed — and
+    # the part is read from there, padded by its own length so that the
+    # read cannot clamp; what follows a window's rows is other rows, not
+    # padding, and is written over or masked all the same.
     def lay(parts):
         rows = max(out_cap, sum(p.shape[0] for p in parts))
         buf = jnp.zeros((rows,), parts[0].dtype)
         for j, part in enumerate(parts):
+            if starts is not None:
+                part = lax.dynamic_slice(
+                    jnp.concatenate([part, jnp.zeros_like(part)]),
+                    (starts[j],), part.shape)
             buf = lax.dynamic_update_slice(buf, part, (offsets[j],))
         return buf[:out_cap]
 
@@ -812,20 +804,23 @@ def segment_running_reduce_traced(vals, valid, seg_start, is_min: bool,
 
 def concat_planes(per_field_datas: List[Tuple[jax.Array, ...]],
                   per_field_valids: List[Tuple[jax.Array, ...]],
-                  num_rows: Sequence[int], out_cap: int):
+                  num_rows: Sequence[int], out_cap: int,
+                  starts: Sequence[int] = None):
     """Concatenate k batches' planes field-wise and compact live rows, in ONE
     jitted dispatch of slice copies (replaces the arrow round trip the
     profiler flagged in ColumnarBatch.concat). ``per_field_datas[f]`` is the
     f-th field's array from each input batch; ``num_rows[j]`` is batch j's
-    live row count. The host sends the k + 1 running row totals, nothing
-    capacity-sized."""
+    live row count, and ``starts[j]`` the row of it they begin at (None:
+    every batch's rows are its prefix). The host sends the k + 1 running row
+    totals (and the k starts), nothing capacity-sized."""
     offsets = np.zeros(len(num_rows) + 1, dtype=np.int32)
     np.cumsum(num_rows, out=offsets[1:])
     return _dispatch(
         _concat_gather,
         tuple(tuple(p) for p in per_field_datas),
         tuple(tuple(p) for p in per_field_valids),
-        jnp.asarray(offsets), out_cap=out_cap)
+        jnp.asarray(offsets), out_cap=out_cap,
+        starts=None if starts is None else jnp.asarray(starts, jnp.int32))
 
 
 # -- radix key partitioning ----------------------------------------------------

@@ -95,23 +95,50 @@ class IpcReaderExec(Operator):
             metrics.add("ipc_decode_in_prefetch", 1)
             return batch
 
-        def _materialize(ref):
-            # process-tier block: the batch reference crossed the exchange
-            # with serde skipped entirely; only the device upload remains
-            # (device-tier references are already on-chip ColumnarBatches —
-            # nothing left to do but count the bytes that never touched
-            # the host)
-            if hasattr(ref, "to_columnar"):
+        def _materialize(refs):
+            # staged references crossed the exchange with serde skipped
+            # entirely. Process tier: a host batch, and only the device
+            # upload remains. Device tier: windows of the batches the map
+            # side routed on the chip (or whole on-chip batches) — copied
+            # together into one batch here, with nothing left to do but
+            # count the bytes that never touched the host
+            from blaze_tpu.core.batch import (ColumnarBatch, HostBatch,
+                                              has_planes)
+
+            if isinstance(refs[0], HostBatch):
+                (ref,) = refs
                 batch = ref.to_columnar()
             else:
-                batch = ref
-                from blaze_tpu.core.batch import DeviceColumn
-                if batch.columns and all(isinstance(c, DeviceColumn)
+                batch = ColumnarBatch.concat(refs, metrics=metrics)
+                if batch.columns and all(has_planes(c)
                                          for c in batch.columns):
                     metrics.add("device_shuffle_bytes", int(batch.nbytes()))
-            metrics.add("serde_elided_batches", 1)
-            _TM_ELIDED.inc()
+            metrics.add("serde_elided_batches", len(refs))
+            _TM_ELIDED.inc(len(refs))
             return batch
+
+        def _grouped(refs):
+            """Staged references in the groups one ``_materialize`` takes:
+            a host batch alone; the device tier's windows together while
+            they stay under a batch's rows, as ``CoalesceBatchesExec``
+            would merge the pieces, and under the concat's fan-in."""
+            from blaze_tpu.core.batch import _CONCAT_FANIN, RowWindow
+
+            group, rows = [], 0
+            for ref in refs:
+                if not isinstance(ref, RowWindow):
+                    if group:
+                        yield group
+                        group, rows = [], 0
+                    yield [ref]
+                    continue
+                group.append(ref)
+                rows += ref.num_rows
+                if rows >= ctx.conf.batch_size or len(group) == _CONCAT_FANIN:
+                    yield group
+                    group, rows = [], 0
+            if group:
+                yield group
 
         # the prefetch and decode threads' spans are this task's
         task = task_context()
@@ -138,8 +165,8 @@ class IpcReaderExec(Operator):
                         # in-process segment references (zero-copy process
                         # tier): materialize on the decode pool so device
                         # upload overlaps downstream compute like decode does
-                        for hb in block[1]:
-                            fu = pool.submit(_materialize, hb)
+                        for refs in _grouped(block[1]):
+                            fu = pool.submit(_materialize, refs)
                             pending = [f for f in pending if not f.done()]
                             pending.append(fu)
                             if not _put(fu):

@@ -9,16 +9,87 @@ single-partition collapse.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-from blaze_tpu.core.batch import ColumnarBatch, HostBatch
+from blaze_tpu.core import kernels as K
+from blaze_tpu.core.batch import (ColumnarBatch, DeviceColumn, HostBatch,
+                                  RowWindow, has_planes)
+from blaze_tpu.exprs import spark_hash as SH
 from blaze_tpu.exprs.compiler import ExprEvaluator
 from blaze_tpu.exprs.spark_hash import hash_batch
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import nodes as N
+from blaze_tpu.ir import types as T
 from blaze_tpu.ops import sort_keys as SK
+
+
+def _pmod(h, n: int):
+    """Spark's pmod of the int32 murmur3 hashes by the partition count."""
+    r = lax.rem(lax.bitcast_convert_type(h, jnp.int32), jnp.int32(n))
+    return jnp.where(r < 0, r + jnp.int32(n), r)
+
+
+@functools.partial(jax.jit, static_argnames=("how", "n"))
+def exchange_route(keys, datas, valids, num_rows, how, n):
+    """One batch routed to ``n`` partitions in ONE device program: the rows'
+    partition ids, a stable order by them, every (data, validity) plane moved
+    into that order by one matrix gather, and the ``n + 1`` partition
+    offsets — all the host reads back. Nothing else crosses the link: no
+    hash, order or index plane.
+
+    ``how`` (static) says where the partition ids come from, ``keys`` holds
+    its operands:
+
+    - ``("hash", kinds)``: Spark's murmur3 (seed 42) of the key planes
+      ``keys = (key_datas, key_valids)``, pmod ``n`` — bit for bit
+      ``HashPartitioner.partition_ids_host``;
+    - ``("range", spec)``: bounds <= the row's sort key, ``keys =
+      (key_datas, key_valids, bound_ops)`` (``core/kernels._range_pids``);
+    - ``("round_robin",)``: ``keys`` is the first row's partition;
+    - ``("pid",)``: ``keys`` is the int32 plane of ids the host computed
+      (keys that are no device planes: var-width, coded, wide decimals).
+
+    Padding rows go past the last partition. The order is one sort of one
+    operand — the id packed above the row's index in a 32- or 64-bit word —
+    so it is stable by construction, and a one-operand sort compiles in
+    seconds where a multi-operand 64-bit one takes a minute an operand
+    (PERF.md section 6). Returns ``(offsets, order, datas, valids)``: rows
+    ``offsets[p]:offsets[p + 1]`` of the moved planes are partition ``p``'s,
+    in the batch's row order; ``order`` is the row standing at each place
+    (for the host columns of a batch that has some)."""
+    cap = min(p.shape[0] for p in (*datas, *valids))
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    exists = iota < num_rows
+    with jax.named_scope("exchange_route"):
+        if how[0] == "hash":
+            h = jnp.full((cap,), 42, jnp.uint32)
+            for d, v, kind in zip(keys[0], keys[1], how[1]):
+                h = SH.murmur3_update_column(h, d[:cap], v[:cap], kind)
+            pid = _pmod(h, n)
+        elif how[0] == "range":
+            key_datas, key_valids, bound_ops = keys
+            pid = K._range_pids(tuple(d[:cap] for d in key_datas),
+                                tuple(v[:cap] for v in key_valids), exists,
+                                bound_ops, how[1])
+        elif how[0] == "round_robin":
+            pid = lax.rem(iota + keys, jnp.int32(n))
+        else:
+            pid = keys[:cap]
+        pid = jnp.where(exists, pid, jnp.int32(n))
+        bits = max(1, (cap - 1).bit_length())
+        word = jnp.uint32 if (n + 1) << bits <= 1 << 32 else jnp.uint64
+        packed = lax.sort((pid.astype(word) << bits) | iota.astype(word))
+        order = (packed & word((1 << bits) - 1)).astype(jnp.int32)
+        offsets = jnp.searchsorted(
+            packed, jnp.arange(n + 1, dtype=word) << bits).astype(jnp.int32)
+        out_d, out_v = K.take_rows_traced(datas, valids, order, exists)
+    return offsets, order, out_d, out_v
 
 
 class Repartitioner:
@@ -59,12 +130,30 @@ class Repartitioner:
         order = np.argsort(pids, kind="stable")
         return order, self._ranges_of(pids[order])
 
-    def bucketize(self, batch: ColumnarBatch) -> List[Tuple[int, ColumnarBatch]]:
-        """Split a batch into per-partition device sub-batches: one stable
-        gather by partition id, then contiguous slices (reference: radix sort
-        by pid in buffered_data.rs). Used when the sub-batches feed further
-        device compute; the serialize path uses bucketize_host."""
+    def _route(self, batch: ColumnarBatch):
+        """``(how, keys)`` of :func:`exchange_route` for this batch. The
+        default hands the program the ids ``partition_ids`` computes (keys
+        that are no device planes); a partitioner whose keys are device
+        planes names them and the program computes the ids itself."""
+        pids = np.full(batch.capacity, self.num_partitions, dtype=np.int32)
+        pids[:batch.num_rows] = self.partition_ids(batch)
+        return ("pid",), jnp.asarray(pids)
+
+    def bucketize(self, batch: ColumnarBatch):
+        """Split a batch into per-partition device sub-batches, the device
+        shuffle tier's staging form: ONE device program
+        (:func:`exchange_route`: ids, stable order, one matrix gather,
+        offsets) and ONE small wait for the ``n + 1`` offsets
+        (``sync:exchange_route``). A partition's rows are then a window of
+        the one moved batch (``RowWindow``: nothing more is dispatched, the
+        reduce side's concat copies the windows); a batch with host columns
+        is cut into slices instead. The rows of every partition keep the
+        batch's order, as ``bucketize_host``'s do (reference: radix sort by
+        pid in buffered_data.rs). Returns ``[(pid, RowWindow |
+        ColumnarBatch)]`` for the partitions that got rows."""
         import time
+
+        from blaze_tpu.utils.device import wait_array
 
         n = batch.num_rows
         if n == 0:
@@ -73,10 +162,31 @@ class Repartitioner:
         if self.num_partitions == 1:
             return [(0, batch)]
         t0 = time.perf_counter_ns()
-        order, ranges = self._split_ranges(self.partition_ids(batch))
+        how, keys = self._route(batch)
+        slots = batch._device_slots()
+        offsets, order, datas, valids = K._dispatch(
+            exchange_route, keys,
+            tuple(batch.columns[i].data for i in slots),
+            tuple(batch.columns[i].validity for i in slots),
+            np.int32(n), how=how, n=self.num_partitions)
         self.split_gathers += 1
-        gathered = batch.take(order)
-        out = [(pid, gathered.slice(s, e - s)) for pid, s, e in ranges]
+        offsets = wait_array(offsets, "exchange_route").tolist()
+        cols = list(batch.columns)
+        for k, i in enumerate(slots):
+            cols[i] = cols[i].like(datas[k], valids[k])
+        if len(slots) < len(cols):
+            # host columns follow the order the device found
+            host_order = np.asarray(order)[:n].astype(np.int64)
+            for i, c in enumerate(cols):
+                if not has_planes(c):
+                    cols[i] = c.take_host(host_order)
+        moved = ColumnarBatch(batch.schema, cols, n)
+        windows = len(slots) == len(cols)
+        out = [(pid, moved if e - s == n
+                else RowWindow(moved, s, e - s) if windows
+                else moved.slice(s, e - s))
+               for pid, (s, e) in enumerate(zip(offsets, offsets[1:]))
+               if e > s]
         self.split_time_ns += time.perf_counter_ns() - t0
         return out
 
@@ -128,6 +238,30 @@ class HashPartitioner(Repartitioner):
         hashes = hash_batch(cols, batch.num_rows, batch.capacity, seed=42)
         n = np.int64(self.num_partitions)
         return (((hashes.astype(np.int64) % n) + n) % n).astype(np.int32)
+
+    def _route(self, batch):
+        """Key planes for the device program where every key is one: a
+        plain column reference is read off the batch (the evaluator pays two
+        eager dispatches a reference, PERF.md section 7), any other
+        expression is evaluated in front of the program. A key that is no
+        fixed-width device plane (var-width or coded: hashed by value on the
+        host; a wide decimal: hashed by its bytes) takes the ids the host
+        computes."""
+        names = [f.name for f in batch.schema.fields]
+        if all(isinstance(e, E.Column) and e.name in names
+               for e in self.exprs):
+            idx = [names.index(e.name) for e in self.exprs]
+            cols = [batch.columns[i] for i in idx]
+            dtypes = [batch.schema[i].dtype for i in idx]
+        else:
+            cols = self.ev.evaluate(batch)
+            dtypes = [c.dtype for c in cols]
+        if not all(isinstance(c, DeviceColumn) and not T.is_wide_decimal(dt)
+                   for c, dt in zip(cols, dtypes)):
+            return super()._route(batch)
+        return (("hash", tuple(SH._dtype_kind(dt) for dt in dtypes)),
+                (tuple(c.data for c in cols),
+                 tuple(c.validity for c in cols)))
 
     def partition_ids_host(self, host):
         """Numpy murmur3 over plain-column integer keys of an already
@@ -192,6 +326,11 @@ class RoundRobinPartitioner(Repartitioner):
     def partition_ids_host(self, host):
         return self.partition_ids(host)  # only reads num_rows
 
+    def _route(self, batch):
+        start = self.next_pid
+        self.next_pid = int((start + batch.num_rows) % self.num_partitions)
+        return ("round_robin",), np.int32(start)
+
 
 class RangePartitioner(Repartitioner):
     """Binary search of sampled bounds over normalized sort keys
@@ -202,9 +341,9 @@ class RangePartitioner(Repartitioner):
     order (the former per-row python ``bisect`` walk was the measured 10M-row
     sort bottleneck, ~4 s per 262k-row batch):
 
-    - device batches: the fused kernel ``core/kernels.range_partition_order``
-      normalizes keys, counts bounds <= key, and pid-sorts rows in ONE
-      dispatch against device-resident bound operands;
+    - device batches: ``exchange_route`` normalizes keys, counts bounds <=
+      key (``core/kernels._range_pids``), orders the rows by partition and
+      moves them in ONE dispatch against device-resident bound operands;
     - host (staged) batches: numpy ``searchsorted`` over fixed-width packed
       big-endian key rows (ops/sort_keys.pack_key_rows).
     """
@@ -270,8 +409,6 @@ class RangePartitioner(Repartitioner):
     def partition_ids(self, batch):
         if not self.bounds:
             return np.zeros(batch.num_rows, dtype=np.int32)
-        from blaze_tpu.core import kernels as K
-
         if SK.supports_device_sort(batch.schema, self.sort_orders):
             datas, valids = self._key_planes(batch)
             pids = K.range_partition_ids(datas, valids, batch.row_exists_mask(),
@@ -304,30 +441,15 @@ class RangePartitioner(Repartitioner):
         return np.searchsorted(self._bounds_packed(), packed,
                                side="right").astype(np.int32)
 
-    def bucketize(self, batch):
-        """Fused device split: ONE kernel dispatch computes pids and the
-        stable pid-sort order, ONE gather materializes the reordered batch,
-        then per-partition sub-batches are contiguous slices."""
-        n = batch.num_rows
-        if n == 0:
-            return []
-        if (not self.bounds or self.num_partitions == 1
-                or not SK.supports_device_sort(batch.schema, self.sort_orders)):
-            return super().bucketize(batch)
-        from blaze_tpu.core import kernels as K
-
-        self.split_batches += 1
+    def _route(self, batch):
+        """The sort keys' planes and the resident bound operands where the
+        keys normalize on the device; else the ids the host finds."""
+        if not self.bounds or \
+                not SK.supports_device_sort(batch.schema, self.sort_orders):
+            return super()._route(batch)
         datas, valids = self._key_planes(batch)
-        sorted_pids, order = K.range_partition_order(
-            datas, valids, batch.row_exists_mask(), self._device_bounds(),
-            SK.key_spec(self.sort_orders))
-        # padding rows carry pid num_partitions+1 and sort past every live
-        # row, so the first n order entries are exactly the live rows
-        spids = np.asarray(sorted_pids)[:n]
-        self.split_gathers += 1
-        gathered = batch.take(np.asarray(order)[:n].astype(np.int64))
-        return [(pid, gathered.slice(s, e - s))
-                for pid, s, e in self._ranges_of(spids)]
+        return (("range", SK.key_spec(self.sort_orders)),
+                (tuple(datas), tuple(valids), self._device_bounds()))
 
 
 def create_repartitioner(partitioning, schema) -> Repartitioner:

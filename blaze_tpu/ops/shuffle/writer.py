@@ -22,7 +22,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from blaze_tpu.core.batch import ColumnarBatch
+from blaze_tpu.core.batch import ColumnarBatch, RowWindow, has_planes
 from blaze_tpu.io.batch_serde import BatchWriter
 from blaze_tpu.obs.telemetry import get_registry
 from blaze_tpu.ops.base import ExecContext, Operator
@@ -46,12 +46,15 @@ _TM_TIER_DEGRADED = get_registry().counter(
     "blaze_shuffle_tier_degraded_total",
     "map outputs whose shm-tier commit ran out of tmpfs headroom and "
     "degraded to the spill-dir tier (redirect marker + disk file) instead "
-    "of failing the query")
+    "of failing the query; on the device tier, batches and map outputs "
+    "that went the host way (host-backed input, a failed placement, the "
+    "byte budget)")
 _TM_DEVICE_RESIDENT = get_registry().counter(
     "blaze_shuffle_device_resident_bytes",
-    "column bytes committed to the segment registry as device-resident "
-    "sub-batch references (the multichip 'device' shuffle tier — no host "
-    "pull between fused stages)")
+    "column bytes, padding included, committed to the segment registry as "
+    "device-resident sub-batch references (the 'device' shuffle tier of a "
+    "pool-less session on an accelerator or a mesh: the rows are routed on "
+    "the chip and never pulled)")
 
 
 class _PartitionStreams:
@@ -102,13 +105,18 @@ class ShuffleWriterExec(Operator):
     becomes a footer-only lineage marker, and the index keeps logical
     staged sizes so AQE coalescing/skew sizing still sees real bytes.
 
-    ``device_sink`` (the multichip "device" tier, refines ``mem_sink``)
-    keeps the staged references DEVICE-RESIDENT: device batches are
-    bucketized on-chip (one gather, contiguous slices) and committed as
-    device sub-batch references, so the next fused stage reads them with
-    no host pull. Degrades to the host staging path per-batch (host-side
-    input, device.put failure) and from there exactly like the process
-    tier (spill / budget / pool → frames → shm or files)."""
+    ``device_sink`` (the "device" tier, refines ``mem_sink``: what a
+    pool-less session negotiates on an accelerator, mesh or no mesh) keeps
+    the staged references DEVICE-RESIDENT: a batch whose columns are all
+    device planes is routed by one device program
+    (``repartitioner.exchange_route``: ids, stable order, one matrix
+    gather, offsets) and committed as windows of the one moved batch
+    (``core/batch.RowWindow``), which the reduce side's reader copies
+    together with no pull and no upload. Degrades to the host staging path
+    per batch (host-backed input) or per map output (``device.put``
+    failure, the byte budget), each counted by ``shuffle_tier_degraded``,
+    and from there exactly like the process tier (spill / budget / pool →
+    frames → shm or files)."""
 
     # a coded var-width column stages as Arrow's dictionary array over its
     # dictionary: codes and one dictionary cross the exchange, not strings
@@ -177,7 +185,9 @@ class _WriterState(MemConsumer):
         self._mem_bytes = 0
         # device tier: stage device-resident sub-batch references. Budget
         # is the tighter of the mem-segment cap and the device-resident
-        # cap — past it the staged set degrades like the process tier.
+        # cap, held against the bytes the staged planes occupy (a routed
+        # batch keeps its capacity bucket: up to twice its rows) — past it
+        # the staged set degrades like the process tier.
         self.device_sink = bool(getattr(op, "device_sink", False)) \
             and self._mem_parts is not None
         self._mem_budget = ctx.conf.zero_copy_mem_segment_max_bytes
@@ -234,6 +244,8 @@ class _WriterState(MemConsumer):
             for pid, rows in part_rows.items():
                 self.metrics.add(f"part_rows_{pid}", rows)
         if self._mem_parts is not None and self._mem_bytes > self._mem_budget:
+            if self.device_sink:
+                self._leave_device_tier()
             self._mem_degrade()
         # hot-path invariant surfaced for soak/tests: one row gather per
         # split batch, never a per-partition take loop
@@ -250,26 +262,36 @@ class _WriterState(MemConsumer):
 
     def _bucketize(self, batch: ColumnarBatch):
         """Route one coalesced batch to per-partition sub-batches. Device
-        tier: bucketize ON-CHIP (one gather + contiguous slices) so the
-        staged references stay device-resident — but only when the batch is
-        actually device-backed, and only while device placement succeeds
-        (``device.put`` failpoint / OOM degrades this writer to the shm
-        tier for the whole map output, matching what the reader expects)."""
+        tier: route ON-CHIP (one program, one small wait, a window of the
+        moved batch a partition) so the staged references stay
+        device-resident — but only when every column of the batch is device
+        planes (values or codes), and only while device placement succeeds
+        (``device.put`` failpoint / OOM degrades this writer to the shm tier
+        for the whole map output, matching what the reader expects)."""
         if self.device_sink and self._mem_parts is not None:
-            from blaze_tpu.core.batch import DeviceColumn
             from blaze_tpu.runtime.failpoints import failpoint
 
-            if batch.columns and all(isinstance(c, DeviceColumn)
-                                     for c in batch.columns):
+            if batch.columns and all(has_planes(c) for c in batch.columns):
                 try:
                     failpoint("device.put")
                     return self.repart.bucketize(batch)
                 except OSError:
-                    self.device_sink = False
-                    self.metrics.add("shuffle_tier_degraded", 1)
-                    _TM_TIER_DEGRADED.inc()
+                    self._leave_device_tier()
                     self._mem_degrade()
+            else:
+                # a host-backed batch goes the host way, this batch only
+                self._count_degraded()
         return self.repart.bucketize_host(batch)
+
+    def _count_degraded(self):
+        self.metrics.add("shuffle_tier_degraded", 1)
+        _TM_TIER_DEGRADED.inc()
+
+    def _leave_device_tier(self):
+        """This map output stops staging on the chip (a failed placement,
+        the byte budget): counted, and what follows goes the host way."""
+        self.device_sink = False
+        self._count_degraded()
 
     def _mem_degrade(self):
         """Leave the process tier for this map output: route the staged
@@ -280,7 +302,8 @@ class _WriterState(MemConsumer):
         s0 = self.streams.serialized_bytes
         for pid in sorted(parts):
             for sub in parts[pid]:
-                self.streams.write(pid, sub)
+                self.streams.write(pid, sub.to_columnar()
+                                   if isinstance(sub, RowWindow) else sub)
         if self.streams.serialized_bytes > s0:
             self.metrics.add("shuffle_bytes_serialized",
                              self.streams.serialized_bytes - s0)
@@ -340,10 +363,9 @@ class _WriterState(MemConsumer):
         device_bytes = 0
         for pid in range(self.n):
             for b in parts.get(pid, ()):
-                nb = _staged_batch_nbytes(b)
-                offsets[pid + 1] += nb
-                if isinstance(b, ColumnarBatch):
-                    device_bytes += nb
+                offsets[pid + 1] += _logical_batch_nbytes(b)
+                if isinstance(b, (ColumnarBatch, RowWindow)):
+                    device_bytes += _staged_batch_nbytes(b)
             offsets[pid + 1] += offsets[pid]
         registry.commit(stage, self.map_id, parts, int(offsets[self.n]))
         if device_bytes:
@@ -455,8 +477,7 @@ class _WriterState(MemConsumer):
             fallback = self._degrade_target()
             offsets = _write_data(fallback)
             write_redirect(data_path, fallback)
-            self.metrics.add("shuffle_tier_degraded", 1)
-            _TM_TIER_DEGRADED.inc()
+            self._count_degraded()
         itmp = f"{self.op.output_index_file}.tmp.{attempt}"
         with open(itmp, "wb") as idx:
             idx.write(offsets.astype("<i8").tobytes())
@@ -504,11 +525,29 @@ def _host_batch_nbytes(hb) -> int:
 
 
 def _staged_batch_nbytes(b) -> int:
-    """Logical staged size of either staging representation: host batches
-    (process tier) or device-resident ColumnarBatches (device tier)."""
+    """What a staged reference holds, the number the budget is held
+    against: a host batch's planes (process tier); on the device tier a
+    device-resident batch's planes with their padding, or a window's share
+    of the routed batch it points into (the windows of one batch share its
+    planes, so their shares add up to them)."""
+    if isinstance(b, RowWindow):
+        return int(b.batch.nbytes()) * b.num_rows // b.batch.num_rows
     if isinstance(b, ColumnarBatch):
         return int(b.nbytes())
     return _host_batch_nbytes(b)
+
+
+def _logical_batch_nbytes(b) -> int:
+    """The staged ROWS' size, the number the logical index records for
+    AQE's advisory math: the same on both tiers for the same rows, so that
+    a plan does not change with the tier. A device plane counts its live
+    rows (values and validity), not its capacity bucket."""
+    if not isinstance(b, (ColumnarBatch, RowWindow)):
+        return _host_batch_nbytes(b)
+    columns = b.batch.columns if isinstance(b, RowWindow) else b.columns
+    return int(sum(
+        b.num_rows * (c.data.dtype.itemsize + c.validity.dtype.itemsize)
+        if has_planes(c) else c.nbytes() for c in columns))
 
 
 def read_index_file(path: str) -> np.ndarray:

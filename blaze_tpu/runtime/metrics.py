@@ -155,19 +155,24 @@ class MetricNode:
 #                                    references (process tier) with serde
 #                                    skipped entirely
 #   shuffle_tier_degraded            map outputs that fell back from the
-#                                    shm tier to the spill dir on ENOSPC
-#                                    (0 on healthy runs; > 0 proves the
-#                                    degrade path ran instead of the query
-#                                    failing)
+#                                    shm tier to the spill dir on ENOSPC,
+#                                    and on the device tier every batch
+#                                    (host-backed input) or map output (a
+#                                    failed placement, the byte budget)
+#                                    that went the host way (0 on healthy
+#                                    runs; > 0 proves the degrade path ran
+#                                    instead of the query failing)
 #   sharded_stages                   stages executed data-parallel across
 #                                    the device mesh (mesh-collective
 #                                    exchanges + shard_map'd fused stages);
 #                                    0 with multichip off, > 0 proves the
 #                                    multichip path actually engaged
-#   device_shuffle_bytes             device-resident column bytes handed
-#                                    between stages through the registry
-#                                    ("device" shuffle tier) with no host
-#                                    pull — the device twin of
+#   device_shuffle_bytes             device-resident column bytes (planes
+#                                    with their padding) handed between
+#                                    stages by reference — the "device"
+#                                    shuffle tier's routed sub-batches and
+#                                    the elided collects — with no pull
+#                                    and no upload: the device twin of
 #                                    serde_elided_batches
 #   collective_bytes                 bytes moved by mesh all-to-all
 #                                    collectives in place of shuffle file

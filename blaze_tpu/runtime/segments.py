@@ -10,11 +10,13 @@ REFERENCES here; readers receive them through ``("batches", ...)`` blocks
 with serde skipped entirely (the ``serde_elided_batches`` tripwire).
 
 The registry is tier-AGNOSTIC about what a staged reference points at:
-the process tier commits host batches, the multichip "device" tier
-commits device-resident ``ColumnarBatch`` references (bucketized on-chip,
-so the next fused stage consumes them with no host pull — the
-``device_shuffle_bytes`` tripwire). Both are plain heap objects holding
-their buffers alive; release semantics are identical.
+the process tier (stages on the CPU backend) commits host batches, the
+"device" tier (a pool-less session on an accelerator, with or without a
+mesh) commits device-resident references — windows of the batches that
+``ops/shuffle/repartitioner.exchange_route`` routed on the chip
+(``core/batch.RowWindow``), so the reduce side consumes them with no pull
+and no upload: the ``device_shuffle_bytes`` tripwire. Both are plain heap objects holding their buffers alive;
+release semantics are identical.
 
 Lineage compatibility: each committed mem segment is paired with a
 footer-only marker data file on disk (a 0-payload footer passes
@@ -100,7 +102,8 @@ class MemSegmentBlockProvider:
 
     def __init__(self, registry: MemSegmentRegistry, stage: int,
                  indexes: List[Tuple[str, "object"]],
-                 groups: List[List[int]] = None):
+                 groups: List[List[int]] = None,
+                 map_subsets: List[List[int]] = None):
         self.registry = registry
         self.stage = stage
         # [(data_path, offsets)] per map; offsets are LOGICAL byte
@@ -108,14 +111,21 @@ class MemSegmentBlockProvider:
         # on them) and physical file offsets for degraded maps
         self.indexes = list(indexes)
         self.groups = groups  # provider partition -> reducer pids (AQE)
+        # provider partition -> the maps that serve it, None = all of them
+        # (the skew-join split: a sub-partition reads a subset of the maps)
+        self.map_subsets = map_subsets
 
     def __call__(self, partition: int):
         from blaze_tpu.runtime.recovery import check_map_output
 
         pids = self.groups[partition] if self.groups is not None \
             else [partition]
+        maps = self.map_subsets[partition] \
+            if self.map_subsets is not None else None
         blocks = []
         for m, (data, offsets) in enumerate(self.indexes):
+            if maps is not None and m not in maps:
+                continue
             seg = self.registry.get(self.stage, m)
             if seg is not None:
                 # marker still on disk? the chaos monkey and lineage sweeps

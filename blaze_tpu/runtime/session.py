@@ -494,6 +494,8 @@ class Session:
                 pass
             elif state != "done" or release_on_finish:
                 self._release_query(qrun)
+            else:
+                self._release_references(qrun)
             _TM_QUERIES.labels(state=state).inc()
             _TM_QUERY_SECS.labels(state=state).observe(dur_ns / 1e9)
             if TRACER.active:
@@ -767,16 +769,10 @@ class Session:
         # unrecoverable by design — recovery must say so, not recompute into
         # a deleted directory
         self._lineage.prune(qrun.stage_meta.keys())
-        # process-tier segments go with their stages: dropping the registry
-        # entries releases the staged batch references (readers that already
-        # hold them keep them alive — plain refcounting, same as mappings
-        # outliving their unlinked files)
-        self.mem_segments.release_stages(qrun.stage_meta.keys())
+        self._release_references(qrun)
         for d in qrun.shuffle_dirs:
             self._unlink_degraded_outputs(d)
             shutil.rmtree(d, ignore_errors=True)
-        for rid in qrun.resource_ids:
-            self.resources.pop(rid, None)
         if qrun.mem_group is not None:
             from blaze_tpu.runtime.memmgr import MemManager
 
@@ -785,6 +781,20 @@ class Session:
                 leaked = mm.release_group(qrun.mem_group)
                 if leaked:
                     self.metrics.add("query_leaked_mem_reclaimed", leaked)
+
+    def _release_references(self, qrun: _QueryRun):
+        """Drop what the query staged BY REFERENCE, which every finished
+        query does whether or not its files stay until session close: the
+        registry's staged sub-batches go with their stages, and the
+        resource-map entries with the collect blocks they serve (readers
+        that already hold a batch keep it alive — plain refcounting, same
+        as mappings outliving their unlinked files). On an accelerator these
+        references are HBM: the device tier's sub-batches, an elided
+        collect's result batches (0.057 GB a query of q67's, PERF.md
+        section 7) — an exchange that kept them would fill the chip."""
+        self.mem_segments.release_stages(qrun.stage_meta.keys())
+        for rid in qrun.resource_ids:
+            self.resources.pop(rid, None)
 
     def discard_cursor(self, cursor: Optional[StageCursor]):
         """Release a paused query's pinned stage state without resuming it
@@ -918,29 +928,40 @@ class Session:
 
     def _shuffle_tier(self) -> str:
         """Negotiate the zero-copy tier for this session's (writer, reader)
-        placement: ``device`` keeps staged sub-batches device-RESIDENT in
-        the segment registry (multichip: the next fused stage reads them
-        with no host pull), ``process`` passes host batch references through
-        the in-memory segment registry (consumer in the same process — serde
-        skipped entirely), ``shm`` commits raw mappable frames that readers
-        mmap (same host, decode skipped), ``ipc`` is the classic framed
-        serde (zero-copy off, or forced). Forced ``process``/``device``
-        degrade to ``shm`` under a worker pool — references cannot cross the
-        process boundary; mesh/RSS exchanges never reach this (they keep
-        their own transports and IPC serde)."""
+        placement from what the session can see: ``device`` keeps staged
+        sub-batches device-RESIDENT in the segment registry (a pool-less
+        session whose stages run on an accelerator, mesh or no mesh: the
+        rows are routed on the chip and the reduce side reads them with no
+        pull and no upload), ``process`` passes host batch references
+        through the in-memory segment registry (consumer in the same
+        process, stages on the CPU backend — there device and host are one
+        memory and numpy routing is the cheaper one — serde skipped
+        entirely), ``shm`` commits raw mappable frames that readers mmap
+        (same host, decode skipped), ``ipc`` is the classic framed serde
+        (zero-copy off, or forced). Forced ``process``/``device`` degrade to
+        ``shm`` under a worker pool — references cannot cross the process
+        boundary; mesh/RSS exchanges never reach this (they keep their own
+        transports and IPC serde)."""
         conf = self.conf
         if not conf.zero_copy_shuffle or conf.zero_copy_tier == "ipc":
             return "ipc"
         if self.pool is not None:
             return "shm"
-        if conf.zero_copy_tier == "shm":
-            return "shm"
-        if conf.zero_copy_tier == "device":
-            return "device"
-        if conf.device_shuffle_tier and conf.multichip_enabled \
-                and self.mesh is not None:
+        if conf.zero_copy_tier in ("shm", "device", "process"):
+            return conf.zero_copy_tier
+        if conf.device_shuffle_tier and (
+                (conf.multichip_enabled and self.mesh is not None)
+                or self._stage_platform() != "cpu"):
             return "device"
         return "process"
+
+    def _stage_platform(self) -> str:
+        """The jax platform this session's stages run on: the CPU backend
+        under a forced host placement, else the thread's effective one."""
+        from blaze_tpu.utils.device import effective_platform
+
+        return "cpu" if self.conf.device_placement == "host" \
+            else effective_platform()
 
     def _boundary(self, fn, node: N.PlanNode):
         """Run one stage-boundary lowering step through the query's stage
@@ -1347,8 +1368,14 @@ class Session:
         # lower the subtrees BELOW the exchanges, then run both map stages
         lex = dataclasses.replace(lex, child=self._lower(lex.child))
         rex = dataclasses.replace(rex, child=self._lower(rex.child))
-        lstage, lindexes = self._exec_map_stage(lex)
-        rstage, rindexes = self._exec_map_stage(rex)
+        # the device tier stages both sides on the chip, as any exchange's
+        # (the other tiers keep their files here: a sub-partition is a set
+        # of map-file segments)
+        on_chip = self._shuffle_tier() == "device"
+        lstage, lindexes = self._exec_map_stage(
+            lex, mem_sink=on_chip, device_sink=on_chip)
+        rstage, rindexes = self._exec_map_stage(
+            rex, mem_sink=on_chip, device_sink=on_chip)
 
         def reducer_sizes(indexes):
             import numpy as np
@@ -1400,10 +1427,18 @@ class Session:
             self.metrics.add("skew_partitions_split", 1)
 
         lrid, rrid = f"shuffle_{lstage}", f"shuffle_{rstage}"
-        self._register_resource(lrid, _SubsetBlockProvider(
-            lindexes, parts, subset_applies=split_left))
-        self._register_resource(rrid, _SubsetBlockProvider(
-            rindexes, parts, subset_applies=split_right))
+
+        def provider(stage, indexes, subset_applies):
+            if not on_chip:
+                return _SubsetBlockProvider(indexes, parts, subset_applies)
+            return MemSegmentBlockProvider(
+                self.mem_segments, stage, indexes,
+                groups=[[r] for r, _ in parts],
+                map_subsets=[chunk if subset_applies else None
+                             for _, chunk in parts])
+
+        self._register_resource(lrid, provider(lstage, lindexes, split_left))
+        self._register_resource(rrid, provider(rstage, rindexes, split_right))
         nparts = len(parts)
         def side(sort, ex, rid) -> N.PlanNode:
             read = N.IpcReader(schema=ex.child.output_schema, resource_id=rid,
@@ -1881,9 +1916,13 @@ class Session:
         (reference: NativeBroadcastExchangeBase.relationFuture + Spark
         TorrentBroadcast of the IPC byte arrays)."""
         stage = next(self._stage_ids)
+        # (a mesh's tasks are pinned to different devices: its broadcast
+        # keeps the serde, which lands the build side wherever it is read)
+        tier = self._shuffle_tier()
         blocks = self._collect_child_chunks(
             node.child, stage, "broadcast",
-            elide=self._shuffle_tier() == "process")
+            elide=tier == "process" or (tier == "device"
+                                        and self.mesh is None))
         rid = f"broadcast_{stage}"
         self._register_resource(rid, _BlockListProvider(blocks))
         return N.IpcReader(schema=node.child.output_schema, resource_id=rid,

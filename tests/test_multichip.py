@@ -88,10 +88,27 @@ def _run(parts, **conf_kw):
 
 
 @pytest.mark.quick
-def test_tier_negotiation_device(eight_devices):
-    with Session() as sess:  # multichip off by default: process tier
+def test_tier_negotiation_device(eight_devices, monkeypatch):
+    with Session() as sess:  # multichip off, CPU backend: process tier
         assert sess.mesh is None
         assert sess._shuffle_tier() == "process"
+        # the same session on an accelerator: its stages run on the chip,
+        # so the exchange stays there (no mesh, no new option)
+        from blaze_tpu.utils import device as _device
+
+        monkeypatch.setattr(_device, "effective_platform", lambda: "tpu")
+        assert sess._shuffle_tier() == "device"
+        sess.pool = object()  # references cannot cross processes
+        assert sess._shuffle_tier() == "shm"
+        sess.pool = None
+    for pinned in ("process", "shm", "ipc"):  # a pinned tier stays pinned
+        with Session(conf=Config(zero_copy_tier=pinned)) as sess:
+            assert sess._shuffle_tier() == pinned
+    with Session(conf=Config(device_shuffle_tier=False)) as sess:
+        assert sess._shuffle_tier() == "process"
+    with Session(conf=Config(device_placement="host")) as sess:
+        assert sess._shuffle_tier() == "process"  # stages pinned to the CPU
+    monkeypatch.undo()
     with Session(conf=Config(zero_copy_tier="device")) as sess:  # pinned
         assert sess._shuffle_tier() == "device"
     with Session(conf=Config(multichip_enabled=True)) as sess:

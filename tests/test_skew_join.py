@@ -55,19 +55,25 @@ def _smj_plan(lpaths, rpath, join_type):
     return N.SortMergeJoin(lsorted, rsorted, [(col("lk"), col("rk"))], join_type)
 
 
+@pytest.mark.parametrize("tier", [None, "device"])
 @pytest.mark.parametrize("join_type", [N.JoinType.INNER, N.JoinType.LEFT,
                                        N.JoinType.LEFT_SEMI])
-def test_skew_split_matches_unsplit(skewed_tables, join_type):
+def test_skew_split_matches_unsplit(skewed_tables, join_type, tier):
+    """(``tier="device"``: both sides staged on the chip, a sub-partition a
+    subset of the registry's maps in place of map-file segments.)"""
     lpaths, rpath, left, right = skewed_tables
     plan = _smj_plan(lpaths, rpath, join_type)
     with config_override(skew_join_enable=False):
         with Session() as s:
             expect = s.execute_to_table(plan).to_pydict()
     with config_override(skew_join_enable=True, skew_join_factor=2.0,
-                         skew_join_min_bytes=1024):
+                         skew_join_min_bytes=1024, zero_copy_tier=tier):
         with Session() as s:
             got = s.execute_to_table(plan).to_pydict()
             nsplit = s.metrics.total("skew_partitions_split")
+            if tier == "device":
+                assert s.metrics.total("device_shuffle_bytes") > 0
+                assert s.metrics.total("shuffle_bytes_serialized") == 0
     assert nsplit >= 1, "the 60%-skew key must trigger a split"
     key = sorted(got.keys())[0]
     order_g = np.lexsort([np.asarray(got[k], dtype=object) for k in sorted(got)][::-1])

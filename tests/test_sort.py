@@ -111,8 +111,9 @@ def _batch_for_bucketize(n=4000, seed=3):
 
 
 def _pydict_of(sub):
-    """HostBatch | ColumnarBatch -> pydict with NaN made comparable."""
-    b = sub.to_columnar() if hasattr(sub, "items") else sub
+    """HostBatch | RowWindow | ColumnarBatch -> pydict with NaN made
+    comparable."""
+    b = sub.to_columnar() if hasattr(sub, "to_columnar") else sub
     return {k: ["<nan>" if isinstance(v, float) and v != v else v
                 for v in vs] for k, vs in b.to_pydict().items()}
 
@@ -171,7 +172,7 @@ def test_bucketize_matches_mask_reference_all_partitioners():
 
     last = None
     for pid, sub in parts:
-        keys = SK.merge_keys_matrix(sub, orders)
+        keys = SK.merge_keys_matrix(sub.to_columnar(), orders)
         rows = [tuple(r) for r in keys]
         if last is not None and rows:
             assert last <= min(rows)

@@ -84,9 +84,14 @@ def _run(parts, **conf_kw):
 # -- tier negotiation ---------------------------------------------------------
 
 
-def test_tier_negotiation():
-    with Session() as sess:  # pool-less, auto
+def test_tier_negotiation(monkeypatch):
+    with Session() as sess:  # pool-less, auto, on the CPU backend
         assert sess._shuffle_tier() == "process"
+        from blaze_tpu.utils import device as _device
+
+        with monkeypatch.context() as on_chip:  # the same, on an accelerator
+            on_chip.setattr(_device, "effective_platform", lambda: "tpu")
+            assert sess._shuffle_tier() == "device"
         # a worker pool forces shm: references cannot cross processes
         sess.pool = object()
         assert sess._shuffle_tier() == "shm"
