@@ -72,7 +72,9 @@ def _dispatch(fn, *args, **kw):
     Perfetto timeline. The registry always gets
     ``blaze_kernel_dispatch_seconds`` and the compile counters (kernel spans
     would flood the flight-recorder ring, so those stay trace-gated).
-    ``DEVICE_STATS.kernel_calls`` counts the dispatch."""
+    ``DEVICE_STATS.kernel_calls`` counts the dispatch. The span says what
+    was enqueued (``operands``: the array leaves of the call's arguments;
+    benchmark: ``enqueue_operands``)."""
     from blaze_tpu.obs.tracer import TRACER
     from blaze_tpu.utils.device import DEVICE_STATS
 
@@ -86,6 +88,7 @@ def _dispatch(fn, *args, **kw):
         except Exception:
             cache0 = -1
     DEVICE_STATS.add_kernel_call()
+    operands = _operands(args, kw) if trace else 0
     t0 = time.perf_counter()
     out = fn(*args, **kw)
     dt = time.perf_counter() - t0
@@ -107,8 +110,15 @@ def _dispatch(fn, *args, **kw):
             now = time.perf_counter_ns()
             TRACER.complete("jit_compile:" + name if compiled else name,
                             "kernel", now - int(dt * 1e9), int(dt * 1e9),
-                            {"compiled": compiled})
+                            {"compiled": compiled, "operands": operands})
     return out
+
+
+def _operands(*trees) -> int:
+    """Array leaves of a dispatch's arguments: what the call flattens and
+    hands to the runtime (counted under tracing only)."""
+    return sum(1 for leaf in jax.tree_util.tree_leaves(trees)
+               if hasattr(leaf, "dtype"))
 
 
 def fused_dispatch(fn, *args):
@@ -128,6 +138,8 @@ def fused_dispatch(fn, *args):
     except Exception:
         cache0 = -1
     DEVICE_STATS.add_kernel_call()
+    trace = TRACER.enabled
+    operands = _operands(args) if trace else 0
     t0 = time.perf_counter()
     out = fn(*args)
     dt = time.perf_counter() - t0
@@ -144,12 +156,12 @@ def fused_dispatch(fn, *args):
             tm_jit_secs.observe(dt)
         else:
             tm_hit.inc()
-    if TRACER.enabled:
+    if trace:
         now = time.perf_counter_ns()
         TRACER.complete(
             "jit_compile:fused_stage" if compiled else "fused_stage",
             "kernel", now - int(dt * 1e9), int(dt * 1e9),
-            {"compiled": compiled})
+            {"compiled": compiled, "operands": operands})
     return out, compiled
 
 

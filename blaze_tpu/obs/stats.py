@@ -641,7 +641,9 @@ def save_profile(profile: dict, conf=None) -> Optional[str]:
     """Persist one QueryProfile under ``<fingerprint>.json`` (the latest
     run of a plan shape overwrites: the store answers "last observed stats
     for this fingerprint"). Atomic write, mtime-GC'd to
-    ``conf.profile_store_max``; never raises."""
+    ``conf.profile_store_max`` when the write added a file (a rewrite of a
+    stored fingerprint leaves the count as it was, so nothing is listed);
+    never raises."""
     try:
         conf = _conf(conf)
         out_dir = getattr(conf, "profile_store_dir", "") or ""
@@ -654,9 +656,12 @@ def save_profile(profile: dict, conf=None) -> Optional[str]:
         os.makedirs(out_dir, exist_ok=True)
         path = os.path.join(out_dir, fp + ".json")
         tmp = f"{path}.tmp{os.getpid()}"
+        added = not os.path.exists(path)
         with open(tmp, "w") as f:
             json.dump(profile, f, default=str)
         os.replace(tmp, path)
+        if not added:
+            return fp
         # GC by mtime — fingerprints are content hashes, so unlike incident
         # ids a lexical sort is NOT chronological here
         names = [n for n in os.listdir(out_dir) if n.endswith(".json")]

@@ -41,6 +41,16 @@ Design constraints:
   context-manager form also opens a ``jax.profiler.TraceAnnotation``
   named ``blaze/<cat>:<name>``, so a profiler trace shows it on the host
   thread's line beside the device's ``XLA Ops``.
+- **CPU beside wall, where a metric reads it**: under full tracing the
+  spans of the categories in :data:`CPU_STAMPED` — ``task`` (a task thread's
+  whole run) and ``transfer`` (an upload's or a pull's copying) — also carry
+  ``args.cpu_us``, the recording thread's own CPU clock
+  (``time.thread_time_ns``) across them, so ``dur - cpu_us`` is the time the
+  thread did not run: waiting for the interpreter, blocked in a call that
+  let it go, or descheduled. The context-manager form stamps it inside its
+  wall stamps; a :meth:`Tracer.complete` site hands in its own. A read of
+  that clock is a system call (6-24 us on a sandboxed host), so no per-batch
+  wait and no operator segment is stamped; the ring's spans carry none.
 """
 
 from __future__ import annotations
@@ -75,8 +85,13 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+# categories whose spans carry ``args.cpu_us`` under full tracing: the two
+# whose CPU a benchmark metric reads (``op_cpu_s``, ``stage_cpu_s``)
+CPU_STAMPED = frozenset({"task", "transfer"})
+
+
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_note")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_c0", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[dict]):
@@ -86,8 +101,9 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        self._note = None
-        if self._tracer.enabled:
+        self._note = self._c0 = None
+        full = self._tracer.enabled
+        if full:
             # the same span in the profiler's own trace (a no-op unless a
             # jax.profiler session is running); it stamps its start when built
             from jax.profiler import TraceAnnotation
@@ -95,6 +111,9 @@ class _Span:
             self._note = TraceAnnotation(f"blaze/{self.cat}:{self.name}")
             self._note.__enter__()
         self._t0 = time.perf_counter_ns()
+        if full and self.cat in CPU_STAMPED:
+            # the CPU stamps lie inside the wall stamps: cpu_us <= dur
+            self._c0 = time.thread_time_ns()
         return self
 
     def set(self, **kw):
@@ -104,10 +123,13 @@ class _Span:
         self.args.update(kw)
 
     def __exit__(self, *exc):
+        cpu_ns = None if self._c0 is None else \
+            time.thread_time_ns() - self._c0
         dur_ns = time.perf_counter_ns() - self._t0
         if self._note is not None:
             self._note.__exit__(*exc)
-        self._tracer._record(self.name, self.cat, self._t0, dur_ns, self.args)
+        self._tracer._record(self.name, self.cat, self._t0, dur_ns, self.args,
+                             cpu_ns)
         return False
 
 
@@ -203,14 +225,16 @@ class Tracer:
                       **({"args": args} if args else {})})
 
     def complete(self, name: str, cat: str, t0_ns: int, dur_ns: int,
-                 args: Optional[dict] = None):
+                 args: Optional[dict] = None, cpu_ns: Optional[int] = None):
         """Record a complete event from explicit perf_counter_ns stamps (for
-        sites that cannot use the context manager, e.g. generators)."""
+        sites that cannot use the context manager, e.g. generators).
+        ``cpu_ns`` is the recording thread's ``thread_time_ns`` across the
+        same stretch, from a site that stamped it (full tracing only)."""
         if not self.active:
             return
-        self._record(name, cat, t0_ns, dur_ns, args)
+        self._record(name, cat, t0_ns, dur_ns, args, cpu_ns)
 
-    def _record(self, name, cat, t0_ns, dur_ns, args):
+    def _record(self, name, cat, t0_ns, dur_ns, args, cpu_ns=None):
         ev = {"ph": "X", "name": name, "cat": cat,
               "ts": (t0_ns - self.perf_epoch_ns) / 1e3,
               "dur": dur_ns / 1e3,
@@ -220,6 +244,8 @@ class Tracer:
             # whose span it is; a key the site set itself wins
             args = {"stage": ctx[0], "part": ctx[1], "q": ctx[2],
                     **(args or {})}
+        if cpu_ns is not None:
+            args = {**(args or {}), "cpu_us": cpu_ns / 1e3}
         if args:
             ev["args"] = args
         self._append(ev)

@@ -947,8 +947,8 @@ class DevicePartialAgger:
     def _note_radix(self, outs, sizes, nbuck: int):
         """Publish one radix pass's bucket histogram: skipper input,
         tripwire counter, and (trace-gated) the Perfetto skew view."""
-        rows = np.asarray(outs[-2])
-        groups = np.asarray(outs[-1])
+        rows = wait_array(outs[-2], "agg_radix_stats")
+        groups = wait_array(outs[-1], "agg_radix_stats")
         self.last_bucket_stats = (rows, groups)
         if self.metrics is not None:
             self.metrics.add("agg_radix_buckets", len(rows))
@@ -2019,7 +2019,7 @@ class DeviceMergeAgger:
                 jnp.any(v).astype(jnp.int64),
                 jnp.min(jnp.where(v, d64, info.max)),
                 jnp.max(jnp.where(v, d64, info.min))]))
-        pr = np.asarray(jnp.stack(rows))
+        pr = wait_array(jnp.stack(rows), "agg_final_probe")
         st = _plan_slot_table(pr, capacity, None,
                               self.conf.radix_agg_max_slots, self.conf)
         if st is _DEFER_PLAN or st is None:

@@ -27,6 +27,7 @@ from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import nodes as N
 from blaze_tpu.ir import types as T
 from blaze_tpu.ops import sort_keys as SK
+from blaze_tpu.utils.device import wait_array
 
 
 def _pmod(h, n: int):
@@ -153,8 +154,6 @@ class Repartitioner:
         ColumnarBatch)]`` for the partitions that got rows."""
         import time
 
-        from blaze_tpu.utils.device import wait_array
-
         n = batch.num_rows
         if n == 0:
             return []
@@ -176,7 +175,8 @@ class Repartitioner:
             cols[i] = cols[i].like(datas[k], valids[k])
         if len(slots) < len(cols):
             # host columns follow the order the device found
-            host_order = np.asarray(order)[:n].astype(np.int64)
+            host_order = wait_array(
+                order, "exchange_host_order")[:n].astype(np.int64)
             for i, c in enumerate(cols):
                 if not has_planes(c):
                     cols[i] = c.take_host(host_order)
@@ -414,7 +414,8 @@ class RangePartitioner(Repartitioner):
             pids = K.range_partition_ids(datas, valids, batch.row_exists_mask(),
                                          self._device_bounds(),
                                          SK.key_spec(self.sort_orders))
-            return np.asarray(pids)[: batch.num_rows].astype(np.int32)
+            return wait_array(pids, "exchange_host_order")[
+                : batch.num_rows].astype(np.int32)
         # var-width keys (no u64 normalization): per-row bisect over
         # python-comparable key tuples, as before
         import bisect

@@ -463,27 +463,33 @@ class Session:
             query["wall_s"] = dur_ns / 1e9
             query["state"] = state
             if qrun.stats is not None:
-                # exclusive wall decomposition + critical path over the
-                # query's tracer window (obs/attribution.py); one attribute
-                # check when the tracer/ring and the knob are off
-                if TRACER.active and \
-                        getattr(self.conf, "attribution_enabled", True):
-                    try:
-                        from blaze_tpu.obs.attribution import query_attribution
+                # what observing the query costs at its end: once a query,
+                # inside the caller's wait (benchmark: ``finish_s``)
+                with TRACER.span("finish", "obs"):
+                    # exclusive wall decomposition + critical path over the
+                    # query's tracer window (obs/attribution.py); one
+                    # attribute check when the tracer/ring and the knob are
+                    # off
+                    if TRACER.active and \
+                            getattr(self.conf, "attribution_enabled", True):
+                        try:
+                            from blaze_tpu.obs.attribution import \
+                                query_attribution
 
-                        qrun.stats.note_attribution(
-                            query_attribution(t0, dur_ns))
-                    except Exception:
-                        pass
-                # fold the stats plane into the record BEFORE it enters the
-                # query log; completed queries also persist their profile
-                # under the plan fingerprint (obs/stats.py store)
-                profile = qrun.stats.finalize_into(query, self.metrics, state)
-                if profile is not None and state == "done":
-                    self.profiles[profile["fingerprint"]] = profile
-                    while len(self.profiles) > 2 * self._QUERY_LOG_MAX:
-                        self.profiles.pop(next(iter(self.profiles)))
-                    _save_profile(profile, self.conf)
+                            qrun.stats.note_attribution(
+                                query_attribution(t0, dur_ns))
+                        except Exception:
+                            pass
+                    # fold the stats plane into the record BEFORE it enters
+                    # the query log; completed queries also persist their
+                    # profile under the plan fingerprint (obs/stats.py store)
+                    profile = qrun.stats.finalize_into(query, self.metrics,
+                                                       state)
+                    if profile is not None and state == "done":
+                        self.profiles[profile["fingerprint"]] = profile
+                        while len(self.profiles) > 2 * self._QUERY_LOG_MAX:
+                            self.profiles.pop(next(iter(self.profiles)))
+                        _save_profile(profile, self.conf)
             with self._qlog_mu:
                 self.inflight.pop(qid, None)
                 self.query_log.append(query)

@@ -245,14 +245,17 @@ def pull_columns(cols, n: int):
         to_pull = [a for i in dev_slots for a in (cols[i].data, cols[i].validity)]
     # start every transfer before blocking on any, so the copies overlap
     t0_ns = time.perf_counter_ns() if TRACER.active else 0
+    cpu0_ns = time.thread_time_ns() if TRACER.enabled else None
     for a in to_pull:
         a.copy_to_host_async()
     pulled = [np.asarray(a)[:n] for a in to_pull]
     nbytes = sum(a.nbytes for a in to_pull)
     DEVICE_STATS.add_to_host(nbytes)
     if t0_ns:
+        cpu_ns = None if cpu0_ns is None else time.thread_time_ns() - cpu0_ns
         TRACER.complete("to_host", "transfer", t0_ns,
-                        time.perf_counter_ns() - t0_ns, {"bytes": nbytes})
+                        time.perf_counter_ns() - t0_ns, {"bytes": nbytes},
+                        cpu_ns)
     out = [None] * len(cols)
     for k, i in enumerate(dev_slots):
         out[i] = (pulled[2 * k], pulled[2 * k + 1])
