@@ -23,6 +23,7 @@ exchange payload for zero full-width transfers."""
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Optional, Tuple
 
 import jax
@@ -918,7 +919,9 @@ class DevicePartialAgger:
             # sync; -1 flags range overflow
             num_groups = wait_int(outs[0], "agg_partial")
             if num_groups >= 0:
-                DEVICE_STATS.add_agg_batch(dense=True)
+                DEVICE_STATS.add_agg_batch(
+                    dense=True, slot_sorted=_is_slot_sorted(
+                        sizes, batch.capacity, nbuck))
                 if nbuck:
                     self._note_radix(outs, sizes, nbuck)
                     outs = outs[:-2]
@@ -1373,26 +1376,13 @@ def _aggregate_sorted(exists, key_data, key_valid, kinds, requests):
     key order (nulls first, then ascending; a null or padding key reads 0,
     a float key its canonical value), zeros past ``num_groups``.
 
-    Shaped by what the TPU charges (PERF.md section 6, PR 29; 131,072 rows):
-    a row-sized int64 scatter 9 ms, a gather 0.9 ms a 32-bit plane it is
-    asked for and no more for a row of many words, a two-operand sort or a
-    prefix scan a few tenths. So nothing is scattered and every plane moves
-    twice, each time with all the others as one matrix of words:
-
-    order   ``lex_order_traced`` over the canonical key words (two-operand
-            sorts only; the 2k + 2-operand sort this replaces compiled for
-            five minutes). Its packed word is equal exactly where all the
-            keys are, so a segment starts where the sorted word changes.
-            ONE ``take_rows_traced`` brings the keys and every request's
-            planes into that order.
-    reduce  contiguous segments reduce by prefix scans
-            (:func:`_reduce_sorted`); a segment's last row holds its value.
-    emit    the groups' last rows go to the front by the stable sort of
-            their flags (PR 27's row map) and ONE ``take_rows_traced``; the
-            running totals then subtract their neighbour's."""
-    capacity = exists.shape[0]
+    The order is ``lex_order_traced`` over the canonical key words
+    (two-operand sorts only; the 2k + 2-operand sort this replaces compiled
+    for five minutes). Its packed word is equal exactly where all the keys
+    are, so a segment starts where the sorted word changes; the keys travel
+    with the requests' planes. :func:`_reduce_ordered` does the rest."""
     nk = len(key_data)
-    iota = jnp.arange(capacity, dtype=jnp.int32)
+    iota = jnp.arange(exists.shape[0], dtype=jnp.int32)
     key_valid = [v & exists for v in key_valid]
     canon = _canonical_keys(key_data, key_valid)
     with jax.named_scope("order"):
@@ -1404,15 +1394,53 @@ def _aggregate_sorted(exists, key_data, key_valid, kinds, requests):
                 cls = jnp.where(exists, cls, 1)
             columns.append((_order_word(d), cls.astype(jnp.int8)))
         order, word = K.lex_order_traced(columns)
+    num_groups, out_valid, keys, states = _reduce_ordered(
+        exists, iota, order, word, [*canon, *key_valid], [], kinds, requests)
+    outs = [num_groups, out_valid]
+    for d, v in zip(keys[:nk], keys[nk:]):
+        outs += [d, v]
+    return tuple(outs + states)
+
+
+def _reduce_ordered(exists, iota, order, word, row_keys, sorted_keys, kinds,
+                    requests, out_cap=None):
+    """Rows in an order that makes every group a contiguous segment ->
+    ``(num_groups, out_valid, group keys, state planes)``, the groups at the
+    front in that order. The order is handed in: ``order`` the row standing
+    at each position with the rows ``exists`` drops last, ``word`` a plane
+    in that order that changes exactly where a group starts, ``iota`` the
+    positions (the caller's, made before its order, so that the sort path's
+    programs stay equation for equation what they were). Its suppliers are ``lex_order_traced`` over any keys
+    (:func:`_aggregate_sorted`) and ONE sort of the packed slot id where the
+    keys fit a slot table (``jit(agg_dense_partial)`` above its crossover).
+    ``row_keys`` are planes by row and ``sorted_keys`` planes by position
+    that say which group a row is in; both come back a group.
+
+    Shaped by what the TPU charges (PERF.md section 6, PR 29; 131,072 rows):
+    a row-sized int64 scatter 9 ms, a gather 0.9 ms a 32-bit plane it is
+    asked for and no more for a row of many words, a two-operand sort or a
+    prefix scan a few tenths. So nothing is scattered and every plane moves
+    twice, each time with all the others as one matrix of words:
+
+    order   ONE ``take_rows_traced`` brings ``row_keys`` and every request's
+            planes into the order.
+    reduce  contiguous segments reduce by prefix scans
+            (:func:`_reduce_sorted`); a segment's last row holds its value.
+    emit    the groups' last rows go to the front by the stable sort of
+            their flags (PR 27's row map) and ONE ``take_rows_traced``, of
+            the first ``out_cap`` of them where the caller knows there are
+            no more groups; the running totals then subtract their
+            neighbour's."""
+    with jax.named_scope("order"):
         live = iota < jnp.sum(exists)  # padding sorts last
         new = live & jnp.concatenate(
             [jnp.ones(1, bool), word[1:] != word[:-1]])
         s_rows = _take_rows(
-            [*canon, *key_valid,
+            [*row_keys,
              *(p for reqs in requests for _op, *planes in reqs for p in planes)],
             order, live)
     with jax.named_scope("reduce"):
-        s_planes = iter(s_rows[2 * nk:])
+        s_planes = iter(s_rows[len(row_keys):])
         # per aggregate, (op, row plane) for every plane its requests give
         reduced = [[(op, plane) for op, *planes in reqs
                     for plane in _reduce_sorted(
@@ -1425,29 +1453,55 @@ def _aggregate_sorted(exists, key_data, key_valid, kinds, requests):
         out_valid = iota < num_groups
         _, last_row = jax.lax.sort(((~is_last).astype(jnp.uint8), iota),
                                    num_keys=1, is_stable=True)
+        if out_cap is not None:
+            out_valid, last_row = out_valid[:out_cap], last_row[:out_cap]
+        s_keys = [*s_rows[:len(row_keys)], *sorted_keys]
         g_rows = _take_rows(
-            [*s_rows[:2 * nk],
-             *(plane for agg in reduced for _op, plane in agg)],
+            [*s_keys, *(plane for agg in reduced for _op, plane in agg)],
             last_row, out_valid)
-        outs = [num_groups, out_valid]
-        for d, v in zip(g_rows[:nk], g_rows[nk:2 * nk]):
-            outs += [d, v]
-        g_planes = iter(g_rows[2 * nk:])
+        g_planes = iter(g_rows[len(s_keys):])
+        states = []
         for kind, agg in zip(kinds, reduced):
-            outs += _finish_state(kind, [
+            states += _finish_state(kind, [
                 _segment_value(op, next(g_planes), out_valid)
                 for op, _plane in agg])
-    return tuple(outs)
+    return num_groups, out_valid, g_rows[:len(s_keys)], states
 
 
-# Largest segment table reduced without scatters (see _seg_reduce). A chip
-# measurement, not a knob: PERF.md section 6, PR 25, has the timings of the
-# slot-table kernel at 16..65,536 slots with the reduction forced each way.
-_MASKED_REDUCE_MAX_SLOTS = 16384
+# The forms a slot table reduces in, picked by :func:`_table_form` from the
+# static shapes. Chip measurements, not knobs: PERF.md section 6 has the
+# slot-table kernel's timings at 16..65,536 slots with the form forced each
+# way (PR 25: masked against scatter; PR 38: both against slot-sorted).
+_MASKED_REDUCE_MAX_SLOTS = 16384  # past it the masked form loses to a scatter
+# From it on the slot-sorted form wins. The masked form is linear in the
+# slots and the slot-sorted one flat, and they cross between 600 and 2,600
+# slots by capacity and kernel; ms a launch, masked / slot-sorted, for one
+# int64 sum on two keys and for COUNT(*) + sum3 + maxw on one:
+#   131,072 rows  1,024 slots 0.77 / 0.67, 3.68 / 2.17   2,048 1.61 / 0.68, 7.78 / 2.18
+#   262,144 rows  1,024 slots 1.31 / 3.63, 6.81 / 6.50   2,048 2.71 / 3.65, 14.3 / 6.51
+#                 4,096 slots 5.78 / 3.67, 29.8 / 6.55  16,384 30.5 / 3.80, 123 / 6.75
+_SLOT_SORT_MIN_SLOTS = 2048
 
 
-def _masked_form(nseg: int, rows: int) -> bool:
-    return nseg <= _MASKED_REDUCE_MAX_SLOTS and nseg < rows
+def _table_form(nseg: int, rows: int, sortable: bool = False) -> str:
+    """How ``rows`` rows reduce into a table of ``nseg`` segments:
+
+    "masked"   a vector reduction a slot (:func:`_seg_reduce`): ``nseg x
+               rows`` lane operations a reduction, the fastest form of a
+               small table and linear in its slots.
+    "sorted"   ONE sort of the packed slot id and :func:`_reduce_ordered`:
+               nearly flat in the slots. Only ``jit(agg_dense_partial)`` has
+               it (``sortable``): it emits groups, not the table.
+    "scatter"  ``.at[seg].<op>``, one serial update a row: what is left to a
+               caller that needs the table itself past the masked form's
+               reach (the radix kernels of the CPU backend) or has a segment
+               a row (the passthrough kernel, where masked is quadratic in
+               the batch)."""
+    if sortable and nseg >= _SLOT_SORT_MIN_SLOTS:
+        return "sorted"
+    if nseg <= _MASKED_REDUCE_MAX_SLOTS and nseg < rows:
+        return "masked"
+    return "scatter"
 
 
 def _slot_hits(seg, nseg: int):
@@ -1476,7 +1530,7 @@ def _seg_reduce(op: str, seg, values, nseg: int, init=None, where=None):
     dtype = jnp.dtype(jnp.int64) if op == "count" else values.dtype
     if op in ("add", "count", "any"):
         init = dtype.type(0)
-    if _masked_form(nseg, seg.shape[0]):
+    if _table_form(nseg, seg.shape[0]) == "masked":
         hit = _slot_hits(seg, nseg)
         if where is not None:
             hit = hit & where[None, :]
@@ -1515,7 +1569,7 @@ def _seg_take(table, seg):
     uses for a table of this size: for a small one a select along the slots
     and not a gather a row. Rows outside the table read anything."""
     nseg = table.shape[0]
-    if _masked_form(nseg, seg.shape[0]):
+    if _table_form(nseg, seg.shape[0]) == "masked":
         return jnp.sum(jnp.where(_slot_hits(seg, nseg), table[:, None],
                                  table.dtype.type(0)),
                        axis=0, dtype=table.dtype)
@@ -1610,6 +1664,14 @@ def _reduce_aggs(specs, args, seg, nseg_total):
     return outs
 
 
+def _is_slot_sorted(sizes, capacity: int, nbuck: int) -> bool:
+    """Does ``jit(agg_dense_partial)`` of these static shapes take the
+    slot-sorted form? Never as the radix variant (``nbuck``): that one
+    reports the table's histogram, so it keeps the table."""
+    return _table_form(math.prod(sizes), capacity,
+                       sortable=not nbuck) == "sorted"
+
+
 @functools.lru_cache(maxsize=256)
 def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
                           specs: Tuple[Tuple[str, int, str], ...],
@@ -1617,11 +1679,16 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
                           sizes: Tuple[int, ...], out_cap: int,
                           nbuck: int = 0):
     """Dense-bucket partial kernel: integer group keys whose observed range
-    fits a small table reduce straight into ``prod(sizes)`` segment slots —
-    no sort, no capacity-sized tables, and up to _MASKED_REDUCE_MAX_SLOTS
-    slots no row-sized scatter either (the TPU analogue of the reference's
+    fits a small table pack into ONE slot id below ``prod(sizes)``
+    (``radix_pack``) and reduce by it (the TPU analogue of the reference's
     agg_hash_map.rs one-pass hash table, but with a static-shape range
-    table). ``bases`` (traced, per key) anchor the ranges so one compiled
+    table), in the form :func:`_table_form` picks from the static shapes: a
+    table of few slots as masked vector reductions and a compaction of the
+    table — no sort, no capacity-sized tables, no row-sized scatter; from
+    ``_SLOT_SORT_MIN_SLOTS`` slots on by one two-operand sort of the slot id
+    and :func:`_reduce_ordered` — no scatter at all and nothing of ``slots x
+    rows``. Either way the groups come out in ascending slot order.
+    ``bases`` (traced, per key) anchor the ranges so one compiled
     kernel serves every batch of the stream; a key outside its range flips
     the fits flag and the host falls back for that batch. Output arrays are
     ``out_cap``-sized (the compact group bucket), shrinking every downstream
@@ -1638,6 +1705,38 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
     for s in sizes:
         S *= s
     strides = K.radix_strides(sizes)
+    slot_sorted = _is_slot_sorted(sizes, capacity, nbuck)
+
+    def group_keys(bases, slot, move, out_valid):
+        """The groups' (data, validity) key planes: a key reconstructs
+        arithmetically from the slot id (exact for ints; no representative
+        row to gather), and ``move`` brings a plane by slot to the groups."""
+        planes = []
+        for i, kdt in enumerate(key_dtypes):
+            code_b = (slot // strides[i]) % sizes[i]
+            kdata = (bases[i] + code_b - 1).astype(jnp.dtype(kdt))
+            planes.append(jnp.where(out_valid, move(kdata),
+                                    jnp.zeros((), jnp.dtype(kdt))))
+            planes.append(move(code_b > 0) & out_valid)
+        return planes
+
+    def slot_sorted_groups(exists, bases, args, seg, fits):
+        iota = jnp.arange(capacity, dtype=jnp.int32)
+        with jax.named_scope("order"):
+            # padding rows carry the slot id S and sort last; ties in row
+            # order, so a float sum adds in one fixed order
+            slot, order = jax.lax.sort((seg, iota), num_keys=2,
+                                       is_stable=False)
+        num_groups, out_valid, (slot,), states = _reduce_ordered(
+            exists, iota, order, slot, [], [slot],
+            [kind for kind, _r, _d in specs],
+            [_partial_requests(spec, arg, exists)
+             for spec, arg in zip(specs, args)], out_cap)
+        with jax.named_scope("emit"):
+            keys = group_keys(bases, slot.astype(jnp.int64), lambda x: x,
+                              out_valid)
+        return (jnp.where(fits, num_groups.astype(jnp.int64), jnp.int64(-1)),
+                out_valid, *keys, *states)
 
     def agg_dense_partial(exists, bases, *flat):
         key_data = [flat[2 * i] for i in range(nk)]
@@ -1656,6 +1755,8 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
         with jax.named_scope("pack"):
             seg, fits = K.radix_pack(key_data, key_valid, exists, bases,
                                      sizes, strides)
+        if slot_sorted:
+            return slot_sorted_groups(exists, bases, args, seg, fits)
         with jax.named_scope("reduce"):
             outs = _reduce_aggs(specs, args, seg, S)
             present = _seg_reduce("any", seg, exists, S)
@@ -1671,15 +1772,8 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
             out_valid = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
             results = [jnp.where(fits, num_groups.astype(jnp.int64),
                                  jnp.int64(-1)), out_valid]
-            # keys reconstruct arithmetically from the bucket index (exact
-            # for ints; no representative-row gathers needed)
-            iota_s = jnp.arange(S, dtype=jnp.int64)
-            for i, kdt in enumerate(key_dtypes):
-                code_b = (iota_s // strides[i]) % sizes[i]
-                kdata = (bases[i] + code_b - 1).astype(jnp.dtype(kdt))
-                results.append(jnp.where(out_valid, compact(kdata),
-                                         jnp.zeros((), jnp.dtype(kdt))))
-                results.append(compact(code_b > 0) & out_valid)
+            results += group_keys(bases, jnp.arange(S, dtype=jnp.int64),
+                                  compact, out_valid)
             for entry in outs:
                 for a in entry[1:]:
                     results.append(compact(a))

@@ -30,8 +30,9 @@ class DeviceStats:
     bytes and calls, blocking syncs (``sync_calls``: the wait itself is the
     ``sync:*`` span of :func:`wait_int`, the benchmark's ``device_wait_s``),
     jitted-kernel dispatches and which PARTIAL aggregation kernel answered a
-    batch. Surfaced at /debug/device; the benchmark reads the deltas a
-    query (``h2d_mb``, ``d2h_mb``, ``sync_points``, ``agg_dense_batches``)."""
+    batch, in which form. Surfaced at /debug/device; the benchmark reads the
+    deltas a query (``h2d_mb``, ``d2h_mb``, ``sync_points``,
+    ``agg_dense_batches``, ``agg_slot_sorted_batches``)."""
 
     def __init__(self):
         self._mu = threading.Lock()
@@ -49,6 +50,7 @@ class DeviceStats:
             self.mapped_bytes = 0
             self.sync_calls = 0
             self.agg_dense_batches = 0
+            self.agg_slot_sorted_batches = 0
             self.agg_sort_batches = 0
 
     def add_to_host(self, nbytes: int):
@@ -74,13 +76,17 @@ class DeviceStats:
         with self._mu:
             self.sync_calls += 1
 
-    def add_agg_batch(self, dense: bool):
+    def add_agg_batch(self, dense: bool, slot_sorted: bool = False):
         """One PARTIAL aggregation batch answered: by the slot-table kernel
-        (``jit(agg_dense_partial)``) or by the sort kernel
-        (``jit(agg_partial)``). Benchmark: ``agg_dense_batches``."""
+        (``jit(agg_dense_partial)``; ``slot_sorted`` where its table was
+        large enough to reduce by one sort of the slot id) or by the sort
+        kernel (``jit(agg_partial)``). Benchmark: ``agg_dense_batches``,
+        ``agg_slot_sorted_batches``."""
         with self._mu:
             if dense:
                 self.agg_dense_batches += 1
+                if slot_sorted:
+                    self.agg_slot_sorted_batches += 1
             else:
                 self.agg_sort_batches += 1
 
@@ -111,6 +117,7 @@ class DeviceStats:
                 "mapped_bytes": self.mapped_bytes,
                 "sync_calls": self.sync_calls,
                 "agg_dense_batches": self.agg_dense_batches,
+                "agg_slot_sorted_batches": self.agg_slot_sorted_batches,
                 "agg_sort_batches": self.agg_sort_batches,
             }
 

@@ -256,22 +256,25 @@ def merge_state(name, cols, rows):
 # -- the comparison --------------------------------------------------------------
 
 
-def check_outputs(outs, exists, keys, names, states_of):
+def check_outputs(outs, exists, keys, names, states_of, out_len=None):
     """``outs`` (a kernel's outputs, numpy) against the reference: the group
     count, the keys in order, every state plane, and zeros past the groups.
-    ``states_of(name_index, rows)`` gives the reference's state of a group."""
+    ``states_of(name_index, rows)`` gives the reference's state of a group.
+    The planes are ``out_len`` long (a batch's capacity, or the slot table's
+    ``out_cap``)."""
     outs = [np.asarray(o) for o in outs]
     groups = group_rows(exists, keys)
     g = len(groups)
+    out_len = len(exists) if out_len is None else out_len
     assert int(outs[0]) == g
-    assert np.array_equal(outs[1], np.arange(len(exists)) < g)
+    assert np.array_equal(outs[1], np.arange(out_len) < g)
     pos = 2
     for d, v in keys:
         cls, val = _canonical(d, v & exists)
         first = np.array([rows[0] for rows in groups], np.int64)
-        want_valid = np.zeros(len(exists), bool)
+        want_valid = np.zeros(out_len, bool)
         want_valid[:g] = cls[first] > 0
-        want = np.zeros(len(exists), d.dtype)
+        want = np.zeros(out_len, d.dtype)
         want[:g] = np.where(cls[first] == 2, np.nan, val[first]) \
             if d.dtype.kind == "f" else val[first]
         assert outs[pos].dtype == d.dtype

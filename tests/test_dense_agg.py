@@ -231,9 +231,10 @@ def test_int64_extreme_ranges_stay_exact():
     assert got["s#sum"] == [200]
 
 
-# -- the reduction's two forms (_seg_reduce) -----------------------------------
+# -- the table's forms (_table_form): masked, slot-sorted, scatter ---------------
 
 CUT = A._MASKED_REDUCE_MAX_SLOTS
+RULE = A._SLOT_SORT_MIN_SLOTS  # the first table that reduces by a sort
 ROWS = 384  # a batch of 300 rows and 84 padding rows
 # (kind, rescale, accumulator dtype), argument dtype
 KINDS = {
@@ -295,7 +296,8 @@ def _rows(nseg, data, seed):
 
 
 def _reduced(monkeypatch, masked, spec, arg, seg, nseg):
-    monkeypatch.setattr(A, "_masked_form", lambda nseg, rows: masked)
+    monkeypatch.setattr(A, "_table_form", lambda nseg, rows, sortable=False:
+                        "masked" if masked else "scatter")
     (out,) = A._reduce_aggs((spec,), [arg], seg, nseg)
     monkeypatch.undo()
     return [np.asarray(a) for a in out[1:]]
@@ -328,6 +330,9 @@ def test_masked_reduction_equals_the_scatter(monkeypatch, name, nseg):
     (128, 128, False),  # a segment a row: the passthrough kernel
     (128, 256, True)])
 def test_form_follows_the_static_shapes(nseg, rows, masked):
+    """``_seg_reduce`` gives the table itself, so it never sorts: masked up
+    to the cut where the table is smaller than the batch, else a scatter."""
+    assert A._table_form(nseg, rows) == ("masked" if masked else "scatter")
     closed = jax.make_jaxpr(
         lambda seg, x: A._seg_reduce("add", seg, x, nseg))(
         jax.ShapeDtypeStruct((rows,), jnp.int32),
@@ -335,6 +340,21 @@ def test_form_follows_the_static_shapes(nseg, rows, masked):
     scatters = [e for e in jaxpr_eqns(closed.jaxpr)
                 if e.primitive.name.startswith("scatter")]
     assert bool(scatters) != masked
+
+
+@pytest.mark.parametrize("nseg,rows,nbuck,form", [
+    (16, 131072, 0, "masked"), (RULE // 2, 131072, 0, "masked"),
+    (RULE, 131072, 0, "sorted"), (CUT, 131072, 0, "sorted"),
+    (4 * CUT, 131072, 0, "sorted"),
+    (4 * CUT, 4 * CUT, 0, "sorted"),  # as many slots as rows: no scatter left
+    (RULE, 131072, 256, "masked"),  # the radix variant keeps the table
+    (4 * CUT, 131072, 256, "scatter")])
+def test_the_slot_table_sorts_from_the_rule_on(nseg, rows, nbuck, form):
+    """A rule on the static shapes alone: ``jit(agg_dense_partial)`` of
+    ``RULE`` slots or more reduces by one sort of the slot id, whatever the
+    capacity, unless it is the radix variant."""
+    assert A._table_form(nseg, rows, sortable=not nbuck) == form
+    assert A._is_slot_sorted((nseg // 4, 4), rows, nbuck) == (form == "sorted")
 
 
 def _avals(key_dtypes, arg_dtypes, cap, bases=False):
@@ -374,13 +394,6 @@ def test_dense_kernel_at_16_slots_has_no_row_sized_scatter(names):
     for eqn in serial:
         sizes = [v.aval.size for v in list(eqn.invars) + list(eqn.outvars)]
         assert max(sizes) < CAPACITY, eqn
-    # and above the cut the same kernel keeps its row-sized scatter-adds
-    big = A._dense_partial_kernel(("int64",), specs, adt, CAPACITY,
-                                  (2 * CUT,), 2 * CUT)
-    closed = jax.make_jaxpr(big)(*avals)
-    assert any(e.primitive.name.startswith("scatter")
-               and max(v.aval.size for v in e.invars) >= CAPACITY
-               for e in jaxpr_eqns(closed.jaxpr))
 
 
 # how many state planes a kind's merge takes and every kernel gives
@@ -402,37 +415,98 @@ def _state_dtypes(name):
 
 
 # key dtypes, aggregates, and sha256 of str(make_jaxpr(_dense_partial_kernel))
-# at 16 and at 2 x CUT slots at PR 28's commit (03958eb, jax 0.9.0): PR 29
-# rewrote the sort path beside it, and the slot-table kernel, its program and
-# the compile-cache entries q01, q06 and q47 hit, must not move.
+# at 16 and at 2 x CUT slots (jax 0.9.0). The 16-slot digests are those of
+# PR 28's commit (03958eb) and no PR since has had any business moving them:
+# PR 29 rewrote the sort path beside the slot table and PR 38 gave a table of
+# RULE slots or more the slot-sorted form, and either way the masked program
+# of a small table, and the compile-cache entries q01, q06 and q22's grand
+# total hit, stay byte for byte. The 2 x CUT digests are PR 38's: the
+# slot-sorted program that replaced the scatter form there (q47's, q51's).
+# Then the sort path's own two kernels at PR 37's commit (034ba47): PR 38
+# factored their body (_aggregate_sorted -> _reduce_ordered) to share it with
+# the slot-sorted form, and their programs (q67, q29, q51, q22) must not move.
 SCHEMAS = {
     "sum_count_1key": (
         ("int64",), ["sum", "count"],
         "e93110f768a05d8b5ee57927301a266c2baa358f3998343c0315047c38931927",
-        "9eeb4b89a0917718a8eaffaead9f9d02c57edf9321b1e6d1bf81c078fb8e2666"),
+        "c5b1a2ea941171acfabdc537a5e0e83ad201a7fdd719d2ea68bbc9d44888f3c4"),
     "narrow_2key": (
         ("int64", "int32"), ["sum", "avg", "min", "max", "count", "sum_f32"],
         "3ae10d49a207373a94025b56d1d04d0faad1fe64fc7ad1b84a12d05b7b159f09",
-        "c1df3eb7eac6195bf0f2cb062948361387bda4e5a6bbefe5413b95771d220806"),
+        "942c22a608a9d0dca9475a1fa4e36a990a07598166bea9a1e77e43251596729a"),
     "wide_1key": (
         ("int64",), ["sum2", "avg2", "sum3", "avg3", "minw", "maxw"],
         "4257a9db6e65ad6b05887834f3b593892b8538d1e359de3751b579c50a68dcf8",
-        "59f9404fba7e47a35e0753cb3b7ce6fe288c6e3066b8031c1075916a1a92f5d6"),
+        "ce762c969b8d6264d9da273e9006a685ab208f0858cb2ebf4a9ecc872a5506ed"),
 }
+SORT_PATH_DIGESTS = {
+    ("sum_count_1key", "partial"):
+        "7008a2c7e01bd20fd784a98fee28157ed888dcfc51c28d3d48be409f88d7c28d",
+    ("sum_count_1key", "merge"):
+        "5feaff504f37522db4ae4c42629d65815d8b762c87c8b3b12e175390344c5dd3",
+    ("narrow_2key", "partial"):
+        "ee7b108e761082c9128c9b836bc87153bcc2ce9ff41ce14bb32db50555bb3160",
+    ("narrow_2key", "merge"):
+        "0adcc66aa412a928c9944c904caee4a52d3712125cd1431238447a660fd49861",
+    ("wide_1key", "partial"):
+        "ee7b3fa7168e931d7ad7ba4c719967b75739031b942afc9508e6277c009b8bc7",
+    ("wide_1key", "merge"):
+        "eb73d73d1f742f281c2682951801a4743d79e2ae875c78e7f37edd58237fb729",
+}
+
+
+def _dense_kernel(case, slots, capacity=CAPACITY, form=None):
+    """``_dense_partial_kernel`` of a schema at ``slots`` slots with its
+    abstract arguments; ``form`` forces the table's form (the kernel is then
+    built past the cache, so no other test meets a forced program)."""
+    key_dtypes, names, *_ = SCHEMAS[case]
+    specs = tuple(KINDS[n][0] for n in names)
+    adt = tuple(KINDS[n][1] for n in names)
+    sizes = (slots,) if len(key_dtypes) == 1 else (slots // 4, 4)
+    build = A._dense_partial_kernel
+    if form is not None:
+        build = build.__wrapped__
+        rule, A._table_form = A._table_form, lambda *_a, **_k: form
+    try:
+        kernel = build(key_dtypes, specs, adt, capacity, sizes,
+                       max(128, min(slots, capacity)))
+    finally:
+        if form is not None:
+            A._table_form = rule
+    return kernel, sizes, _avals(key_dtypes, adt, capacity, bases=True)
+
+
+def _digest(kernel, avals):
+    text = str(jax.make_jaxpr(kernel)(*avals))
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 @pytest.mark.parametrize("case", sorted(SCHEMAS))
 def test_dense_kernel_jaxpr_is_the_parents(case):
-    key_dtypes, names, *digests = SCHEMAS[case]
-    specs = tuple(KINDS[n][0] for n in names)
-    adt = tuple(KINDS[n][1] for n in names)
-    avals = _avals(key_dtypes, adt, CAPACITY, bases=True)
-    for slots, digest in zip((16, 2 * CUT), digests):
-        sizes = (slots,) if len(key_dtypes) == 1 else (slots // 4, 4)
-        kernel = A._dense_partial_kernel(key_dtypes, specs, adt, CAPACITY,
-                                         sizes, max(128, slots))
-        text = str(jax.make_jaxpr(kernel)(*avals))
-        assert hashlib.sha256(text.encode()).hexdigest() == digest, slots
+    *_, small, large = SCHEMAS[case]
+    for slots, digest in ((16, small), (2 * CUT, large)):
+        kernel, _sizes, avals = _dense_kernel(case, slots)
+        assert _digest(kernel, avals) == digest, slots
+
+
+def _sort_path_kernel(case, which):
+    key_dtypes, names, *_ = SCHEMAS[case]
+    if which == "partial":
+        adt = tuple(KINDS[n][1] for n in names)
+        kernel = A._partial_kernel(
+            key_dtypes, tuple(KINDS[n][0] for n in names), adt, CAPACITY)
+        return kernel, _avals(key_dtypes, adt, CAPACITY)
+    states = tuple(_state_dtypes(n) for n in names)
+    kernel = A._merge_kernel(
+        key_dtypes, tuple(KINDS[n][0][0] for n in names), states, CAPACITY)
+    return kernel, _avals(key_dtypes, [dt for dts in states for dt in dts],
+                          CAPACITY)
+
+
+@pytest.mark.parametrize("case,which", sorted(SORT_PATH_DIGESTS))
+def test_sort_path_jaxprs_are_the_parents(case, which):
+    assert _digest(*_sort_path_kernel(case, which)) == \
+        SORT_PATH_DIGESTS[case, which]
 
 
 @pytest.mark.parametrize("which", ["partial", "merge"])
@@ -443,18 +517,7 @@ def test_sort_path_kernels_touch_no_row_at_a_time(case, which):
     and one out: no scatter with a batch-sized operand or update, at most two
     gathers with batch-sized indices, no sort of more than two operands, and
     no ``cond`` (one path, whatever the keys)."""
-    key_dtypes, names, *_ = SCHEMAS[case]
-    if which == "partial":
-        adt = tuple(KINDS[n][1] for n in names)
-        kernel = A._partial_kernel(
-            key_dtypes, tuple(KINDS[n][0] for n in names), adt, CAPACITY)
-        avals = _avals(key_dtypes, adt, CAPACITY)
-    else:
-        states = tuple(_state_dtypes(n) for n in names)
-        kernel = A._merge_kernel(
-            key_dtypes, tuple(KINDS[n][0][0] for n in names), states, CAPACITY)
-        avals = _avals(key_dtypes, [dt for dts in states for dt in dts],
-                       CAPACITY)
+    kernel, avals = _sort_path_kernel(case, which)
     eqns = list(jaxpr_eqns(jax.make_jaxpr(kernel)(*avals).jaxpr))
     names_seen = {e.primitive.name for e in eqns}
     assert "cond" not in names_seen and "while" not in names_seen
@@ -469,6 +532,109 @@ def test_sort_path_kernels_touch_no_row_at_a_time(case, which):
             gathers += eqn.invars[1].aval.shape[0] >= CAPACITY
     assert 1 <= gathers <= 2
     assert "sort" in names_seen and "cumsum" in names_seen
+
+
+@pytest.mark.parametrize("slots", [RULE, CUT, 4 * CUT])
+@pytest.mark.parametrize("case", sorted(SCHEMAS))
+def test_slot_sorted_kernel_touches_no_row_at_a_time(case, slots):
+    """From the rule on ``jit(agg_dense_partial)`` is the sort path's body
+    behind ONE sort of the slot id: no scatter of any size, nothing of
+    ``slots x rows`` elements (nor of more than a row of words a batch row),
+    no sort of more than two operands and none of a 64-bit operand, one
+    gather with batch-sized indices in and one of ``out_cap`` rows out, no
+    ``cond`` or ``while``."""
+    kernel, _sizes, avals = _dense_kernel(case, slots)
+    eqns = list(jaxpr_eqns(jax.make_jaxpr(kernel)(*avals).jaxpr))
+    names_seen = {e.primitive.name for e in eqns}
+    assert not [n for n in names_seen if n.startswith("scatter")]
+    assert "cond" not in names_seen and "while" not in names_seen
+    gathers = []
+    for eqn in eqns:
+        for v in list(eqn.invars) + list(eqn.outvars):
+            # the widest thing is the word matrix: a few words a row
+            assert v.aval.size <= 64 * CAPACITY, eqn
+        if eqn.primitive.name == "sort":
+            assert len(eqn.invars) <= 2, eqn
+            assert all(v.aval.dtype.itemsize <= 4 for v in eqn.invars), eqn
+        elif eqn.primitive.name == "gather":
+            gathers.append(eqn.invars[1].aval.shape[0])
+    assert sorted(gathers) == [min(slots, CAPACITY), CAPACITY]
+    assert "sort" in names_seen and "cumsum" in names_seen
+
+
+SMALL = 256  # rows a batch of the equality tests: 200 and 56 padding rows
+
+
+def _dense_inputs(case, sizes, data, seed):
+    """(exists, keys, args, bases) in numpy for a kernel of ``SMALL`` rows:
+    keys inside the slot table's ranges but for ``out_of_range``."""
+    from tests.test_agg_sorted import _valid, _value_planes
+
+    key_dtypes, names, *_ = SCHEMAS[case]
+    rng = np.random.default_rng(seed)
+    exists = np.arange(SMALL) < (SMALL if data == "one_slot" else 200)
+    bases = [-5, 3][:len(sizes)]
+    keys = []
+    for kd, base, size in zip(key_dtypes, bases, sizes):
+        if data == "one_slot":  # every row in the table's last slot
+            k, v = np.full(SMALL, base + size - 2), np.ones(SMALL, bool)
+        else:
+            # crowded low codes, some far ones: more slots than groups
+            k = base + rng.integers(0, min(size - 1, 12), SMALL)
+            k[::7] = base + rng.integers(0, size - 1, len(k[::7]))
+            v = _valid(rng, SMALL)
+        keys.append((k.astype(kd), v))
+    if data == "out_of_range":
+        keys[0][0][17], keys[0][1][17] = bases[0] + sizes[0] - 1, True
+    draw = "extremes" if data == "one_slot" else "random"
+    args = [(_value_planes(rng, KINDS[n][1], SMALL, draw), _valid(rng, SMALL))
+            for n in names]
+    return exists, keys, args, np.array(bases, np.int64)
+
+
+@pytest.mark.parametrize("slots", [RULE, CUT, 4 * CUT])
+@pytest.mark.parametrize("case", sorted(SCHEMAS))
+def test_slot_sorted_form_equals_the_masked_form_and_numpy(case, slots):
+    """Every aggregate kind at the first size that sorts, at 16,384 and at
+    65,536 slots: the slot-sorted form gives the masked form's outputs plane
+    for plane, row for row and in row order (a float sum by the suite's
+    tolerance), and both give a numpy group-by's (``test_agg_sorted``'s
+    reference; the groups in ascending slot order, which is key order with
+    nulls first), over null keys, null arguments, garbage in the padding
+    rows, far more slots than groups, every row in one slot with sums that
+    wrap, and a key outside the table (``num_groups`` -1 from both)."""
+    from tests.test_agg_sorted import check_outputs, partial_state
+
+    _key_dtypes, names, *_ = SCHEMAS[case]
+    sorted_kernel, sizes, _ = _dense_kernel(case, slots, SMALL)
+    masked_kernel, _, _ = _dense_kernel(case, slots, SMALL, form="masked")
+    assert A._is_slot_sorted(sizes, SMALL, 0)
+    for seed, data in enumerate(("random", "one_slot", "out_of_range")):
+        exists, keys, args, bases = _dense_inputs(case, sizes, data,
+                                                  100 * slots + seed)
+        flat = [p for d, v in keys for p in (d, v)]
+        flat += [p for planes, v in args for p in (*planes, v)]
+        flat = [jnp.asarray(exists), jnp.asarray(bases),
+                *map(jnp.asarray, flat)]
+        got = [np.asarray(o) for o in sorted_kernel(*flat)]
+        want = [np.asarray(o) for o in masked_kernel(*flat)]
+        assert len(got) == len(want)
+        if data == "out_of_range":
+            assert int(got[0]) == int(want[0]) == -1
+            continue
+        for i, (g, w) in enumerate(zip(got, want)):
+            assert g.dtype == w.dtype and g.shape == w.shape, i
+            if g.dtype.kind == "f":  # a float sum's order is the form's own
+                np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-2)
+            else:
+                assert np.array_equal(g, w), (data, i)
+        # under a null key the slot table's data plane reads base - 1, in
+        # either form (only the validity is contract); the reference reads 0
+        for at in range(2, 2 + 2 * len(keys), 2):
+            got[at] = np.where(got[at + 1], got[at], 0).astype(got[at].dtype)
+        check_outputs(got, exists, keys, names,
+                      lambda i, rows: partial_state(names[i], *args[i], rows),
+                      out_len=SMALL)
 
 
 # -- selection and counters ----------------------------------------------------
@@ -511,6 +677,47 @@ def test_auto_engages_whatever_the_backend_and_counts_what_ran(
         assert wide._bucket_state is None and wide._dense_ok is False
         assert s3["agg_sort_batches"] - s2["agg_sort_batches"] == 1
         assert s3["agg_dense_batches"] == s2["agg_dense_batches"]
+
+
+def test_slot_sorted_batches_are_counted_and_a_key_outside_widens():
+    """``agg_slot_sorted_batches`` counts the slot-table batches whose table
+    was large enough to reduce by a sort, and no other; a key outside such a
+    table reads -1, and ``_try_dense`` probes again and runs the widened
+    table, still slot-sorted, with exact results."""
+    def delta(after, before):
+        return {k: after[k] - before[k] for k in (
+            "agg_dense_batches", "agg_slot_sorted_batches",
+            "agg_sort_batches")}
+
+    s0 = DEVICE_STATS.snapshot()
+    _agger().process(_batch([3, 4, 5] * 100, [1] * 300))
+    s1 = DEVICE_STATS.snapshot()
+    assert delta(s1, s0) == {"agg_dense_batches": 1, "agg_sort_batches": 0,
+                             "agg_slot_sorted_batches": 0}
+    agger = _agger()
+    n = 4 * RULE
+    ks = 5 + np.arange(n) % (RULE - 2)  # RULE - 2 values and the null: RULE
+    out = agger.process(_batch(ks.tolist(), [1] * n))
+    s2 = DEVICE_STATS.snapshot()
+    _, bases, sizes, _ = agger._bucket_state
+    assert sizes == (RULE,) and out.num_rows == RULE - 2
+    assert delta(s2, s1) == {"agg_dense_batches": 1, "agg_sort_batches": 0,
+                             "agg_slot_sorted_batches": 1}
+    ks[-1] = 5 + RULE + 10
+    out = agger.process(_batch(ks.tolist(), [2] * n))
+    s3 = DEVICE_STATS.snapshot()
+    assert agger._bucket_state[0] == "dense"
+    assert agger._bucket_state[2] == (2 * RULE,), "the union of both ranges"
+    assert delta(s3, s2) == {"agg_dense_batches": 1, "agg_sort_batches": 0,
+                             "agg_slot_sorted_batches": 1}
+    # the -1 run, the second probe and the widened run: three syncs
+    assert s3["sync_calls"] - s2["sync_calls"] == 3
+    got = out.to_arrow().to_pydict()
+    want = {}
+    for k in ks.tolist():
+        want[k] = want.get(k, 0) + 2
+    assert got["k1"] == sorted(want), "groups leave in key order"
+    assert got["s#sum"] == [want[k] for k in sorted(want)]
 
 
 def test_dense_agg_false_forces_the_sort_kernel():

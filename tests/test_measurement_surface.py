@@ -64,7 +64,8 @@ def test_device_stats_snapshot_is_integers_with_the_readers_keys():
     after = DEVICE_STATS.snapshot()
     assert all(type(v) is int for v in after.values()), after
     for key in ("to_host_bytes", "to_device_bytes", "sync_calls",
-                "agg_dense_batches", "agg_sort_batches", "kernel_calls"):
+                "agg_dense_batches", "agg_slot_sorted_batches",
+                "agg_sort_batches", "kernel_calls"):
         assert after[key] >= before[key]
     assert after["to_device_bytes"] > before["to_device_bytes"]
     assert after["kernel_calls"] > before["kernel_calls"]
