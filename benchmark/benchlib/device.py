@@ -6,10 +6,11 @@ from typing import List, Optional
 
 # Published peaks per chip, keyed by `device_kind` as JAX reports it.
 # Source: Google Cloud documentation, "TPU v5e" system architecture page:
-# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip.
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip, and
+# an interchip (ICI) bandwidth of 1,600 Gbps per chip, 200 GB/s.
 PEAKS = {
     "TPU v5 lite": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12,
-                    "hbm_bytes": 16e9},
+                    "hbm_bytes": 16e9, "ici_bytes_per_s": 200e9},
 }
 
 

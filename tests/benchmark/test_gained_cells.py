@@ -1,6 +1,7 @@
-"""The cells that three per-layer metrics' lists gained (PR 34:
-`sort_device_s` reads `q51_cume_window`, `join_self_s` and `join_host_s`
-read `q47_sort_rank`): each reader gives a number for its new cell in the
+"""The cells that per-layer metrics' lists gained (PR 34: `sort_device_s`
+reads `q51_cume_window`, `join_self_s` and `join_host_s` read
+`q47_sort_rank`; PR 39: those two and `row_move_device_s` read
+`q22_inv_rollup`): each reader gives a number for its new cell in the
 traced rehearsal of the chip's plan. A CPU trace has no `XLA Modules` line,
 so for the metric that reads launches the rehearsal lays the program's own
 `kernel:<fn>` spans (the enqueue of `jit(<fn>)`) in the launches' place:
@@ -20,7 +21,10 @@ from benchlib import manifest as M  # noqa: E402
 
 GAINED = [("sort_device_s", "q51_cume_window"),
           ("join_self_s", "q47_sort_rank"),
-          ("join_host_s", "q47_sort_rank")]
+          ("join_host_s", "q47_sort_rank"),
+          ("join_self_s", "q22_inv_rollup"),
+          ("join_host_s", "q22_inv_rollup"),
+          ("row_move_device_s", "q22_inv_rollup")]
 
 
 def _enqueues_as_launches(monkeypatch, metric):

@@ -1,8 +1,17 @@
 """`BENCHMARK.json` keeps the contract's rules, and the checker that says so
-refuses the manifests it should."""
+refuses the manifests it should.
+
+The rule for every test of this directory: later PRs append configurations,
+cells (on one chip or four) and metrics by files and entries, and may not
+edit a test. So a test of a PR's additions names those additions and checks
+them. It never asserts the manifest's size, its last entry, the chips of
+cells it did not add, or that a cell is absent from a list."""
 
 import copy
+import glob
 import json
+import os
+import re
 
 import pytest
 
@@ -52,6 +61,19 @@ def test_configuration_files_say_what_the_manifest_says():
         assert sorted(config["reduced_from"]) == sorted(entry["reduced"])
         for key in ("assumed", "guarantees", "counters_must", "deployment"):
             assert config[key], key
+
+
+def test_no_test_here_holds_a_manifest_list_to_its_last_entry():
+    """The rule above, where a pattern can see it: a later PR's entries come
+    last, so no test of this directory reads a list of the manifest from its
+    end (PR 32's `per_layer[-1]` stood in every cell-adding PR's way)."""
+    from_the_end = re.compile(
+        r"""\[\s*["'](configs|workloads|end_to_end|per_layer)["']\s*\]\s*\[\s*-""")
+    here = os.path.dirname(os.path.abspath(__file__))
+    for path in sorted(glob.glob(os.path.join(here, "*.py"))):
+        with open(path) as f:
+            for number, line in enumerate(f, 1):
+                assert not from_the_end.search(line), f"{path}:{number}"
 
 
 def _break_name(d):

@@ -10,8 +10,14 @@ import pytest
 
 from tests.benchmark import helpers
 
+helpers.load_run()  # puts the benchmark's directory on sys.path
+from benchlib import manifest as M  # noqa: E402
+from benchlib.registry import Registry  # noqa: E402
+
 with open(helpers.MANIFEST) as _f:
-    CELLS = [w["name"] for w in json.load(_f)["workloads"]] + [helpers.MESH_CELL]
+    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+if helpers.MESH_CELL not in CELLS:  # the stand-in, until the real one is there
+    CELLS.append(helpers.MESH_CELL)
 
 
 @pytest.fixture()
@@ -57,6 +63,65 @@ def test_mesh_cell_traced_reports_the_mesh_metrics(manifest_path, capsys):
     # the metrics every cell reports are there beside the mesh's
     assert {"device_idle_share", "device_busy_s", "compiles_in_window"} \
         <= set(result["metrics"])
+
+
+def _with_a_real_mesh_configuration(root):
+    """The manifest as the PR that adds `tpcds_sf1_mesh4` leaves it, by files
+    and entries only: the configuration (`tpcds_sf1_chip1` through the
+    multichip Session), its two cells and the mesh's four metrics appended.
+    Returns its path and the configuration; the configuration's file lies
+    under ``root``. Once that PR has come, the manifest as it is."""
+    with open(helpers.MANIFEST) as f:
+        manifest = json.load(f)
+    for entry in manifest["configs"]:
+        if entry["name"] == helpers.MESH_CONFIG:
+            with open(f"{helpers.ROOT}/{entry['file']}") as f:
+                return helpers.MANIFEST, json.load(f)
+    (chip1,) = [c for c in manifest["configs"] if c["name"] == "tpcds_sf1_chip1"]
+    with open(f"{helpers.ROOT}/{chip1['file']}") as f:
+        config = helpers.as_mesh(json.load(f))
+    config["source"] = "the real one"  # not the stand-in's file
+    (root / "mesh4.json").write_text(json.dumps(config))
+    manifest["configs"].append(dict(chip1, name=helpers.MESH_CONFIG,
+                                    source="the real one",
+                                    file=str(root / "mesh4.json")))
+    cells = [helpers.MESH_CELL, "q67_agg_rank_mesh4"]
+    for cell, traffic in zip(cells, ("q01_repeat", "q67_repeat")):
+        manifest["workloads"].append({
+            "name": cell, "config": helpers.MESH_CONFIG, "traffic": traffic,
+            "chips": 4, "why": "the real mesh cell"})
+    for name, unit, source in helpers.MESH_METRICS:
+        manifest["per_layer"].append({
+            "name": name, "unit": unit, "better": "lower", "source": source,
+            "layer": "mesh", "moves": "query_s", "workloads": cells})
+    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    return str(root / "BENCHMARK.json"), config
+
+
+def test_the_stand_in_gives_way_to_a_real_mesh_configuration(
+        tmp_path, monkeypatch):
+    (tmp_path / "real").mkdir(), (tmp_path / "tiny_copy").mkdir()
+    real, config = _with_a_real_mesh_configuration(tmp_path / "real")
+    monkeypatch.setattr(helpers, "MANIFEST", real)
+    seen = {}
+
+    def edit(manifest, tmp):
+        seen["tiny_file"] = (tmp / "tiny" / f"{helpers.MESH_CONFIG}.json").read_text()
+        seen["manifest"] = json.loads(json.dumps(manifest))
+        helpers.add_mesh_cell(manifest, tmp)
+
+    path = helpers.tiny_manifest(tmp_path / "tiny_copy", edit)
+    m = M.Manifest(path)
+    assert M.problems(m, Registry(m.paths).find) == []
+    assert m.data == seen["manifest"]  # nothing appended
+    for kind in ("configs", "workloads", "per_layer"):
+        names = [e["name"] for e in m.data[kind]]
+        assert len(names) == len(set(names)), kind
+    # the real configuration's tiny file, as `tiny_manifest` wrote it
+    tiny = tmp_path / "tiny_copy" / "tiny" / f"{helpers.MESH_CONFIG}.json"
+    assert tiny.read_text() == seen["tiny_file"]
+    config["generator_params"]["table_rows"] = helpers.TINY_ROWS
+    assert json.loads(tiny.read_text()) == config
 
 
 def test_cpu_without_allow_cpu_fails_and_prints_no_result(manifest_path, capsys):

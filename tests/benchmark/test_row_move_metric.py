@@ -38,8 +38,7 @@ def test_the_manifest_lists_the_metric_for_the_cells_that_move_rows():
         "name": "row_move_device_s", "unit": "s", "better": "lower",
         "source": "device_trace", "layer": "kernels", "moves": "query_s",
         "workloads": ["q01_scan_topk", "q47_sort_rank", "q67_agg_rank",
-                      "q29_smj_facts", "q51_cume_window"]}
-    assert m.data["per_layer"][-1] is entry  # appended, nothing moved
+                      "q29_smj_facts", "q51_cume_window", "q22_inv_rollup"]}
     assert callable(Registry(m.paths).reader("row_move_device_s"))
 
 
