@@ -54,12 +54,13 @@ class Config:
     # to the spill-capable host table.
     device_merge_max_bytes: int = 256 << 20
 
-    # Mesh-exchange reducer outputs stay device-resident (HBM, pinned in
-    # the session's resource map until close()) only while the TOTAL
-    # payload across the session's live exchanges stays below this — the
-    # session debits each resident exchange from the budget, and anything
-    # beyond it materializes to host RAM like shuffle files, so stacked
-    # exchanges cannot accumulate unbounded HBM.
+    # Mesh-exchange reducer outputs stay device-resident (HBM, held by the
+    # resource map until their query is released) only while the TOTAL
+    # payload across the live queries' exchanges stays below this — the
+    # session charges each resident exchange to its query's resource and
+    # gives it back with the query, and anything beyond it materializes to
+    # host RAM like shuffle files, so stacked exchanges cannot accumulate
+    # unbounded HBM.
     mesh_device_resident_max_bytes: int = 128 << 20
 
     # Per-device per-round byte budget for the compacted mesh exchange's
@@ -71,10 +72,9 @@ class Config:
 
     # Multichip device-primary execution: when enabled, a Session without
     # an explicit ``mesh=`` argument builds one over the local devices
-    # (parallel/mesh.py make_mesh) and exchanges whose stages the placement
-    # model puts on-device ride the ICI all-to-all; fused-stage closures of
-    # concurrent same-shape batches additionally run data-parallel under
-    # shard_map across the mesh. Off by default: CI's tier-1 command
+    # (parallel/mesh.py make_mesh), runs every task on the chip of its
+    # partition and lowers exchanges onto the ICI all-to-all between the
+    # chips. Off by default: CI's tier-1 command
     # (JAX_PLATFORMS=cpu) must behave exactly as before. Dev boxes emulate
     # the mesh with XLA_FLAGS=--xla_force_host_platform_device_count=8.
     multichip_enabled: bool = False

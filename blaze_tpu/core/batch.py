@@ -781,26 +781,6 @@ class ColumnarBatch:
         ncols = len(batches[0].columns)
         cols: List[Column] = [None] * ncols
         if slots:
-            # multichip sessions feed batches committed to DIFFERENT mesh
-            # devices (sharded fused outputs, device-tier shuffle segments);
-            # one dispatch over mixed commitments raises, so align stragglers
-            # onto the first batch's device before tracing
-            devs = {kernels.committed_device(b.columns[i].data)
-                    for b in batches for i in slots}
-            devs.discard(None)
-            if len(devs) > 1:
-                target = kernels.committed_device(
-                    batches[0].columns[slots[0]].data) or next(iter(devs))
-                aligned = []
-                for b in batches:
-                    cols = list(b.columns)
-                    for i in slots:
-                        c = cols[i]
-                        cols[i] = c.like(jax.device_put(c.data, target),
-                                         jax.device_put(c.validity, target))
-                    aligned.append(ColumnarBatch(b.schema, cols, b.num_rows))
-                batches = aligned
-                cols = [None] * ncols
             datas, valids = kernels.concat_planes(
                 [tuple(b.columns[i].data for b in batches) for i in slots],
                 [tuple(b.columns[i].validity for b in batches) for i in slots],

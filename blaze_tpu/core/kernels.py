@@ -165,28 +165,6 @@ def fused_dispatch(fn, *args):
     return out, compiled
 
 
-def committed_device(arr):
-    """The single device ``arr`` is committed to, or None (uncommitted /
-    sharded / non-jax input). Multichip sessions hand batches around whose
-    planes live on different mesh devices (sharded fused outputs, per-task
-    device pinning); call sites that feed several batches into ONE dispatch
-    use this to detect and heal the mix before jax raises."""
-    try:
-        devs = arr.devices()
-    except Exception:
-        return None
-    return next(iter(devs)) if len(devs) == 1 else None
-
-
-def align_planes(datas: Sequence[jax.Array], valids: Sequence[jax.Array],
-                 device):
-    """Move a batch's (data, validity) planes to ``device``. device_put of
-    an already-resident array is a no-op, so calling this on aligned
-    batches costs nothing beyond the committed-device checks."""
-    return (tuple(jax.device_put(d, device) for d in datas),
-            tuple(jax.device_put(v, device) for v in valids))
-
-
 @jax.jit
 def _gather(datas, valids, idx, live):
     with jax.named_scope("move"):

@@ -126,7 +126,7 @@ class FusedStageExec(Operator):
         stream = self.execute_child(0, partition, ctx, metrics)
         for part in self.pipeline:
             if isinstance(part, _FusedSegment):
-                stream = self._fused_stream(stream, part, metrics, ctx)
+                stream = self._fused_stream(stream, part, metrics)
             else:
                 stream = self._coalesce_stream(stream, part[1], ctx)
         yield from stream
@@ -154,19 +154,7 @@ class FusedStageExec(Operator):
 
     # -- jitted segment --------------------------------------------------------
 
-    def _fused_stream(self, stream, seg: _FusedSegment, metrics, ctx=None):
-        """Dispatch path selection: with a sharded-fused runner registered
-        (multichip on, driver-run, mesh built) same-shape batches stack
-        across the device mesh; otherwise each batch dispatches alone."""
-        runner = None
-        if ctx is not None and getattr(ctx.conf, "multichip_enabled", False):
-            runner = ctx.resources.get("__sharded_fused__")
-        if runner is None or getattr(runner, "n", 1) <= 1:
-            yield from self._fused_stream_single(stream, seg, metrics)
-        else:
-            yield from self._fused_stream_sharded(stream, seg, metrics, runner)
-
-    def _fused_stream_single(self, stream, seg: _FusedSegment, metrics):
+    def _fused_stream(self, stream, seg: _FusedSegment, metrics):
         for batch in stream:
             yield from self._single_batch(seg, batch, metrics)
 
@@ -207,71 +195,6 @@ class FusedStageExec(Operator):
                 DeviceColumn(f.dtype, d, v) for f, d, v in
                 zip(seg.out_schema.fields, datas, valids)]
             yield ColumnarBatch(seg.out_schema, out_cols, count)
-
-    def _fused_stream_sharded(self, stream, seg: _FusedSegment, metrics,
-                              runner):
-        """Multichip path: stack up to ``runner.n`` consecutive same-shape
-        fusable batches and run the segment closure once under shard_map —
-        one device per batch, so a full stack costs one dispatch for n
-        batches. Per-batch results are EXACTLY what the single-device
-        closure returns for that batch (the body squeezes the stack axis
-        and calls the same jitted closure), so output bits do not depend on
-        the mesh size. Non-fusable batches, shape changes, and short tails
-        flush the stack; a sharded-dispatch failure raises like any other."""
-        buf = []            # [(batch, datas, valids)] awaiting dispatch
-        key = None          # (closure id, capacity, dtypes) of the stack
-        fn_cell = [None]
-        sharded_seen = [False]
-
-        def flush():
-            if not buf:
-                return
-            staged, buf[:] = list(buf), []
-            if len(staged) == 1:
-                yield from self._single_batch(seg, staged[0][0], metrics)
-                return
-            fn = fn_cell[0]
-            try:
-                outs, compiled = runner.dispatch(
-                    fn,
-                    [d for _, d, _ in staged],
-                    [v for _, _, v in staged],
-                    [b.num_rows for b, _, _ in staged])
-            except Exception as err:
-                raise FusedStageError(seg.fingerprint, err) from err
-            if not sharded_seen[0]:
-                metrics.add("sharded_stages", 1)
-                sharded_seen[0] = True
-            metrics.add("sharded_batches", len(staged))
-            metrics.add("jit_cache_misses" if compiled else "jit_cache_hits",
-                        1)
-            for (b, _, _), (groups, counts) in zip(staged, outs):
-                yield from self._emit_groups(seg, b.num_rows, groups, counts)
-
-        for batch in stream:
-            cols = batch.columns
-            fusable = (
-                cols and all(isinstance(c, DeviceColumn) for c in cols)
-                and len({c.capacity for c in cols}) == 1)
-            if not fusable:
-                yield from flush()
-                key = None
-                metrics.add("fused_fallback_batches", 1)
-                yield from self._eager_steps(seg, batch)
-                continue
-            fn = seg.closure()
-            k = (id(fn), cols[0].capacity,
-                 tuple(c.data.dtype.name for c in cols),
-                 tuple(c.validity.dtype.name for c in cols))
-            if key is not None and k != key:
-                yield from flush()
-            key = k
-            fn_cell[0] = fn
-            buf.append((batch, tuple(c.data for c in cols),
-                        tuple(c.validity for c in cols)))
-            if len(buf) >= runner.n:
-                yield from flush()
-        yield from flush()
 
     # -- eager fallback (unfused semantics, per batch) -------------------------
 

@@ -142,24 +142,41 @@ class Repartitioner:
 
     def bucketize(self, batch: ColumnarBatch):
         """Split a batch into per-partition device sub-batches, the device
-        shuffle tier's staging form: ONE device program
-        (:func:`exchange_route`: ids, stable order, one matrix gather,
-        offsets) and ONE small wait for the ``n + 1`` offsets
-        (``sync:exchange_route``). A partition's rows are then a window of
-        the one moved batch (``RowWindow``: nothing more is dispatched, the
-        reduce side's concat copies the windows); a batch with host columns
-        is cut into slices instead. The rows of every partition keep the
-        batch's order, as ``bucketize_host``'s do (reference: radix sort by
-        pid in buffered_data.rs). Returns ``[(pid, RowWindow |
-        ColumnarBatch)]`` for the partitions that got rows."""
-        import time
-
+        shuffle tier's staging form: the batch routed by :meth:`route`, a
+        partition's rows then a window of the one moved batch
+        (``RowWindow``: nothing more is dispatched, the reduce side's concat
+        copies the windows); a batch with host columns is cut into slices
+        instead. The rows of every partition keep the batch's order, as
+        ``bucketize_host``'s do (reference: radix sort by pid in
+        buffered_data.rs). Returns ``[(pid, RowWindow | ColumnarBatch)]``
+        for the partitions that got rows."""
         n = batch.num_rows
         if n == 0:
             return []
         self.split_batches += 1
         if self.num_partitions == 1:
             return [(0, batch)]
+        moved, offsets = self.route(batch)
+        windows = all(has_planes(c) for c in moved.columns)
+        return [(pid, moved if e - s == n
+                 else RowWindow(moved, s, e - s) if windows
+                 else moved.slice(s, e - s))
+                for pid, (s, e) in enumerate(zip(offsets, offsets[1:]))
+                if e > s]
+
+    def route(self, batch: ColumnarBatch):
+        """The batch ordered by partition where its planes are, and the
+        ``n + 1`` partition offsets: ONE device program
+        (:func:`exchange_route`: ids, stable order, one matrix gather,
+        offsets) and ONE small wait for the offsets
+        (``sync:exchange_route``); host columns follow the order the device
+        found. Rows ``offsets[p]:offsets[p + 1]`` of the moved batch are
+        partition ``p``'s, in the batch's row order."""
+        import time
+
+        n = batch.num_rows
+        if self.num_partitions == 1:
+            return batch, [0, n]
         t0 = time.perf_counter_ns()
         how, keys = self._route(batch)
         slots = batch._device_slots()
@@ -180,15 +197,8 @@ class Repartitioner:
             for i, c in enumerate(cols):
                 if not has_planes(c):
                     cols[i] = c.take_host(host_order)
-        moved = ColumnarBatch(batch.schema, cols, n)
-        windows = len(slots) == len(cols)
-        out = [(pid, moved if e - s == n
-                else RowWindow(moved, s, e - s) if windows
-                else moved.slice(s, e - s))
-               for pid, (s, e) in enumerate(zip(offsets, offsets[1:]))
-               if e > s]
         self.split_time_ns += time.perf_counter_ns() - t0
-        return out
+        return ColumnarBatch(batch.schema, cols, n), offsets
 
     def bucketize_host(self, batch: ColumnarBatch) -> List[Tuple[int, HostBatch]]:
         """Shuffle-write fast path: ONE device pull, then numpy-speed routing.

@@ -8,23 +8,25 @@ aggregation merges with ``psum`` — XLA inserts the collectives
 (scaling-book recipe: pick a mesh, annotate shardings, let XLA place
 collectives on ICI).
 
-Two layers:
+Three layers:
 
 - :func:`exchange_and_aggregate` — a single jittable SPMD step: local
   partial aggregation, all-to-all row exchange routed by spark-exact
   murmur3 pmod (so a row lands on the same reducer a file-based shuffle
-  would pick), local final aggregation. This is the building block the
-  mesh session composes and what ``__graft_entry__.dryrun_multichip``
-  compiles.
+  would pick), local final aggregation: what
+  ``__graft_entry__.dryrun_multichip`` compiles.
+- :class:`MeshBatchExchange` and :func:`task_chip` — the engine's exchange
+  of a multichip Session: every task runs on the chip of its partition,
+  map outputs arrive routed on their chips, and one all_to_all of
+  compacted per-reducer segments moves them to the reducers' chips.
 - :func:`make_mesh` — mesh construction over the available devices.
 
-Fixed shapes: each device ships one (num_devices, capacity) tile pair per
-exchanged column — rows not routed to a peer are masked, not compacted, so
-the collective is static-shaped (SURVEY.md §7.4.1)."""
+Fixed shapes: the collective is static-shaped (SURVEY.md §7.4.1)."""
 
 from __future__ import annotations
 
 import functools
+import time
 from typing import List, Optional, Tuple
 
 import jax
@@ -35,6 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
 from blaze_tpu.exprs.spark_hash import murmur3_int64
+from blaze_tpu.obs.tracer import TRACER
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "data") -> Mesh:
@@ -207,6 +210,77 @@ def run_broadcast_join(probe_keys: np.ndarray, build_keys: np.ndarray,
 # ---------------------------------------------------------------------------
 
 
+def task_chip(p: int, num_tasks: int, n: int) -> int:
+    """The mesh slot that runs task ``p`` of a stage of ``num_tasks`` tasks:
+    contiguous blocks of ``ceil(num_tasks / n)`` tasks a chip, ascending.
+    It is the exchange's own grouping of reducers (``G = ceil(R / n)`` a
+    chip), so a stage that reads an exchange runs every task on the chip
+    that holds its reducer's rows, and a map stage's tasks fold onto the
+    slots in map order (with the exchange's shard-major assembly, a
+    reducer's rows then come in the file path's map-order concat at every
+    mesh size)."""
+    return p // -(-num_tasks // n)
+
+
+@functools.partial(jax.jit, static_argnames=("scap",))
+def _segments(pieces, starts, counts, round_, scap):
+    """One shard's send buffer for one round, on the shard's chip: ``Rpad``
+    segments of ``scap`` rows, segment ``r`` holding the shard's rows of
+    reducer ``r`` from rank ``round_ * scap`` on. ``pieces`` are the
+    shard's routed map outputs, ``(datas, valids)`` each, their rows in
+    reducer order; ``starts[p, r]`` is the row (in the pieces' planes laid
+    end to end) where piece ``p``'s rows of reducer ``r`` begin and
+    ``counts[p, r]`` how many there are. A reducer's rows run piece after
+    piece, each in its row order; slots past them are padding. One matrix
+    gather moves every plane (``core/kernels.take_rows_traced``)."""
+    from blaze_tpu.core.kernels import take_rows_traced
+
+    ncols = len(pieces[0][0])
+    datas = tuple(jnp.concatenate([p[0][i] for p in pieces])
+                  for i in range(ncols))
+    valids = tuple(jnp.concatenate([p[1][i] for p in pieces])
+                   for i in range(ncols))
+    rpad = counts.shape[1]
+    with jax.named_scope("segments"):
+        upto = jnp.cumsum(counts, axis=0)              # (P, Rpad)
+        slot = jnp.arange(rpad * scap, dtype=jnp.int32)
+        r = slot // scap
+        q = round_ * scap + slot % scap                # rank in reducer r
+        live = q < upto[-1][r]
+        piece = jnp.sum(q[None, :] >= upto[:, r], axis=0)
+        piece = jnp.minimum(piece, counts.shape[0] - 1)
+        before = upto[piece, r] - counts[piece, r]
+        src = jnp.where(live, starts[piece, r] + q - before, 0)
+        return take_rows_traced(datas, valids, src, live)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "chunk", "scap", "out_cap"))
+def _reducer_rows(rounds, counts, group, n, chunk, scap, out_cap):
+    """One reducer's rows out of what its chip received, on that chip, as
+    planes of ``out_cap`` under the padding contract. ``rounds`` are the
+    received ``(datas, valids)`` of every round (``n`` peer chunks of
+    ``chunk`` rows each, the reducer's segment ``group`` of each), and
+    ``counts[s]`` the reducer's rows from shard ``s`` over all rounds: the
+    rows come shard-major, each shard's in rank order, whatever the round
+    split (the order does not depend on the mesh size)."""
+    from blaze_tpu.core.kernels import take_rows_traced
+
+    ncols = len(rounds[0][0])
+    datas = tuple(jnp.concatenate([t[0][i] for t in rounds])
+                  for i in range(ncols))
+    valids = tuple(jnp.concatenate([t[1][i] for t in rounds])
+                   for i in range(ncols))
+    with jax.named_scope("extract"):
+        upto = jnp.cumsum(counts)
+        k = jnp.arange(out_cap, dtype=jnp.int32)
+        live = k < upto[-1]
+        shard = jnp.minimum(jnp.searchsorted(upto, k, side="right"), n - 1)
+        q = k - (upto[shard] - counts[shard])
+        idx = (q // scap) * (n * chunk) + shard * chunk + group * scap \
+            + q % scap
+        return take_rows_traced(datas, valids, jnp.where(live, idx, 0), live)
+
+
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "nplanes",
                                              "chunk"))
 def _exchange_compact_step(mesh, axis, nplanes, chunk, *planes):
@@ -244,13 +318,17 @@ class MeshBatchExchange:
     (``shuffle/buffered_data.rs:48-541`` + ``ipc_reader_exec.rs:132-325``,
     SURVEY.md §5.8 "TPU-native equivalent").
 
-    Columns of any engine type move: device columns (ints, floats, dates,
-    timestamps, decimal<=18 as unscaled int64, agg partial states) ship as
-    raw planes + validity; host columns (strings, wide decimals) ship as
-    dictionary codes against a driver-built global dictionary and are
-    rematerialized on the reducer. Partition ids come from the SAME
-    Repartitioner as the file path (spark-exact murmur3 pmod), so a row
-    lands on the same reducer either way."""
+    Every map output arrives ROUTED on the chip that made it
+    (``Repartitioner.route``: the rows in reducer order and the ``R + 1``
+    offsets, the same spark-exact ids as the file path, so a row lands on
+    the same reducer either way). Device columns (ints, floats, dates,
+    timestamps, decimal<=18 as unscaled int64, agg partial states) never
+    leave the chips: each shard's send buffer is cut from its routed
+    batches on its chip, one all_to_all moves every plane, and each
+    reducer's rows are gathered on the chip that received them. The host
+    reads the offsets and nothing else. Host columns (strings, wide
+    decimals) ride as int32 codes against one global dictionary built on the host
+    and are rematerialized on the host."""
 
     def __init__(self, mesh: Mesh, axis: Optional[str] = None):
         assert len(mesh.axis_names) == 1, (
@@ -258,123 +336,57 @@ class MeshBatchExchange:
         self.mesh = mesh
         self.axis = axis or mesh.axis_names[0]
         self.n = mesh.shape[self.axis]
+        self.devices = list(mesh.devices.flat)
 
-    def run(self, schema, shard_batches: List[Optional["object"]],
-            shard_pids: List[Optional[np.ndarray]],
-            num_reducers: int,
+    def run(self, schema, shards: List[List[tuple]], num_reducers: int,
             device_resident_budget: Optional[int] = None
             ) -> List[Optional["object"]]:
-        """shard_batches[s]: ColumnarBatch (or None) held by mesh slot s;
-        shard_pids[s]: per-row reducer ids. Returns one ColumnarBatch (or
-        None when empty) per reducer — device columns stay DEVICE-RESIDENT
-        end to end: producer device planes are permuted into compacted
-        per-reducer segments on device, exchanged over the collective, and
-        the reducer output is sliced out on device, so the next stage's
-        device aggregation consumes them without a host round trip. Host
-        columns (strings, wide decimals) ride as int32 dictionary codes
-        against a driver-built global dictionary, exactly as before.
+        """``shards[s]``: the routed map outputs that mesh slot ``s`` holds,
+        in map order, each ``(batch, offsets)`` with the batch's planes on
+        slot ``s``'s chip and ``offsets`` its ``num_reducers + 1`` reducer
+        offsets. Returns one batch (or None when empty) per reducer: a
+        ColumnarBatch whose device columns live on the reducer's chip
+        (``task_chip(r, num_reducers, n)``), or a HostBatch where the payload is larger than
+        ``device_resident_budget`` (default ``mesh_device_resident_max_bytes``).
 
         ``num_reducers`` may exceed the mesh size: reducers are grouped
         G = ceil(R/n) per device and each all_to_all chunk carries one
-        device's reducer group.
-
-        The per-reducer segment capacity comes from the exchanged row
-        counts (here a host bincount — the driver already holds the pids),
-        so the wire carries ~max-routed-rows per segment instead of the
-        full producer capacity; ``last_wire_bytes`` /
-        ``last_wire_bytes_uncompacted`` record the realized vs naive
-        payload for observability."""
+        device's reducer group. The segment capacity comes from the
+        offsets, so the wire carries ~max-routed-rows per segment instead of
+        the full producer capacity; one skewed reducer is bounded by
+        ``mesh_exchange_round_bytes`` and takes more rounds of the same
+        compiled step. ``last_wire_bytes`` / ``last_wire_bytes_uncompacted``
+        record the realized vs naive payload for observability."""
         from blaze_tpu.config import get_config
-        from blaze_tpu.core.batch import (ColumnarBatch, DeviceColumn,
-                                          HostColumn, arrow_fixed_planes)
-        from blaze_tpu.ir import types as T
+        from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn, \
+            HostBatch, HostColumn
         from blaze_tpu.utils.device import is_device_dtype
 
-        import pyarrow as pa
-
-        n = self.n
+        n, devs = self.n, self.devices
         R = num_reducers
         G = -(-R // n)          # reducer groups per device
         Rpad = G * n
-        assert len(shard_batches) == n
+        assert len(shards) == n
         ncols = len(schema)
         conf = get_config()
-        host_slots = [i for i, f in enumerate(schema.fields)
-                      if not is_device_dtype(f.dtype)]
+        host_slots = {i for i, f in enumerate(schema.fields)
+                      if not is_device_dtype(f.dtype)}
 
-        # --- "exchange counts first": per-shard per-reducer row counts.
-        # The driver orchestrates every shard in this embedding, so the
-        # count exchange is a host bincount; on a multi-host runtime this
-        # becomes one tiny all_gather of the (R,) count vectors.
+        # --- counts first: rows per (shard, piece, reducer), from the
+        # offsets the map tasks already read
         counts = np.zeros((n, Rpad), np.int64)
-        for s, p in enumerate(shard_pids):
-            if p is not None and len(p):
-                counts[s] += np.bincount(p, minlength=Rpad)
-        maxc = int(counts.max())
+        piece_counts = []
+        for s, pieces in enumerate(shards):
+            pc = np.zeros((max(1, len(pieces)), Rpad), np.int64)
+            for p, (_b, offsets) in enumerate(pieces):
+                pc[p, :R] = np.diff(np.asarray(offsets, np.int64))
+            piece_counts.append(pc)
+            counts[s] = pc.sum(axis=0)
+        maxc = int(counts.max()) if counts.size else 0
 
-        # --- dictionary-encode host columns (global dict, as before)
-        dictionaries: dict = {}
-        host_codes = {i: [None] * n for i in host_slots}
-        for i in host_slots:
-            arrays, present = [], []
-            for s, b in enumerate(shard_batches):
-                if b is None or b.num_rows == 0:
-                    continue
-                c = b.columns[i]
-                arr = c.array if isinstance(c, HostColumn) \
-                    else c.to_arrow(b.num_rows)
-                if isinstance(arr, pa.ChunkedArray):
-                    arr = arr.combine_chunks()
-                arrays.append(arr)
-                present.append(s)
-            if not arrays:
-                dictionaries[i] = pa.array(
-                    [], type=T.to_arrow_type(schema[i].dtype))
-                continue
-            if len({a.type for a in arrays}) > 1:
-                from blaze_tpu.core.batch import decode_dictionary
-
-                arrays = [decode_dictionary(a, schema[i].dtype)
-                          for a in arrays]
-            combined = pa.concat_arrays(arrays)
-            denc = combined.dictionary_encode()
-            from blaze_tpu.core.batch import decode_dictionary
-
-            # large_*-normalize the dictionary VALUES so reducer-side
-            # `.take` emits the engine's convention type (plain `string`
-            # would break downstream concat and caps offsets at 2GB)
-            dictionaries[i] = decode_dictionary(denc.dictionary,
-                                                schema[i].dtype)
-            codes = denc.indices
-            off = 0
-            for s in present:
-                k = shard_batches[s].num_rows
-                sl = codes.slice(off, k)
-                valid = ~np.asarray(sl.is_null()) if sl.null_count \
-                    else np.ones(k, bool)
-                host_codes[i][s] = (
-                    sl.fill_null(0).to_numpy(zero_copy_only=False)
-                    .astype(np.int32), valid)
-                off += k
-
-        # --- column plane dtypes
-        col_dtypes: List[np.dtype] = []
-        for i in range(ncols):
-            if i in host_slots:
-                col_dtypes.append(np.dtype(np.int32))
-                continue
-            dt = None
-            for b in shard_batches:
-                if b is not None and b.num_rows:
-                    c = b.columns[i]
-                    dt = np.dtype(c.data.dtype) if isinstance(c, DeviceColumn) \
-                        else None
-                    if dt is None:
-                        d, _ = arrow_fixed_planes(c.array, schema[i].dtype)
-                        dt = d.dtype
-                    break
-            col_dtypes.append(dt or np.dtype(
-                schema[i].dtype.np_dtype or np.int64))
+        dictionaries, codes = self._encode_host_columns(schema, shards,
+                                                        host_slots)
+        col_dtypes = self._plane_dtypes(schema, shards, host_slots)
 
         # --- segment capacity, bounded per round. scap is the max
         # per-(shard, reducer) routed-row count at 512 granularity (tight
@@ -383,12 +395,11 @@ class MeshBatchExchange:
         # segment to the hot size, so the per-device send buffer is capped
         # at mesh_exchange_round_bytes and the exchange loops bounded
         # rounds over the same compiled step instead.
-        slot_bytes = 1 + sum(np.dtype(dt).itemsize + 1 for dt in col_dtypes)
+        slot_bytes = sum(np.dtype(dt).itemsize + 1 for dt in col_dtypes)
         budget = int(conf.mesh_exchange_round_bytes)
-        # granularity scales DOWN for huge reducer counts (session no
-        # longer caps num_reducers at mesh size): the 512-row floor alone
-        # would allocate Rpad*512 slots and silently blow past the
-        # configured budget for tens of thousands of reducers
+        # granularity scales DOWN for huge reducer counts: the 512-row
+        # floor alone would allocate Rpad*512 slots and silently blow past
+        # the configured budget for tens of thousands of reducers
         gran = 512
         while gran > 8 and Rpad * gran * slot_bytes > budget:
             gran //= 2
@@ -407,12 +418,11 @@ class MeshBatchExchange:
         seg_len = Rpad * scap  # == n * chunk
 
         # residency decision from the ACTUAL routed payload (padding-free):
-        # device-resident only while the payload fits the remaining HBM
-        # budget (the CALLER accounts across stacked exchanges —
-        # session.py's _mesh_pinned_bytes); larger exchanges land in host
-        # RAM like shuffle files so device memory cannot accumulate.
-        total_rows = int(counts.sum())
-        self.last_payload_bytes = total_rows * slot_bytes * 2
+        # device-resident only while the payload fits the budget the caller
+        # hands in (session.py charges each resident exchange to its query
+        # until the query is released); larger exchanges land in host RAM
+        # like shuffle files so device memory cannot accumulate.
+        self.last_payload_bytes = int(counts.sum()) * slot_bytes * 2
         resident_budget = conf.mesh_device_resident_max_bytes \
             if device_resident_budget is None else device_resident_budget
         device_resident = self.last_payload_bytes <= resident_budget
@@ -421,314 +431,196 @@ class MeshBatchExchange:
         from jax.sharding import NamedSharding
 
         sharding = NamedSharding(self.mesh, P(self.axis))
-        devs = list(self.mesh.devices.flat)
-
-        # per-shard routing and device-resident column planes, precomputed
-        # ONCE across rounds (only the round's permutation indices change
-        # with t — re-uploading the full columns every round would multiply
-        # host-to-device traffic by the round count)
-        shard_route = []
-        shard_cols: List[Optional[List]] = []
-        for s, b in enumerate(shard_batches):
-            if b is None or b.num_rows == 0:
-                shard_route.append(None)
-                shard_cols.append(None)
+        # each shard's pieces as planes on its chip, and where every piece's
+        # rows of each reducer begin in them laid end to end
+        shard_in = []
+        for s, pieces in enumerate(shards):
+            if not pieces:
+                shard_in.append(None)
                 continue
-            pids = shard_pids[s]
-            order = np.argsort(pids, kind="stable")
-            starts = np.zeros(Rpad, np.int64)
-            starts[1:] = np.cumsum(counts[s])[:-1]
-            psort = pids[order]
-            rank = np.arange(b.num_rows) - starts[psort]
-            shard_route.append((order, psort, rank))
-            scols = []
-            for i in range(ncols):
-                if i in host_slots:
-                    d, v = host_codes[i][s]
-                    scols.append((jnp.asarray(d), jnp.asarray(v)))
-                else:
-                    c = b.columns[i]
-                    if isinstance(c, DeviceColumn):
-                        scols.append((c.data, c.validity))
-                    else:
-                        d, v = arrow_fixed_planes(c.array, schema[i].dtype)
-                        if v is None:
-                            v = np.ones(len(d), bool)
-                        scols.append((jnp.asarray(d), jnp.asarray(v)))
-            shard_cols.append(scols)
+            planes, starts, base = [], np.zeros((len(pieces), Rpad), np.int64), 0
+            for p, (b, offsets) in enumerate(pieces):
+                planes.append(self._piece_planes(
+                    b, schema, host_slots, codes[s][p], devs[s]))
+                starts[p, :R] = base + np.asarray(offsets[:-1], np.int64)
+                base += planes[-1][0][0].shape[0]
+            shard_in.append((tuple(planes), starts.astype(np.int32),
+                             piece_counts[s].astype(np.int32)))
 
-        red_cnt = counts.sum(axis=0)
-        pieces: List[List] = [[] for _ in range(Rpad)]  # per reducer, per round
+        received = []  # per round: per plane, the n per-device shards
         self.last_wire_bytes = 0
         for t in range(rounds):
-            shard_planes: List[List] = [[] for _ in range(1 + 2 * ncols)]
-            for s, b in enumerate(shard_batches):
-                route = shard_route[s]
-                if route is None:
-                    shard_planes[0].append(jnp.zeros(seg_len, bool))
-                    for i in range(ncols):
-                        shard_planes[1 + 2 * i].append(
-                            jnp.zeros(seg_len, col_dtypes[i]))
-                        shard_planes[2 + 2 * i].append(
-                            jnp.zeros(seg_len, bool))
-                    continue
-                order, psort, rank = route
-                sel = (rank >= t * scap) & (rank < (t + 1) * scap)
-                dest = psort[sel] * scap + (rank[sel] - t * scap)
-                src = np.full(seg_len, -1, np.int64)
-                src[dest] = order[sel]
-                live_h = src >= 0
-                sidx = jnp.asarray(np.where(live_h, src, 0).astype(np.int32))
-                lv = jnp.asarray(live_h)
-                shard_planes[0].append(lv)
-                for i in range(ncols):
-                    dd, vv = shard_cols[s][i]
-                    shard_planes[1 + 2 * i].append(
-                        jnp.where(lv, jnp.take(dd, sidx, mode="clip"),
-                                  jnp.zeros((), dd.dtype)))
-                    shard_planes[2 + 2 * i].append(
-                        jnp.take(vv, sidx, mode="clip") & lv)
-
-            # global sharded planes: each shard's segment placed directly
-            # on ITS mesh device — no single-device concatenate funnel
-            gplanes = []
-            for ps in shard_planes:
-                shards = [jax.device_put(p, devs[s])
-                          for s, p in enumerate(ps)]
-                gplanes.append(jax.make_array_from_single_device_arrays(
-                    (n * seg_len,), sharding, shards))
-            import time as _time
-
-            from blaze_tpu.obs.tracer import TRACER
-
-            t0_ns = _time.perf_counter_ns() if TRACER.active else 0
+            segments = []
+            for s in range(n):
+                with jax.default_device(devs[s]):
+                    if shard_in[s] is None:
+                        segments.append(
+                            (tuple(jnp.zeros(seg_len, dt) for dt in col_dtypes),
+                             tuple(jnp.zeros(seg_len, bool) for _ in col_dtypes)))
+                    else:
+                        pieces, starts, pcounts = shard_in[s]
+                        segments.append(_segments(pieces, starts, pcounts,
+                                                  np.int32(t), scap=scap))
+            gplanes = [jax.make_array_from_single_device_arrays(
+                (n * seg_len,), sharding,
+                [segments[s][kind][i] for s in range(n)])
+                for kind in (0, 1) for i in range(ncols)]
+            t0_ns = time.perf_counter_ns() if TRACER.active else 0
             with self.mesh:
                 outs = _exchange_compact_step(self.mesh, self.axis,
                                               len(gplanes), chunk, *gplanes)
             if t0_ns:
                 TRACER.complete("mesh_exchange", "collective", t0_ns,
-                                _time.perf_counter_ns() - t0_ns,
+                                time.perf_counter_ns() - t0_ns,
                                 {"planes": len(gplanes), "devices": n})
             self.last_wire_bytes += sum(
                 n * seg_len * np.dtype(p.dtype).itemsize for p in gplanes)
-
-            # per-reducer extraction for THIS round: gather only live rows
-            # (device arrays sized by actual data, so cross-round storage
-            # is bounded by the payload, not the padding). Split the
-            # collective's outputs into their per-device shards FIRST:
-            # reducer r's slots live wholly inside device r//G's shard, so
-            # every gather below is a plain single-device program. Indexing
-            # the global sharded array instead compiles each take into a
-            # fresh n-participant collective, and at scale those interleave
-            # with the next round's all_to_all and wedge the XLA CPU
-            # rendezvous (observed: q67 at 2M rows on the 8-device mesh).
-            shard_view: List[List] = []
+            # the outputs split into their per-device shards: reducer r's
+            # slots live wholly inside device r//G's shard, so every gather
+            # below is a plain single-device program (indexing the global
+            # array instead compiles each take into an n-participant
+            # collective)
+            by_dev = []
             for p in outs:
-                by_dev = {next(iter(s.data.devices())): s.data
-                          for s in p.addressable_shards}
-                shard_view.append([by_dev[dv] for dv in devs])
-            live_np = [np.asarray(sv) for sv in shard_view[0]]
-            for r in range(Rpad):
-                if red_cnt[r] == 0:
-                    continue
-                d, g = divmod(r, G)
-                base = np.add.outer(np.arange(n) * chunk + g * scap,
-                                    np.arange(scap)).ravel()
-                rows = np.nonzero(live_np[d][base])[0]
-                if not len(rows):
-                    continue
-                fidx_dev = jnp.asarray(base[rows])
-                cols_rt = []
-                for i in range(ncols):
-                    pd_ = jnp.take(shard_view[1 + 2 * i][d], fidx_dev)
-                    pv = jnp.take(shard_view[2 + 2 * i][d], fidx_dev)
-                    if device_resident and i not in host_slots:
-                        # downstream single-stream operators expect all
-                        # operands on the primary device
-                        cols_rt.append((jax.device_put(pd_, devs[0]),
-                                        jax.device_put(pv, devs[0])))
-                    else:
-                        cols_rt.append((np.asarray(pd_), np.asarray(pv)))
-                # this round's live rows per source shard (the extraction
-                # gather above is shard-major, ranks contiguous per shard)
-                c_live = np.minimum(np.maximum(
-                    counts[:, r] - t * scap, 0), scap)
-                pieces[r].append((cols_rt, c_live))
+                held = {next(iter(a.data.devices())): a.data
+                        for a in p.addressable_shards}
+                by_dev.append([held[d] for d in devs])
+            received.append(by_dev)
 
         # wire observability: naive masked-tile equivalent for comparison
-        cap = conf.capacity_for(
-            max([b.num_rows for b in shard_batches if b is not None] or [1]))
+        cap = conf.capacity_for(max([b.num_rows for pieces in shards
+                                     for b, _o in pieces] or [1]))
         self.last_wire_bytes_uncompacted = sum(
             n * n * cap * np.dtype(dt).itemsize
-            for dt in [np.dtype(bool)]  # live plane
-            + [col_dtypes[i] for i in range(ncols)]
-            + [np.dtype(bool)] * ncols)
+            for dt in [np.dtype(bool)]  # the masked tiles' live plane
+            + list(col_dtypes) + [np.dtype(bool)] * ncols)
 
-        # --- final per-reducer assembly across rounds
-        from blaze_tpu.core.batch import HostBatch
-
-        results: List[Optional[ColumnarBatch]] = []
+        # --- each reducer's rows, gathered on the chip that received them
+        results: List[Optional[object]] = []
         for r in range(R):
-            ps = pieces[r]
-            cnt = sum(int(cl.sum()) for _, cl in ps) if ps else 0
-            if cnt == 0:
+            total = int(counts[:, r].sum())
+            if total == 0:
                 results.append(None)
                 continue
-            # canonical row order: each reducer's rows sorted shard-major
-            # (source shard, then original row order), INDEPENDENT of the
-            # round split. A skew-driven extra round appends rows
-            # round-major; left unpermuted that row order — and with it
-            # float accumulation order and sort-tie order downstream —
-            # would depend on scap, i.e. on the mesh size, breaking the
-            # bit-identical-across-meshes contract.
-            perm = None
-            if len(ps) > 1:
-                key = np.concatenate(
-                    [np.repeat(np.arange(n), cl) for _, cl in ps])
-                p_ = np.argsort(key, kind="stable")
-                if not np.array_equal(p_, np.arange(len(p_))):
-                    perm = p_
-            out_cap = conf.capacity_for(cnt)
+            d, g = divmod(r, G)
+            rounds_d = tuple(
+                (tuple(rv[i][d] for i in range(ncols)),
+                 tuple(rv[ncols + i][d] for i in range(ncols)))
+                for rv in received)
+            with jax.default_device(devs[d]):
+                datas, valids = _reducer_rows(
+                    rounds_d, counts[:, r].astype(np.int32), np.int32(g),
+                    n=n, chunk=chunk, scap=scap,
+                    out_cap=conf.capacity_for(total))
             cols = []
-            hitems = []
             for i, f in enumerate(schema.fields):
-                dparts = [cr[i][0] for cr, _ in ps]
-                vparts = [cr[i][1] for cr, _ in ps]
                 if i in host_slots:
-                    cd = np.concatenate(dparts)
-                    cv = np.concatenate(vparts)
-                    if perm is not None:
-                        cd, cv = cd[perm], cv[perm]
-                    codes = pa.array(cd, type=pa.int32()) if cv.all() else \
-                        pa.array(np.where(cv, cd, 0), type=pa.int32(),
-                                 mask=~cv)
-                    taken = dictionaries[i].take(codes)
-                    if device_resident:
-                        cols.append(HostColumn(f.dtype, taken))
-                    else:
-                        hitems.append(taken)
-                elif device_resident:
-                    pad = out_cap - cnt
-                    ddata = jnp.concatenate(dparts) if len(dparts) > 1 \
-                        else dparts[0]
-                    dvalid = jnp.concatenate(vparts) if len(vparts) > 1 \
-                        else vparts[0]
-                    if perm is not None:
-                        jperm = jnp.asarray(perm)
-                        ddata = jnp.take(ddata, jperm)
-                        dvalid = jnp.take(dvalid, jperm)
-                    if pad:
-                        ddata = jnp.concatenate(
-                            [ddata, jnp.zeros(pad, ddata.dtype)])
-                        dvalid = jnp.concatenate([dvalid,
-                                                  jnp.zeros(pad, bool)])
-                    cols.append(DeviceColumn(f.dtype, ddata, dvalid))
+                    cols.append(HostColumn(f.dtype, self._decode(
+                        dictionaries[i], datas[i], valids[i], total)))
                 else:
-                    cd = np.concatenate(dparts)
-                    cv = np.concatenate(vparts)
-                    if perm is not None:
-                        cd, cv = cd[perm], cv[perm]
-                    hitems.append((cd, cv))
-            results.append(ColumnarBatch(schema, cols, cnt)
-                           if device_resident
-                           else HostBatch(schema, hitems, cnt))
+                    cols.append(DeviceColumn(f.dtype, datas[i], valids[i]))
+            batch = ColumnarBatch(schema, cols, total)
+            results.append(batch if device_resident
+                           else HostBatch.from_batch(batch))
         return results
 
+    @staticmethod
+    def _encode_host_columns(schema, shards, host_slots):
+        """One dictionary a host column over every piece of every shard, and
+        each piece's int32 codes and validity in its (routed) row order."""
+        import pyarrow as pa
 
-class ShardedFusedRunner:
-    """Run a fused-stage closure (ops/fused.py) data-parallel across the
-    mesh: k <= n consecutive same-shape batches stack into one
-    ``(n, capacity)`` NamedSharding global per column plane — one batch per
-    device — and the ORIGINAL per-batch jitted closure runs inside a
-    ``shard_map`` body that squeezes its device's leading axis. Per batch
-    the math is byte-for-byte the single-device dispatch (no row resharding,
-    no cross-shard compaction), so results are bit-identical across 1/2/8
-    device meshes by construction; the win is the n bodies executing
-    concurrently on n chips instead of queueing on one stream.
+        from blaze_tpu.core.batch import HostColumn, decode_dictionary
+        from blaze_tpu.ir import types as T
 
-    Short flushes pad by repeating the last batch (padded outputs are
-    dropped), so the compiled step is reused at one shape per
-    (closure, capacity, dtypes) key. Outputs are consolidated onto the
-    first mesh device: downstream single-stream operators (concat, agg
-    state) must not see operands committed to different devices."""
+        dictionaries = {}
+        codes = [[{} for _ in pieces] for pieces in shards]
+        for i in sorted(host_slots):
+            arrays, where = [], []
+            for s, pieces in enumerate(shards):
+                for p, (b, _o) in enumerate(pieces):
+                    c = b.columns[i]
+                    arr = c.array if isinstance(c, HostColumn) \
+                        else c.to_arrow(b.num_rows)
+                    if isinstance(arr, pa.ChunkedArray):
+                        arr = arr.combine_chunks()
+                    arrays.append(arr)
+                    where.append((s, p))
+            if not arrays:
+                dictionaries[i] = pa.array(
+                    [], type=T.to_arrow_type(schema[i].dtype))
+                continue
+            if len({a.type for a in arrays}) > 1:
+                arrays = [decode_dictionary(a, schema[i].dtype)
+                          for a in arrays]
+            denc = pa.concat_arrays(arrays).dictionary_encode()
+            # large_*-normalize the dictionary VALUES so the reducer's
+            # `.take` emits the engine's convention type (plain `string`
+            # would break downstream concat and caps offsets at 2GB)
+            dictionaries[i] = decode_dictionary(denc.dictionary,
+                                                schema[i].dtype)
+            off = 0
+            for (s, p), arr in zip(where, arrays):
+                sl = denc.indices.slice(off, len(arr))
+                valid = ~np.asarray(sl.is_null()) if sl.null_count \
+                    else np.ones(len(arr), bool)
+                codes[s][p][i] = (sl.fill_null(0).to_numpy(
+                    zero_copy_only=False).astype(np.int32), valid)
+                off += len(arr)
+        return dictionaries, codes
 
-    def __init__(self, mesh: Mesh, axis: Optional[str] = None):
-        assert len(mesh.axis_names) == 1, (
-            f"ShardedFusedRunner needs a 1-D mesh, got {mesh.axis_names}")
-        self.mesh = mesh
-        self.axis = axis or mesh.axis_names[0]
-        self.n = mesh.shape[self.axis]
-        self.devices = list(mesh.devices.flat)
-        self._wrapped: dict = {}  # id(fn) -> (fn ref, shard_map'd closure)
-        self.dispatches = 0
+    @staticmethod
+    def _plane_dtypes(schema, shards, host_slots):
+        """The dtype of each column's plane on the wire (a host column's
+        codes are int32), read off the first piece."""
+        from blaze_tpu.core.batch import arrow_fixed_planes, has_planes
 
-    def _wrap(self, fn):
-        hit = self._wrapped.get(id(fn))
-        if hit is not None:
-            return hit[1]
+        first = next((b for pieces in shards for b, _o in pieces), None)
+        out = []
+        for i, f in enumerate(schema.fields):
+            c = None if first is None else first.columns[i]
+            out.append(np.dtype(np.int32) if i in host_slots
+                       else np.dtype(f.dtype.np_dtype or np.int64) if c is None
+                       else np.dtype(c.data.dtype) if has_planes(c)
+                       else arrow_fixed_planes(c.array, f.dtype)[0].dtype)
+        return out
 
-        axis = self.axis
+    @staticmethod
+    def _piece_planes(batch, schema, host_slots, codes, device):
+        """``(datas, valids)`` of one routed piece, every plane of the
+        piece's capacity on ``device`` (where its device columns already
+        are): host columns as their codes, a fixed-width column that is no
+        device plane as its Arrow buffers."""
+        from blaze_tpu.core.batch import arrow_fixed_planes, has_planes
 
-        def body(datas, valids, nrows):
-            out = fn(tuple(d[0] for d in datas),
-                     tuple(v[0] for v in valids), nrows[0])
-            # re-add the leading per-device axis so out_specs=P(axis)
-            # reassembles one global row per batch
-            return jax.tree_util.tree_map(lambda a: a[None], out)
+        cap = batch.capacity
+        datas, valids = [], []
+        for i, f in enumerate(schema.fields):
+            c = batch.columns[i]
+            if i not in host_slots and has_planes(c):
+                datas.append(c.data)
+                valids.append(c.validity)
+                continue
+            if i in host_slots:
+                d, v = codes[i]
+            else:
+                d, v = arrow_fixed_planes(c.array, f.dtype)
+                v = np.ones(len(d), bool) if v is None else v
+            pad = cap - len(d)
+            datas.append(jax.device_put(
+                np.concatenate([d, np.zeros(pad, d.dtype)]), device))
+            valids.append(jax.device_put(
+                np.concatenate([v, np.zeros(pad, bool)]), device))
+        return tuple(datas), tuple(valids)
 
-        wrapped = jax.jit(shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(axis), P(axis), P(axis)),
-            out_specs=P(axis)))
-        # hold fn so the id() key cannot be reused by a reclaimed closure
-        self._wrapped[id(fn)] = (fn, wrapped)
-        return wrapped
+    @staticmethod
+    def _decode(dictionary, data, validity, num_rows):
+        import pyarrow as pa
 
-    def dispatch(self, fn, batch_datas, batch_valids, batch_nrows):
-        """``batch_datas[i]``/``batch_valids[i]``: per-batch tuples of
-        (capacity,) column planes; ``batch_nrows[i]``: that batch's row
-        count. Returns ``(outs, compiled)`` where ``outs[i]`` is exactly
-        what ``fn(datas, valids, nrows)`` returns for batch i, with every
-        leaf committed to the first mesh device."""
-        from jax.sharding import NamedSharding
-
-        from blaze_tpu.core import kernels
-
-        k = len(batch_datas)
-        if k < self.n:  # pad with the tail batch; outputs dropped below
-            batch_datas = list(batch_datas) + [batch_datas[-1]] * (self.n - k)
-            batch_valids = list(batch_valids) + \
-                [batch_valids[-1]] * (self.n - k)
-            batch_nrows = list(batch_nrows) + \
-                [batch_nrows[-1]] * (self.n - k)
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        devs = self.devices
-
-        def gput(per_batch):
-            per_batch = [jnp.asarray(a) for a in per_batch]
-            shards = [jax.device_put(a[None], devs[j])
-                      for j, a in enumerate(per_batch)]
-            return jax.make_array_from_single_device_arrays(
-                (self.n,) + per_batch[0].shape, sharding, shards)
-
-        ncols = len(batch_datas[0])
-        gdatas = tuple(gput([bd[i] for bd in batch_datas])
-                       for i in range(ncols))
-        gvalids = tuple(gput([bv[i] for bv in batch_valids])
-                        for i in range(ncols))
-        gnrows = gput([jnp.asarray(nr, jnp.int64) for nr in batch_nrows])
-        out, compiled = kernels.fused_dispatch(
-            self._wrap(fn), gdatas, gvalids, gnrows)
-        self.dispatches += 1
-        # consolidate onto one device, then slice per batch: downstream
-        # operators mix these leaves with driver-created arrays and jax
-        # refuses ops across different committed devices
-        dev0 = devs[0]
-        out0 = jax.tree_util.tree_map(
-            lambda a: jax.device_put(a, dev0), out)
-        outs = [jax.tree_util.tree_map(lambda a, i=i: a[i], out0)
-                for i in range(k)]
-        return outs, compiled
+        cd, cv = np.asarray(data)[:num_rows], np.asarray(validity)[:num_rows]
+        codes = pa.array(cd, type=pa.int32()) if cv.all() else \
+            pa.array(np.where(cv, cd, 0), type=pa.int32(), mask=~cv)
+        return dictionary.take(codes)
 
 
 def run_distributed_sum(keys: np.ndarray, vals: np.ndarray,

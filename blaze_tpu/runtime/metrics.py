@@ -162,11 +162,21 @@ class MetricNode:
 #                                    that went the host way (0 on healthy
 #                                    runs; > 0 proves the degrade path ran
 #                                    instead of the query failing)
-#   sharded_stages                   stages executed data-parallel across
-#                                    the device mesh (mesh-collective
-#                                    exchanges + shard_map'd fused stages);
-#                                    0 with multichip off, > 0 proves the
+#   sharded_stages                   exchanges lowered onto the device
+#                                    mesh's all-to-all collective; 0 with
+#                                    multichip off, > 0 proves the
 #                                    multichip path actually engaged
+#   mesh_tasks_off_primary           tasks a multichip session ran on a
+#                                    chip other than the mesh's first (every
+#                                    task runs on the chip of its partition:
+#                                    parallel/mesh.task_chip); 0 where every
+#                                    task of a mesh is funnelled to chip 0
+#   mesh_host_resident_exchanges == 0  ... on a mesh whose exchanges fit
+#                                    mesh_device_resident_max_bytes: mesh
+#                                    exchanges whose reducer batches went to
+#                                    host RAM because the live queries'
+#                                    device-resident exchanges had taken the
+#                                    budget
 #   device_shuffle_bytes             device-resident column bytes (planes
 #                                    with their padding) handed between
 #                                    stages by reference — the "device"
@@ -254,6 +264,8 @@ TRIPWIRE_METRICS = (
     "serde_elided_batches",
     "shuffle_tier_degraded",
     "sharded_stages",
+    "mesh_tasks_off_primary",
+    "mesh_host_resident_exchanges",
     "device_shuffle_bytes",
     "collective_bytes",
     "smj_device_joins",

@@ -306,8 +306,8 @@ class Session:
         if mesh is None and self.conf.multichip_enabled:
             # multichip: build the exchange mesh from config over the local
             # devices (multichip_devices == 0 → all of them; make_mesh
-            # clamps). A 1-device mesh still exercises the sharded code
-            # paths, which keeps 1/2/8-device bit-identity testable.
+            # clamps). A 1-device mesh still exercises the mesh exchange,
+            # which keeps 1/2/8-device bit-identity testable.
             import jax as _jax
 
             from blaze_tpu.parallel.mesh import make_mesh
@@ -330,15 +330,10 @@ class Session:
 
         self._lineage = LineageRegistry()
         self.resources = {}
-        if self.mesh is not None and self.conf.multichip_enabled \
-                and self.pool is None:
-            # sharded fused execution: fused stages reach this through
-            # ExecContext.resources. Driver-only — the runner holds live
-            # device handles that cannot cross a process boundary (pool
-            # workers fall back to per-batch dispatch).
-            from blaze_tpu.parallel.mesh import ShardedFusedRunner
-
-            self.resources["__sharded_fused__"] = ShardedFusedRunner(self.mesh)
+        # device-resident bytes of the live mesh exchanges, by resource id:
+        # released with the query that registered the resource
+        self._mesh_pins: Dict[str, int] = {}
+        self._mesh_pin_mu = threading.Lock()
         self._ids = itertools.count()
         self._stage_ids = itertools.count()
         self.metrics = MetricNode("session")
@@ -578,8 +573,8 @@ class Session:
             scope = (STATS_HUB.scoped(qrun.stats.scope_key(StatsPlane.RESULT_STAGE))
                      if qrun.stats is not None else contextlib.nullcontext())
             try:
-                with placement.placed(where), scope, \
-                        ctx.mem.group_scope(qrun.mem_group):
+                with self._on_task_chip(p, nparts), placement.placed(where), \
+                        scope, ctx.mem.group_scope(qrun.mem_group):
                     yield from op.execute(p, ctx,
                                           self.metrics.named_child(f"result_{p}"))
             finally:
@@ -801,6 +796,10 @@ class Session:
         self.mem_segments.release_stages(qrun.stage_meta.keys())
         for rid in qrun.resource_ids:
             self.resources.pop(rid, None)
+        if self._mesh_pins:
+            with self._mesh_pin_mu:
+                for rid in qrun.resource_ids:
+                    self._mesh_pins.pop(rid, None)
 
     def discard_cursor(self, cursor: Optional[StageCursor]):
         """Release a paused query's pinned stage state without resuming it
@@ -857,6 +856,7 @@ class Session:
         self.ingest.clear()
         self.mem_segments.clear()
         self.resources.clear()
+        self._mesh_pins.clear()
         import glob
 
         for d in glob.glob(os.path.join(self.shuffle_root, "shuffle_*")):
@@ -1585,16 +1585,17 @@ class Session:
         return True if ok else None
 
     def _run_mesh_exchange(self, node: N.ShuffleExchange) -> N.PlanNode:
-        """Lower a ShuffleExchange onto the device mesh: run map partitions,
-        route rows with the SAME Repartitioner as the file path (spark-exact
-        pids), then move them with one ICI all-to-all instead of writing
-        data+index files (parallel/mesh.py). Result batches land in the
-        resource map behind a BatchSource."""
-        import numpy as np
-
-        from blaze_tpu.core.batch import ColumnarBatch
+        """Lower a ShuffleExchange onto the device mesh: every map task runs
+        on its chip (``_run_tasks``) and routes its output there with the
+        SAME Repartitioner as the file path (spark-exact ids, one device
+        program and one wait for the offsets: ``Repartitioner.route``);
+        then one ICI all-to-all moves the rows to the reducers' chips in
+        place of data+index files (parallel/mesh.py). Result batches land
+        in the resource map behind a BatchSource, each on the chip its
+        reduce task runs on."""
         from blaze_tpu.ops.shuffle.repartitioner import create_repartitioner
-        from blaze_tpu.parallel.mesh import MeshBatchExchange
+        from blaze_tpu.parallel.mesh import MeshBatchExchange, task_chip
+        from blaze_tpu.utils.logutil import clear_task_context, set_task_context
 
         stage = next(self._stage_ids)
         child_op = build_operator(node.child)
@@ -1605,25 +1606,21 @@ class Session:
         n = self.mesh.devices.size
 
         def run_map(m: int):
-            """Collect one map partition and compute its rows' reducer ids
-            (per-task repartitioner, matching the file path's determinism)."""
-            from blaze_tpu.utils.logutil import clear_task_context, set_task_context
-
+            """One map partition, routed by reducer on the task's chip:
+            ``(batch, offsets)``, or None when it has no rows."""
             ctx = self._make_ctx(m, stage)
             task_metrics = self.metrics.named_child(f"stage_{stage}").named_child(f"map_{m}")
             set_task_context(stage, m, self._qid())
             try:
-                repart = create_repartitioner(node.partitioning, schema)
-                batches, pids = [], []
-                for b in child_op.execute(m, ctx, task_metrics):
-                    if b.num_rows == 0:
-                        continue
-                    batches.append(b)
-                    pids.append(repart.partition_ids(b))
-                if not batches:
-                    return None, None
-                return (ColumnarBatch.concat(batches, schema),
-                        np.concatenate(pids).astype(np.int32))
+                with TRACER.span("task", "task",
+                                 {"stage": stage, "map": m,
+                                  "chip": task_chip(m, num_maps, n)}):
+                    batches = [b for b in child_op.execute(m, ctx, task_metrics)
+                               if b.num_rows]
+                    if not batches:
+                        return None
+                    repart = create_repartitioner(node.partitioning, schema)
+                    return repart.route(ColumnarBatch.concat(batches, schema))
             finally:
                 clear_task_context()
 
@@ -1632,47 +1629,41 @@ class Session:
         if qrun is not None and qrun.stats is not None:
             qrun.stats.on_map_stage(stage, "mesh_map", num_maps, num_reducers)
 
-        # fold map partitions onto the n mesh slots in CONTIGUOUS blocks
-        # (slot = m*n // num_maps, ascending): together with the exchange's
-        # shard-major reducer assembly this keeps every reducer's row order
-        # equal to the file path's map-order concat at EVERY mesh size — a
-        # round-robin fold would interleave map outputs differently per n
-        # and break the bit-identical-across-meshes contract
-        shard_batches: List[Optional[ColumnarBatch]] = [None] * n
-        shard_pids: List[Optional[np.ndarray]] = [None] * n
-        for m, (b, p) in enumerate(outputs):
-            if b is None:
-                continue
-            s = (m * n) // num_maps
-            if shard_batches[s] is None:
-                shard_batches[s], shard_pids[s] = b, p
-            else:
-                shard_batches[s] = ColumnarBatch.concat([shard_batches[s], b], schema)
-                shard_pids[s] = np.concatenate([shard_pids[s], p])
+        # a map output stays on the slot its task ran on, in map order: the
+        # contiguous fold keeps every reducer's row order equal to the file
+        # path's map-order concat at EVERY mesh size
+        shards: List[list] = [[] for _ in range(n)]
+        for m, out in enumerate(outputs):
+            if out is not None:
+                shards[task_chip(m, num_maps, n)].append(out)
 
         exchange = MeshBatchExchange(self.mesh)
-        # device residency budgeted ACROSS the session's live exchanges:
-        # results pin HBM in the resource map until close(), so each
-        # exchange only gets what earlier ones have not already pinned
-        pinned = getattr(self, "_mesh_pinned_bytes", 0)
-        remaining = max(0, self.conf.mesh_device_resident_max_bytes - pinned)
-        reducer_batches = exchange.run(schema, shard_batches, shard_pids,
-                                       num_reducers,
-                                       device_resident_budget=remaining)
+        rid = f"mesh_shuffle_{stage}"
+        with TRACER.span("exchange", "mesh",
+                         {"stage": stage, "reducers": num_reducers}):
+            # device residency budgeted ACROSS the live queries' exchanges:
+            # each resident one is charged to its resource id until the
+            # query that made it is released
+            with self._mesh_pin_mu:
+                remaining = max(0, self.conf.mesh_device_resident_max_bytes
+                                - sum(self._mesh_pins.values()))
+            reducer_batches = exchange.run(schema, shards, num_reducers,
+                                           device_resident_budget=remaining)
+        stage_node = self.metrics.named_child(f"stage_{stage}")
         if exchange.last_device_resident:
-            self._mesh_pinned_bytes = pinned + exchange.last_payload_bytes
+            with self._mesh_pin_mu:
+                self._mesh_pins[rid] = exchange.last_payload_bytes
+        else:
+            stage_node.add("mesh_host_resident_exchanges", 1)
         # tripwires: the mesh path actually engaged, and how many bytes the
         # collective carried in place of shuffle file writes
-        stage_node = self.metrics.named_child(f"stage_{stage}")
         stage_node.add("sharded_stages", 1)
         stage_node.add("collective_bytes", int(exchange.last_wire_bytes))
         _TM_SHARDED_STAGES.inc()
         _TM_COLLECTIVE_BYTES.inc(int(exchange.last_wire_bytes))
-        rid = f"mesh_shuffle_{stage}"
-        # reducer batches (parallel/mesh.py): device-resident ColumnarBatch
-        # for small exchanges (the next stage's device aggregation consumes
-        # them without a host round trip), HostBatch beyond the HBM budget,
-        # None for an empty reducer
+        # reducer batches (parallel/mesh.py): a ColumnarBatch on its reduce
+        # task's chip, a HostBatch beyond the HBM budget (uploaded by the
+        # reduce task, on its chip), None for an empty reducer
         from blaze_tpu.core.batch import HostBatch as _HB
 
         def _read(r):
@@ -1941,8 +1932,27 @@ class Session:
                              ValueError, KeyError, IndexError,
                              ZeroDivisionError)
 
+    def _on_task_chip(self, p: int, num_tasks: int):
+        """A multichip session runs task ``p`` of a stage of ``num_tasks``
+        on the chip of its partition (``parallel/mesh.task_chip``): the task
+        thread's ``jax.default_device``, so its uploads, constants and
+        launches land there, and an exchange's reducer rows are read on the
+        chip that received them. Counted (``mesh_tasks_off_primary``) where
+        that is not the mesh's first chip."""
+        if self.mesh is None or self.mesh.devices.size == 1:
+            return contextlib.nullcontext()
+        import jax
+
+        from blaze_tpu.parallel.mesh import task_chip
+
+        chip = task_chip(p, num_tasks, self.mesh.devices.size)
+        if chip:
+            self.metrics.add("mesh_tasks_off_primary", 1)
+        return jax.default_device(self.mesh.devices.flat[chip])
+
     def _run_tasks(self, fn, partitions) -> list:
-        """Run map tasks with classified retries (round-1 verdict weak #6:
+        """Run a stage's map tasks (``partitions``: all of them, ``range(num
+        tasks)``) with classified retries (round-1 verdict weak #6:
         the previous single blind retry re-ran deterministic failures too).
         Transient errors (IO, worker loss, memory races) retry up to
         conf.task_max_retries with exponential backoff; deterministic
@@ -1960,21 +1970,24 @@ class Session:
         # closure, then re-established as their own TLS below
         qrun = self._qrun()
 
-        def run_task(p):
-            if qrun is None:
-                return fn(p)
-            if qrun.token is not None:
-                qrun.token.check()  # don't even start a doomed task
-            prev = getattr(self._tls, "qrun", None)
-            self._tls.qrun = qrun
-            try:
-                from blaze_tpu.runtime.memmgr import MemManager
+        parts = list(partitions)
 
-                mm = MemManager.get_or_init(self.conf)
-                with mm.group_scope(qrun.mem_group):
+        def run_task(p):
+            with self._on_task_chip(p, len(parts)):
+                if qrun is None:
                     return fn(p)
-            finally:
-                self._tls.qrun = prev
+                if qrun.token is not None:
+                    qrun.token.check()  # don't even start a doomed task
+                prev = getattr(self._tls, "qrun", None)
+                self._tls.qrun = qrun
+                try:
+                    from blaze_tpu.runtime.memmgr import MemManager
+
+                    mm = MemManager.get_or_init(self.conf)
+                    with mm.group_scope(qrun.mem_group):
+                        return fn(p)
+                finally:
+                    self._tls.qrun = prev
 
         def run_with_retry(p):
             from blaze_tpu.runtime.memmgr import SpillFailed
@@ -2043,7 +2056,6 @@ class Session:
                         self.conf.task_max_retries, delay)
                     time.sleep(delay)
 
-        parts = list(partitions)
         if len(parts) <= 1 or self.max_workers <= 1:
             return [run_with_retry(p) for p in parts]
         with ThreadPoolExecutor(max_workers=self.max_workers) as pool:

@@ -230,7 +230,8 @@ def _parts(nparts=4, n=20_000, seed=36):
 
 
 TRACKED = ("shuffle_tier_degraded", "device_shuffle_bytes",
-           "serde_elided_batches", "shuffle_bytes_serialized")
+           "serde_elided_batches", "shuffle_bytes_serialized",
+           "collective_bytes", "mesh_host_resident_exchanges")
 
 
 def _run(parts, watch=None, **conf):
@@ -304,7 +305,8 @@ def test_cell_rehearsal_on_the_device_tier(cell, tmp_path, capsys, monkeypatch):
     """A tiny rehearsal of each cell with the session told its stages run on
     an accelerator: it negotiates the device tier by itself, every answer
     equals the reference's, every ``counters_must`` holds, nothing degrades
-    and the reducers read device-resident sub-batches."""
+    and the reducers read device-resident sub-batches (on a mesh, the
+    reducer batches the collective left on their chips)."""
     import json
 
     from tests.benchmark import helpers
@@ -327,12 +329,22 @@ def test_cell_rehearsal_on_the_device_tier(cell, tmp_path, capsys, monkeypatch):
     monkeypatch.setattr(Session, "_stage_platform", lambda self: "tpu")
     monkeypatch.setattr(Session, "close", closing)
     path = helpers.tiny_manifest(tmp_path, as_on_the_chip)
+    with open(path) as f:
+        manifest = json.load(f)
+    (config,) = [c for c in manifest["configs"] if c["name"] == next(
+        w["config"] for w in manifest["workloads"] if w["name"] == cell)]
+    with open(tmp_path / config["file"]) as f:
+        mesh = json.load(f)["session"]["conf"].get("multichip_enabled", False)
     rc, lines = helpers.run_cell(capsys, path, cell)
     assert rc == 0, lines
     result = json.loads(lines[-1])
     assert result["correct"] and result["failed"] == 0, lines[-1]
     assert seen["tier"] == "device"
     assert seen["shuffle_tier_degraded"] == 0
-    assert seen["device_shuffle_bytes"] > 0
+    if mesh:
+        assert seen["collective_bytes"] > 0
+        assert seen["mesh_host_resident_exchanges"] == 0
+    else:
+        assert seen["device_shuffle_bytes"] > 0
     assert seen["shuffle_bytes_serialized"] == 0
     assert seen["left"] == 0

@@ -255,6 +255,29 @@ def test_mesh_exchange_more_reducers_than_devices(eight_devices, tmp_path):
     assert got == expect
 
 
+def _routed(mesh, batches, pids, num_reducers):
+    """Each slot's batch routed by the given reducer ids on the slot's own
+    chip (``Repartitioner.route``), as a map task hands it to the exchange:
+    ``shards[s] = [(batch, offsets)]``."""
+    import jax
+
+    from blaze_tpu.ops.shuffle.repartitioner import Repartitioner
+
+    class _Given(Repartitioner):
+        def __init__(self, ids):
+            super().__init__(num_reducers)
+            self.ids = ids
+
+        def partition_ids(self, batch):
+            return self.ids
+
+    shards = []
+    for dev, b, p in zip(mesh.devices.flat, batches, pids):
+        with jax.default_device(dev):
+            shards.append([_Given(p).route(b)])
+    return shards
+
+
 def test_mesh_exchange_wire_bytes_compacted(eight_devices):
     """Compacted segments must carry >=5x less than the old (n, capacity)
     masked tiles at 8 devices with uniform routing (round-2 verdict item 4's
@@ -275,7 +298,7 @@ def test_mesh_exchange_wire_bytes_compacted(eight_devices):
             "v": pa.array(rng.integers(0, 100, per), type=pa.int64())})
         batches.append(ColumnarBatch.from_arrow(t, schema))
         pids.append(rng.integers(0, 8, per).astype(np.int32))
-    results = ex.run(schema, batches, pids, 8)
+    results = ex.run(schema, _routed(mesh, batches, pids, 8), 8)
     total = sum(r.num_rows for r in results if r is not None)
     assert total == 8 * per
     assert ex.last_wire_bytes * 5 <= ex.last_wire_bytes_uncompacted, (
@@ -304,7 +327,7 @@ def test_mesh_exchange_large_payload_lands_on_host(eight_devices, monkeypatch):
                                type=pa.int64())}), schema) for _ in range(8)]
     pids = [rng.integers(0, 8, per).astype(np.int32) for _ in range(8)]
     monkeypatch.setattr(get_config(), "mesh_device_resident_max_bytes", 1)
-    results = ex.run(schema, batches, pids, 8)
+    results = ex.run(schema, _routed(mesh, batches, pids, 8), 8)
     assert all(isinstance(r, HostBatch) for r in results if r is not None)
     total = sum(r.num_rows for r in results if r is not None)
     assert total == 8 * per
@@ -338,7 +361,7 @@ def test_mesh_exchange_skewed_reducer_runs_bounded_rounds(eight_devices,
         pids.append(p)
     # tiny round budget: forces multiple rounds
     monkeypatch.setattr(get_config(), "mesh_exchange_round_bytes", 1 << 20)
-    results = ex.run(schema, batches, pids, 8)
+    results = ex.run(schema, _routed(mesh, batches, pids, 8), 8)
     got = sorted(int(x) for r in results if r is not None
                  for x in r.to_columnar().to_arrow()["k"].to_pylist()
                  ) if hasattr(results[0], "to_columnar") else sorted(
@@ -370,7 +393,7 @@ def test_mesh_reducer_strings_large_typed_and_concatable(eight_devices,
                                 ).dictionary_encode()}), schema)
         for _ in range(8)]
     pids = [np.arange(64, dtype=np.int32) % 8 for _ in range(8)]
-    results = ex.run(schema, batches, pids, 8)
+    results = ex.run(schema, _routed(mesh, batches, pids, 8), 8)
     other = ColumnarBatch.from_arrow(
         pa.table({"s": pa.array(["x", "y"])}), schema)
     for r in results:
@@ -379,3 +402,45 @@ def test_mesh_reducer_strings_large_typed_and_concatable(eight_devices,
         rb = r.to_columnar() if hasattr(r, "to_columnar") else r
         merged = ColumnarBatch.concat([rb, other], schema)
         assert merged.num_rows == rb.num_rows + 2
+
+
+def test_mesh_exchange_keeps_rows_on_the_chips_and_reads_nothing(
+        eight_devices):
+    """Send buffers are cut on each shard's chip and each reducer's rows are
+    gathered on the chip that received them (reducer r of 13 on chip
+    r // 2, where its reduce task runs): the exchange pulls nothing to the
+    host and waits on nothing, the offsets it was handed being all it
+    reads. The rows are the shard-major concat of every shard's rows of the
+    reducer, in their order."""
+    from blaze_tpu.core.batch import ColumnarBatch
+    from blaze_tpu.parallel.mesh import MeshBatchExchange, task_chip
+    from blaze_tpu.utils.device import DEVICE_STATS
+
+    rng = np.random.default_rng(16)
+    mesh = make_mesh(8)
+    ex = MeshBatchExchange(mesh)
+    schema = T.schema_from_arrow(pa.schema([("k", pa.int64()),
+                                            ("v", pa.int64())]))
+    batches, pids = [], []
+    for s in range(8):
+        per = 700 + 100 * s
+        batches.append(ColumnarBatch.from_arrow(pa.table({
+            "k": pa.array(np.arange(per) + 10_000 * s, type=pa.int64()),
+            "v": pa.array(rng.integers(-9, 9, per), type=pa.int64())}),
+            schema))
+        pids.append(rng.integers(0, 13, per).astype(np.int32))
+    shards = _routed(mesh, batches, pids, 13)
+    for dev, ((b, _offsets),) in zip(mesh.devices.flat, shards):
+        assert b.columns[0].data.devices() == {dev}
+    before = DEVICE_STATS.snapshot()
+    results = ex.run(schema, shards, 13)
+    after = DEVICE_STATS.snapshot()
+    for key in ("to_host_calls", "sync_calls", "to_device_bytes"):
+        assert after[key] == before[key], key
+    for r, got in enumerate(results):
+        dev = mesh.devices.flat[task_chip(r, 13, 8)]
+        assert task_chip(r, 13, 8) == r // 2
+        assert {d for c in got.columns for d in c.data.devices()} == {dev}
+        want = [int(k) for b, p in zip(batches, pids)
+                for k in b.to_arrow()["k"].to_numpy()[p == r]]
+        assert got.to_arrow()["k"].to_pylist() == want

@@ -44,7 +44,7 @@ def _summed(sess, name: str) -> int:
 
 _TRACKED = ("shuffle_bytes_serialized", "serde_elided_batches",
             "sharded_stages", "collective_bytes", "device_shuffle_bytes",
-            "shuffle_tier_degraded", "sharded_batches")
+            "shuffle_tier_degraded")
 
 
 def _two_stage_plan(batch_parts, reducers=4):
@@ -146,9 +146,9 @@ def test_multichip_bit_identical_across_meshes(eight_devices):
         assert m["collective_bytes"] > 0
 
 
-def test_multichip_composes_with_fused_sharding(eight_devices):
-    """More map partitions than devices: the fused stage's batch-stacking
-    runner and the mesh exchange compose, still bit-identical."""
+def test_multichip_eight_tasks_on_eight_chips(eight_devices):
+    """Eight map tasks, a chip each, through the mesh exchange: still
+    bit-identical with the one-chip session."""
     parts = _make_parts(seed=24, n=64_000, nparts=8)
     ref, _ = _run(parts)
     out, m = _run(parts, multichip_enabled=True, multichip_devices=8)
@@ -251,7 +251,7 @@ def bench_paths(tmp_path_factory):
     import bench
 
     bench.ROWS = 60_000
-    bench.PARTS = 2
+    bench.PARTS = 4
     td = str(tmp_path_factory.mktemp("mcbench"))
     return bench.make_data(td)
 
@@ -261,14 +261,130 @@ def bench_paths(tmp_path_factory):
 def test_bench_shapes_identical_across_meshes(bench_paths, shape,
                                               eight_devices):
     """Each bench shape under device-primary execution must return
-    byte-for-byte the same table at 1, 2 and 8 mesh devices."""
+    byte-for-byte the same table at 1, 2, 4 and 8 mesh devices, with every
+    task on the chip of its partition, and the file-shuffle path's rows (in
+    its order where the final Sort makes the order total); q01 and q67 (the
+    four-chip cells' classes) equal the Acero reference too."""
     import bench
 
-    plan_fn = {s[0]: s[1] for s in bench.SHAPES}[shape]
+    (_name, plan_fn, _pandas, acero, _check, tables_of), = [
+        s for s in bench.SHAPES if s[0] == shape]
+    with config_override(zero_copy_shuffle=False):
+        with Session() as sess:
+            want = sess.execute_to_table(plan_fn(bench_paths))
+    if shape in ("q01", "q67"):
+        ref = acero(bench.load_tables(bench_paths, tables_of))
+        assert bench.canon_rows(shape, want, "engine") == \
+            bench.canon_rows(shape, ref, "acero")
     tables = []
-    for k in (1, 2, 8):
+    for k in (1, 2, 4, 8):
         with config_override(multichip_enabled=True, multichip_devices=k):
             with Session() as sess:
                 tables.append(sess.execute_to_table(plan_fn(bench_paths)))
-    assert tables[0].equals(tables[1]), f"{shape}: 1 vs 2 devices diverged"
-    assert tables[0].equals(tables[2]), f"{shape}: 1 vs 8 devices diverged"
+                off = _summed(sess, "mesh_tasks_off_primary")
+        assert tables[-1].equals(tables[0]), f"{shape}: {k} devices diverged"
+        assert (off > 0) == (k > 1), (k, off)
+    assert bench.canon_rows(shape, tables[0], "engine") == \
+        bench.canon_rows(shape, want, "engine")
+
+
+# -- a task a chip --------------------------------------------------------------
+
+
+def _spy_exchanges(monkeypatch):
+    """Every MeshBatchExchange.run of the test: what it was handed, what it
+    returned, and whether it read or waited on anything meanwhile."""
+    from blaze_tpu.parallel.mesh import MeshBatchExchange
+    from blaze_tpu.utils.device import DEVICE_STATS
+
+    seen = []
+    real = MeshBatchExchange.run
+
+    def run(self, schema, shards, num_reducers, **kw):
+        before = DEVICE_STATS.snapshot()
+        out = real(self, schema, shards, num_reducers, **kw)
+        after = DEVICE_STATS.snapshot()
+        seen.append({"exchange": self, "shards": shards, "results": out,
+                     "num_reducers": num_reducers,
+                     "pulls": after["to_host_calls"] - before["to_host_calls"],
+                     "waits": after["sync_calls"] - before["sync_calls"],
+                     "resident": self.last_device_resident,
+                     "payload": self.last_payload_bytes})
+        return out
+
+    monkeypatch.setattr(MeshBatchExchange, "run", run)
+    return seen
+
+
+def _planes_on(batch):
+    return {d for c in batch.columns for a in (c.data, c.validity)
+            for d in a.devices()}
+
+
+def test_each_task_runs_on_the_chip_of_its_partition(eight_devices,
+                                                     monkeypatch):
+    """Four map tasks on four chips: each routes its output on its own
+    chip, the exchange reads nothing but the offsets, every reducer's batch
+    lands on the chip its reduce task runs on, and the answer is the
+    one-chip session's."""
+    from blaze_tpu.parallel.mesh import task_chip
+
+    parts = _make_parts(seed=27)
+    ref, _ = _run(parts)
+    seen = _spy_exchanges(monkeypatch)
+    out, m = _run(parts, multichip_enabled=True, multichip_devices=4)
+    assert out.equals(ref)
+    devs = eight_devices[:4]
+    assert len(seen) == 2  # the hash exchange, then the final collect
+    for ex in seen:
+        for s, pieces in enumerate(ex["shards"]):
+            for batch, offsets in pieces:
+                assert _planes_on(batch) == {devs[s]}
+                assert offsets[-1] == batch.num_rows
+        assert ex["pulls"] == 0 and ex["waits"] == 0
+        for r, batch in enumerate(ex["results"]):
+            if batch is not None:
+                assert _planes_on(batch) == \
+                    {devs[task_chip(r, ex["num_reducers"], 4)]}
+    assert [len(p) for p in seen[0]["shards"]] == [1, 1, 1, 1]
+
+
+def test_mesh_tasks_off_primary_counts_a_four_task_stage(eight_devices):
+    """A four-task stage on a four-chip mesh has three tasks off chip 0; its
+    one-partition result stage runs on chip 0."""
+    parts = _make_parts(seed=28)
+    schema = parts[0][0].schema
+    scan = N.FFIReader(schema=schema, resource_id="src",
+                       num_partitions=len(parts))
+    plan = N.ShuffleExchange(scan, N.HashPartitioning([_col("k")], 1))
+    with config_override(multichip_enabled=True, multichip_devices=4):
+        with Session() as sess:
+            sess.resources["src"] = lambda p: [x.to_arrow() for x in parts[p]]
+            out = sess.execute_to_table(plan)
+            assert _summed(sess, "mesh_tasks_off_primary") == 3
+            assert _summed(sess, "sharded_stages") == 1
+    assert out.num_rows == sum(b.num_rows for p in parts for b in p)
+
+
+def test_twenty_queries_keep_every_exchange_on_the_chips(eight_devices,
+                                                         monkeypatch):
+    """A query's device-resident exchanges are charged to it until it is
+    released: twenty queries of the q67 class in one session, under a budget
+    that holds two queries' exchanges, never send an exchange to host RAM
+    (when the charge outlived its query, the third query's went there)."""
+    parts = _make_parts(seed=29)
+    seen = _spy_exchanges(monkeypatch)
+    conf = dict(multichip_enabled=True, multichip_devices=4)
+    _run(parts, **conf)
+    budget = 2 * sum(ex["payload"] for ex in seen)
+    seen.clear()
+    with config_override(mesh_device_resident_max_bytes=budget, **conf):
+        with Session() as sess:
+            sess.resources["src"] = lambda p: [x.to_arrow() for x in parts[p]]
+            first = sess.execute_to_table(_two_stage_plan(parts))
+            for _ in range(19):
+                assert sess.execute_to_table(
+                    _two_stage_plan(parts)).equals(first)
+            assert not sess._mesh_pins
+            assert _summed(sess, "mesh_host_resident_exchanges") == 0
+    assert len(seen) == 40 and all(ex["resident"] for ex in seen)
