@@ -326,8 +326,11 @@ class Config:
     # True is the same; False forces the sort kernel (tests compare the two).
     dense_agg: Optional[bool] = None
 
-    # Upper bound on the dense-agg bucket-table size (product of per-key
-    # rounded ranges). Ranges beyond this fall back to the sort kernel.
+    # Upper bound on the dense-agg table (product of per-key rounded ranges)
+    # in the forms that hold the table: masked and scatter, linear in the
+    # slots. Past it a table is radix where radix_agg is on, else slot-sorted
+    # (ONE sort of the packed slot id, flat in the slots) while the id fits
+    # 62 bits, else the sort kernel.
     dense_agg_max_buckets: int = 65536
 
     # Radix-partitioned grouped aggregation: the high-cardinality extension

@@ -22,6 +22,7 @@ indexes (PERF.md §6, PR 27 and PR 32).
 from __future__ import annotations
 
 import functools
+import math
 import time
 from typing import List, Sequence, Tuple
 
@@ -833,23 +834,25 @@ def radix_strides(sizes: Sequence[int]) -> Tuple[int, ...]:
     return tuple(reversed(strides))
 
 
-def radix_pack(key_data, key_valid, exists, bases, sizes, strides):
-    """Traced: pack per-key integer planes into one slot code (int32 seg).
+def radix_pack(key_data, key_valid, exists, bases, sizes, strides,
+               sentinel=None):
+    """Traced: pack per-key integer planes into one slot code (seg: int32
+    where the sentinel fits it, else int64).
 
     Per key, code 0 is the null bucket and 1..size-1 map base..base+size-2;
     per-key codes combine mixed-radix via ``strides``. ``bases`` is a traced
-    int64 vector so one compiled kernel serves every batch of a stream.
-    Returns (seg, fits): padding rows route to the prod(sizes) sentinel
-    slot; ``fits`` flips False when any existing valid key fell outside its
+    int64 vector so one compiled kernel serves every batch of a stream;
+    ``sizes`` and ``strides`` are static, or traced vectors beside a static
+    ``sentinel`` above every slot. Returns (seg, fits): padding rows route
+    to the sentinel slot, prod(sizes) by default; ``fits`` flips False when
+    any existing valid key fell outside its
     range. The in-range test is overflow-safe: ``diff`` wraps when
     |key - base| exceeds 2^63, which could land a far-away key inside
     [0, size) and silently mis-bucket it — requiring d64 >= base AND
     diff >= 0 rejects both the wrapped case (wrapped diff is negative when
     d64 >= base) and key == base-1 (which would collide with the null
     bucket at code 0)."""
-    S = 1
-    for s in sizes:
-        S *= s
+    S = math.prod(sizes) if sentinel is None else sentinel
     cap = exists.shape[0]
     seg = jnp.zeros(cap, jnp.int64)
     fits = jnp.bool_(True)
@@ -860,7 +863,10 @@ def radix_pack(key_data, key_valid, exists, bases, sizes, strides):
         infit = (d64 >= bases[i]) & (diff >= 0) & (diff < sizes[i] - 1)
         fits = fits & jnp.all(jnp.where(exists & v, infit, True))
         seg = seg + jnp.clip(code, 0, sizes[i] - 1) * strides[i]
-    return jnp.where(exists, seg, S).astype(jnp.int32), fits
+    seg = jnp.where(exists, seg, S)
+    if S < 1 << 31:
+        seg = seg.astype(jnp.int32)
+    return seg, fits
 
 
 def radix_bucket_shift(S: int, nbuck: int) -> Tuple[int, int]:

@@ -806,9 +806,13 @@ class DevicePartialAgger:
         return _plan_slot_table(probe, capacity, prev, max_slots, self.conf)
 
     def _plan_bucketed(self, probe: np.ndarray, capacity: int, prev):
-        """Pick the scatter-table plan for this stream: dense when the key
+        """Pick the slot-table plan for this stream: dense when the key
         space fits the small-table cap, else radix-partitioned up to
-        radix_agg_max_slots. Returns ("dense"|"radix", bases, sizes,
+        radix_agg_max_slots where the radix gate is on, else (the chip) a
+        "wide" table of any size whose packed slot id fits
+        ``_SLOT_ID_MAX_SLOTS`` (slot-sorted, which is flat in the slots; its
+        sizes traced, so one program serves every such table of the
+        stream's key types). Returns ("dense"|"radix"|"wide", bases, sizes,
         out_cap), _DEFER_PLAN, or None (sort fallback)."""
         if self._dense_enabled():
             st = self._plan_table(
@@ -825,6 +829,10 @@ class DevicePartialAgger:
                 return _DEFER_PLAN
             if st is not None:
                 return ("radix",) + st
+        elif self._dense_enabled():
+            st = self._plan_table(probe, capacity, prev, _SLOT_ID_MAX_SLOTS)
+            if st is not None:
+                return ("wide",) + st
         return None
 
     def _dense_call(self, batch: ColumnarBatch, bases, sizes, out_cap,
@@ -914,8 +922,17 @@ class DevicePartialAgger:
                     return None
                 self._bucket_state = st
             table, bases, sizes, out_cap = st
+            if math.prod(sizes) > out_cap:
+                # a table wider than the batch it was planned on: a batch
+                # has as many groups as its capacity, as on the sort path
+                out_cap = self.conf.capacity_for(
+                    min(math.prod(sizes), batch.capacity))
             nbuck = self.conf.radix_agg_buckets if table == "radix" else 0
-            outs = self._dense_call(batch, bases, sizes, out_cap, nbuck)
+            if table == "wide":
+                outs = self._dense_call(batch, _wide_table(bases, sizes),
+                                        None, out_cap)
+            else:
+                outs = self._dense_call(batch, bases, sizes, out_cap, nbuck)
             # sync; -1 flags range overflow
             num_groups = wait_int(outs[0], "agg_partial")
             if num_groups >= 0:
@@ -1481,6 +1498,41 @@ _MASKED_REDUCE_MAX_SLOTS = 16384  # past it the masked form loses to a scatter
 #   262,144 rows  1,024 slots 1.31 / 3.63, 6.81 / 6.50   2,048 2.71 / 3.65, 14.3 / 6.51
 #                 4,096 slots 5.78 / 3.67, 29.8 / 6.55  16,384 30.5 / 3.80, 123 / 6.75
 _SLOT_SORT_MIN_SLOTS = 2048
+# The widest table a packed slot id numbers: where no radix table is planned
+# (the chip) a table past ``dense_agg_max_buckets`` is "wide": slot-sorted,
+# whose cost is the sort of the id and not the slots, so what bounds it is the
+# id's width. A wide table's sizes are traced and its id is int64 whatever its
+# size, so one program a key type and batch capacity serves every wide table
+# (each grouping set of a ROLLUP, each re-plan after an overflow), as one
+# sort-path program did: a program a table size multiplied the executables a
+# process compiles and loads (PERF.md section 6). Its padding rows
+# take the id ``_WIDE_SENTINEL``, above every slot.
+_SLOT_ID_MAX_SLOTS = (1 << 62) - 1
+_WIDE_SENTINEL = 1 << 62
+
+
+def _wide_table(bases, sizes):
+    """A wide table's traced description: int64 rows of the keys' anchors,
+    sizes and strides' bit shifts (every size is a power of two)."""
+    shifts = [(math.prod(sizes[i + 1:])).bit_length() - 1
+              for i in range(len(sizes))]
+    return np.array([bases, sizes, shifts], np.int64)
+
+
+def _sort_slot_ids(seg, iota):
+    """ONE sort of the packed slot ids ``seg`` -> (the ids in order, the row
+    standing at each position). Padding rows carry an id above every slot
+    and sort last; ties in row order, so a float sum adds in one fixed
+    order. An int64 id
+    sorts as its two uint32 halves: on the chip 0.141 / 0.313 ms at 131,072
+    / 262,144 rows, where the int64 operand takes 0.183 / 0.412 and compiles
+    as long (PERF.md section 6)."""
+    if seg.dtype != jnp.int64:
+        return jax.lax.sort((seg, iota), num_keys=2, is_stable=False)
+    hi, lo, order = jax.lax.sort(
+        ((seg >> 32).astype(jnp.uint32), seg.astype(jnp.uint32), iota),
+        num_keys=3, is_stable=False)
+    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64), order
 
 
 def _table_form(nseg: int, rows: int, sortable: bool = False) -> str:
@@ -1685,9 +1737,12 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
     table), in the form :func:`_table_form` picks from the static shapes: a
     table of few slots as masked vector reductions and a compaction of the
     table — no sort, no capacity-sized tables, no row-sized scatter; from
-    ``_SLOT_SORT_MIN_SLOTS`` slots on by one two-operand sort of the slot id
-    and :func:`_reduce_ordered` — no scatter at all and nothing of ``slots x
+    ``_SLOT_SORT_MIN_SLOTS`` slots on by one sort of the slot id and
+    :func:`_reduce_ordered` — no scatter at all and nothing of ``slots x
     rows``. Either way the groups come out in ascending slot order.
+    With ``sizes`` None the table is wide (past dense_agg_max_buckets, up
+    to ``_SLOT_ID_MAX_SLOTS``): slot-sorted, ``bases`` is
+    :func:`_wide_table`'s traced description and the id is int64.
     ``bases`` (traced, per key) anchor the ranges so one compiled
     kernel serves every batch of the stream; a key outside its range flips
     the fits flag and the host falls back for that batch. Output arrays are
@@ -1701,32 +1756,32 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
     its outputs — the cardinality signal the partial-skipping heuristic
     and the Perfetto skew view consume."""
     nk = len(key_dtypes)
-    S = 1
-    for s in sizes:
-        S *= s
-    strides = K.radix_strides(sizes)
-    slot_sorted = _is_slot_sorted(sizes, capacity, nbuck)
+    wide = sizes is None
+    if not wide:
+        S = math.prod(sizes)
+        strides = K.radix_strides(sizes)
+    slot_sorted = wide or _is_slot_sorted(sizes, capacity, nbuck)
 
-    def group_keys(bases, slot, move, out_valid):
+    def digit(slot, i):
+        return (slot // strides[i]) % sizes[i]
+
+    def group_keys(bases, slot, move, out_valid, digit=digit):
         """The groups' (data, validity) key planes: a key reconstructs
         arithmetically from the slot id (exact for ints; no representative
         row to gather), and ``move`` brings a plane by slot to the groups."""
         planes = []
         for i, kdt in enumerate(key_dtypes):
-            code_b = (slot // strides[i]) % sizes[i]
+            code_b = digit(slot, i)
             kdata = (bases[i] + code_b - 1).astype(jnp.dtype(kdt))
             planes.append(jnp.where(out_valid, move(kdata),
                                     jnp.zeros((), jnp.dtype(kdt))))
             planes.append(move(code_b > 0) & out_valid)
         return planes
 
-    def slot_sorted_groups(exists, bases, args, seg, fits):
+    def slot_sorted_groups(exists, bases, args, seg, fits, digit=digit):
         iota = jnp.arange(capacity, dtype=jnp.int32)
         with jax.named_scope("order"):
-            # padding rows carry the slot id S and sort last; ties in row
-            # order, so a float sum adds in one fixed order
-            slot, order = jax.lax.sort((seg, iota), num_keys=2,
-                                       is_stable=False)
+            slot, order = _sort_slot_ids(seg, iota)
         num_groups, out_valid, (slot,), states = _reduce_ordered(
             exists, iota, order, slot, [], [slot],
             [kind for kind, _r, _d in specs],
@@ -1734,7 +1789,7 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
              for spec, arg in zip(specs, args)], out_cap)
         with jax.named_scope("emit"):
             keys = group_keys(bases, slot.astype(jnp.int64), lambda x: x,
-                              out_valid)
+                              out_valid, digit)
         return (jnp.where(fits, num_groups.astype(jnp.int64), jnp.int64(-1)),
                 out_valid, *keys, *states)
 
@@ -1752,6 +1807,15 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
                 args.append((flat[pos], flat[pos + 1] & exists))
                 pos += 2
         # the scopes split the program's device time in a trace
+        if wide:  # bases is _wide_table's (3, keys)
+            bases, sizes_t, shifts = bases
+            with jax.named_scope("pack"):
+                seg, fits = K.radix_pack(
+                    key_data, key_valid, exists, bases, sizes_t,
+                    jnp.int64(1) << shifts, _WIDE_SENTINEL)
+            return slot_sorted_groups(
+                exists, bases, args, seg, fits,
+                lambda slot, i: (slot >> shifts[i]) & (sizes_t[i] - 1))
         with jax.named_scope("pack"):
             seg, fits = K.radix_pack(key_data, key_valid, exists, bases,
                                      sizes, strides)

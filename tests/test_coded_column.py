@@ -563,8 +563,9 @@ def test_a_rollups_sets_through_the_partial_aggregation_equal_pandas():
 def test_each_grouping_set_plans_its_slot_table_for_itself():
     """A ROLLUP's grand total is one slot, its finest set every name: the
     partial aggregation keeps a slot-table state a null signature of its
-    keys, so the narrow sets take `jit(agg_dense_partial)` although the
-    wide one was refused, at one probe a signature."""
+    keys, so each set plans the table of its own key space, at one probe a
+    signature — the finest past ``dense_agg_max_buckets`` and slot-sorted,
+    the grand total eight slots and masked."""
     import dataclasses
 
     from blaze_tpu.config import get_config
@@ -598,12 +599,83 @@ def test_each_grouping_set_plans_its_slot_table_for_itself():
     before = DEVICE_STATS.snapshot()
     got = collect(partial, ctx)
     after = DEVICE_STATS.snapshot()
-    # 512 x 512 x 2 slots is refused, 512 x 2 x 2 and 2 x 2 x 2 are not:
-    # two sets of the three, in both batches
-    assert after["agg_dense_batches"] - before["agg_dense_batches"] == 4
-    assert after["agg_sort_batches"] - before["agg_sort_batches"] == 2
+    # 512 x 512 x 2 slots passes the cap and is slot-sorted, 512 x 2 x 2 is
+    # inside it and slot-sorted, 2 x 2 x 2 masked: in both batches
+    assert after["agg_dense_batches"] - before["agg_dense_batches"] == 6
+    assert after["agg_slot_sorted_batches"] - \
+        before["agg_slot_sorted_batches"] == 4
+    assert after["agg_sort_batches"] == before["agg_sort_batches"]
     total = got.filter(pa.compute.equal(got["g"], 3))
     assert total["s#sum"].to_pylist() == [2 * int(batch.to_arrow()["v"].to_numpy().sum())]
+
+
+def test_a_q22_rollups_product_sets_are_slot_sorted_and_equal_the_sort_kernel():
+    """q22's shape without a radix table (the chip): a five-set ROLLUP over
+    four coded names, the product's dictionary 18,000 entries. Every set
+    that keeps the product is one slot table past ``dense_agg_max_buckets``
+    (2^19 to 2^36 slots), slot-sorted, and all four run ONE program; the
+    grand total is masked; the partial rows equal the sort kernel's row for
+    row."""
+    import dataclasses
+
+    from blaze_tpu.config import get_config
+    from blaze_tpu.ops import agg_device as A
+    from blaze_tpu.ops.agg import AggExec
+    from blaze_tpu.utils.device import DEVICE_STATS
+
+    rng = np.random.default_rng(22)
+    n = 3000
+    keys = ("product", "brand", "class", "category")
+    vocab = {"product": 18_000, "brand": 1_000, "class": 50, "category": 10}
+
+    def coded(name):
+        words = pa.array([f"{name}-{i:05d}" for i in range(vocab[name])])
+        codes = rng.integers(0, vocab[name], n).astype(np.int32)
+        return pa.DictionaryArray.from_arrays(
+            pa.array(codes, mask=rng.random(n) < 0.005), words)
+
+    schema = T.Schema.of(*((k, T.STRING) for k in keys), ("q", T.I64))
+    table = pa.table({**{k: coded(k) for k in keys},
+                      "q": pa.array(rng.integers(0, 1000, n), type=pa.int64(),
+                                    mask=rng.random(n) < 0.01)})
+    batch = ColumnarBatch.from_arrow(table, schema)
+    projections = [
+        [*(E.Column(k) if i < kept else E.Literal(None, T.STRING)
+           for i, k in enumerate(keys)),
+         E.Literal(gid, T.I64), E.Column("q")]
+        for kept, gid in ((4, 0), (3, 1), (2, 3), (1, 7), (0, 15))]
+    out_schema = T.Schema.of(*((k, T.STRING) for k in keys), ("gid", T.I64),
+                             ("q", T.I64))
+
+    def partial(dense_agg):
+        expand = ExpandExec(MemoryScanExec(schema, [[batch, batch]]),
+                            projections, out_schema)
+        agg = AggExec(expand, E.AggExecMode.HASH_AGG,
+                      [(k, E.Column(k)) for k in (*keys, "gid")],
+                      [N.AggColumn(E.AggExpr(E.AggFunction.AVG,
+                                             [E.Column("q")]),
+                                   E.AggMode.PARTIAL, "qoh")])
+        ctx = ExecContext(conf=dataclasses.replace(
+            get_config(), radix_agg=False, dense_agg=dense_agg))
+        before = DEVICE_STATS.snapshot()
+        got = collect(agg, ctx)
+        after = DEVICE_STATS.snapshot()
+        assert ctx.metrics.total("host_key_batches") == 0
+        return got, {k: after[k] - before[k] for k in (
+            "agg_dense_batches", "agg_slot_sorted_batches",
+            "agg_sort_batches")}
+
+    built = A._dense_partial_kernel.cache_info().misses
+    got, counts = partial(None)
+    # the wide tables' program, and the grand total's unless built before
+    assert A._dense_partial_kernel.cache_info().misses - built in (1, 2)
+    want, sort_counts = partial(False)
+    # two batches: four product sets slot-sorted, the grand total masked
+    assert counts == {"agg_dense_batches": 10, "agg_slot_sorted_batches": 8,
+                      "agg_sort_batches": 0}
+    assert sort_counts["agg_sort_batches"] == 10
+    assert got.to_pydict() == want.to_pydict()
+    assert got.num_rows > 3 * n  # a product set: a group nearly a row
 
 
 def test_a_typed_null_is_marked_and_a_mover_drops_the_mark():

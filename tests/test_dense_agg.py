@@ -10,6 +10,7 @@ kernel on nullable multi-key input.
 """
 
 import hashlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,7 @@ from tests.util import jaxpr_eqns
 SCHEMA = pa.schema([("k1", pa.int64()), ("k2", pa.int64()), ("v", pa.int64())])
 
 
-def _scan_stub():
+def _scan_stub(schema=SCHEMA):
     import tempfile
 
     import pyarrow.parquet as pq
@@ -39,8 +40,8 @@ def _scan_stub():
     from blaze_tpu.ops.parquet import scan_node_for_files
 
     td = tempfile.mkdtemp(prefix="dense_agg_")
-    pq.write_table(pa.table({"k1": [1], "k2": [0], "v": [1]},
-                            schema=SCHEMA), td + "/t.parquet")
+    pq.write_table(pa.table({f.name: [1] for f in schema}, schema=schema),
+                   td + "/t.parquet")
     return scan_node_for_files([td + "/t.parquet"], num_partitions=1)
 
 
@@ -562,6 +563,32 @@ def test_slot_sorted_kernel_touches_no_row_at_a_time(case, slots):
     assert "sort" in names_seen and "cumsum" in names_seen
 
 
+@pytest.mark.parametrize("case", sorted(SCHEMAS))
+def test_the_wide_kernel_sorts_the_ids_halves_once_and_scatters_nothing(case):
+    """A wide table (traced sizes, int64 id) is the slot-sorted body behind
+    ONE sort of the id's two uint32 halves and the row iota: no scatter, no
+    ``cond`` or ``while``, one gather with batch-sized indices in and one
+    out, and the same program whatever the table's size."""
+    key_dtypes, names, *_ = SCHEMAS[case]
+    adt = tuple(KINDS[n][1] for n in names)
+    kernel = A._dense_partial_kernel.__wrapped__(
+        key_dtypes, tuple(KINDS[n][0] for n in names), adt, CAPACITY, None,
+        CAPACITY)
+    avals = _avals(key_dtypes, adt, CAPACITY)
+    avals.insert(1, jax.ShapeDtypeStruct((3, len(key_dtypes)), jnp.int64))
+    eqns = list(jaxpr_eqns(jax.make_jaxpr(kernel)(*avals).jaxpr))
+    names_seen = {e.primitive.name for e in eqns}
+    assert not [n for n in names_seen if n.startswith("scatter")]
+    assert "cond" not in names_seen and "while" not in names_seen
+    sorts = [e for e in eqns if e.primitive.name == "sort"]
+    # the id's halves and the iota; the emit's flags and the iota
+    assert [[str(v.aval.dtype) for v in e.invars] for e in sorts] == [
+        ["uint32", "uint32", "int32"], ["uint8", "int32"]]
+    gathers = [e.invars[1].aval.shape[0] for e in eqns
+               if e.primitive.name == "gather"]
+    assert sorted(gathers) == [CAPACITY, CAPACITY]
+
+
 SMALL = 256  # rows a batch of the equality tests: 200 and 56 padding rows
 
 
@@ -665,18 +692,19 @@ def test_auto_engages_whatever_the_backend_and_counts_what_ran(
     assert s2["agg_dense_batches"] - s1["agg_dense_batches"] == 1
     assert s2["sync_calls"] - s1["sync_calls"] == 1, "one probe a stream"
     # a range past dense_agg_max_buckets: radix where its gate allows, else
-    # the sort kernel for the rest of the stream
+    # one slot-sorted table as wide as the range
     wide = _agger()
     out = wide.process(_batch([5, 900_005] * 100, [2] * 200))
     s3 = DEVICE_STATS.snapshot()
     assert sorted(out.to_arrow().to_pydict()["s#sum"]) == [200, 200]
+    assert s3["agg_dense_batches"] - s2["agg_dense_batches"] == 1
+    assert s3["agg_sort_batches"] == s2["agg_sort_batches"]
     if backend_is_cpu:
         assert wide._bucket_state[0] == "radix"
-        assert s3["agg_dense_batches"] - s2["agg_dense_batches"] == 1
     else:
-        assert wide._bucket_state is None and wide._dense_ok is False
-        assert s3["agg_sort_batches"] - s2["agg_sort_batches"] == 1
-        assert s3["agg_dense_batches"] == s2["agg_dense_batches"]
+        assert wide._bucket_state == ("wide", (5,), (1 << 20,), 256)
+        assert s3["agg_slot_sorted_batches"] - \
+            s2["agg_slot_sorted_batches"] == 1
 
 
 def test_slot_sorted_batches_are_counted_and_a_key_outside_widens():
@@ -718,6 +746,173 @@ def test_slot_sorted_batches_are_counted_and_a_key_outside_widens():
         want[k] = want.get(k, 0) + 2
     assert got["k1"] == sorted(want), "groups leave in key order"
     assert got["s#sum"] == [want[k] for k in sorted(want)]
+
+
+# -- a table past dense_agg_max_buckets, where no radix table is planned --------
+
+WIDE_KEYS = ("k1", "k2", "k3", "k4", "k5")
+# name -> the key columns' (anchor, span of values) on the first batch; the
+# second batch adds one key a span past the first key's, which widens it
+WIDE_TABLES = {
+    # 32,768 x 16 slots: q67's item x store
+    "2key_under_2e31": ((10**12, 20_000), (-7, 12)),
+    # 1,048,576 x 4,096 slots
+    "2key_past_2e31": ((-(10**15), 600_000), (5, 3_000)),
+    # 1,024 x 32 x 8 x 8 x 4 slots
+    "5key_under_2e31": ((10**12, 600), (-40, 20), (0, 5), (3, 5), (9, 2)),
+    # 32,768 x 1,024 x 64 x 16 x 2 slots: q22's finest set
+    "5key_past_2e31": ((10**12, 18_000), (-500, 1_000), (0, 50), (3, 10),
+                       (7, 1)),
+}
+# batches of capacity 4,096, 8,192 (more groups than the first one holds)
+# and 4,096
+WIDE_ROWS = (3_000, 5_000, 3_000)
+
+
+def _wide_agger(nkeys: int, conf):
+    from blaze_tpu.config import Config
+
+    schema = pa.schema([(k, pa.int64()) for k in WIDE_KEYS[:nkeys]] +
+                       [("v", pa.int64())])
+    scan = _scan_stub(schema)
+    mode = E.AggMode.PARTIAL
+    node = N.Agg(scan, E.AggExecMode.HASH_AGG,
+                 [(k, E.Column(k)) for k in WIDE_KEYS[:nkeys]], [
+        N.AggColumn(E.AggExpr(E.AggFunction.SUM, [E.Column("v")]), mode, "s"),
+        N.AggColumn(E.AggExpr(E.AggFunction.COUNT, []), mode, "c"),
+        N.AggColumn(E.AggExpr(E.AggFunction.MIN, [E.Column("v")]), mode, "mn"),
+        N.AggColumn(E.AggExpr(E.AggFunction.MAX, [E.Column("v")]), mode, "mx")])
+    return DevicePartialAgger(build_operator(node), T.schema_from_arrow(schema),
+                              conf=Config(**{"radix_agg": False, **conf}))
+
+
+def _wide_batches(ranges, seed):
+    """``WIDE_ROWS`` batches over ``ranges`` with 2% null keys and null
+    values; the last holds one key past the first key's span."""
+    rng = np.random.default_rng(seed)
+    tables = []
+    for b, rows in enumerate(WIDE_ROWS):
+        cols = {}
+        for i, (anchor, span) in enumerate(ranges):
+            k = anchor + rng.integers(0, span, rows)
+            k[::50] = anchor + rng.integers(0, 3, len(k[::50]))  # groups > 1 row
+            if b == len(WIDE_ROWS) - 1 and i == 0:
+                k[17] = anchor + 2 * span
+            ks = k.astype(object)
+            ks[rng.random(rows) < 0.02] = None
+            cols[WIDE_KEYS[i]] = pa.array(list(ks), type=pa.int64())
+        v = rng.integers(-10**6, 10**6, rows).astype(object)
+        v[rng.random(rows) < 0.02] = None
+        cols["v"] = pa.array(list(v), type=pa.int64())
+        tables.append(pa.table(cols))
+    return tables
+
+
+def _oracle(table, nkeys):
+    """Numpy's partial state of one batch: groups nulls first, ascending."""
+    keys = [table[k].to_pylist() for k in WIDE_KEYS[:nkeys]]
+    vals = table["v"].to_pylist()
+    groups = {}
+    for row, v in zip(zip(*keys), vals):
+        g = groups.setdefault(row, [0, 0, None, None])
+        g[1] += 1
+        if v is not None:
+            g[0] += v
+            g[2] = v if g[2] is None else min(g[2], v)
+            g[3] = v if g[3] is None else max(g[3], v)
+    order = sorted(groups, key=lambda r: tuple((k is not None, k or 0)
+                                               for k in r))
+    return order, [groups[r] for r in order]
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_TABLES))
+def test_a_table_past_the_cap_is_slot_sorted_and_equals_the_sort_kernel(case):
+    """Without a radix table (the chip) a key space past
+    ``dense_agg_max_buckets`` is ONE wide slot table, slot-sorted, its id
+    int64 and its sizes traced, below 2^31 slots and past. Over nulls,
+    anchors far from zero
+    a batch with more groups than the one the table was planned on, and a
+    key outside the table that widens it once, each batch's partial state
+    is the sort kernel's row for row and numpy's."""
+    ranges = WIDE_TABLES[case]
+    nkeys = len(ranges)
+    agger = _wide_agger(nkeys, {})
+    sort_agger = _wide_agger(nkeys, {"dense_agg": False})
+    plans = []
+    for b, table in enumerate(_wide_batches(ranges, sum(map(ord, case)))):
+        batch = ColumnarBatch.from_arrow(table)
+        s0 = DEVICE_STATS.snapshot()
+        got = agger.process(batch).to_arrow()
+        s1 = DEVICE_STATS.snapshot()
+        assert s1["agg_slot_sorted_batches"] - s0["agg_slot_sorted_batches"] \
+            == s1["agg_dense_batches"] - s0["agg_dense_batches"] == 1
+        assert s1["agg_sort_batches"] == s0["agg_sort_batches"]
+        plans.append(agger._bucket_state)
+        want = sort_agger.process(batch).to_arrow()
+        assert got.to_pydict() == want.to_pydict(), b
+        order, states = _oracle(table, nkeys)
+        got = got.to_pydict()
+        assert list(zip(*(got[k] for k in WIDE_KEYS[:nkeys]))) == order
+        assert got["c#count"] == [s[1] for s in states]
+        assert [s if h else 0 for s, h in zip(got["s#sum"], got["s#has"])] \
+            == [s[0] for s in states]
+        assert [m if h else None for m, h in zip(got["mn#val"],
+                                                 got["mn#has"])] == \
+            [s[2] for s in states]
+        assert [m if h else None for m, h in zip(got["mx#val"],
+                                                 got["mx#has"])] == \
+            [s[3] for s in states]
+    (kind0, _, sizes0, _), second, (kind2, _, sizes2, _) = plans
+    assert second == plans[0] and kind0 == kind2 == "wide"
+    assert sizes2[0] > sizes0[0] and sizes2[1:] == sizes0[1:], "widened once"
+    slots = math.prod(sizes0)
+    assert slots > agger.conf.dense_agg_max_buckets
+    assert (slots >= 1 << 31) == ("past" in case)
+
+
+def test_an_id_past_62_bits_takes_the_sort_path():
+    """A table whose packed id would pass ``_SLOT_ID_MAX_SLOTS`` is refused:
+    the stream takes the sort kernel, exactly."""
+    from blaze_tpu.config import Config
+
+    conf = Config(radix_agg=False)
+    probe = np.array([(1, 0, (1 << 31) - 3), (1, 0, (1 << 30) - 3)])
+    bases, sizes, out_cap = A._plan_slot_table(
+        probe, 4096, None, A._SLOT_ID_MAX_SLOTS, conf)
+    assert sizes == (1 << 31, 1 << 30) and out_cap == 4096
+    probe[1, 2] = (1 << 31) - 3
+    assert A._plan_slot_table(probe, 4096, None, A._SLOT_ID_MAX_SLOTS,
+                              conf) is None
+    agger = _wide_agger(2, {})
+    s0 = DEVICE_STATS.snapshot()
+    out = agger.process(ColumnarBatch.from_arrow(pa.table({
+        "k1": pa.array([0, 1 << 40, 0], type=pa.int64()),
+        "k2": pa.array([0, 1 << 30, 0], type=pa.int64()),
+        "v": pa.array([1, 2, 3], type=pa.int64())})))
+    s1 = DEVICE_STATS.snapshot()
+    assert agger._bucket_state is None and agger._dense_ok is False
+    assert s1["agg_sort_batches"] - s0["agg_sort_batches"] == 1
+    assert s1["agg_dense_batches"] == s0["agg_dense_batches"]
+    got = out.to_arrow().to_pydict()
+    assert got["k1"] == [0, 1 << 40] and got["s#sum"] == [4, 2]
+
+
+def test_with_the_radix_gate_on_a_table_past_the_cap_plans_radix():
+    """Where ``radix_agg`` is on (the CPU backend's default) the planning is
+    the parent's: dense within the cap, radix up to ``radix_agg_max_slots``,
+    the sort kernel past it."""
+    agger = _wide_agger(2, {"radix_agg": True})
+    batch = _wide_batches(WIDE_TABLES["2key_under_2e31"], 5)[0]
+    out = agger.process(ColumnarBatch.from_arrow(batch))
+    assert agger._bucket_state[0] == "radix"
+    assert math.prod(agger._bucket_state[2]) == 32_768 * 16
+    order, _ = _oracle(batch, 2)
+    assert list(zip(*(out.to_arrow()[k].to_pylist() for k in ("k1", "k2")))) \
+        == order
+    wide = _wide_agger(2, {"radix_agg": True})
+    wide.process(ColumnarBatch.from_arrow(
+        _wide_batches(WIDE_TABLES["2key_past_2e31"], 5)[0]))
+    assert wide._bucket_state is None and wide._radix_ok is False
 
 
 def test_dense_agg_false_forces_the_sort_kernel():

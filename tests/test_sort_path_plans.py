@@ -1,11 +1,13 @@
 """q67's and q29's plans as the chip runs them, end to end on the CPU.
 
 On the CPU ``radix_agg`` defaults on and the aggregation goes through the
-radix slot table, so tier-1 otherwise never runs ``jit(agg_partial)`` /
-``jit(agg_merge)`` under a whole query (ROADMAP.md D12). Here it is off, as on
-the chip, over a cut-down ``tpcds_star`` data set whose (item, store) range
-refuses the slot table as SF1's does: every PARTIAL batch takes the sort
-path, the FINAL side merges on the device, and the answer is Acero's."""
+radix slot table, so tier-1 otherwise never runs the chip's aggregation
+under a whole query (ROADMAP.md D12). Here it is off, as on the chip, over a
+cut-down ``tpcds_star`` data set whose (item, store) range passes
+``dense_agg_max_buckets`` as SF1's does: every PARTIAL batch reduces by one
+sort of its packed slot id (``jit(agg_dense_partial)``, slot-sorted), or with
+``dense_agg`` off by the sort kernel (``jit(agg_partial)``); the FINAL side
+merges on the device (``jit(agg_merge)``), and the answer is Acero's."""
 
 import dataclasses
 
@@ -39,9 +41,10 @@ def star(registry, tmp_path_factory):
                          config["shuffle_partitions"])
 
 
+@pytest.mark.parametrize("dense_agg", [None, False])
 @pytest.mark.parametrize("query", sorted(CONFIGS))
 def test_the_chips_plan_takes_the_sort_path_and_answers_as_acero(
-        query, registry, star):
+        query, dense_agg, registry, star):
     from blaze_tpu.config import get_config
     from blaze_tpu.ops.joins.bhj import clear_build_cache
     from blaze_tpu.runtime.session import Session
@@ -50,7 +53,8 @@ def test_the_chips_plan_takes_the_sort_path_and_answers_as_acero(
     cls = registry.module("queries", query)
     overrides = registry.data("configs", CONFIGS[query])["session"]["conf"]
     conf = dataclasses.replace(get_config(), radix_agg=False,
-                               fused_filter_agg=False, **overrides)
+                               fused_filter_agg=False, dense_agg=dense_agg,
+                               **overrides)
     want = plans.rows_of(cls.reference({t: star.table(t) for t in cls.TABLES}),
                          cls.REFERENCE_COLUMNS, cls.ORDERED)
     session = Session(conf=conf)
@@ -64,6 +68,13 @@ def test_the_chips_plan_takes_the_sort_path_and_answers_as_acero(
         clear_build_cache()
     assert plans.rows_of(got, cls.ENGINE_COLUMNS, cls.ORDERED) == want
     assert len(want) > 100 or query == "q29"  # q29 keeps the first 100 groups
-    assert after["agg_sort_batches"] - before["agg_sort_batches"] > 0
-    assert after["agg_dense_batches"] == before["agg_dense_batches"]
+    delta = {k: after[k] - before[k] for k in (
+        "agg_sort_batches", "agg_dense_batches", "agg_slot_sorted_batches")}
+    if dense_agg is None:
+        assert delta["agg_slot_sorted_batches"] == \
+            delta["agg_dense_batches"] > 0
+        assert delta["agg_sort_batches"] == 0
+    else:
+        assert delta["agg_sort_batches"] > 0
+        assert delta["agg_dense_batches"] == 0
     assert merged["device_merge_batches"] >= 1
