@@ -13,6 +13,8 @@ from typing import List, Tuple
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import types as T
 
+_INTEGRAL = (T.Int8Type, T.Int16Type, T.Int32Type, T.Int64Type)
+
 
 def avg_sum_type(arg_t: T.DataType) -> T.DataType:
     """The type AVG carries its sum in. A decimal's is Spark's (p + 10). An
@@ -24,9 +26,26 @@ def avg_sum_type(arg_t: T.DataType) -> T.DataType:
     what Spark computes. Floats sum as double."""
     if isinstance(arg_t, T.DecimalType):
         return T.DecimalType(min(arg_t.precision + 10, 38), arg_t.scale)
-    if isinstance(arg_t, (T.Int8Type, T.Int16Type, T.Int32Type, T.Int64Type)):
+    if isinstance(arg_t, _INTEGRAL):
         return T.I64
     return T.F64
+
+
+def moment_state_fields(arg_t: T.DataType) -> List[Tuple[str, T.DataType]]:
+    """STDDEV_SAMP's state. An INTEGRAL argument keeps exact integer
+    moments: the count n, Σx as the three limbs a wide-decimal SUM keeps
+    (two 32-bit chunks and a signed high word: exact to 2^127) and Σx² as
+    four (three chunks and a high word: x² of a long takes 126 bits, so
+    three limbs would wrap at two rows of the largest longs; four are exact
+    for any long over fewer than 2^32 rows a group). They live on a device
+    without float64 and finish once a group, exactly, on the host
+    (ops/aggfns.MomentAgg). Any other argument keeps Spark's CentralMomentAgg
+    state in double (n, mean, m2), on the host."""
+    if isinstance(arg_t, _INTEGRAL):
+        return [("mom_n", T.I64), ("mom_s0", T.I64), ("mom_s1", T.I64),
+                ("mom_s2", T.I64), ("mom_q0", T.I64), ("mom_q1", T.I64),
+                ("mom_q2", T.I64), ("mom_q3", T.I64)]
+    return [("mom_n", T.F64), ("mom_avg", T.F64), ("mom_m2", T.F64)]
 
 
 def limb_layout(result_t: T.DataType) -> bool:
@@ -176,6 +195,8 @@ def agg_state_fields(fn: E.AggFunction, arg_t: T.DataType,
         return [("sum", result_t), ("has", T.BOOL)]
     if fn == F.COUNT:
         return [("count", T.I64)]
+    if fn == F.STDDEV_SAMP:
+        return moment_state_fields(arg_t)
     if fn == F.AVG:
         sum_t = avg_sum_type(arg_t)
         # wide-decimal AVG rides the same limb layouts as SUM: two limbs

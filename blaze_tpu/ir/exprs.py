@@ -267,6 +267,8 @@ class AggFunction(enum.Enum):
     BRICKHOUSE_COLLECT = "brickhouse_collect"
     BRICKHOUSE_COMBINE_UNIQUE = "brickhouse_combine_unique"
     UDAF = "udaf"
+    # Spark's StddevSamp (CentralMomentAgg): a DOUBLE, NULL below two rows
+    STDDEV_SAMP = "stddev_samp"
 
 
 class AggMode(enum.Enum):
@@ -387,6 +389,8 @@ def common_type(lt: T.DataType, rt: T.DataType) -> T.DataType:
 def agg_result_type(fn: AggFunction, arg_t: T.DataType) -> T.DataType:
     if fn == AggFunction.COUNT:
         return T.I64
+    if fn == AggFunction.STDDEV_SAMP:
+        return T.F64
     if fn == AggFunction.AVG:
         if isinstance(arg_t, T.DecimalType):
             # Spark: avg(decimal(p,s)) -> decimal(p+4, s+4) bounded

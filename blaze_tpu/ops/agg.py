@@ -31,6 +31,7 @@ from blaze_tpu.ir import types as T
 from blaze_tpu.ops import aggfns
 from blaze_tpu.ops.base import ExecContext, Operator
 from blaze_tpu.runtime.memmgr import MemConsumer, SpillFile
+from blaze_tpu.utils.device import DEVICE_STATS
 
 _KEY_COL = "#aggkey"
 
@@ -610,6 +611,15 @@ class _PartialSkipper:
         return table.num_slots / max(table.rows_processed, 1) > self.ratio
 
 
+def _adopt_moments(fns, metrics) -> bool:
+    """Hands the STDDEV_SAMP functions among ``fns`` the node their finish
+    counts on; whether there is one."""
+    moments = [fn for fn in fns if isinstance(fn, aggfns.MomentAgg)]
+    for fn in moments:
+        fn.metrics = metrics
+    return bool(moments)
+
+
 class AggTable(MemConsumer):
     def __init__(self, op: AggExec, child_schema: T.Schema, ctx: ExecContext, metrics):
         super().__init__("AggTable", spillable=True)
@@ -640,6 +650,7 @@ class AggTable(MemConsumer):
         self.spills: List[SpillFile] = []
         self.rows_processed = 0
         self.row_order = 0
+        self._moments = _adopt_moments(self.fns, metrics)
 
     def _reset(self):
         self.key_map = {}
@@ -906,6 +917,9 @@ class AggTable(MemConsumer):
         # the host table groups a coded key through its dictionary as it
         # groups any dictionary-encoded host column (_host_key_plane)
         batch = batch.coded_to_host(self.metrics)
+        if self._moments:
+            self.metrics.add("moment_host_batches", 1)
+            DEVICE_STATS.add_moment(host_batches=1)
         self.rows_processed += n
         cols = self._grouping_columns(batch)
         slots_np = self._intern_keys(batch, cols)

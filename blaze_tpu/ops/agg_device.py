@@ -54,7 +54,8 @@ def _radix_counter():
     return _TM_RADIX
 
 _DEVICE_AGG_FNS = (E.AggFunction.SUM, E.AggFunction.COUNT, E.AggFunction.AVG,
-                   E.AggFunction.MIN, E.AggFunction.MAX)
+                   E.AggFunction.MIN, E.AggFunction.MAX,
+                   E.AggFunction.STDDEV_SAMP)
 
 # jitted fused (filter+partial-agg) kernels, shared across agger instances
 _FUSED_KERNELS = {}
@@ -510,6 +511,9 @@ class DevicePartialAgger:
                 kind, rescale, acc_dt = "avg3", 0, ""
             elif kind in ("min", "max") and lm == "w":
                 kind, rescale, acc_dt = kind + "w", 0, ""
+            elif kind == "stddev_samp":
+                # an integral argument's exact moments (aggfns.MomentAgg)
+                kind, rescale, acc_dt = "moment", 0, "int64"
             elif kind == "sum":
                 acc_dt = "int64" if isinstance(fn.result_type, T.DecimalType) \
                     else str(np.dtype(fn.result_type.np_dtype))
@@ -519,6 +523,7 @@ class DevicePartialAgger:
             else:
                 acc_dt = ""
             self.specs.append((kind, rescale, acc_dt))
+        self._moments = any(kind == "moment" for kind, _r, _d in self.specs)
 
     # the slot-table state of the signature in hand
     _dense_ok = property(lambda self: self._table_state.dense_ok,
@@ -990,10 +995,17 @@ class DevicePartialAgger:
         DEVICE_STATS.add_agg_batch(dense=False)
         return wait_int(outs[0], "agg_partial")
 
+    def _note_moments(self):
+        """A batch whose moment state a kernel of this agger reduces (every
+        path of ``process`` and ``passthrough`` is one)."""
+        if self._moments:
+            _note_moment_batches(self.metrics, 1)
+
     def process(self, batch: ColumnarBatch) -> Optional[ColumnarBatch]:
         n = batch.num_rows
         if n == 0:
             return None
+        self._note_moments()
         if self.fused_joins and \
                 not all(s.batch_eligible(batch) for s in self.fused_joins):
             # non-flattenable probe batch: run the joins for real
@@ -1064,6 +1076,7 @@ class DevicePartialAgger:
         n = batch.num_rows
         if n == 0:
             return None
+        self._note_moments()
         exists = batch.row_exists_mask()
         self.group_ev._reset_cse(batch)
         for ev in self.agg_evs:
@@ -1130,6 +1143,11 @@ class DevicePartialAgger:
                 cols.append(DeviceColumn(fn.result_type, v, has & out_valid_mask))
                 cols.append(DeviceColumn(T.BOOL, has, out_valid_mask))
                 ci += 2
+            elif kind == "moment":
+                cols.extend(DeviceColumn(T.I64, p, out_valid_mask)
+                            for p in outs[pos:pos + _MOMENT_PLANES])
+                pos += _MOMENT_PLANES
+                ci += _MOMENT_PLANES
             elif kind in _WIDE_KINDS:
                 a0, a1, a2, last = outs[pos:pos + 4]; pos += 4
                 cols.append(DeviceColumn(T.I64, a0, out_valid_mask))
@@ -1140,6 +1158,17 @@ class DevicePartialAgger:
                     out_valid_mask))
                 ci += 4
         return ColumnarBatch(schema, cols, num_groups)
+
+
+# the moment state's planes: the count, Σx's three limbs, Σx²'s four
+_MOMENT_PLANES = 8
+
+
+def _note_moment_batches(metrics, batches: int):
+    """``moment_device_batches``: on the operator's node and the process's."""
+    if metrics is not None:
+        metrics.add("moment_device_batches", batches)
+    DEVICE_STATS.add_moment(device_batches=batches)
 
 
 def _key_ranges(mask, keys):
@@ -1298,6 +1327,11 @@ def _partial_requests(spec, arg, exists):
     sv = sv & exists
     tail = ("count" if kind.startswith("avg") else "any", sv)
     z = jnp.int64(0)
+    if kind == "moment":
+        from blaze_tpu.ops.aggfns import moment_row_planes
+
+        return [("count", sv)] + [("add", jnp.where(sv, p, z))
+                                  for p in moment_row_planes(sa)]
     if kind in ("sum3", "avg3"):
         return [("add", jnp.where(sv, p, z)) for p in sa] + [tail]
     if kind in ("minw", "maxw"):
@@ -1326,7 +1360,7 @@ def _merge_requests(kind: str, scols, exists):
     def add(d, m):
         return ("add", jnp.where(m, d, jnp.zeros((), d.dtype)))
 
-    if kind in ("count", "avg"):  # (count) / (sum, count): each its own mask
+    if kind in ("count", "avg", "moment"):  # each plane its own mask
         return [add(d, v) for d, v in cols]
     # every other state ends in a column that says whether (sum, min, max,
     # the wide kinds) or how many (avg2, avg3) rows it holds
@@ -1354,6 +1388,10 @@ def _finish_state(kind: str, reduced):
 
         s0, s1, s2, last = reduced
         return [*_limb3_renorm(s0, s1, s2), last]
+    if kind == "moment":
+        from blaze_tpu.ops.aggfns import moment_renorm
+
+        return moment_renorm(*reduced)
     if kind in ("min", "max"):
         acc, has = reduced
         return [jnp.where(has, acc, jnp.zeros((), acc.dtype)), has]
@@ -1684,6 +1722,11 @@ def _reduce_aggs(specs, args, seg, nseg_total):
             b0, b1, b2, shas = _segment_lex3(p0, p1, p2, sv, seg,
                                              nseg_total, kind == "maxw")
             outs.append((kind, b0, b1, b2, shas))
+        elif kind == "moment":
+            from blaze_tpu.ops.aggfns import moment_renorm, moment_row_planes
+
+            outs.append((kind, *moment_renorm(count(sv), *(
+                add(p, sv) for p in moment_row_planes(sa)))))
         elif kind in ("sum2", "avg2"):
             # wide-decimal sum as two int64 limbs (lo 32 bits, hi rest):
             # per-segment limb sums fit int64 for any capacity, totals
@@ -1909,6 +1952,13 @@ def _merge_reduce(kinds, states, seg, CAP):
                 jnp.where(m, sd, jnp.zeros((), sd.dtype)), mode="drop")
             shas = jnp.zeros(CAP, bool).at[seg].max(m, mode="drop")
             outs.append((ssum, shas))
+        elif kind == "moment":
+            from blaze_tpu.ops.aggfns import moment_renorm
+
+            outs.append(tuple(moment_renorm(*(
+                jnp.zeros(CAP, jnp.int64).at[seg].add(
+                    jnp.where(v, d, jnp.int64(0)), mode="drop")
+                for d, v in scols))))
         elif kind == "count":
             (cd, cv), = scols
             scnt = jnp.zeros(CAP, jnp.int64).at[seg].add(
@@ -2054,7 +2104,7 @@ class DeviceMergeAgger:
 
     _KINDS = {E.AggFunction.SUM: "sum", E.AggFunction.COUNT: "count",
               E.AggFunction.AVG: "avg", E.AggFunction.MIN: "min",
-              E.AggFunction.MAX: "max"}
+              E.AggFunction.MAX: "max", E.AggFunction.STDDEV_SAMP: "moment"}
 
     def __init__(self, op, child_schema: T.Schema, conf=None, metrics=None):
         from blaze_tpu.config import get_config
@@ -2064,6 +2114,9 @@ class DeviceMergeAgger:
         self.conf = conf or get_config()
         self.metrics = metrics
         self.fns = op._make_fns(child_schema)
+        from blaze_tpu.ops.agg import _adopt_moments
+
+        _adopt_moments(self.fns, metrics)
 
         def kind_of(a, fn):
             lm = getattr(fn, "limbs", False)
@@ -2083,6 +2136,8 @@ class DeviceMergeAgger:
         batches = [b for b in batches if b.num_rows]
         if not batches:
             return []
+        if "moment" in self.kinds:
+            _note_moment_batches(self.metrics, len(batches))
         big = ColumnarBatch.concat(batches, self.child_schema, self.metrics)
         ev = ExprEvaluator([e for _, e in op.groupings], big.schema,
                            self.metrics)
@@ -2144,7 +2199,7 @@ class DeviceMergeAgger:
         for a, fn, kind in zip(op.aggs, self.fns, self.kinds):
             nstate = {"sum": 2, "sum2": 3, "count": 1, "avg": 2, "avg2": 3,
                       "sum3": 4, "avg3": 4, "minw": 4, "maxw": 4,
-                      "min": 2, "max": 2}[kind]
+                      "min": 2, "max": 2, "moment": _MOMENT_PLANES}[kind]
             state = list(outs[p:p + nstate])
             p += nstate
             if final:

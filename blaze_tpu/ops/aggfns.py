@@ -129,6 +129,88 @@ def _limb3_renorm(l0, l1, l2):
     return l0, l1, l2 + c1
 
 
+def _chunks_renorm(*limbs):
+    """:func:`_limb3_renorm` for any number of limbs: every limb but the
+    last back in [0, 2^32), the carries moved up into the last."""
+    out, carry = [], None
+    for limb in limbs[:-1]:
+        if carry is not None:
+            limb = limb + carry
+        carry = limb >> 32
+        out.append(limb & jnp.int64(0xFFFFFFFF))
+    return out + [limbs[-1] + carry]
+
+
+def moment_row_planes(x):
+    """A long's seven planes of the exact moment state (ir/aggstate
+    ``moment_state_fields``) before any reduction: x's limbs (two 32-bit
+    chunks and the sign) and x²'s (three chunks and the rest). |x| as a
+    uint64 holds every long's magnitude, 2^63 too, and its halves a < 2^32,
+    b <= 2^31 multiply exactly in uint64: x² = a² + 2ab·2^32 + b²·2^64, so
+    every plane is exact, none is negative but the sign, and none passes
+    2^34 — a batch's segment sums stay far inside int64."""
+    x = x.astype(jnp.int64)
+    chunk = jnp.int64(0xFFFFFFFF)
+    u = jnp.abs(x).astype(jnp.uint64)
+    a, b = u & jnp.uint64(0xFFFFFFFF), u >> jnp.uint64(32)
+    aa, ab, bb = ((p * q).astype(jnp.int64) for p, q in ((a, a), (a, b), (b, b)))
+    logical = jax.lax.shift_right_logical
+    return [x & chunk, (x >> 32) & chunk, x >> 63,
+            aa & chunk, logical(aa, jnp.int64(32)) + ((ab & chunk) << 1),
+            ((ab >> 32) << 1) + (bb & chunk), bb >> 32]
+
+
+def moment_renorm(n, s0, s1, s2, q0, q1, q2, q3):
+    """The moment state's limb invariant again after a reduction: the count
+    as it is, Σx's and Σx²'s chunks back in [0, 2^32)."""
+    return [n, *_limb3_renorm(s0, s1, s2), *_chunks_renorm(q0, q1, q2, q3)]
+
+
+_EXACT_DOUBLE = 1 << 53   # every integer below it is a double
+_SMALL_MOMENT = 1 << 26   # n, |Σx|, Σx² under it: N and D stay under 2^53
+
+
+def moment_stddev(packed: np.ndarray):
+    """STDDEV_SAMP of each group from its exact moments (``packed``: the
+    eight state planes as int64 rows, one column a group) -> (values,
+    valid, groups finished as a Fraction). With N = n·Σx² - (Σx)² and
+    D = n·(n - 1) the variance is N / D rounded once: where both are under
+    2^53 one IEEE division of two exact doubles, which is that rounding;
+    elsewhere ``float(Fraction(N, D))`` by Python integers, and those groups
+    are counted. The result is the square root of it, correctly rounded, so
+    it may differ from Spark's running double in the last bits. NULL under
+    two rows (Spark 3.1+: ``spark.sql.legacy.statisticalAggregate`` false).
+    Groups whose n, |Σx| and Σx² are under 2^26 go as int64 arrays."""
+    import math
+    from fractions import Fraction
+
+    n, s0, s1, s2, q0, q1, q2, q3 = packed
+    valid = n > 1
+    chunk = (1 << 32) - 1
+    small_s = ((s2 == 0) & (s1 == 0) & (s0 < _SMALL_MOMENT)) | (
+        (s2 == -1) & (s1 == chunk) & (s0 >= (1 << 32) - _SMALL_MOMENT))
+    small = valid & small_s & (q3 == 0) & (q2 == 0) & (q1 == 0) & \
+        (q0 < _SMALL_MOMENT) & (n < _SMALL_MOMENT)
+    s = np.where(s2 == 0, s0, s0 - (1 << 32))
+    num = np.where(small, n * q0 - s * s, 0)
+    den = np.where(small, n * (n - 1), 1)
+    out = np.sqrt(num.astype(np.float64) / den.astype(np.float64))
+    exact = 0
+    for i in np.flatnonzero(valid & ~small).tolist():
+        total = int(s0[i]) + (int(s1[i]) << 32) + (int(s2[i]) << 64)
+        squares = int(q0[i]) + (int(q1[i]) << 32) + (int(q2[i]) << 64) + \
+            (int(q3[i]) << 96)
+        count = int(n[i])
+        num_i, den_i = count * squares - total * total, count * (count - 1)
+        if num_i < _EXACT_DOUBLE and den_i < _EXACT_DOUBLE:
+            var = float(num_i) / float(den_i)
+        else:
+            var = float(Fraction(num_i, den_i))
+            exact += 1
+        out[i] = math.sqrt(var)
+    return np.where(valid, out, 0.0), valid, exact
+
+
 def _wide_value_limbs(arr: pa.Array):
     """decimal128 array -> (l0, l1, l2, validity) numpy planes: l0/l1 the
     low/high 32-bit chunks of the unsigned low word (nonnegative int64),
@@ -829,6 +911,112 @@ class MinMaxAgg(AggFunction):
         return super().mem_used(state)
 
 
+class MomentAgg(AggFunction):
+    """STDDEV_SAMP. Over an INTEGRAL argument the state is the exact moments
+    (ir/aggstate ``moment_state_fields``): device arrays here, and the same
+    planes in the device kernels' ``moment`` kind (ops/agg_device), so the
+    two stages and the exchange between them see one layout wherever each
+    ran; the group finishes once, on the host (:func:`moment_stddev`). Any
+    other argument keeps Spark's (n, mean, m2) in double on the host: a
+    batch's per-group moments by two passes, merged as Spark merges two
+    states (CentralMomentAgg), so it can differ from Spark's row-by-row
+    update in the last bits."""
+
+    def __init__(self, agg, arg_type, result_type):
+        super().__init__(agg, arg_type, result_type)
+        from blaze_tpu.ir.aggstate import _INTEGRAL, moment_state_fields
+
+        self.exact = isinstance(arg_type, _INTEGRAL)
+        self.host = not self.exact
+        self._fields = moment_state_fields(arg_type)
+        self.metrics = None  # the operator's node, set by the table using it
+
+    def state_fields(self):
+        return list(self._fields)
+
+    def init_state(self, capacity):
+        if self.exact:
+            return [jnp.zeros(capacity, jnp.int64) for _ in self._fields]
+        return [np.zeros(capacity, np.float64) for _ in self._fields]
+
+    def update(self, state, slots, value, validity, mask, order=None):
+        if self.exact:
+            m = validity & mask
+            z = jnp.int64(0)
+            planes = [m.astype(jnp.int64)] + [
+                jnp.where(m, p, z) for p in moment_row_planes(value)]
+            return moment_renorm(*(acc.at[slots].add(p, mode="drop")
+                                   for acc, p in zip(state, planes)))
+        x, valid = _arr_np(value.cast(pa.float64(), safe=False), np.float64)
+        m = valid & mask
+        return _moment_merge(state, *_moment_parts(slots[m], x[m],
+                                                   len(state[0])))
+
+    def merge(self, state, slots, partial_cols, mask, n):
+        if self.exact:
+            m = partial_cols[0].validity & mask
+            z = jnp.int64(0)
+            return moment_renorm(*(
+                acc.at[slots].add(jnp.where(m, p.data, z), mode="drop")
+                for acc, p in zip(state, partial_cols)))
+        (cn, _), (mean, _), (m2, _) = (_col_np(c, n, np.float64)
+                                       for c in partial_cols)
+        m = np.asarray(mask)[:n] & (cn > 0)
+        sl, cn, mean, m2 = slots[:n][m], cn[m], mean[m], m2[m]
+        cap = len(state[0])
+        # the parts of one slot first, then the slot's state and their sum
+        tot = np.bincount(sl, weights=cn, minlength=cap)
+        avg = np.divide(np.bincount(sl, weights=cn * mean, minlength=cap),
+                        tot, out=np.zeros(cap), where=tot > 0)
+        dev = np.bincount(sl, weights=m2 + cn * (mean - avg[sl]) ** 2,
+                          minlength=cap)
+        return _moment_merge(state, tot, avg, dev)
+
+    def state_columns(self, state, num_slots, capacity):
+        grown = self.grow(state, capacity)
+        if self.exact:
+            ones = jnp.ones(capacity, bool)
+            return [DeviceColumn(T.I64, g, ones) for g in grown]
+        ones = np.ones(num_slots, bool)
+        return [_host_col_out(T.F64, g[:num_slots], ones) for g in grown]
+
+    def final_column(self, state, num_slots, capacity):
+        if not self.exact:
+            n, _mean, m2 = (s[:num_slots] for s in state)
+            valid = n > 1
+            out = np.sqrt(np.where(valid, m2, 0.0) / np.where(valid, n - 1, 1))
+            return _host_col_out(T.F64, out, valid)
+        from blaze_tpu.obs.tracer import TRACER
+        from blaze_tpu.utils.device import DEVICE_STATS, wait_array
+
+        packed = wait_array(jnp.stack([p[:num_slots] for p in state]),
+                            "agg_moment_state")
+        with TRACER.detail("moment_finish", "agg", {"groups": num_slots}):
+            out, valid, exact = moment_stddev(packed)
+        DEVICE_STATS.add_moment(exact_groups=exact)
+        if exact and self.metrics is not None:
+            self.metrics.add("moment_exact_groups", exact)
+        return _host_col_out(T.F64, out, valid)
+
+
+def _moment_parts(slots, x, capacity):
+    """(n, mean, m2) a slot of one batch's valid values, by two passes."""
+    n = np.bincount(slots, minlength=capacity).astype(np.float64)
+    mean = np.divide(np.bincount(slots, weights=x, minlength=capacity), n,
+                     out=np.zeros(capacity), where=n > 0)
+    m2 = np.bincount(slots, weights=(x - mean[slots]) ** 2, minlength=capacity)
+    return n, mean, m2
+
+
+def _moment_merge(state, n2, avg2, m2_2):
+    """Spark's CentralMomentAgg merge of two (n, mean, m2) states."""
+    n1, avg1, m2_1 = state
+    n = n1 + n2
+    delta = avg2 - avg1
+    delta_n = np.divide(delta, n, out=np.zeros_like(n), where=n > 0)
+    return [n, avg1 + delta_n * n2, m2_1 + m2_2 + delta * delta_n * n1 * n2]
+
+
 class FirstAgg(AggFunction):
     """FIRST / FIRST_IGNORES_NULL: winner = smallest global row order; two
     scatter passes (order min, then conditional value write)."""
@@ -1174,4 +1362,6 @@ def create_agg_function(agg: E.AggExpr, input_schema: T.Schema,
         return BloomFilterAgg(agg, arg_t, result_t)
     if agg.fn == F.UDAF:
         return UDAFAgg(agg, arg_t, result_t)
+    if agg.fn == F.STDDEV_SAMP:
+        return MomentAgg(agg, arg_t, result_t)
     raise NotImplementedError(f"agg function {agg.fn}")

@@ -244,6 +244,17 @@ class MetricNode:
 #                                    batches that left jit(bhj_inner_fast)
 #                                    (device_inner_batches counts its own)
 #                                    for the generic probe
+#   moment_device_batches >= 1       STDDEV_SAMP over an integral argument:
+#                                    aggregation batches (partial or merge)
+#                                    whose exact moments a device kernel
+#                                    reduced (jit(agg_dense_partial),
+#                                    jit(agg_partial), jit(agg_merge))
+#   moment_host_batches == 0         ... none went through the generic table
+#                                    (a DOUBLE or decimal argument's double
+#                                    state always does)
+#   moment_exact_groups              groups whose finish passed 2^53 and went
+#                                    by Python integers (ops/aggfns
+#                                    moment_stddev)
 TRIPWIRE_METRICS = (
     "split_batches",
     "split_gathers",
@@ -280,6 +291,9 @@ TRIPWIRE_METRICS = (
     "dict_entries",
     "dict_remap_rows",
     "join_generic_batches",
+    "moment_device_batches",
+    "moment_host_batches",
+    "moment_exact_groups",
 )
 
 

@@ -30,9 +30,10 @@ class DeviceStats:
     bytes and calls, blocking syncs (``sync_calls``: the wait itself is the
     ``sync:*`` span of :func:`wait_int`, the benchmark's ``device_wait_s``),
     jitted-kernel dispatches and which PARTIAL aggregation kernel answered a
-    batch, in which form. Surfaced at /debug/device; the benchmark reads the
-    deltas a query (``h2d_mb``, ``d2h_mb``, ``sync_points``,
-    ``agg_dense_batches``, ``agg_slot_sorted_batches``)."""
+    batch, in which form, and where STDDEV_SAMP's moments were reduced and
+    finished. Surfaced at /debug/device; the benchmark reads the deltas a
+    query (``h2d_mb``, ``d2h_mb``, ``sync_points``, ``agg_dense_batches``,
+    ``agg_slot_sorted_batches``, ``moment_device_batches``)."""
 
     def __init__(self):
         self._mu = threading.Lock()
@@ -52,6 +53,9 @@ class DeviceStats:
             self.agg_dense_batches = 0
             self.agg_slot_sorted_batches = 0
             self.agg_sort_batches = 0
+            self.moment_device_batches = 0
+            self.moment_host_batches = 0
+            self.moment_exact_groups = 0
 
     def add_to_host(self, nbytes: int):
         """One blocking pull of ``nbytes``: a transfer and a sync."""
@@ -90,6 +94,19 @@ class DeviceStats:
             else:
                 self.agg_sort_batches += 1
 
+    def add_moment(self, device_batches: int = 0, host_batches: int = 0,
+                   exact_groups: int = 0):
+        """STDDEV_SAMP's moments: aggregation batches (partial or merge)
+        whose moment state a device kernel reduced, those the generic table
+        reduced (a DOUBLE or decimal argument's double state, or any table
+        the device path declined), and groups whose finish went by Python
+        integers (``ops/aggfns.moment_stddev``). Benchmark:
+        ``moment_device_batches``."""
+        with self._mu:
+            self.moment_device_batches += device_batches
+            self.moment_host_batches += host_batches
+            self.moment_exact_groups += exact_groups
+
     def add_mapped(self, nbytes: int):
         """Bytes entering device arrays from MAPPED shuffle segments —
         buffers handed to jax straight off an mmap/registry view with no
@@ -119,6 +136,9 @@ class DeviceStats:
                 "agg_dense_batches": self.agg_dense_batches,
                 "agg_slot_sorted_batches": self.agg_slot_sorted_batches,
                 "agg_sort_batches": self.agg_sort_batches,
+                "moment_device_batches": self.moment_device_batches,
+                "moment_host_batches": self.moment_host_batches,
+                "moment_exact_groups": self.moment_exact_groups,
             }
 
 
