@@ -1467,7 +1467,8 @@ def _reduce_ordered(exists, iota, order, word, row_keys, sorted_keys, kinds,
     positions (the caller's, made before its order, so that the sort path's
     programs stay equation for equation what they were). Its suppliers are ``lex_order_traced`` over any keys
     (:func:`_aggregate_sorted`) and ONE sort of the packed slot id where the
-    keys fit a slot table (``jit(agg_dense_partial)`` above its crossover).
+    keys fit a slot table (``jit(agg_dense_partial)`` above its crossover,
+    ``jit(agg_merge_sorted)``).
     ``row_keys`` are planes by row and ``sorted_keys`` planes by position
     that say which group a row is in; both come back a group.
 
@@ -2040,6 +2041,17 @@ def _radix_merge_kernel(key_dtypes: Tuple[str, ...], kinds: Tuple[str, ...],
     return jax.jit(agg_radix_merge)
 
 
+def _merge_planes(flat, nk: int, state_dtypes):
+    """A merge kernel's flat arguments -> (key data, key validity, every
+    aggregate's (data, valid) state columns)."""
+    states, pos = [], 2 * nk
+    for dts in state_dtypes:
+        states.append([(flat[pos + 2 * j], flat[pos + 2 * j + 1])
+                       for j in range(len(dts))])
+        pos += 2 * len(dts)
+    return list(flat[0:2 * nk:2]), list(flat[1:2 * nk:2]), states
+
+
 @functools.lru_cache(maxsize=256)
 def _merge_kernel(key_dtypes: Tuple[str, ...], kinds: Tuple[str, ...],
                   state_dtypes: Tuple[Tuple[str, ...], ...], capacity: int):
@@ -2049,25 +2061,57 @@ def _merge_kernel(key_dtypes: Tuple[str, ...], kinds: Tuple[str, ...],
     table). The partial kernel's body (:func:`_aggregate_sorted`) over
     state reductions (:func:`_merge_requests`): sum (sum,has), count (count),
     avg (sum,count), min/max (val,has), the wide kinds their limbs."""
-    nk = len(key_dtypes)
-
     def agg_merge(exists, *flat):
-        key_data = [flat[2 * i] for i in range(nk)]
-        key_valid = [flat[2 * i + 1] for i in range(nk)]
-        pos = 2 * nk
-        states = []
-        for dts in state_dtypes:
-            cols = []
-            for _ in dts:
-                cols.append((flat[pos], flat[pos + 1]))
-                pos += 2
-            states.append(cols)
+        key_data, key_valid, states = _merge_planes(flat, len(key_dtypes),
+                                                    state_dtypes)
         return _aggregate_sorted(
             exists, key_data, key_valid, kinds,
             [_merge_requests(kind, cols, exists)
              for kind, cols in zip(kinds, states)])
 
     return jax.jit(agg_merge)
+
+
+@functools.lru_cache(maxsize=256)
+def _slot_merge_kernel(key_dtypes: Tuple[str, ...], kinds: Tuple[str, ...],
+                       state_dtypes: Tuple[Tuple[str, ...], ...],
+                       capacity: int):
+    """FINAL/PARTIAL_MERGE over integer keys whose observed ranges pack into
+    one int64 slot id (the partial side's wide table, :func:`_wide_table`'s
+    traced description as ``table``): ONE sort of the id
+    (:func:`_sort_slot_ids`) in place of ``lex_order_traced``'s ranking of
+    every key word, then :func:`_reduce_ordered` over
+    :func:`_merge_requests`. The id orders rows as ``_merge_kernel``'s
+    order does (per key the null's code 0, then the values ascending, key 0
+    most significant; ties in row order), so the outputs are that kernel's
+    bit for bit, float sums included, at the same capacity. A key
+    reconstructs from its group's id. One program a key type and capacity
+    serves every plan; a key outside the table reads ``num_groups`` -1."""
+    def agg_merge_sorted(exists, table, *flat):
+        key_data, key_valid, states = _merge_planes(flat, len(key_dtypes),
+                                                    state_dtypes)
+        bases, sizes, shifts = table
+        with jax.named_scope("pack"):
+            seg, fits = K.radix_pack(key_data, key_valid, exists, bases, sizes,
+                                     jnp.int64(1) << shifts, _WIDE_SENTINEL)
+        iota = jnp.arange(capacity, dtype=jnp.int32)
+        with jax.named_scope("order"):
+            slot, order = _sort_slot_ids(seg, iota)
+        num_groups, out_valid, (slot,), merged = _reduce_ordered(
+            exists, iota, order, slot, [], [slot], kinds,
+            [_merge_requests(kind, cols, exists)
+             for kind, cols in zip(kinds, states)])
+        outs = [jnp.where(fits, num_groups, jnp.int64(-1)), out_valid]
+        with jax.named_scope("emit"):
+            for i, kdt in enumerate(key_dtypes):
+                # past the groups the id reads 0: a null key's code
+                code = (slot >> shifts[i]) & (sizes[i] - 1)
+                outs += [jnp.where(code > 0, bases[i] + code - 1,
+                                   jnp.int64(0)).astype(jnp.dtype(kdt)),
+                         code > 0]
+        return tuple(outs + merged)
+
+    return jax.jit(agg_merge_sorted)
 
 
 def supports_device_merge(op, child_schema: T.Schema) -> bool:
@@ -2162,29 +2206,9 @@ class DeviceMergeAgger:
                 dts.append(str(col.data.dtype))
                 pos += 1
             state_dtypes.append(tuple(dts))
-        capacity = big.capacity
-        outs = None
-        radix = self._radix_plan(flat, exists, key_dtypes, capacity)
-        if radix is not None:
-            bases, sizes, out_cap = radix
-            kernel = _radix_merge_kernel(
-                tuple(key_dtypes), self.kinds, tuple(state_dtypes),
-                capacity, sizes, out_cap)
-            outs = kernel(exists, jnp.asarray(np.asarray(bases, np.int64)),
-                          *flat)
-            num_groups = wait_int(outs[0], "agg_merge")
-            if num_groups < 0:
-                # probe/pack disagreement (shouldn't happen: the plan comes
-                # from a probe over this very data) — sort fallback
-                outs = None
-            else:
-                capacity = out_cap
-                self._note_radix(sizes)
-        if outs is None:
-            kernel = _merge_kernel(tuple(key_dtypes), self.kinds,
-                                   tuple(state_dtypes), big.capacity)
-            outs = kernel(exists, *flat)
-            num_groups = wait_int(outs[0], "agg_merge")
+        outs, num_groups, capacity = self._merge(
+            exists, flat, tuple(key_dtypes), tuple(state_dtypes),
+            big.capacity)
         if num_groups == 0:
             return []
         out_valid = outs[1]
@@ -2208,9 +2232,71 @@ class DeviceMergeAgger:
                 cols.extend(fn.state_columns(state, num_groups, capacity))
         return [ColumnarBatch(out_schema, cols, num_groups)]
 
+    def _merge(self, exists, flat, key_dtypes, state_dtypes, capacity):
+        """The merge kernel's outputs over the concatenated input's planes,
+        its group count and the outputs' capacity: the radix table where
+        its gate plans one, else ONE sort of the packed key id where the
+        keys' observed ranges give one (:meth:`_slot_plan`), else the sort
+        path's ``lex_order_traced``."""
+        radix = self._radix_plan(flat, exists, key_dtypes, capacity)
+        if radix is not None:
+            bases, sizes, out_cap = radix
+            kernel = _radix_merge_kernel(key_dtypes, self.kinds, state_dtypes,
+                                         capacity, sizes, out_cap)
+            outs = kernel(exists, jnp.asarray(np.asarray(bases, np.int64)),
+                          *flat)
+            num_groups = wait_int(outs[0], "agg_merge")
+            if num_groups >= 0:
+                self._note_radix(sizes)
+                return outs, num_groups, out_cap
+            # probe/pack disagreement (shouldn't happen: the plan comes
+            # from a probe over this very data) — sort fallback
+        else:
+            table = self._slot_plan(flat, exists, key_dtypes, capacity)
+            if table is not None:
+                outs = _slot_merge_kernel(key_dtypes, self.kinds, state_dtypes,
+                                          capacity)(exists, table, *flat)
+                num_groups = wait_int(outs[0], "agg_merge")
+                if num_groups >= 0:
+                    if self.metrics is not None:
+                        self.metrics.add("merge_slot_sorted_batches", 1)
+                    DEVICE_STATS.add_merge_slot_sorted()
+                    return outs, num_groups, capacity
+        kernel = _merge_kernel(key_dtypes, self.kinds, state_dtypes, capacity)
+        outs = kernel(exists, *flat)
+        return outs, wait_int(outs[0], "agg_merge"), capacity
+
+    def _slot_plan(self, flat, exists, key_dtypes, capacity):
+        """The keys' observed ranges as a wide slot table
+        (:func:`_wide_table`), probed over the concatenated input (one small
+        sync), where every key is a signed integer plane (dictionary codes
+        too) and the packed id stays within ``_SLOT_ID_MAX_SLOTS``; else
+        None."""
+        if not key_dtypes or not all(
+                np.issubdtype(np.dtype(dt), np.signedinteger)
+                for dt in key_dtypes):
+            return None
+        probe = self._probe(flat, exists, len(key_dtypes), "agg_merge_probe")
+        # a merge has no later batch to anchor a key with no valid row: its
+        # null's slot and one beside it
+        probe[probe[:, 0] == 0] = (1, 0, 0)
+        plan = _plan_slot_table(probe, capacity, None, _SLOT_ID_MAX_SLOTS,
+                                self.conf)
+        if plan is None:
+            return None
+        bases, sizes, _out_cap = plan
+        return _wide_table(bases, sizes)
+
+    @staticmethod
+    def _probe(flat, exists, nk: int, what: str) -> np.ndarray:
+        """The keys' (any_valid, min, max) rows over the concatenated input:
+        one small sync, ``sync:<what>``."""
+        keys = list(zip(flat[0:2 * nk:2], flat[1:2 * nk:2]))
+        return np.array(wait_array(_key_ranges_jit(exists, keys), what))
+
     def _radix_plan(self, flat, exists, key_dtypes, capacity):
         """Probe key ranges over the concatenated input (one small sync)
-        and plan a radix slot table; None routes to the sort-path merge.
+        and plan a radix slot table; None routes to :meth:`_slot_plan`.
         Gated like the partial radix path: conf.radix_agg (auto = CPU
         backend hint) and integer keys only."""
         ra = self.conf.radix_agg
@@ -2223,16 +2309,7 @@ class DeviceMergeAgger:
         if not all(np.issubdtype(np.dtype(dt), np.integer)
                    for dt in key_dtypes):
             return None
-        info = jnp.iinfo(jnp.int64)
-        rows = []
-        for i in range(len(key_dtypes)):
-            d64 = flat[2 * i].astype(jnp.int64)
-            v = flat[2 * i + 1]  # already masked with exists by run()
-            rows.append(jnp.stack([
-                jnp.any(v).astype(jnp.int64),
-                jnp.min(jnp.where(v, d64, info.max)),
-                jnp.max(jnp.where(v, d64, info.min))]))
-        pr = wait_array(jnp.stack(rows), "agg_final_probe")
+        pr = self._probe(flat, exists, len(key_dtypes), "agg_final_probe")
         st = _plan_slot_table(pr, capacity, None,
                               self.conf.radix_agg_max_slots, self.conf)
         if st is _DEFER_PLAN or st is None:

@@ -255,6 +255,10 @@ class MetricNode:
 #   moment_exact_groups              groups whose finish passed 2^53 and went
 #                                    by Python integers (ops/aggfns
 #                                    moment_stddev)
+#   merge_slot_sorted_batches        FINAL / PARTIAL_MERGE merges reduced by
+#                                    one sort of their packed integer key id
+#                                    (jit(agg_merge_sorted)) in place of
+#                                    jit(agg_merge)'s lex_order_traced
 TRIPWIRE_METRICS = (
     "split_batches",
     "split_gathers",
@@ -294,6 +298,7 @@ TRIPWIRE_METRICS = (
     "moment_device_batches",
     "moment_host_batches",
     "moment_exact_groups",
+    "merge_slot_sorted_batches",
 )
 
 

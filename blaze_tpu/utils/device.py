@@ -33,7 +33,8 @@ class DeviceStats:
     batch, in which form, and where STDDEV_SAMP's moments were reduced and
     finished. Surfaced at /debug/device; the benchmark reads the deltas a
     query (``h2d_mb``, ``d2h_mb``, ``sync_points``, ``agg_dense_batches``,
-    ``agg_slot_sorted_batches``, ``moment_device_batches``)."""
+    ``agg_slot_sorted_batches``, ``merge_slot_sorted_batches``,
+    ``moment_device_batches``)."""
 
     def __init__(self):
         self._mu = threading.Lock()
@@ -53,6 +54,7 @@ class DeviceStats:
             self.agg_dense_batches = 0
             self.agg_slot_sorted_batches = 0
             self.agg_sort_batches = 0
+            self.merge_slot_sorted_batches = 0
             self.moment_device_batches = 0
             self.moment_host_batches = 0
             self.moment_exact_groups = 0
@@ -93,6 +95,13 @@ class DeviceStats:
                     self.agg_slot_sorted_batches += 1
             else:
                 self.agg_sort_batches += 1
+
+    def add_merge_slot_sorted(self):
+        """One FINAL / PARTIAL_MERGE merge reduced by ONE sort of its packed
+        key id (``jit(agg_merge_sorted)``). Benchmark:
+        ``merge_slot_sorted_batches``."""
+        with self._mu:
+            self.merge_slot_sorted_batches += 1
 
     def add_moment(self, device_batches: int = 0, host_batches: int = 0,
                    exact_groups: int = 0):
@@ -136,6 +145,7 @@ class DeviceStats:
                 "agg_dense_batches": self.agg_dense_batches,
                 "agg_slot_sorted_batches": self.agg_slot_sorted_batches,
                 "agg_sort_batches": self.agg_sort_batches,
+                "merge_slot_sorted_batches": self.merge_slot_sorted_batches,
                 "moment_device_batches": self.moment_device_batches,
                 "moment_host_batches": self.moment_host_batches,
                 "moment_exact_groups": self.moment_exact_groups,

@@ -510,6 +510,30 @@ def test_sort_path_jaxprs_are_the_parents(case, which):
         SORT_PATH_DIGESTS[case, which]
 
 
+# The merge by one sort of the packed key id (``jit(agg_merge_sorted)``),
+# beside ``jit(agg_merge)``, which keeps its digest above: its program as it
+# entered, so that a change to it is a decision.
+SLOT_MERGE_DIGESTS = {
+    "narrow_2key":
+        "7240edf06504422246fa8b155582f2739a2d2a283f3f1282ad87ebfc4aba7ca0",
+    "sum_count_1key":
+        "0a129764824d48d0ae4aeb4cfc6f336cc35a9534452a23d8776d068ef374e427",
+    "wide_1key":
+        "9d49911bb64b34a7467f8aa155c9d8ef0f62748522fee78e4c0b4d588c1778d6",
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLOT_MERGE_DIGESTS))
+def test_slot_merge_jaxprs_are_pinned(case):
+    key_dtypes, names, *_ = SCHEMAS[case]
+    states = tuple(_state_dtypes(n) for n in names)
+    kernel = A._slot_merge_kernel(
+        key_dtypes, tuple(KINDS[n][0][0] for n in names), states, CAPACITY)
+    avals = _avals(key_dtypes, [dt for dts in states for dt in dts], CAPACITY)
+    avals.insert(1, jax.ShapeDtypeStruct((3, len(key_dtypes)), jnp.int64))
+    assert _digest(kernel, avals) == SLOT_MERGE_DIGESTS[case]
+
+
 @pytest.mark.parametrize("which", ["partial", "merge"])
 @pytest.mark.parametrize("case", sorted(SCHEMAS))
 def test_sort_path_kernels_touch_no_row_at_a_time(case, which):

@@ -7,7 +7,8 @@ cut-down ``tpcds_star`` data set whose (item, store) range passes
 ``dense_agg_max_buckets`` as SF1's does: every PARTIAL batch reduces by one
 sort of its packed slot id (``jit(agg_dense_partial)``, slot-sorted), or with
 ``dense_agg`` off by the sort kernel (``jit(agg_partial)``); the FINAL side
-merges on the device (``jit(agg_merge)``), and the answer is Acero's."""
+merges on the device by one sort of its packed key id
+(``jit(agg_merge_sorted)``), and the answer is Acero's."""
 
 import dataclasses
 
@@ -69,7 +70,8 @@ def test_the_chips_plan_takes_the_sort_path_and_answers_as_acero(
     assert plans.rows_of(got, cls.ENGINE_COLUMNS, cls.ORDERED) == want
     assert len(want) > 100 or query == "q29"  # q29 keeps the first 100 groups
     delta = {k: after[k] - before[k] for k in (
-        "agg_sort_batches", "agg_dense_batches", "agg_slot_sorted_batches")}
+        "agg_sort_batches", "agg_dense_batches", "agg_slot_sorted_batches",
+        "merge_slot_sorted_batches")}
     if dense_agg is None:
         assert delta["agg_slot_sorted_batches"] == \
             delta["agg_dense_batches"] > 0
@@ -78,3 +80,5 @@ def test_the_chips_plan_takes_the_sort_path_and_answers_as_acero(
         assert delta["agg_sort_batches"] > 0
         assert delta["agg_dense_batches"] == 0
     assert merged["device_merge_batches"] >= 1
+    # the FINAL merge groups by integers: one sort of their packed id
+    assert delta["merge_slot_sorted_batches"] >= 1
