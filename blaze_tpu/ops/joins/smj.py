@@ -18,11 +18,11 @@ scans over that order give every left row its run's right rows, and prefix
 sums of the wanted rows (the pairs, the unmatched or matched rows of a side)
 give each output slot its source. One wait (``sync:smj_count``) tells the
 host how many rows each kind has; ``jit(smj_pairs)`` / ``jit(smj_rows)``
-then fill one output batch a dispatch: a binary search of the slot numbers
-in the prefix sums, then ONE gather a side, its planes side by side
-(``kernels.take_rows_traced``) — no row-sized scatter. Unmatched and
-semi/anti rows come out in key order (the input's order, when it arrives
-sorted). The operator's metric node counts ``smj_device_joins``.
+then fill one output batch a dispatch: the joint position of every slot,
+by ONE int32 scatter of each run's first slot and a running max, then ONE
+gather a side, its planes side by side (``kernels.take_rows_traced``).
+Unmatched and semi/anti rows come out in key order (the input's order, when
+it arrives sorted). The operator's metric node counts ``smj_device_joins``.
 
 **Host path** — var-width keys, keys kept on the host (DOUBLE where the
 chip has no exact f64), or a ``condition``: each side's key rows are
@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
+from jax import lax
 
 from blaze_tpu.core import kernels as K
 from blaze_tpu.core.batch import ColumnarBatch, DeviceColumn, HostColumn
@@ -115,10 +116,28 @@ def smj_probe(lkeys, rkeys, nl, nr, selections):
 def _slots(sums, offset, count, cap):
     """Output slots ``offset .. offset + cap - 1`` of a selection: which are
     live, and the joint position each takes its row from (the first whose
-    prefix sum exceeds the slot number)."""
+    prefix sum exceeds the slot number).
+
+    Not a binary search of ``sums``: on a TPU v5e that costs a gather of
+    the slots a step, 40 of the 48 ms of a launch at q29's shape (PERF.md
+    §6). A position's rows are the slots ``sums - w .. sums - 1``. The
+    position whose run holds the batch's first slot, and each later one
+    whose run starts inside the batch, writes its number at its first slot
+    in the batch — ONE int32 scatter of the joint length, every index its
+    own — and since positions rise with their runs' starts, a running max
+    carries a run's position over the rest of its slots. Slots past
+    ``count`` take the last position, as the search's clip gave them."""
+    n = sums.shape[0]
+    at = jnp.arange(n, dtype=jnp.int32)
+    before = jnp.concatenate([jnp.zeros(1, sums.dtype), sums[:-1]])
+    first = jnp.maximum(before - offset, 0)  # the run's first slot here
+    opens = (sums - offset > first) & (first < cap)
+    idx = jnp.where(opens, first, cap + at).astype(jnp.int32)
+    pos = lax.cummax(jnp.zeros(cap, jnp.int32).at[idx].set(
+        at, mode="drop", unique_indices=True), axis=0)
     slot = offset + jnp.arange(cap, dtype=jnp.int64)
-    pos = jnp.searchsorted(sums, slot, side="right")
-    return slot, slot < count, jnp.clip(pos, 0, sums.shape[0] - 1)
+    live = slot < count
+    return slot, live, jnp.where(live, pos, n - 1)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "cap_r"))

@@ -3,22 +3,30 @@
 three fixed-width keys, with null keys on either side, many-to-many runs, an
 empty side and a partition of several batches. Each case asserts the answer,
 which path ran (`smj_device_joins` / `smj_host_joins`), and that the device
-path pulled no key column. A string key and a `condition` pin the host path."""
+path pulled no key column. A string key and a `condition` pin the host path.
+The slot map (``smj._slots``: which joint position fills each output slot)
+is held to ``np.searchsorted`` on its own, and the programs that use it to
+one scatter and no loop."""
 
 import dataclasses
+import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
 
 from blaze_tpu.config import get_config
+from blaze_tpu.core import kernels as K
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir.nodes import JoinType
 from blaze_tpu.ops.base import ExecContext
+from blaze_tpu.ops.joins import smj
 from blaze_tpu.ops.joins.smj import SortMergeJoinExec
 from blaze_tpu.ops.sort import SortExec
 from blaze_tpu.utils.device import DEVICE_STATS
-from tests.util import mem_scan
+from tests.util import jaxpr_eqns, mem_scan
 
 KEY_RANGES = (7, 3, 2)  # few distinct values a key: runs on both sides
 CASES = ("nulls", "many_to_many", "empty_left", "empty_right",
@@ -177,3 +185,114 @@ def test_the_host_path_keeps_var_width_keys_and_conditions(why):
     assert got == _sorted(want)
     assert ctx.metrics.totals(["smj_device_joins", "smj_host_joins"]) == {
         "smj_device_joins": 0, "smj_host_joins": 1}
+
+
+def _prefix_sums(rng, case):
+    """(inclusive prefix sums of a selection over the joint order, count):
+    most positions wide 0, runs of 1 to 40 rows."""
+    n = 300
+    w = np.where(rng.random(n) < 0.7, 0, rng.integers(1, 41, n))
+    if case == "no_live_row":
+        w[:] = 0
+    elif case == "straddle":  # one long run holds slots 60 .. 99
+        w[:] = 0
+        w[[3, 50, 51, 200]] = [60, 40, 1, 7]
+    sums = np.cumsum(w).astype(np.int64)
+    return sums, int(sums[-1])
+
+
+SLOT_CASES = [("zero_widths", 0, 64), ("zero_widths", "middle", 64),
+              ("zero_widths", "last", 64), ("zero_widths", 0, 8192),
+              ("straddle", 64, 64), ("straddle", 0, 64), ("straddle", 96, 32),
+              ("no_live_row", 0, 64)]
+
+
+@pytest.mark.parametrize("case,offset,cap", SLOT_CASES,
+                         ids=[f"{c}-{o}-{k}" for c, o, k in SLOT_CASES])
+def test_the_slot_map_finds_what_a_binary_search_finds(case, offset, cap):
+    """Every slot of a batch — live or past ``count`` — takes the position
+    ``np.searchsorted(sums, slot, side="right")`` names, clipped into the
+    joint order, as the search the map replaced gave it: zero-width
+    positions skipped, a run straddling the batch's first slot, the first,
+    a middle and the last batch, a batch wider than what is left (``cap >
+    count``), and a selection with no row (``count`` 0)."""
+    sums, count = _prefix_sums(np.random.default_rng(7), case)
+    if offset == "middle":
+        offset = count // 2
+    elif offset == "last":
+        offset = (count - 1) // cap * cap
+    slot_fn = jax.jit(smj._slots, static_argnums=3)
+    slot, live, pos = slot_fn(jnp.asarray(sums), np.int64(offset),
+                              np.int64(count), cap)
+    want_slot = offset + np.arange(cap)
+    want = np.clip(np.searchsorted(sums, want_slot, side="right"), 0,
+                   len(sums) - 1)
+    np.testing.assert_array_equal(np.asarray(slot), want_slot)
+    np.testing.assert_array_equal(np.asarray(live), want_slot < count)
+    np.testing.assert_array_equal(np.asarray(pos), want)
+    if case == "no_live_row":
+        assert count == 0 and not np.asarray(live).any()
+    if case == "straddle" and offset == 64:
+        assert (np.asarray(pos)[:36] == 50).all()  # the run that began at 60
+
+
+def test_a_run_straddling_a_batch_keeps_its_pairs_in_order():
+    """A 10 x 10 many-to-many run whose 100 pairs begin at slot 30 of a
+    64-row batch and run through two more batches: the pairs come left-major
+    in key order, each left row's right rows in input order, across the
+    batch boundaries."""
+    lkeys = [1] * 30 + [5] * 10 + [9] * 4
+    rkeys = [1] + [5] * 10 + [7]
+    ldata = {"lk0": pa.array(lkeys, type=pa.int64()),
+             "lv": pa.array(range(len(lkeys)), type=pa.int64())}
+    rdata = {"rk0": pa.array(rkeys, type=pa.int64()),
+             "rv": pa.array(range(100, 100 + len(rkeys)), type=pa.int64())}
+    ctx = ExecContext(conf=dataclasses.replace(get_config(), batch_size=64))
+    batches = list(_join(ldata, rdata, 1, JoinType.INNER).execute(0, ctx))
+    assert [b.num_rows for b in batches] == [64, 64, 2]
+    got = []
+    for b in batches:
+        table = b.to_arrow().to_pydict()
+        got += list(zip(table["lv"], table["rv"]))
+    assert got == [(i, 100) for i in range(30)] + [
+        (i, j) for i in range(30, 40) for j in range(101, 111)]
+
+
+def _plane_avals(cap, n):
+    return (tuple(jax.ShapeDtypeStruct((cap,), jnp.int64) for _ in range(n)),
+            tuple(jax.ShapeDtypeStruct((cap,), jnp.bool_) for _ in range(n)))
+
+
+def _primitives(fn, *avals):
+    return [e.primitive.name for e in jaxpr_eqns(jax.make_jaxpr(fn)(*avals).jaxpr)]
+
+
+@pytest.mark.parametrize("program", ["smj_pairs", "smj_rows"])
+def test_the_slot_map_is_one_scatter_and_no_search_loop(program):
+    """``jit(smj_pairs)`` / ``jit(smj_rows)`` at q29's shape (1,048,576 left
+    and 131,072 right rows, a 131,072-slot batch): no ``while`` (the binary
+    search's loop), no sort, and exactly ONE scatter beyond what the
+    programs' ``take_rows_traced`` gathers emit."""
+    cap_l, cap_r, cap = 1 << 20, 1 << 17, 1 << 17
+    joint = jax.ShapeDtypeStruct((cap_l + cap_r,), jnp.int32)
+    sums = jax.ShapeDtypeStruct((cap_l + cap_r,), jnp.int64)
+    at = (jax.ShapeDtypeStruct((), jnp.int64),) * 2
+    lplanes, rplanes = _plane_avals(cap_l, 5), _plane_avals(cap_r, 4)
+    if program == "smj_pairs":
+        seen = _primitives(functools.partial(smj.smj_pairs, cap=cap,
+                                             cap_r=cap_r),
+                           joint, joint, joint, sums, lplanes, rplanes, *at)
+        movers = [lplanes, rplanes]
+    else:
+        seen = _primitives(functools.partial(smj.smj_rows, cap=cap,
+                                             base=cap_r),
+                           joint, joint, sums, lplanes, *at)
+        movers = [lplanes]
+    idx = jax.ShapeDtypeStruct((cap,), jnp.int32)
+    live = jax.ShapeDtypeStruct((cap,), jnp.bool_)
+    moved = [n for planes in movers
+             for n in _primitives(K.take_rows_traced, *planes, idx, live)]
+    scatters = lambda names: [n for n in names if n.startswith("scatter")]
+    assert "while" not in seen
+    assert seen.count("sort") == moved.count("sort")
+    assert len(scatters(seen)) == len(scatters(moved)) + 1
