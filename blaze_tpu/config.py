@@ -293,12 +293,10 @@ class Config:
     # AuronConverters.scala:99-140). Checked by the plan converter/session.
     enabled_ops: dict = dataclasses.field(default_factory=dict)
 
-    # Trace upstream FilterExec predicates into the device partial-agg
-    # kernel. None = auto: ON when the process's backend is the CPU (the
-    # compaction it removes is the CPU hot spot, bench 0.37s -> 0.17s), OFF
-    # on accelerator backends — chosen for a link that is gone; not measured
-    # on the chip. True/False force it.
-    fused_filter_agg: Optional[bool] = None
+    # Trace upstream FilterExec predicates (and unique-key broadcast joins)
+    # into the device partial-agg kernel. Off by default, on every backend:
+    # chosen for a link that is gone; not measured on the chip.
+    fused_filter_agg: bool = False
 
     # Whole-stage fusion (ir/fusion.py): collapse maximal chains of narrow
     # batch-local operators (project / filter / rename / expand, with
@@ -328,31 +326,14 @@ class Config:
 
     # Upper bound on the dense-agg table (product of per-key rounded ranges)
     # in the forms that hold the table: masked and scatter, linear in the
-    # slots. Past it a table is radix where radix_agg is on, else slot-sorted
-    # (ONE sort of the packed slot id, flat in the slots) while the id fits
-    # 62 bits, else the sort kernel.
+    # slots. Past it a table is "wide": slot-sorted (ONE sort of the packed
+    # slot id, flat in the slots) while the id fits 62 bits, else the sort
+    # kernel. The merge side plans the same wide table.
     dense_agg_max_buckets: int = 65536
 
-    # Radix-partitioned grouped aggregation: the high-cardinality extension
-    # of dense_agg. Packed integer keys are bucketed by their high code bits
-    # and deduped/accumulated with one scatter pass into a slot table whose
-    # size is the product of the per-key rounded ranges — far past
-    # dense_agg_max_buckets, bounded by radix_agg_max_slots. Replaces the
-    # O(n log n) sort segmentation for wide key ranges (q67-class ~570k
-    # groups) on both the partial and the merge side. None = auto: ON when
-    # the process's backend is the CPU (a probe sync a stream; its kernel
-    # scatters a row at a time and was not measured on the chip: ROADMAP
-    # S4). True/False force it.
-    radix_agg: Optional[bool] = None
-
-    # Upper bound on the radix slot-table size (product of per-key rounded
-    # ranges). Key spaces beyond this fall back to the sort kernel.
-    radix_agg_max_slots: int = 1 << 22
-
-    # Number of radix buckets (power of two). Buckets partition the packed
-    # key code by its high bits; the per-bucket (rows, groups) histogram
-    # feeds the partial-skipping heuristic and the Perfetto skew view.
-    radix_agg_buckets: int = 256
+    # Kept only so that configurations that switch the removed radix kernel
+    # off still parse; True is refused.
+    radix_agg: bool = False
 
     # Ship dictionary codes + dictionaries through the shuffle instead of
     # decoded values: partial-agg output keeps var-width group keys
@@ -484,6 +465,11 @@ class Config:
     # Capacity bucketing: device buffers are padded up to the next bucket to
     # bound XLA recompilation. Buckets are powers of two >= min_capacity.
     min_capacity: int = 256
+
+    def __post_init__(self):
+        if self.radix_agg:
+            raise ValueError("radix_agg: the radix aggregation kernel was "
+                             "removed; only False is accepted")
 
     def capacity_for(self, n: int) -> int:
         cap = self.min_capacity

@@ -814,13 +814,12 @@ def concat_planes(per_field_datas: List[Tuple[jax.Array, ...]],
         starts=None if starts is None else jnp.asarray(starts, jnp.int32))
 
 
-# -- radix key partitioning ----------------------------------------------------
-# Traced primitives shared by the dense-bucket and radix-partitioned hash
-# aggregation kernels (ops/agg_device): integer group keys pack into ONE
-# int64 slot code from per-key (base, pow2 size) strides, and the code's
-# high bits are the radix bucket id — so dedup, scatter-accumulate, AND the
-# per-bucket skew histogram all come out of the same scatter pass. These run
-# INSIDE jitted kernels; sizes/strides are static, bases are traced.
+# -- slot-id packing -----------------------------------------------------------
+# Traced primitives of the slot-table aggregation kernels (ops/agg_device:
+# the dense and wide partial tables, jit(agg_merge_sorted)): integer group
+# keys pack into ONE slot code from per-key (base, pow2 size) mixed-radix
+# strides. These run INSIDE jitted kernels; bases are traced, sizes and
+# strides static or traced.
 
 
 def radix_strides(sizes: Sequence[int]) -> Tuple[int, ...]:
@@ -867,24 +866,3 @@ def radix_pack(key_data, key_valid, exists, bases, sizes, strides,
     if S < 1 << 31:
         seg = seg.astype(jnp.int32)
     return seg, fits
-
-
-def radix_bucket_shift(S: int, nbuck: int) -> Tuple[int, int]:
-    """(shift, effective bucket count): a slot code's high bits select its
-    radix bucket. S and nbuck are powers of two; nbuck clamps to S."""
-    nb = min(nbuck, S)
-    return (S // nb).bit_length() - 1, nb
-
-
-def radix_histogram(seg, exists, present, S: int, nbuck: int):
-    """Traced per-bucket (rows, groups) histogram from one partial pass:
-    ``seg`` routes each existing row to its slot (sentinel S for padding,
-    dropped here), ``present`` marks occupied slots. This is the skew
-    signal the partial-skipping heuristic and the Perfetto trace consume."""
-    shift, nb = radix_bucket_shift(S, nbuck)
-    rows = jnp.zeros(nb, jnp.int64).at[seg.astype(jnp.int64) >> shift].add(
-        exists.astype(jnp.int64), mode="drop")
-    iota_s = jnp.arange(S, dtype=jnp.int64) >> shift
-    groups = jnp.zeros(nb, jnp.int64).at[iota_s].add(
-        present.astype(jnp.int64), mode="drop")
-    return rows, groups

@@ -26,10 +26,10 @@ Cost model (the SystemML-style cut points, kept deliberately small):
   estimated eager dispatch count exceeds the fused dispatch count (one per
   jitted segment) by at least ``conf.fusion_min_saved_dispatches`` — a lone
   column-reference projection or a bare coalesce stays unfused.
-- **Leave agg's filter alone.** A filter directly under an Agg is already
-  absorbed into the device partial-agg kernel (``fused_filter_agg``, the
-  0.37s->0.17s bench win); swallowing it here would disengage that path, so
-  the chain may start only below it.
+- **Leave agg's filter alone.** A filter directly under an Agg is the one
+  the device partial-agg kernel absorbs where ``fused_filter_agg`` is on;
+  swallowing it here would disengage that path, so the chain may start only
+  below it.
 
 The pass runs at operator-build time (``runtime/executor.build_operator``),
 not at plan-optimization time, so it sees post-lowering trees (including

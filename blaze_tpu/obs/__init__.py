@@ -9,9 +9,9 @@ from blaze_tpu.obs.dump import (dump_profile, list_incidents, load_incident,
 from blaze_tpu.obs.explain import (fmt_bytes, fmt_ns, humanize_metrics_dict,
                                    merge_partition_metrics, op_shape,
                                    render_explain_analyze)
-from blaze_tpu.obs.stats import (STATS_HUB, StatsPlane, list_profiles,
-                                 load_profile, plan_fingerprint, save_profile,
-                                 skew_summary, stage_summary_line)
+from blaze_tpu.obs.stats import (StatsPlane, list_profiles, load_profile,
+                                 plan_fingerprint, save_profile,
+                                 stage_summary_line)
 from blaze_tpu.obs.telemetry import (REGISTRY, Counter, Gauge, Histogram,
                                      MetricsRegistry, get_registry,
                                      parse_prometheus_text)
@@ -24,6 +24,6 @@ __all__ = [
     "fmt_ns", "fmt_bytes", "humanize_metrics_dict", "op_shape",
     "merge_partition_metrics", "render_explain_analyze", "dump_profile",
     "record_incident", "list_incidents", "load_incident",
-    "STATS_HUB", "StatsPlane", "plan_fingerprint", "skew_summary",
-    "stage_summary_line", "save_profile", "load_profile", "list_profiles",
+    "StatsPlane", "plan_fingerprint", "stage_summary_line", "save_profile",
+    "load_profile", "list_profiles",
 ]

@@ -1,17 +1,15 @@
 """Per-query stats plane: structured runtime statistics for adaptive use.
 
-The runtime already *measures* everything an adaptive driver needs — radix
-bucket histograms, DEVICE_STATS transfer counts, per-operator self-time,
-per-reducer map-output sizes — but emitted them as scattered trace instants
-and global counters. This module is the structured substrate ROADMAP items
+The runtime already *measures* everything an adaptive driver needs —
+DEVICE_STATS transfer counts, per-operator self-time, per-reducer
+map-output sizes — but emitted them as scattered trace instants and global
+counters. This module is the structured substrate ROADMAP items
 1/3/4 stand on: every stage commit feeds a per-query :class:`StatsPlane`,
 and a completed query folds into one compact ``QueryProfile`` dict
 
 - per-stage map-output partition sizes + row counts (all three zero-copy
   shuffle tiers: the offsets index is written by every tier, rows ride the
   writer's ``part_rows_<pid>`` metrics),
-- key-skew summaries promoted from the ``radix_bucket_histogram`` trace
-  instants into structured records (min/p50/max bucket weight, hot ids),
 - per-operator estimated-vs-actual cardinalities (estimates from
   ``ir/estimates.py`` on the logical plan, actuals from executor
   ``output_rows``),
@@ -26,16 +24,14 @@ like incident bundles — capped, GC'd, atomic — so a future AQE pass or a
 plan-fingerprint cache reads "last observed stats for this plan shape" in
 O(1) via ``Session.profile(...)`` or ``GET /debug/profiles/<fingerprint>``.
 
-Worker-side stats ride task replies (``reply["stats"]`` from
-:func:`_StatsHub.drain_all_merged`) and merge driver-side exactly like the
-telemetry deltas of the worker pool. With ``conf.stats_enabled = False``
-every hook is one attribute check — the disabled path stays inside the
-test-guarded <5% overhead budget.
+Worker-side partition rows ride the task's metric tree back to the driver.
+With ``conf.stats_enabled = False`` no plane is built and the shuffle
+writers' partition-row hook is one attribute check — the disabled path
+stays inside the test-guarded <5% overhead budget.
 """
 
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import json
 import os
@@ -58,15 +54,11 @@ PROFILE_FIELDS = (
 STAGE_FIELDS = (
     "stage", "kind", "num_tasks", "partitions", "partition_bytes",
     "partition_rows", "total_bytes", "total_rows", "max_partition_bytes",
-    "median_partition_bytes", "partition_skew_ratio", "truncated", "skew",
+    "median_partition_bytes", "partition_skew_ratio", "truncated",
     "compute_time_ns", "recovered_tasks",
 )
 OPERATOR_FIELDS = (
     "op", "est_rows", "actual_rows", "compute_time_ns",
-)
-SKEW_FIELDS = (
-    "buckets", "min_bucket_rows", "p50_bucket_rows", "max_bucket_rows",
-    "hot_bucket_ids", "radix_passes",
 )
 RESIDENCY_FIELDS = (
     "to_device_bytes", "to_host_bytes", "mapped_bytes", "shm_bytes_mapped",
@@ -102,7 +94,7 @@ CACHE_FIELDS = (
 )
 
 ALL_PROFILE_FIELDS = (PROFILE_FIELDS + STAGE_FIELDS + OPERATOR_FIELDS +
-                      SKEW_FIELDS + RESIDENCY_FIELDS + SPILL_FIELDS +
+                      RESIDENCY_FIELDS + SPILL_FIELDS +
                       RECOVERY_FIELDS + ATTRIBUTION_FIELDS +
                       CRITICAL_PATH_FIELDS + AUDIT_FIELDS + CACHE_FIELDS)
 
@@ -152,136 +144,16 @@ def plan_fingerprint(plan) -> str:
     return hashlib.sha256(canon.encode()).hexdigest()[:24]
 
 
-# -- skew ----------------------------------------------------------------------
-
-
-def _acc_elementwise(dst: List[int], src) -> None:
-    for i, v in enumerate(src):
-        if i < len(dst):
-            dst[i] += int(v)
-        else:
-            dst.append(int(v))
-
-
-def skew_summary(rec: Optional[dict]) -> Optional[dict]:
-    """Structured skew record from an accumulated radix histogram: min/p50/
-    max live-bucket row weight plus the hottest bucket ids (> 2x median)."""
-    if not rec:
-        return None
-    rows = rec.get("bucket_rows") or []
-    live = sorted(r for r in rows if r > 0)
-    if not live:
-        return None
-    med = live[len(live) // 2]
-    hot = [i for i, r in enumerate(rows) if r > 2 * med]
-    hot.sort(key=lambda i: -rows[i])
-    return {
-        "buckets": len(rows),
-        "min_bucket_rows": int(live[0]),
-        "p50_bucket_rows": int(med),
-        "max_bucket_rows": int(live[-1]),
-        "hot_bucket_ids": hot[:8],
-        "radix_passes": int(rec.get("radix_passes") or 0),
-    }
-
-
-def _merge_radix(dst: Optional[dict], src: Optional[dict]) -> Optional[dict]:
-    if not src:
-        return dst
-    if not dst:
-        return {"bucket_rows": list(src.get("bucket_rows") or []),
-                "bucket_groups": list(src.get("bucket_groups") or []),
-                "radix_passes": int(src.get("radix_passes") or 0)}
-    _acc_elementwise(dst["bucket_rows"], src.get("bucket_rows") or [])
-    _acc_elementwise(dst["bucket_groups"], src.get("bucket_groups") or [])
-    dst["radix_passes"] += int(src.get("radix_passes") or 0)
-    return dst
-
-
-# -- the process-global hub ----------------------------------------------------
-
-
-class _StatsHub:
-    """Scoped accumulation point for stats noted deep inside operator code
-    (the radix histogram in agg_device). Driver task closures set a
-    thread-local scope key per (query, stage); worker processes set none —
-    their notes pool under ``None`` and ride the task reply via
-    :meth:`drain_all_merged`. One ``enabled`` check when stats are off."""
-
-    _MAX_SCOPES = 256  # backstop for scopes recovery re-runs leave behind
-
-    def __init__(self):
-        self._mu = threading.Lock()
-        self._tls = threading.local()
-        self._scopes: Dict = {}
-        self.enabled = True
-
-    def configure_from(self, conf) -> None:
-        self.enabled = bool(getattr(conf, "stats_enabled", True))
-
-    @contextlib.contextmanager
-    def scoped(self, key):
-        prev = getattr(self._tls, "key", None)
-        self._tls.key = key
-        try:
-            yield
-        finally:
-            self._tls.key = prev
-
-    def note_radix(self, rows, groups) -> None:
-        """Accumulate one radix pass's per-bucket (rows, groups) histogram
-        under the current scope."""
-        if not self.enabled:
-            return
-        key = getattr(self._tls, "key", None)
-        r = [int(x) for x in rows]
-        g = [int(x) for x in groups]
-        with self._mu:
-            rec = self._scopes.get(key)
-            if rec is None:
-                if len(self._scopes) >= self._MAX_SCOPES:
-                    self._scopes.pop(next(iter(self._scopes)))
-                rec = self._scopes[key] = {"bucket_rows": [],
-                                           "bucket_groups": [],
-                                           "radix_passes": 0}
-            _acc_elementwise(rec["bucket_rows"], r)
-            _acc_elementwise(rec["bucket_groups"], g)
-            rec["radix_passes"] += 1
-
-    def drain(self, key) -> Optional[dict]:
-        with self._mu:
-            return self._scopes.pop(key, None)
-
-    def drain_all_merged(self) -> dict:
-        """Worker side: pop every scope, merged — the ``reply["stats"]``
-        payload. Empty dict when nothing was noted."""
-        with self._mu:
-            scopes, self._scopes = self._scopes, {}
-        merged: Optional[dict] = None
-        for rec in scopes.values():
-            merged = _merge_radix(merged, rec)
-        return merged or {}
-
-
-STATS_HUB = _StatsHub()
-
-
-def configure(conf) -> None:
-    STATS_HUB.configure_from(conf)
-
-
 # -- the per-query plane -------------------------------------------------------
 
 
 class StatsPlane:
     """Driver-side accumulator for ONE query. Stage commits call
-    ``on_map_stage``/``on_collect_stage``; pool replies fold in via
-    ``merge_task_stats``; recovery paths call ``note_recovery``; and
+    ``on_map_stage``/``on_collect_stage``; recovery paths call
+    ``note_recovery``; and
     ``finalize_into`` builds the QueryProfile onto the query record the
     session keeps in ``query_log``. Every entry point is best-effort and
     never raises into the execution path."""
-
-    RESULT_STAGE = -1
 
     def __init__(self, plan, conf):
         self.conf = conf
@@ -294,7 +166,6 @@ class StatsPlane:
             self.estimates = []
         self._mu = threading.Lock()
         self._stages: Dict[int, dict] = {}
-        self._worker_radix: Dict[int, dict] = {}
         self._recovery: List[dict] = []
         self._cache_notes: List[dict] = []
         self._attribution: Optional[dict] = None
@@ -313,11 +184,6 @@ class StatsPlane:
             self._audit0 = audit_snapshot()
         except Exception:
             self._audit0 = None
-
-    def scope_key(self, stage: int):
-        """The STATS_HUB scope driver task threads of ``stage`` run under
-        (``RESULT_STAGE`` for result-partition streams)."""
-        return (id(self), stage)
 
     # -- stage commits --------------------------------------------------------
 
@@ -346,10 +212,7 @@ class StatsPlane:
                     rec["partition_skew_ratio"] = round(
                         live[-1] / med, 2) if med else 0.0
                 rec["partition_bytes"] = sizes[:MAX_PARTITIONS_RECORDED]
-            radix = STATS_HUB.drain(self.scope_key(stage))
             with self._mu:
-                radix = _merge_radix(radix, self._worker_radix.pop(stage, None))
-                rec["skew"] = skew_summary(radix)
                 self._stages[stage] = rec
         except Exception:
             pass
@@ -372,22 +235,10 @@ class StatsPlane:
             rec = {"stage": stage, "kind": kind, "num_tasks": num_tasks,
                    "partitions": 1, "partition_bytes": [total],
                    "total_bytes": total, "truncated": False}
-            radix = STATS_HUB.drain(self.scope_key(stage))
             with self._mu:
-                radix = _merge_radix(radix, self._worker_radix.pop(stage, None))
-                rec["skew"] = skew_summary(radix)
                 self._stages[stage] = rec
         except Exception:
             pass
-
-    def merge_task_stats(self, stage: int, rec: Optional[dict]) -> None:
-        """Fold one worker task reply's drained hub record into the stage
-        (driver-side merge, like the pool's telemetry deltas)."""
-        if not rec:
-            return
-        with self._mu:
-            self._worker_radix[stage] = _merge_radix(
-                self._worker_radix.get(stage), rec)
 
     def note_attribution(self, attr: Optional[dict]) -> None:
         """Attach the per-query exclusive decomposition + critical path
@@ -452,15 +303,6 @@ class StatsPlane:
 
         operators = self._operator_records(trees)
         stages = self._stage_records(query, session_metrics)
-        # result-partition streams note radix skew under the RESULT_STAGE
-        # scope (there is no stage commit for the final stage: drain here)
-        result_skew = skew_summary(
-            STATS_HUB.drain(self.scope_key(self.RESULT_STAGE)))
-        if result_skew:
-            stages.append({"stage": self.RESULT_STAGE, "kind": "result",
-                           "num_tasks": query.get("nparts") or 0,
-                           "partitions": query.get("nparts") or 0,
-                           "truncated": False, "skew": result_skew})
 
         def tree_total(metric: str) -> int:
             return sum(t.total(metric) for _, t in trees if t is not None)
@@ -571,13 +413,6 @@ class StatsPlane:
     def _stage_records(self, query: dict, session_metrics) -> List[dict]:
         with self._mu:
             stages = {sid: dict(rec) for sid, rec in self._stages.items()}
-            # a pending worker radix rec whose stage commit never fired
-            # (e.g. failure mid-stage) still surfaces
-            for sid, radix in self._worker_radix.items():
-                rec = stages.setdefault(sid, {"stage": sid, "kind": "partial",
-                                              "num_tasks": 0, "partitions": 0,
-                                              "truncated": False})
-                rec["skew"] = skew_summary(radix)
             recovered: Dict[Optional[int], int] = {}
             for ev in self._recovery:
                 recovered[ev.get("stage")] = recovered.get(ev.get("stage"), 0) + 1
@@ -602,7 +437,7 @@ class StatsPlane:
 
 def stage_summary_line(stage_rec: dict) -> str:
     """One-line per-stage summary for /debug/queries and explain output:
-    partition count, total bytes, max/median ratio, hot radix buckets."""
+    partition count, total bytes, max/median ratio."""
     from blaze_tpu.obs.explain import fmt_bytes
 
     parts = [f"stage {stage_rec.get('stage')}",
@@ -616,11 +451,6 @@ def stage_summary_line(stage_rec: dict) -> str:
         parts.append(f"row_count={stage_rec['total_rows']}")
     if stage_rec.get("partition_skew_ratio") is not None:
         parts.append(f"max/med={stage_rec['partition_skew_ratio']}")
-    skew = stage_rec.get("skew")
-    if skew:
-        parts.append(
-            f"radix[p50={skew['p50_bucket_rows']} max={skew['max_bucket_rows']}"
-            f" hot={skew['hot_bucket_ids']}]")
     if stage_rec.get("recovered_tasks"):
         parts.append(f"recovered={stage_rec['recovered_tasks']}")
     return " ".join(parts)

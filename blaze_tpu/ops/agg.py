@@ -206,15 +206,9 @@ class AggExec(Operator):
             source = child_op
             fused_preds = None
 
-            # fusion is auto-on when the PROCESS backend is the CPU (the
-            # compaction it removes is the CPU hot spot — bench 0.37s ->
-            # 0.17s); on an accelerator it stays opt-in — chosen for a link
-            # that is gone, not measured on the chip.
-            from blaze_tpu.runtime import placement
-
-            fuse_conf = ctx.conf.fused_filter_agg
-            fuse_ok = fuse_conf if fuse_conf is not None \
-                else placement.backend_is_cpu_hint()
+            # opt-in (conf.fused_filter_agg): chosen for a link that is
+            # gone, not measured on the chip
+            fuse_ok = ctx.conf.fused_filter_agg
             # non-device agg args keep the agg on the eager path UNLESS
             # they are bare wide-decimal columns, which the fused kernels
             # consume directly as limb-plane jit inputs. Any OTHER traced
@@ -323,13 +317,13 @@ class AggExec(Operator):
             # stops (and batches flow through) once it exceeds the merge
             # budget or cardinality stays near-unique (partial-skipping
             # philosophy — merging near-unique partials is wasted work).
-            # adaptive partial skipping on the device path: the radix
-            # partial pass reports a per-bucket (rows, groups) histogram
-            # per batch; once the bucket-summed cardinality estimate says
-            # partials are not reducing, remaining batches route through
-            # the passthrough kernel (singleton groups, no dedup/sort).
-            # passthrough has no trace support, so fused preds/joins/steps
-            # keep the skipper off — the work they saved already paid.
+            # adaptive partial skipping on the device path: once the
+            # skipper says partials are not reducing, remaining batches
+            # route through the passthrough kernel (singleton groups, no
+            # dedup/sort). No device kernel feeds it a cardinality signal
+            # today (ROADMAP S12c), so it never fires here. passthrough has
+            # no trace support, so fused preds/joins/steps keep the skipper
+            # off — the work they saved already paid.
             skipper = _PartialSkipper(self, ctx) if (
                 self.supports_partial_skipping
                 and self.is_partial_output
@@ -358,11 +352,8 @@ class AggExec(Operator):
                         batch = _encode_var_width_keys(self, batch, metrics)
                     metrics.add("coded_key_batches", 1)
                 out = agger.process(batch)
-                if skipper is not None:
-                    if agger.last_bucket_stats is not None:
-                        skipper.observe_buckets(*agger.last_bucket_stats)
-                    if skipper.should_skip():
-                        skipping = True
+                if skipper is not None and skipper.should_skip():
+                    skipping = True
                 if out is None or not out.num_rows:
                     continue
                 if gave_up or skipping:
@@ -458,6 +449,7 @@ class AggExec(Operator):
                     yield from table.output()
                     for rest in child_iter:
                         out = table.passthrough_batch(rest)
+                        metrics.add("partial_skipped_batches", 1)
                         if out is not None:
                             yield out
                     return
@@ -575,37 +567,17 @@ def _partial_arg_schema(a: E.AggExpr, child_schema: T.Schema, pos: int):
 
 
 class _PartialSkipper:
-    """Adaptive partial-skipping decision (reference: agg_table.rs).
-
-    Two signal sources, best available wins:
-
-    - Radix bucket stats (device path): the radix partial pass emits a
-      per-bucket (rows, groups) histogram for every batch. Summing
-      ``min(groups, rows)`` per bucket across batches approximates the
-      rows a per-batch partial would EMIT — exactly the quantity the
-      skip decision trades against streaming rows through untouched. A
-      whole-table ratio hides skew: one hot bucket with heavy
-      duplication reads as "high cardinality" when averaged against a
-      long tail of near-unique buckets, and vice versa.
-    - Whole-table ratio (host table path, or device path before any
-      radix batch ran): ``num_slots / rows_processed``, the legacy
-      signal.
-    """
+    """Adaptive partial-skipping decision (reference: agg_table.rs): skip
+    once the host table has seen ``partial_agg_skipping_min_rows`` rows and
+    its whole-table ratio ``num_slots / rows_processed`` passes
+    ``partial_agg_skipping_ratio``. Without a table (the device path) there
+    is no signal and it never skips."""
 
     def __init__(self, op: AggExec, ctx: ExecContext):
         self.min_rows = ctx.conf.partial_agg_skipping_min_rows
         self.ratio = ctx.conf.partial_agg_skipping_ratio
-        self._rows = 0  # rows observed via bucket histograms
-        self._est = 0   # estimated rows a per-batch partial would emit
-
-    def observe_buckets(self, bucket_rows, bucket_groups) -> None:
-        """Accumulate one batch's per-bucket (rows, groups) histogram."""
-        self._rows += int(bucket_rows.sum())
-        self._est += int(np.minimum(bucket_groups, bucket_rows).sum())
 
     def should_skip(self, table: Optional["AggTable"] = None) -> bool:
-        if self._rows >= self.min_rows:
-            return self._est / max(self._rows, 1) > self.ratio
         if table is None or table.rows_processed < self.min_rows:
             return False
         return table.num_slots / max(table.rows_processed, 1) > self.ratio

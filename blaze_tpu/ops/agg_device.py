@@ -39,20 +39,6 @@ from blaze_tpu.ops.sort_keys import orderable_word_traced
 from blaze_tpu.utils.device import (DEVICE_STATS, is_device_dtype,
                                     wait_array, wait_int)
 
-_TM_RADIX = None
-
-
-def _radix_counter():
-    # lazy: registry import stays off the module-import path
-    global _TM_RADIX
-    if _TM_RADIX is None:
-        from blaze_tpu.obs.telemetry import get_registry
-
-        _TM_RADIX = get_registry().counter(
-            "blaze_agg_radix_buckets_total",
-            "radix buckets scanned by partitioned agg kernel passes")
-    return _TM_RADIX
-
 _DEVICE_AGG_FNS = (E.AggFunction.SUM, E.AggFunction.COUNT, E.AggFunction.AVG,
                    E.AggFunction.MIN, E.AggFunction.MAX,
                    E.AggFunction.STDDEV_SAMP)
@@ -414,13 +400,13 @@ def supports_fused_filter(filter_op, grandchild_schema: T.Schema) -> bool:
 
 class _TableState:
     """A stream's slot-table state (for one null signature of its keys):
-    ``dense_ok`` / ``radix_ok`` None = eligibility undecided, False =
-    ineligible or refused; ``bucket_state`` the active plan."""
+    ``dense_ok`` None = eligibility undecided, False = ineligible or
+    refused; ``bucket_state`` the active plan."""
 
-    __slots__ = ("dense_ok", "radix_ok", "bucket_state")
+    __slots__ = ("dense_ok", "bucket_state")
 
     def __init__(self):
-        self.dense_ok = self.radix_ok = self.bucket_state = None
+        self.dense_ok = self.bucket_state = None
 
 
 class DevicePartialAgger:
@@ -460,9 +446,9 @@ class DevicePartialAgger:
         self.metrics = metrics
         self.conf = conf or get_config()
         self._fused_cache = {}
-        # dense/radix bucket path: _dense_ok/_radix_ok None = eligibility
-        # undecided, False = ineligible/disabled; a bucket state is the
-        # active plan ("dense"|"radix", bases, sizes, out_cap)
+        # slot-table path: _dense_ok None = eligibility undecided, False =
+        # ineligible/disabled; a bucket state is the active plan
+        # ("dense"|"wide", bases, sizes, out_cap)
         # ... kept a NULL SIGNATURE of the stream (which grouping keys
         # arrive as a ROLLUP's typed NULL, `_null_signature`): each grouping
         # set of an Expand has its own key space and plans for itself. A
@@ -471,9 +457,6 @@ class DevicePartialAgger:
         self._table_state = self._table_states[()]
         # the dictionaries of the batch in hand's coded keys (_key_planes)
         self._key_dicts = [None] * len(op.groupings)
-        # per-radix-pass (rows, groups) numpy histograms, consumed by the
-        # partial-skipping heuristic between process() calls
-        self.last_bucket_stats = None
         self.group_ev = ExprEvaluator([e for _, e in op.groupings], child_schema,
                                       metrics)
         self.agg_evs = [
@@ -528,8 +511,6 @@ class DevicePartialAgger:
     # the slot-table state of the signature in hand
     _dense_ok = property(lambda self: self._table_state.dense_ok,
                          lambda self, v: setattr(self._table_state, "dense_ok", v))
-    _radix_ok = property(lambda self: self._table_state.radix_ok,
-                         lambda self, v: setattr(self._table_state, "radix_ok", v))
     _bucket_state = property(
         lambda self: self._table_state.bucket_state,
         lambda self, v: setattr(self._table_state, "bucket_state", v))
@@ -760,19 +741,6 @@ class DevicePartialAgger:
                 self._int_keys()
         return self._dense_ok
 
-    def _radix_enabled(self) -> bool:
-        """Radix-partitioned kernel eligibility: the dense path's
-        high-cardinality extension, same key/backend gates, bounded by
-        radix_agg_max_slots instead of dense_agg_max_buckets."""
-        if self._radix_ok is None:
-            ra = self.conf.radix_agg
-            if ra is None:
-                from blaze_tpu.runtime import placement
-
-                ra = placement.backend_is_cpu_hint()
-            self._radix_ok = bool(ra) and self._int_keys()
-        return self._radix_ok
-
     def _probe_eager(self, batch: ColumnarBatch):
         """Range probe for the unfused path: evaluates keys eagerly (the
         batch may carry HostColumns the jitted probe cannot flatten) and
@@ -811,61 +779,47 @@ class DevicePartialAgger:
         return _plan_slot_table(probe, capacity, prev, max_slots, self.conf)
 
     def _plan_bucketed(self, probe: np.ndarray, capacity: int, prev):
-        """Pick the slot-table plan for this stream: dense when the key
-        space fits the small-table cap, else radix-partitioned up to
-        radix_agg_max_slots where the radix gate is on, else (the chip) a
-        "wide" table of any size whose packed slot id fits
-        ``_SLOT_ID_MAX_SLOTS`` (slot-sorted, which is flat in the slots; its
-        sizes traced, so one program serves every such table of the
-        stream's key types). Returns ("dense"|"radix"|"wide", bases, sizes,
-        out_cap), _DEFER_PLAN, or None (sort fallback)."""
-        if self._dense_enabled():
-            st = self._plan_table(
-                probe, capacity, prev,
-                min(self.conf.dense_agg_max_buckets, capacity))
-            if st is _DEFER_PLAN:
-                return _DEFER_PLAN
-            if st is not None:
-                return ("dense",) + st
-        if self._radix_enabled():
-            st = self._plan_table(probe, capacity, prev,
-                                  self.conf.radix_agg_max_slots)
-            if st is _DEFER_PLAN:
-                return _DEFER_PLAN
-            if st is not None:
-                return ("radix",) + st
-        elif self._dense_enabled():
-            st = self._plan_table(probe, capacity, prev, _SLOT_ID_MAX_SLOTS)
-            if st is not None:
-                return ("wide",) + st
-        return None
+        """Pick the slot-table plan for this stream: "dense" when the key
+        space fits ``min(dense_agg_max_buckets, capacity)``, else a "wide"
+        table of any size whose packed slot id fits ``_SLOT_ID_MAX_SLOTS``
+        (slot-sorted, which is flat in the slots; its sizes traced, so one
+        program serves every such table of the stream's key types). Returns
+        ("dense"|"wide", bases, sizes, out_cap), _DEFER_PLAN, or None (the
+        sort kernel)."""
+        if not self._dense_enabled():
+            return None
+        st = self._plan_table(probe, capacity, prev,
+                              min(self.conf.dense_agg_max_buckets, capacity))
+        if st is _DEFER_PLAN:
+            return _DEFER_PLAN
+        if st is not None:
+            return ("dense",) + st
+        st = self._plan_table(probe, capacity, prev, _SLOT_ID_MAX_SLOTS)
+        return None if st is None else ("wide",) + st
 
-    def _dense_call(self, batch: ColumnarBatch, bases, sizes, out_cap,
-                    nbuck: int = 0):
+    def _dense_call(self, batch: ColumnarBatch, bases, sizes, out_cap):
         bases_arr = jnp.asarray(np.asarray(bases, np.int64))
         if self._needs_trace():
             cap_key = self._cap_key(batch)
-            key = ("dense", self._structural_key(), cap_key, sizes, out_cap,
-                   nbuck)
+            key = ("dense", self._structural_key(), cap_key, sizes, out_cap)
             fn = _FUSED_KERNELS.get(key)
             if fn is None:
                 agger = self._trace_clone()
 
                 def fused(num_rows, b, *flat):
                     tb, mask = agger._trace_tb_mask(num_rows, flat)
-                    return agger._flow_dense(tb, mask, b, sizes, out_cap,
-                                             nbuck)
+                    return agger._flow_dense(tb, mask, b, sizes, out_cap)
 
                 fn = jax.jit(fused)
                 _FUSED_KERNELS[key] = fn
             return fn(jnp.int64(batch.num_rows), bases_arr,
                       *self._jit_flat(batch))
         return self._flow_dense(batch, batch.row_exists_mask(), bases_arr,
-                                sizes, out_cap, nbuck)
+                                sizes, out_cap)
 
     def _flow_dense(self, batch: ColumnarBatch, exists, bases, sizes,
-                    out_cap, nbuck: int = 0):
-        """_flow twin routing to the dense/radix bucket kernel."""
+                    out_cap):
+        """_flow twin routing to the slot-table kernel."""
         self.group_ev._reset_cse(batch)
         for ev in self.agg_evs:
             if ev is not None:
@@ -877,7 +831,7 @@ class DevicePartialAgger:
             tuple(str(d.dtype) for d in key_data), tuple(self.specs),
             tuple("wide3" if isinstance(a[0], tuple) else str(a[0].dtype)
                   for a in args), batch.capacity,
-            sizes, out_cap, nbuck)
+            sizes, out_cap)
         flat = []
         for d, v in zip(key_data, key_valid):
             flat += [d, v]
@@ -886,15 +840,13 @@ class DevicePartialAgger:
         return kernel(exists, bases, *flat)
 
     def _try_dense(self, batch: ColumnarBatch):
-        """Dense/radix-path orchestration: probe on first use, run the
-        specialized scatter kernel, re-probe + widen once on range overflow.
-        Returns (outs, num_groups) or None to fall back to the sort
-        kernel. Radix passes additionally publish the per-bucket (rows,
-        groups) histogram through ``last_bucket_stats``."""
-        self.last_bucket_stats = None
+        """Slot-table orchestration: probe on first use, run the table's
+        kernel (``jit(agg_dense_partial)``, dense or wide), re-probe + widen
+        once on range overflow. Returns (outs, num_groups) or None to fall
+        back to the sort kernel."""
         nulled = self._null_signature(batch)
         self._table_state = self._table_states.setdefault(nulled, _TableState())
-        if not (self._dense_enabled() or self._radix_enabled()):
+        if not self._dense_enabled():
             return None  # not eligible, or this signature's range refused
         st = self._bucket_state
         prev = None
@@ -918,11 +870,10 @@ class DevicePartialAgger:
                     self._bucket_state = None
                     return None
                 if st is None:
-                    # observed range too wide for even the radix cap: stop
-                    # probing for the rest of this stream (of its batches
-                    # with these keys nulled)
+                    # observed range too wide for even a packed slot id:
+                    # stop probing for the rest of this stream (of its
+                    # batches with these keys nulled)
                     self._dense_ok = False
-                    self._radix_ok = False
                     self._bucket_state = None
                     return None
                 self._bucket_state = st
@@ -932,21 +883,17 @@ class DevicePartialAgger:
                 # has as many groups as its capacity, as on the sort path
                 out_cap = self.conf.capacity_for(
                     min(math.prod(sizes), batch.capacity))
-            nbuck = self.conf.radix_agg_buckets if table == "radix" else 0
             if table == "wide":
                 outs = self._dense_call(batch, _wide_table(bases, sizes),
                                         None, out_cap)
             else:
-                outs = self._dense_call(batch, bases, sizes, out_cap, nbuck)
+                outs = self._dense_call(batch, bases, sizes, out_cap)
             # sync; -1 flags range overflow
             num_groups = wait_int(outs[0], "agg_partial")
             if num_groups >= 0:
                 DEVICE_STATS.add_agg_batch(
-                    dense=True, slot_sorted=_is_slot_sorted(
-                        sizes, batch.capacity, nbuck))
-                if nbuck:
-                    self._note_radix(outs, sizes, nbuck)
-                    outs = outs[:-2]
+                    dense=True,
+                    slot_sorted=_is_slot_sorted(sizes, batch.capacity))
                 return outs, num_groups
             prev, st = (bases, sizes), None
         self._bucket_state = None
@@ -968,26 +915,6 @@ class DevicePartialAgger:
             if isinstance(col, CodedColumn) and col.null_literal:
                 nulled.append(i)
         return tuple(nulled)
-
-    def _note_radix(self, outs, sizes, nbuck: int):
-        """Publish one radix pass's bucket histogram: skipper input,
-        tripwire counter, and (trace-gated) the Perfetto skew view."""
-        rows = wait_array(outs[-2], "agg_radix_stats")
-        groups = wait_array(outs[-1], "agg_radix_stats")
-        self.last_bucket_stats = (rows, groups)
-        if self.metrics is not None:
-            self.metrics.add("agg_radix_buckets", len(rows))
-        _radix_counter().inc(len(rows))
-        from blaze_tpu.obs.stats import STATS_HUB
-
-        STATS_HUB.note_radix(rows, groups)
-        from blaze_tpu.obs.tracer import TRACER
-
-        if TRACER.active:
-            TRACER.instant(
-                "radix_bucket_histogram", "agg",
-                args={"buckets": len(rows), "sizes": list(sizes),
-                      "rows": rows.tolist(), "groups": groups.tolist()})
 
     def _sort_groups(self, outs) -> int:
         """The sort kernel's sync point (it completes here): the batch's
@@ -1195,7 +1122,7 @@ def _plan_slot_table(probe: np.ndarray, capacity: int, prev,
     previous plan on overflow so re-bucketed batches keep fitting. Sizes
     round to powers of two to bound kernel recompiles. None when the slot
     table would exceed ``max_slots``; shared by the partial aggers and the
-    radix merge."""
+    merge's slot plan."""
     bases, sizes, S = [], [], 1
     for i, (anyv, kmin, kmax) in enumerate(probe):
         if not anyv:
@@ -1353,8 +1280,8 @@ def _partial_requests(spec, arg, exists):
 
 def _merge_requests(kind: str, scols, exists):
     """One aggregate's reductions over partial STATE rows (its (data, valid)
-    state columns), for :func:`_aggregate_sorted`: what :func:`_merge_reduce`
-    scatters."""
+    state columns), for :func:`_reduce_ordered` in ``jit(agg_merge)`` and
+    ``jit(agg_merge_sorted)``: the merge's :func:`_partial_requests`."""
     cols = [(d, v & exists) for d, v in scols]
 
     def add(d, m):
@@ -1537,8 +1464,8 @@ _MASKED_REDUCE_MAX_SLOTS = 16384  # past it the masked form loses to a scatter
 #   262,144 rows  1,024 slots 1.31 / 3.63, 6.81 / 6.50   2,048 2.71 / 3.65, 14.3 / 6.51
 #                 4,096 slots 5.78 / 3.67, 29.8 / 6.55  16,384 30.5 / 3.80, 123 / 6.75
 _SLOT_SORT_MIN_SLOTS = 2048
-# The widest table a packed slot id numbers: where no radix table is planned
-# (the chip) a table past ``dense_agg_max_buckets`` is "wide": slot-sorted,
+# The widest table a packed slot id numbers: a table past
+# ``dense_agg_max_buckets`` is "wide": slot-sorted,
 # whose cost is the sort of the id and not the slots, so what bounds it is the
 # id's width. A wide table's sizes are traced and its id is int64 whatever its
 # size, so one program a key type and batch capacity serves every wide table
@@ -1584,10 +1511,10 @@ def _table_form(nseg: int, rows: int, sortable: bool = False) -> str:
                nearly flat in the slots. Only ``jit(agg_dense_partial)`` has
                it (``sortable``): it emits groups, not the table.
     "scatter"  ``.at[seg].<op>``, one serial update a row: what is left to a
-               caller that needs the table itself past the masked form's
-               reach (the radix kernels of the CPU backend) or has a segment
-               a row (the passthrough kernel, where masked is quadratic in
-               the batch)."""
+               caller that needs the table itself and has at least a slot a
+               row (a dense table as large as its batch, the passthrough
+               kernel's segment a row, where masked is quadratic in the
+               batch)."""
     if sortable and nseg >= _SLOT_SORT_MIN_SLOTS:
         return "sorted"
     if nseg <= _MASKED_REDUCE_MAX_SLOTS and nseg < rows:
@@ -1688,7 +1615,7 @@ def _segment_lex3(p0, p1, p2, m, seg, nseg, is_max: bool):
 
 def _reduce_aggs(specs, args, seg, nseg_total):
     """Per-aggregate segment reductions of the partial kernels whose ``seg``
-    is in no order (slot table, radix, passthrough; sorted rows take
+    is in no order (a masked slot table, passthrough; sorted rows take
     :func:`_partial_requests`). ``args[i]`` is the i-th aggregate's
     already-masked (data, valid) pair aligned with ``specs``; rows route to
     ``seg`` (out-of-range segments drop). Returns one ("kind", arrays...)
@@ -1760,20 +1687,17 @@ def _reduce_aggs(specs, args, seg, nseg_total):
     return outs
 
 
-def _is_slot_sorted(sizes, capacity: int, nbuck: int) -> bool:
+def _is_slot_sorted(sizes, capacity: int) -> bool:
     """Does ``jit(agg_dense_partial)`` of these static shapes take the
-    slot-sorted form? Never as the radix variant (``nbuck``): that one
-    reports the table's histogram, so it keeps the table."""
-    return _table_form(math.prod(sizes), capacity,
-                       sortable=not nbuck) == "sorted"
+    slot-sorted form?"""
+    return _table_form(math.prod(sizes), capacity, sortable=True) == "sorted"
 
 
 @functools.lru_cache(maxsize=256)
 def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
                           specs: Tuple[Tuple[str, int, str], ...],
                           arg_dtypes: Tuple[str, ...], capacity: int,
-                          sizes: Tuple[int, ...], out_cap: int,
-                          nbuck: int = 0):
+                          sizes: Tuple[int, ...], out_cap: int):
     """Dense-bucket partial kernel: integer group keys whose observed range
     fits a small table pack into ONE slot id below ``prod(sizes)``
     (``radix_pack``) and reduce by it (the TPU analogue of the reference's
@@ -1791,20 +1715,13 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
     kernel serves every batch of the stream; a key outside its range flips
     the fits flag and the host falls back for that batch. Output arrays are
     ``out_cap``-sized (the compact group bucket), shrinking every downstream
-    consumer of the partial batch.
-
-    With ``nbuck`` > 0 this is the RADIX-partitioned variant: the slot
-    table may be much larger than dense_agg_max_buckets (bounded by
-    radix_agg_max_slots), the packed code's high bits are the radix bucket
-    id, and the kernel appends the per-bucket (rows, groups) histogram to
-    its outputs — the cardinality signal the partial-skipping heuristic
-    and the Perfetto skew view consume."""
+    consumer of the partial batch."""
     nk = len(key_dtypes)
     wide = sizes is None
     if not wide:
         S = math.prod(sizes)
         strides = K.radix_strides(sizes)
-    slot_sorted = wide or _is_slot_sorted(sizes, capacity, nbuck)
+    slot_sorted = wide or _is_slot_sorted(sizes, capacity)
 
     def digit(slot, i):
         return (slot // strides[i]) % sizes[i]
@@ -1885,160 +1802,9 @@ def _dense_partial_kernel(key_dtypes: Tuple[str, ...],
             for entry in outs:
                 for a in entry[1:]:
                     results.append(compact(a))
-            if nbuck:
-                brows, bgroups = K.radix_histogram(seg, exists, present, S,
-                                                   nbuck)
-                results += [brows, bgroups]
         return tuple(results)
 
     return jax.jit(agg_dense_partial)
-
-
-def _merge_reduce(kinds, states, seg, CAP):
-    """Per-aggregate partial-STATE merges of the radix merge kernel (sorted
-    rows take :func:`_merge_requests`). ``states[i]`` is aggregate i's list
-    of already-masked (data, valid) state-column pairs aligned with
-    ``kinds``; rows route to ``seg`` (out-of-range segments drop), so it
-    works for ANY seg mapping. One output tuple of merged state arrays
-    (length ``CAP``) per aggregate."""
-    outs = []
-    for kind, scols in zip(kinds, states):
-        if kind in ("sum2", "avg2"):
-            (ld, lv), (hd, _hv), (sd, sv) = scols
-            m = lv & sd.astype(bool) & sv
-            slo = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                jnp.where(m, ld, jnp.int64(0)), mode="drop")
-            shi = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                jnp.where(m, hd, jnp.int64(0)), mode="drop")
-            carry = slo >> 32
-            slo, shi = slo & jnp.int64(0xFFFFFFFF), shi + carry
-            if kind == "avg2":
-                scnt = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                    jnp.where(m, sd, jnp.int64(0)), mode="drop")
-                outs.append((slo, shi, scnt))
-            else:
-                shas = jnp.zeros(CAP, bool).at[seg].max(m, mode="drop")
-                outs.append((slo, shi, shas))
-        elif kind in ("sum3", "avg3"):
-            # three-limb wide-decimal sums: per-limb segment adds with
-            # the shared carry renormalization (aggfns._limb3_renorm)
-            from blaze_tpu.ops.aggfns import _limb3_renorm
-
-            (d0, v0l), (d1, _v1), (d2, _v2), (sd, sv) = scols
-            m = v0l & sd.astype(bool) & sv
-            s0 = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                jnp.where(m, d0, jnp.int64(0)), mode="drop")
-            s1 = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                jnp.where(m, d1, jnp.int64(0)), mode="drop")
-            s2 = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                jnp.where(m, d2, jnp.int64(0)), mode="drop")
-            s0, s1, s2 = _limb3_renorm(s0, s1, s2)
-            if kind == "avg3":
-                scnt = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                    jnp.where(m, sd, jnp.int64(0)), mode="drop")
-                outs.append((s0, s1, s2, scnt))
-            else:
-                shas = jnp.zeros(CAP, bool).at[seg].max(m, mode="drop")
-                outs.append((s0, s1, s2, shas))
-        elif kind in ("minw", "maxw"):
-            # shared lexicographic segment extreme (_segment_lex3)
-            (d0, v0l), (d1, _v1), (d2, _v2), (hd, hv) = scols
-            m = v0l & hd.astype(bool) & hv
-            outs.append(_segment_lex3(d0, d1, d2, m, seg, CAP,
-                                      kind == "maxw"))
-        elif kind == "sum":
-            (sd, sv), (hd, hv) = scols
-            m = sv & hd.astype(bool) & hv
-            ssum = jnp.zeros(CAP, sd.dtype).at[seg].add(
-                jnp.where(m, sd, jnp.zeros((), sd.dtype)), mode="drop")
-            shas = jnp.zeros(CAP, bool).at[seg].max(m, mode="drop")
-            outs.append((ssum, shas))
-        elif kind == "moment":
-            from blaze_tpu.ops.aggfns import moment_renorm
-
-            outs.append(tuple(moment_renorm(*(
-                jnp.zeros(CAP, jnp.int64).at[seg].add(
-                    jnp.where(v, d, jnp.int64(0)), mode="drop")
-                for d, v in scols))))
-        elif kind == "count":
-            (cd, cv), = scols
-            scnt = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                jnp.where(cv, cd, 0), mode="drop")
-            outs.append((scnt,))
-        elif kind == "avg":
-            (sd, sv), (cd, cv) = scols
-            ssum = jnp.zeros(CAP, sd.dtype).at[seg].add(
-                jnp.where(sv, sd, jnp.zeros((), sd.dtype)), mode="drop")
-            scnt = jnp.zeros(CAP, jnp.int64).at[seg].add(
-                jnp.where(cv, cd, 0), mode="drop")
-            outs.append((ssum, scnt))
-        else:  # min / max
-            (vd, vv), (hd, hv) = scols
-            m = vv & hd.astype(bool) & hv
-            sent = _extreme_sentinel(kind, vd.dtype)
-            x = jnp.where(m, vd, sent)
-            acc = jnp.full(CAP, sent, vd.dtype)
-            acc = acc.at[seg].min(x, mode="drop") if kind == "min" else \
-                acc.at[seg].max(x, mode="drop")
-            shas = jnp.zeros(CAP, bool).at[seg].max(m, mode="drop")
-            outs.append((acc, shas))
-    return outs
-
-
-@functools.lru_cache(maxsize=256)
-def _radix_merge_kernel(key_dtypes: Tuple[str, ...], kinds: Tuple[str, ...],
-                        state_dtypes: Tuple[Tuple[str, ...], ...],
-                        capacity: int, sizes: Tuple[int, ...], out_cap: int):
-    """Radix merge kernel: FINAL/PARTIAL_MERGE over integer keys whose
-    probed range fits a radix slot table. Rows scatter their partial states
-    straight into ``prod(sizes)`` slots via the packed key code — replacing
-    the O(n log n) lax.sort segmentation that dominated the q67 profile
-    (one ~2M-row sort merge) with one linear scatter pass. Keys reconstruct
-    arithmetically from the slot index; outputs are ``out_cap``-sized."""
-    nk = len(key_dtypes)
-    S = 1
-    for s in sizes:
-        S *= s
-    strides = K.radix_strides(sizes)
-
-    def agg_radix_merge(exists, bases, *flat):
-        key_data = [flat[2 * i] for i in range(nk)]
-        key_valid = [flat[2 * i + 1] & exists for i in range(nk)]
-        pos = 2 * nk
-        states = []
-        for dts in state_dtypes:
-            cols = []
-            for _ in dts:
-                cols.append((flat[pos], flat[pos + 1] & exists))
-                pos += 2
-            states.append(cols)
-        seg, fits = K.radix_pack(key_data, key_valid, exists, bases,
-                                 sizes, strides)
-        outs = _merge_reduce(kinds, states, seg, S)
-        present = jnp.zeros(S, bool).at[seg].max(exists, mode="drop")
-        num_groups = jnp.sum(present)
-        cpos = jnp.cumsum(present) - 1
-        scat = jnp.where(present, cpos, out_cap).astype(jnp.int32)
-
-        def compact(x):
-            return jnp.zeros((out_cap,), x.dtype).at[scat].set(x, mode="drop")
-
-        out_valid = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
-        results = [jnp.where(fits, num_groups.astype(jnp.int64),
-                             jnp.int64(-1)), out_valid]
-        iota_s = jnp.arange(S, dtype=jnp.int64)
-        for i, kdt in enumerate(key_dtypes):
-            code_b = (iota_s // strides[i]) % sizes[i]
-            kdata = (bases[i] + code_b - 1).astype(jnp.dtype(kdt))
-            results.append(jnp.where(out_valid, compact(kdata),
-                                     jnp.zeros((), jnp.dtype(kdt))))
-            results.append(compact(code_b > 0) & out_valid)
-        for group in outs:
-            for a in group:
-                results.append(compact(a))
-        return tuple(results)
-
-    return jax.jit(agg_radix_merge)
 
 
 def _merge_planes(flat, nk: int, state_dtypes):
@@ -2234,34 +2000,22 @@ class DeviceMergeAgger:
 
     def _merge(self, exists, flat, key_dtypes, state_dtypes, capacity):
         """The merge kernel's outputs over the concatenated input's planes,
-        its group count and the outputs' capacity: the radix table where
-        its gate plans one, else ONE sort of the packed key id where the
-        keys' observed ranges give one (:meth:`_slot_plan`), else the sort
-        path's ``lex_order_traced``."""
-        radix = self._radix_plan(flat, exists, key_dtypes, capacity)
-        if radix is not None:
-            bases, sizes, out_cap = radix
-            kernel = _radix_merge_kernel(key_dtypes, self.kinds, state_dtypes,
-                                         capacity, sizes, out_cap)
-            outs = kernel(exists, jnp.asarray(np.asarray(bases, np.int64)),
-                          *flat)
+        its group count and the outputs' capacity: ONE sort of the packed
+        key id where the keys' observed ranges give one (:meth:`_slot_plan`,
+        ``jit(agg_merge_sorted)``), else the sort path's
+        ``lex_order_traced`` (``jit(agg_merge)``)."""
+        table = self._slot_plan(flat, exists, key_dtypes, capacity)
+        if table is not None:
+            outs = _slot_merge_kernel(key_dtypes, self.kinds, state_dtypes,
+                                      capacity)(exists, table, *flat)
             num_groups = wait_int(outs[0], "agg_merge")
             if num_groups >= 0:
-                self._note_radix(sizes)
-                return outs, num_groups, out_cap
-            # probe/pack disagreement (shouldn't happen: the plan comes
-            # from a probe over this very data) — sort fallback
-        else:
-            table = self._slot_plan(flat, exists, key_dtypes, capacity)
-            if table is not None:
-                outs = _slot_merge_kernel(key_dtypes, self.kinds, state_dtypes,
-                                          capacity)(exists, table, *flat)
-                num_groups = wait_int(outs[0], "agg_merge")
-                if num_groups >= 0:
-                    if self.metrics is not None:
-                        self.metrics.add("merge_slot_sorted_batches", 1)
-                    DEVICE_STATS.add_merge_slot_sorted()
-                    return outs, num_groups, capacity
+                if self.metrics is not None:
+                    self.metrics.add("merge_slot_sorted_batches", 1)
+                DEVICE_STATS.add_merge_slot_sorted()
+                return outs, num_groups, capacity
+            # probe/pack disagreement (the plan comes from a probe over this
+            # very data): the sort path
         kernel = _merge_kernel(key_dtypes, self.kinds, state_dtypes, capacity)
         outs = kernel(exists, *flat)
         return outs, wait_int(outs[0], "agg_merge"), capacity
@@ -2293,41 +2047,6 @@ class DeviceMergeAgger:
         one small sync, ``sync:<what>``."""
         keys = list(zip(flat[0:2 * nk:2], flat[1:2 * nk:2]))
         return np.array(wait_array(_key_ranges_jit(exists, keys), what))
-
-    def _radix_plan(self, flat, exists, key_dtypes, capacity):
-        """Probe key ranges over the concatenated input (one small sync)
-        and plan a radix slot table; None routes to :meth:`_slot_plan`.
-        Gated like the partial radix path: conf.radix_agg (auto = CPU
-        backend hint) and integer keys only."""
-        ra = self.conf.radix_agg
-        if ra is None:
-            from blaze_tpu.runtime import placement
-
-            ra = placement.backend_is_cpu_hint()
-        if not ra or not key_dtypes:
-            return None
-        if not all(np.issubdtype(np.dtype(dt), np.integer)
-                   for dt in key_dtypes):
-            return None
-        pr = self._probe(flat, exists, len(key_dtypes), "agg_final_probe")
-        st = _plan_slot_table(pr, capacity, None,
-                              self.conf.radix_agg_max_slots, self.conf)
-        if st is _DEFER_PLAN or st is None:
-            return None
-        return st
-
-    def _note_radix(self, sizes):
-        S = 1
-        for s in sizes:
-            S *= s
-        nbuck = K.radix_bucket_shift(S, self.conf.radix_agg_buckets)[1]
-        if self.metrics is not None:
-            self.metrics.add("agg_radix_buckets", nbuck)
-        _radix_counter().inc(nbuck)
-        # no per-bucket histogram on this path; still counts as a pass
-        from blaze_tpu.obs.stats import STATS_HUB
-
-        STATS_HUB.note_radix((), ())
 
 
 @functools.lru_cache(maxsize=256)

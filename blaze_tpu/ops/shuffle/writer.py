@@ -226,9 +226,7 @@ class _WriterState(MemConsumer):
         t0 = self.repart.split_time_ns
         c0 = self.streams.codes_bytes
         s0 = self.streams.serialized_bytes
-        from blaze_tpu.obs.stats import STATS_HUB
-
-        part_rows = {} if STATS_HUB.enabled else None
+        part_rows = {} if self.ctx.conf.stats_enabled else None
         for pid, sub in self._bucketize(batch):
             if part_rows is not None:
                 part_rows[pid] = part_rows.get(pid, 0) + sub.num_rows
@@ -577,12 +575,10 @@ class RssShuffleWriterExec(Operator):
         pending_rows = 0
 
         def _push(batch):
-            from blaze_tpu.obs.stats import STATS_HUB
-
             b0, g0 = repart.split_batches, repart.split_gathers
             t0 = repart.split_time_ns
             for pid, sub in repart.bucketize_host(batch):
-                if STATS_HUB.enabled:
+                if ctx.conf.stats_enabled:
                     metrics.add(f"part_rows_{pid}", sub.num_rows)
                 buf = io.BytesIO()
                 bw = BatchWriter(buf, codec=codec,

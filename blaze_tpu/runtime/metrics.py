@@ -139,9 +139,6 @@ class MetricNode:
 #   agg_reintern_rows == 0           var-width agg keys cross the exchange
 #                                    as dictionary codes; merge tables never
 #                                    re-encode decoded values per batch
-#   agg_radix_buckets > 0            on high-cardinality int-keyed aggs:
-#                                    the radix-partitioned device kernel ran
-#                                    (counts buckets scanned per pass)
 #   codes_shuffle_bytes              bytes shipped as codes+dictionaries by
 #                                    the code-carrying shuffle (0 on plans
 #                                    without dictionary columns)
@@ -272,7 +269,6 @@ TRIPWIRE_METRICS = (
     "jit_cache_misses",
     "fused_fallback_batches",
     "agg_reintern_rows",
-    "agg_radix_buckets",
     "codes_shuffle_bytes",
     "shuffle_bytes_serialized",
     "shm_bytes_mapped",

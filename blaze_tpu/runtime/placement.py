@@ -50,14 +50,6 @@ def decide(conf) -> str:
     return "device"
 
 
-def backend_is_cpu_hint() -> bool:
-    """Is this process's default JAX backend the CPU? (The tri-state "auto"
-    kernel switches key on the process backend, not on a stage's pin.)"""
-    import jax
-
-    return jax.default_backend() == "cpu"
-
-
 @contextlib.contextmanager
 def placed(decision: str):
     """Scope a task thread to the decided backend. "device" is the process

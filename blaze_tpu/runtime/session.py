@@ -32,8 +32,7 @@ from blaze_tpu.core.batch import ColumnarBatch
 from blaze_tpu.ir import nodes as N
 from blaze_tpu.ir import types as T
 from blaze_tpu.obs.explain import op_shape, render_explain_analyze
-from blaze_tpu.obs.stats import STATS_HUB, StatsPlane
-from blaze_tpu.obs.stats import configure as _stats_configure
+from blaze_tpu.obs.stats import StatsPlane
 from blaze_tpu.obs.stats import save_profile as _save_profile
 from blaze_tpu.obs.telemetry import get_registry
 from blaze_tpu.obs.telemetry import configure_from as _telemetry_configure
@@ -341,7 +340,6 @@ class Session:
         # records consumed by explain_analyze, /debug/trace, /debug/queries
         _tracer_configure(self.conf)
         _telemetry_configure(self.conf)
-        _stats_configure(self.conf)
         # fault injection: arm (or disarm) the DRIVER process from conf —
         # workers arm themselves per task from the shipped conf, but
         # in-driver task paths (process tier, lineage recompute, collect
@@ -570,11 +568,9 @@ class Session:
 
             ctx = self._make_ctx(p, qrun=qrun)
             set_task_context(0, p, qrun.qid)
-            scope = (STATS_HUB.scoped(qrun.stats.scope_key(StatsPlane.RESULT_STAGE))
-                     if qrun.stats is not None else contextlib.nullcontext())
             try:
                 with self._on_task_chip(p, nparts), placement.placed(where), \
-                        scope, ctx.mem.group_scope(qrun.mem_group):
+                        ctx.mem.group_scope(qrun.mem_group):
                     yield from op.execute(p, ctx,
                                           self.metrics.named_child(f"result_{p}"))
             finally:
@@ -1190,12 +1186,9 @@ class Session:
                 device_sink=device_sink)
             ctx = self._make_ctx(m, stage)
             task_metrics = self.metrics.named_child(f"stage_{stage}").named_child(f"map_{m}")
-            scope = (STATS_HUB.scoped(qrun.stats.scope_key(stage))
-                     if qrun is not None and qrun.stats is not None
-                     else contextlib.nullcontext())
             set_task_context(stage, m, self._qid())
             try:
-                with placement.placed(where_cell[0]), scope, \
+                with placement.placed(where_cell[0]), \
                         TRACER.span("task", "task",
                                     {"stage": stage, "map": m}):
                     for _ in writer.execute(m, ctx, task_metrics):
@@ -1539,13 +1532,9 @@ class Session:
                 ctx = self._make_ctx(m, stage)
                 task_metrics = self.metrics.named_child(
                     f"stage_{stage}").named_child(f"map_{m}")
-                qr = self._qrun()
-                scope = (STATS_HUB.scoped(qr.stats.scope_key(stage))
-                         if qr is not None and qr.stats is not None
-                         else contextlib.nullcontext())
                 set_task_context(stage, m, self._qid())
                 try:
-                    with placement.placed(where), scope, \
+                    with placement.placed(where), \
                             TRACER.span("task", "task",
                                         {"stage": stage, "map": m}):
                         for _ in writer.execute(m, ctx, task_metrics):
@@ -1736,10 +1725,6 @@ class Session:
         for m, r in enumerate(replies):
             stage_metrics.named_child(f"map_{m}").merge_dict(
                 r.get("metrics") or {})
-            # worker-side stats (drained hub records) merge like telemetry
-            # deltas: folded into the plane's per-stage skew accumulators
-            if qrun is not None and qrun.stats is not None and r.get("stats"):
-                qrun.stats.merge_task_stats(stage, r["stats"])
             # worker-process spans ride back with the task result; re-base
             # them into the driver timeline (wall epochs anchor the shift)
             tr = r.get("trace")
@@ -1776,13 +1761,6 @@ class Session:
         committed: Dict[int, tuple] = {}  # m -> ("batches"|"bytes", items)
         lock = threading.Lock()
         where = self._decide_placement(f"stage_{stage}")
-        qrun = self._qrun()
-
-        def _stats_scope():
-            return (STATS_HUB.scoped(qrun.stats.scope_key(stage))
-                    if qrun is not None and qrun.stats is not None
-                    else contextlib.nullcontext())
-
         class _Bucket:
             def __init__(self):
                 self.parts: List[bytes] = []
@@ -1818,7 +1796,7 @@ class Session:
 
             set_task_context(stage, m, self._qid())
             try:
-                with placement.placed(where), _stats_scope(), \
+                with placement.placed(where), \
                         TRACER.span("task", "task",
                                     {"stage": stage, "map": m}):
                     for b in child_op.execute(m, ctx, task_metrics):
@@ -1854,7 +1832,7 @@ class Session:
                 f"stage_{stage}").named_child(f"map_{m}")
             set_task_context(stage, m, self._qid())
             try:
-                with placement.placed(where), _stats_scope(), \
+                with placement.placed(where), \
                         TRACER.span("task", "task",
                                     {"stage": stage, "map": m}):
                     for _ in writer.execute(m, ctx, task_metrics):
@@ -1887,6 +1865,7 @@ class Session:
                     blocks.append(("batches", items))
             else:
                 blocks.extend(("bytes", b) for b in items)
+        qrun = self._qrun()
         if qrun is not None and qrun.stats is not None:
             qrun.stats.on_collect_stage(stage, f"{prefix}_collect", num_maps,
                                         blocks)

@@ -37,7 +37,6 @@ def run_task(msg: dict, shared: dict = None) -> dict:
 
     from blaze_tpu.config import Config, set_config
     from blaze_tpu.ir.protoserde import task_definition_from_bytes
-    from blaze_tpu.obs.stats import STATS_HUB
     from blaze_tpu.obs.telemetry import get_registry
     from blaze_tpu.obs.telemetry import configure_from as _telemetry_configure
     from blaze_tpu.obs.tracer import TRACER
@@ -52,7 +51,6 @@ def run_task(msg: dict, shared: dict = None) -> dict:
         set_config(conf)
         _tracer_configure(conf)
         _telemetry_configure(conf)
-        STATS_HUB.configure_from(conf)
         # fault injection must reach task code in THIS process, not just
         # the driver: arm (or disarm) from the conf that shipped with the
         # task, so a chaos soak's spec applies fleet-wide
@@ -94,11 +92,6 @@ def run_task(msg: dict, shared: dict = None) -> dict:
         deltas = get_registry().drain_deltas()
         if deltas:
             reply["telemetry"] = deltas
-        # radix histograms noted during execution merge driver-side into
-        # the query's StatsPlane (Session._ship_stage_to_pool)
-        stats = STATS_HUB.drain_all_merged()
-        if stats:
-            reply["stats"] = stats
         return reply
     finally:
         clear_task_context()

@@ -195,7 +195,6 @@ def check_profile_fields():
         ("PROFILE_FIELDS", stats.PROFILE_FIELDS),
         ("STAGE_FIELDS", stats.STAGE_FIELDS),
         ("OPERATOR_FIELDS", stats.OPERATOR_FIELDS),
-        ("SKEW_FIELDS", stats.SKEW_FIELDS),
         ("RESIDENCY_FIELDS", stats.RESIDENCY_FIELDS),
         ("SPILL_FIELDS", stats.SPILL_FIELDS),
         ("RECOVERY_FIELDS", stats.RECOVERY_FIELDS),

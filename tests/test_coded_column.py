@@ -593,9 +593,9 @@ def test_each_grouping_set_plans_its_slot_table_for_itself():
                       [(k, E.Column(k)) for k in ("a", "b", "g")],
                       [N.AggColumn(E.AggExpr(E.AggFunction.SUM, [E.Column("v")]),
                                    E.AggMode.PARTIAL, "s")])
-    # as on the chip: no radix table; a slot table of at most 4,096 slots
+    # a slot table of at most 4,096 slots
     ctx = ExecContext(conf=dataclasses.replace(
-        get_config(), radix_agg=False, dense_agg_max_buckets=4096))
+        get_config(), dense_agg_max_buckets=4096))
     before = DEVICE_STATS.snapshot()
     got = collect(partial, ctx)
     after = DEVICE_STATS.snapshot()
@@ -610,7 +610,7 @@ def test_each_grouping_set_plans_its_slot_table_for_itself():
 
 
 def test_a_q22_rollups_product_sets_are_slot_sorted_and_equal_the_sort_kernel():
-    """q22's shape without a radix table (the chip): a five-set ROLLUP over
+    """q22's shape as the chip runs it: a five-set ROLLUP over
     four coded names, the product's dictionary 18,000 entries. Every set
     that keeps the product is one slot table past ``dense_agg_max_buckets``
     (2^19 to 2^36 slots), slot-sorted, and all four run ONE program; the
@@ -656,7 +656,7 @@ def test_a_q22_rollups_product_sets_are_slot_sorted_and_equal_the_sort_kernel():
                                              [E.Column("q")]),
                                    E.AggMode.PARTIAL, "qoh")])
         ctx = ExecContext(conf=dataclasses.replace(
-            get_config(), radix_agg=False, dense_agg=dense_agg))
+            get_config(), dense_agg=dense_agg))
         before = DEVICE_STATS.snapshot()
         got = collect(agg, ctx)
         after = DEVICE_STATS.snapshot()

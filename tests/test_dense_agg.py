@@ -9,6 +9,7 @@ fallback beyond the bucket cap, and end-to-end equality with the sort
 kernel on nullable multi-key input.
 """
 
+import dataclasses
 import hashlib
 import math
 
@@ -18,6 +19,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from blaze_tpu.config import Config
 from blaze_tpu.core.batch import ColumnarBatch
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import nodes as N
@@ -45,13 +47,16 @@ def _scan_stub(schema=SCHEMA):
     return scan_node_for_files([td + "/t.parquet"], num_partitions=1)
 
 
-def _agger(groupings=("k1",)):
-    schema = T.schema_from_arrow(SCHEMA)
-    node = N.Agg(_scan_stub(), E.AggExecMode.HASH_AGG,
+def _partial_node(groupings=("k1",)):
+    return N.Agg(_scan_stub(), E.AggExecMode.HASH_AGG,
                  [(g, E.Column(g)) for g in groupings], [
         N.AggColumn(E.AggExpr(E.AggFunction.SUM, [E.Column("v")]),
                     E.AggMode.PARTIAL, "s")])
-    return DevicePartialAgger(build_operator(node), schema)
+
+
+def _agger(groupings=("k1",)):
+    return DevicePartialAgger(build_operator(_partial_node(groupings)),
+                              T.schema_from_arrow(SCHEMA))
 
 
 def _batch(ks, vs):
@@ -88,10 +93,10 @@ def test_range_overflow_beyond_dense_cap_goes_radix():
     o1 = agger.process(_batch([5, 6, 7] * 100, [1] * 300))
     assert agger._bucket_state[0] == "dense"
     # union with 10005.. needs 16k slots > batch capacity: the dense plan
-    # overflows and the re-plan lands on the radix table, results stay exact
+    # overflows and the re-plan lands on the wide table, results stay exact
     o2 = agger.process(_batch([10005, 10006] * 100, [2] * 200))
     assert agger._bucket_state is not None
-    assert agger._bucket_state[0] == "radix"
+    assert agger._bucket_state[0] == "wide"
     assert sorted(o2.to_arrow().to_pydict()["s#sum"]) == [200, 200]
     assert o1.num_rows == 3
 
@@ -99,12 +104,17 @@ def test_range_overflow_beyond_dense_cap_goes_radix():
 def test_range_overflow_beyond_radix_cap_falls_back_correctly():
     agger = _agger()
     o1 = agger.process(_batch([5, 6, 7] * 100, [1] * 300))
-    # union with 9_000_005.. would need ~9M slots > radix_agg_max_slots
-    # (4M): every scatter table disables, the sort kernel takes over,
-    # results stay exact
-    o2 = agger.process(_batch([9_000_005, 9_000_006] * 100, [2] * 200))
-    assert agger._dense_ok is False and agger._radix_ok is False
+    # union with 2^62 + 5.. would need a packed id past
+    # _SLOT_ID_MAX_SLOTS: every slot table disables, the sort kernel takes
+    # over, results stay exact
+    far = (1 << 62) + 5
+    s0 = DEVICE_STATS.snapshot()
+    o2 = agger.process(_batch([far, far + 1] * 100, [2] * 200))
+    s1 = DEVICE_STATS.snapshot()
+    assert agger._dense_ok is False
     assert agger._bucket_state is None
+    assert s1["agg_sort_batches"] - s0["agg_sort_batches"] == 1
+    assert sorted(o2.to_arrow().to_pydict()["k1"]) == [far, far + 1]
     assert sorted(o2.to_arrow().to_pydict()["s#sum"]) == [200, 200]
     assert o1.num_rows == 3
 
@@ -343,19 +353,21 @@ def test_form_follows_the_static_shapes(nseg, rows, masked):
     assert bool(scatters) != masked
 
 
-@pytest.mark.parametrize("nseg,rows,nbuck,form", [
-    (16, 131072, 0, "masked"), (RULE // 2, 131072, 0, "masked"),
-    (RULE, 131072, 0, "sorted"), (CUT, 131072, 0, "sorted"),
-    (4 * CUT, 131072, 0, "sorted"),
-    (4 * CUT, 4 * CUT, 0, "sorted"),  # as many slots as rows: no scatter left
-    (RULE, 131072, 256, "masked"),  # the radix variant keeps the table
-    (4 * CUT, 131072, 256, "scatter")])
-def test_the_slot_table_sorts_from_the_rule_on(nseg, rows, nbuck, form):
+@pytest.mark.parametrize("nseg,rows,sortable,form", [
+    (16, 131072, True, "masked"), (RULE // 2, 131072, True, "masked"),
+    (RULE, 131072, True, "sorted"), (CUT, 131072, True, "sorted"),
+    (4 * CUT, 131072, True, "sorted"),
+    (4 * CUT, 4 * CUT, True, "sorted"),  # as many slots as rows: no scatter
+    (RULE, 131072, False, "masked"),  # a caller that keeps the table
+    (4 * CUT, 131072, False, "scatter")])
+def test_the_slot_table_sorts_from_the_rule_on(nseg, rows, sortable, form):
     """A rule on the static shapes alone: ``jit(agg_dense_partial)`` of
     ``RULE`` slots or more reduces by one sort of the slot id, whatever the
-    capacity, unless it is the radix variant."""
-    assert A._table_form(nseg, rows, sortable=not nbuck) == form
-    assert A._is_slot_sorted((nseg // 4, 4), rows, nbuck) == (form == "sorted")
+    capacity; a caller that needs the table itself (``_seg_reduce``) never
+    sorts."""
+    assert A._table_form(nseg, rows, sortable=sortable) == form
+    if sortable:
+        assert A._is_slot_sorted((nseg // 4, 4), rows) == (form == "sorted")
 
 
 def _avals(key_dtypes, arg_dtypes, cap, bases=False):
@@ -659,7 +671,7 @@ def test_slot_sorted_form_equals_the_masked_form_and_numpy(case, slots):
     _key_dtypes, names, *_ = SCHEMAS[case]
     sorted_kernel, sizes, _ = _dense_kernel(case, slots, SMALL)
     masked_kernel, _, _ = _dense_kernel(case, slots, SMALL, form="masked")
-    assert A._is_slot_sorted(sizes, SMALL, 0)
+    assert A._is_slot_sorted(sizes, SMALL)
     for seed, data in enumerate(("random", "one_slot", "out_of_range")):
         exists, keys, args, bases = _dense_inputs(case, sizes, data,
                                                   100 * slots + seed)
@@ -691,16 +703,10 @@ def test_slot_sorted_form_equals_the_masked_form_and_numpy(case, slots):
 # -- selection and counters ----------------------------------------------------
 
 
-@pytest.mark.parametrize("backend_is_cpu", [False, True])
-def test_auto_engages_whatever_the_backend_and_counts_what_ran(
-        monkeypatch, backend_is_cpu):
-    """``dense_agg=None`` selects from the probed key range alone; the radix
-    table keeps its backend gate, so off the CPU a wide range goes to the
-    sort kernel. Each answered batch is counted as what ran."""
-    from blaze_tpu.runtime import placement
-
-    monkeypatch.setattr(placement, "backend_is_cpu_hint",
-                        lambda: backend_is_cpu)
+def test_auto_engages_whatever_the_backend_and_counts_what_ran():
+    """``dense_agg=None`` selects from the probed key range alone, and a
+    range past ``dense_agg_max_buckets`` plans one slot-sorted table as wide
+    as the range. Each answered batch is counted as what ran."""
     agger = _agger()
     assert agger.conf.dense_agg is None
     s0 = DEVICE_STATS.snapshot()
@@ -715,20 +721,101 @@ def test_auto_engages_whatever_the_backend_and_counts_what_ran(
     s2 = DEVICE_STATS.snapshot()
     assert s2["agg_dense_batches"] - s1["agg_dense_batches"] == 1
     assert s2["sync_calls"] - s1["sync_calls"] == 1, "one probe a stream"
-    # a range past dense_agg_max_buckets: radix where its gate allows, else
-    # one slot-sorted table as wide as the range
+    # a range past dense_agg_max_buckets: one slot-sorted table as wide as
+    # the range
     wide = _agger()
     out = wide.process(_batch([5, 900_005] * 100, [2] * 200))
     s3 = DEVICE_STATS.snapshot()
     assert sorted(out.to_arrow().to_pydict()["s#sum"]) == [200, 200]
     assert s3["agg_dense_batches"] - s2["agg_dense_batches"] == 1
     assert s3["agg_sort_batches"] == s2["agg_sort_batches"]
-    if backend_is_cpu:
-        assert wide._bucket_state[0] == "radix"
+    assert wide._bucket_state == ("wide", (5,), (1 << 20,), 256)
+    assert s3["agg_slot_sorted_batches"] - s2["agg_slot_sorted_batches"] == 1
+
+
+COUNTERS = ("agg_dense_batches", "agg_slot_sorted_batches",
+            "agg_sort_batches", "merge_slot_sorted_batches")
+# what the chip plans for each form, whatever the backend: the table the
+# partial plans (None: the sort kernel) or the merge's kernel, and the
+# batches each counter gains
+CHIP_PLANS = {
+    "dense_masked": ("dense", (1, 0, 0, 0)),
+    "dense_slot_sorted": ("dense", (1, 1, 0, 0)),
+    "wide": ("wide", (1, 1, 0, 0)),
+    "merge_sorted": ("agg_merge_sorted", (0, 0, 0, 1)),
+    "float_key": (None, (0, 0, 1, 0)),
+    "id_past_62_bits": (None, (0, 0, 1, 0)),
+}
+
+
+def _run_form(form):
+    """One batch of ``form`` through the default ``Config()``: (the plan,
+    the counters' gains)."""
+    if form == "float_key":
+        schema = pa.schema([("k", pa.float64()), ("v", pa.int64())])
+        node = N.Agg(_scan_stub(schema), E.AggExecMode.HASH_AGG,
+                     [("k", E.Column("k"))], [
+            N.AggColumn(E.AggExpr(E.AggFunction.SUM, [E.Column("v")]),
+                        E.AggMode.PARTIAL, "s")])
+        agger = DevicePartialAgger(build_operator(node),
+                                   T.schema_from_arrow(schema))
+        batch = ColumnarBatch.from_arrow(pa.table({
+            "k": pa.array([1.5, 2.5, 1.5]), "v": pa.array([1, 2, 3])}))
+    elif form == "id_past_62_bits":
+        agger = _wide_agger(2, {})
+        batch = ColumnarBatch.from_arrow(pa.table({
+            "k1": pa.array([0, 1 << 40, 0], type=pa.int64()),
+            "k2": pa.array([0, 1 << 30, 0], type=pa.int64()),
+            "v": pa.array([1, 2, 3], type=pa.int64())}))
     else:
-        assert wide._bucket_state == ("wide", (5,), (1 << 20,), 256)
-        assert s3["agg_slot_sorted_batches"] - \
-            s2["agg_slot_sorted_batches"] == 1
+        agger = _agger()
+        ks = {"dense_masked": [3, 4, 5] * 100,
+              "dense_slot_sorted": (5 + np.arange(4 * RULE)
+                                    % (RULE - 2)).tolist(),
+              "wide": [5, 900_005] * 100,
+              "merge_sorted": [5, 900_005] * 100}[form]
+        batch = _batch(ks, [1] * len(ks))
+    s0 = DEVICE_STATS.snapshot()
+    out = agger.process(batch)
+    plan = agger._bucket_state and agger._bucket_state[0]
+    if form == "merge_sorted":
+        from blaze_tpu.runtime.metrics import MetricNode
+
+        final = build_operator(N.Agg(
+            _partial_node(), E.AggExecMode.HASH_AGG, [("k1", E.Column("k1"))],
+            [N.AggColumn(E.AggExpr(E.AggFunction.SUM, [E.Column("v")]),
+                         E.AggMode.FINAL, "s")]))
+        s0 = DEVICE_STATS.snapshot()
+        metrics = MetricNode("merge")
+        merged = A.DeviceMergeAgger(final, final.children[0].schema,
+                                    metrics=metrics).run([out, out])
+        assert merged[0].to_arrow().to_pydict()["s"] == [200, 200]
+        assert metrics.total("merge_slot_sorted_batches") == 1
+        plan = "agg_merge_sorted"
+    s1 = DEVICE_STATS.snapshot()
+    return plan, tuple(s1[k] - s0[k] for k in COUNTERS)
+
+
+@pytest.mark.parametrize("form", sorted(CHIP_PLANS))
+def test_the_default_config_plans_what_the_chip_plans(monkeypatch, form):
+    """No kernel choice depends on the backend: under the default
+    ``Config()`` each form plans the chip's table or merge, with the process
+    backend the CPU and with it reading as the TPU."""
+    assert _run_form(form) == CHIP_PLANS[form]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _run_form(form) == CHIP_PLANS[form]
+
+
+@pytest.mark.parametrize("make", [
+    lambda: Config(radix_agg=True),
+    lambda: dataclasses.replace(Config(), radix_agg=True)])
+def test_radix_agg_true_is_refused(make):
+    """The radix kernel is gone: asking for it is an error naming the field,
+    while ``radix_agg=False`` (what configurations written for the chip
+    say) still parses."""
+    with pytest.raises(ValueError, match="radix_agg"):
+        make()
+    assert Config(radix_agg=False).radix_agg is False
 
 
 def test_slot_sorted_batches_are_counted_and_a_key_outside_widens():
@@ -772,7 +859,7 @@ def test_slot_sorted_batches_are_counted_and_a_key_outside_widens():
     assert got["s#sum"] == [want[k] for k in sorted(want)]
 
 
-# -- a table past dense_agg_max_buckets, where no radix table is planned --------
+# -- a table past dense_agg_max_buckets ----------------------------------------
 
 WIDE_KEYS = ("k1", "k2", "k3", "k4", "k5")
 # name -> the key columns' (anchor, span of values) on the first batch; the
@@ -794,8 +881,6 @@ WIDE_ROWS = (3_000, 5_000, 3_000)
 
 
 def _wide_agger(nkeys: int, conf):
-    from blaze_tpu.config import Config
-
     schema = pa.schema([(k, pa.int64()) for k in WIDE_KEYS[:nkeys]] +
                        [("v", pa.int64())])
     scan = _scan_stub(schema)
@@ -807,7 +892,7 @@ def _wide_agger(nkeys: int, conf):
         N.AggColumn(E.AggExpr(E.AggFunction.MIN, [E.Column("v")]), mode, "mn"),
         N.AggColumn(E.AggExpr(E.AggFunction.MAX, [E.Column("v")]), mode, "mx")])
     return DevicePartialAgger(build_operator(node), T.schema_from_arrow(schema),
-                              conf=Config(**{"radix_agg": False, **conf}))
+                              conf=Config(**conf))
 
 
 def _wide_batches(ranges, seed):
@@ -851,8 +936,7 @@ def _oracle(table, nkeys):
 
 @pytest.mark.parametrize("case", sorted(WIDE_TABLES))
 def test_a_table_past_the_cap_is_slot_sorted_and_equals_the_sort_kernel(case):
-    """Without a radix table (the chip) a key space past
-    ``dense_agg_max_buckets`` is ONE wide slot table, slot-sorted, its id
+    """A key space past ``dense_agg_max_buckets`` is ONE wide slot table, slot-sorted, its id
     int64 and its sizes traced, below 2^31 slots and past. Over nulls,
     anchors far from zero
     a batch with more groups than the one the table was planned on, and a
@@ -897,9 +981,7 @@ def test_a_table_past_the_cap_is_slot_sorted_and_equals_the_sort_kernel(case):
 def test_an_id_past_62_bits_takes_the_sort_path():
     """A table whose packed id would pass ``_SLOT_ID_MAX_SLOTS`` is refused:
     the stream takes the sort kernel, exactly."""
-    from blaze_tpu.config import Config
-
-    conf = Config(radix_agg=False)
+    conf = Config()
     probe = np.array([(1, 0, (1 << 31) - 3), (1, 0, (1 << 30) - 3)])
     bases, sizes, out_cap = A._plan_slot_table(
         probe, 4096, None, A._SLOT_ID_MAX_SLOTS, conf)
@@ -921,34 +1003,14 @@ def test_an_id_past_62_bits_takes_the_sort_path():
     assert got["k1"] == [0, 1 << 40] and got["s#sum"] == [4, 2]
 
 
-def test_with_the_radix_gate_on_a_table_past_the_cap_plans_radix():
-    """Where ``radix_agg`` is on (the CPU backend's default) the planning is
-    the parent's: dense within the cap, radix up to ``radix_agg_max_slots``,
-    the sort kernel past it."""
-    agger = _wide_agger(2, {"radix_agg": True})
-    batch = _wide_batches(WIDE_TABLES["2key_under_2e31"], 5)[0]
-    out = agger.process(ColumnarBatch.from_arrow(batch))
-    assert agger._bucket_state[0] == "radix"
-    assert math.prod(agger._bucket_state[2]) == 32_768 * 16
-    order, _ = _oracle(batch, 2)
-    assert list(zip(*(out.to_arrow()[k].to_pylist() for k in ("k1", "k2")))) \
-        == order
-    wide = _wide_agger(2, {"radix_agg": True})
-    wide.process(ColumnarBatch.from_arrow(
-        _wide_batches(WIDE_TABLES["2key_past_2e31"], 5)[0]))
-    assert wide._bucket_state is None and wide._radix_ok is False
-
-
 def test_dense_agg_false_forces_the_sort_kernel():
-    from blaze_tpu.config import Config
-
     schema = T.schema_from_arrow(SCHEMA)
     node = N.Agg(_scan_stub(), E.AggExecMode.HASH_AGG,
                  [("k1", E.Column("k1"))], [
         N.AggColumn(E.AggExpr(E.AggFunction.SUM, [E.Column("v")]),
                     E.AggMode.PARTIAL, "s")])
     agger = DevicePartialAgger(build_operator(node), schema,
-                               conf=Config(dense_agg=False, radix_agg=False))
+                               conf=Config(dense_agg=False))
     s0 = DEVICE_STATS.snapshot()
     out = agger.process(_batch([3, 4, 5] * 100, [1] * 300))
     s1 = DEVICE_STATS.snapshot()

@@ -311,13 +311,6 @@ def test_cell_rehearsal_on_the_device_tier(cell, tmp_path, capsys, monkeypatch):
 
     from tests.benchmark import helpers
 
-    def as_on_the_chip(manifest, tmp):
-        for entry in manifest["configs"]:
-            path = tmp / entry["file"]
-            config = json.loads(path.read_text())
-            config["session"]["conf"]["fused_filter_agg"] = False
-            path.write_text(json.dumps(config))
-
     seen = {}
     close = Session.close
 
@@ -328,7 +321,7 @@ def test_cell_rehearsal_on_the_device_tier(cell, tmp_path, capsys, monkeypatch):
 
     monkeypatch.setattr(Session, "_stage_platform", lambda self: "tpu")
     monkeypatch.setattr(Session, "close", closing)
-    path = helpers.tiny_manifest(tmp_path, as_on_the_chip)
+    path = helpers.tiny_manifest(tmp_path)
     with open(path) as f:
         manifest = json.load(f)
     (config,) = [c for c in manifest["configs"] if c["name"] == next(

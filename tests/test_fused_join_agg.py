@@ -10,12 +10,20 @@ import pyarrow as pa
 import pyarrow.parquet as pq
 import pytest
 
+from blaze_tpu.config import config_override
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import nodes as N
 from blaze_tpu.ir import types as T
 from blaze_tpu.runtime.session import Session
 
 F = E.AggFunction
+
+
+@pytest.fixture(autouse=True)
+def _fused():
+    """The fuser is opt-in (``fused_filter_agg``): these tests ask for it."""
+    with config_override(fused_filter_agg=True):
+        yield
 
 
 def _write(tmp_path, name, table):

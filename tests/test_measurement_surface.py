@@ -84,12 +84,22 @@ def test_driver_layer_imports_nothing_from_the_operators():
     assert not [m for m in imported if m.startswith("blaze_tpu.ops")], imported
 
 
+# fields kept only so that configurations written for a removed kernel still
+# parse (ROADMAP.md D17), beside the value Config refuses for each
+PARSE_ONLY = {"radix_agg": True}
+
+
 def test_every_config_field_is_read_in_the_package():
+    """Every field is read outside ``config.py``, but a parse-only one,
+    which ``Config`` refuses to be set."""
     sources = "\n".join(src for path, src in _package_sources()
                         if path != os.path.join(PACKAGE, "config.py"))
     unread = [f.name for f in dataclasses.fields(Config)
               if not re.search(rf"\b{f.name}\b", sources)]
-    assert unread == []
+    assert unread == sorted(PARSE_ONLY)
+    for name, value in PARSE_ONLY.items():
+        with pytest.raises(ValueError, match=name):
+            Config(**{name: value})
 
 
 def test_profile_and_explain_name_no_enqueue_clock_figure(tmp_path):

@@ -1,8 +1,8 @@
 """The merge by ONE sort of a packed key id (ops/agg_device.py:
 ``DeviceMergeAgger._merge`` and ``jit(agg_merge_sorted)``).
 
-Where no radix table is planned (the chip; ``radix_agg=False`` here), a
-FINAL / PARTIAL_MERGE merge whose keys are signed integer planes probes
+On every backend a FINAL / PARTIAL_MERGE merge whose keys are signed
+integer planes probes
 their ranges and reduces by one sort of the packed int64 id in place of
 ``lex_order_traced``'s ranking of every key word. Its outputs must be the
 sort-path merge's (``jit(agg_merge)``) bit for bit: the same groups in the
@@ -109,11 +109,10 @@ class _Metrics:
 
 
 def _merger(kinds, metrics):
-    """The merge of a ``DeviceMergeAgger`` over these kinds, as the chip
-    plans it (no radix table), without the operator around it."""
+    """The merge of a ``DeviceMergeAgger`` over these kinds, without the
+    operator around it."""
     agger = object.__new__(A.DeviceMergeAgger)
-    agger.conf, agger.kinds, agger.metrics = Config(radix_agg=False), kinds, \
-        metrics
+    agger.conf, agger.kinds, agger.metrics = Config(), kinds, metrics
     return agger
 
 

@@ -1,7 +1,7 @@
 """STDDEV_SAMP: exact integer moments (n, Σx, Σx²) on the device, finished
 once a group on the host. Every form that reduces them — the slot table (a
-masked table, a slot-sorted one, a wide one), the sort path, the radix
-kernels of the CPU backend, the host table — against Python's ``Fraction``
+masked table, a slot-sorted one, a wide one), the sort path, the host
+table — against Python's ``Fraction``
 exactly and against Acero's ``stddev`` (ddof=1) to 1e-12 relative; FINAL
 across four partitions; NULL arguments, groups of no row and of one; a long
 argument whose Σx² passes 2^63 (the limbs) and a group whose N passes 2^53
@@ -80,12 +80,11 @@ def _run(conf, keys, xs, x_type=pa.int64(), batch_rows=None):
     return got.to_pydict(), counted
 
 
-# the plans the forms come from: the chip's (no radix table), the CPU's
-# (radix tables), the sort path alone (no slot table)
+# the plans the forms come from: the default's (the chip's, on every
+# backend), the sort path alone (no slot table)
 FORMS = {
-    "chip": dict(radix_agg=False, fused_filter_agg=False),
-    "cpu": dict(),
-    "sorted": dict(radix_agg=False, dense_agg=False),
+    "default": dict(),
+    "sorted": dict(dense_agg=False),
 }
 
 
@@ -99,11 +98,11 @@ def _draw(rng, rows, groups, lo, hi, nulls=0.1):
     return keys, [None if rng.random() < nulls else x for x in xs]
 
 
-@pytest.mark.parametrize("form", ["chip", "cpu", "sorted"])
+@pytest.mark.parametrize("form", ["default", "sorted"])
 @pytest.mark.parametrize("groups", [7, 3000, 40_000])
 def test_device_forms_equal_the_fraction(form, groups):
     """Few groups: a masked slot table; thousands: a slot-sorted one; tens of
-    thousands: past `dense_agg_max_buckets`, wide on the chip's plan."""
+    thousands: past `dense_agg_max_buckets`, wide."""
     rng = np.random.default_rng(groups)
     keys, xs = _draw(rng, 60_000, groups, -1000, 1000)
     # a group of one row and a group of only NULLs: both NULL
@@ -137,15 +136,14 @@ def test_host_table_keeps_the_same_state(monkeypatch):
     assert counted["moment_host_batches"] >= PARTS
 
 
-@pytest.mark.parametrize("form", ["chip", "cpu"])
-def test_long_arguments_past_two_to_the_63(form):
+def test_long_arguments_past_two_to_the_63():
     """Σx² passes 2^63 within a group (the limbs), the largest longs and
     their negatives among them; N passes 2^53, so the finish goes by Python
     integers and counts the groups."""
     big = [2**63 - 1, -(2**63), 2**62 + 12345, -(2**40) - 7, 3 * 2**31, 17]
     keys = [i % 3 for i in range(len(big) * 40)]
     xs = [big[(i * 7) % len(big)] for i in range(len(big) * 40)]
-    got, counted = _run(_conf(form), keys, xs)
+    got, counted = _run(_conf("default"), keys, xs)
     want = _expected(keys, xs)
     for k, sd, n in zip(got["k"], got["sd"], got["n"]):
         assert (n, sd) == want[k], k
@@ -157,7 +155,7 @@ def test_acero_agrees_to_1e_12_on_dense_groups():
     double (ddof=1), which the 1e-12 covers at up to 10^4 rows a group."""
     rng = np.random.default_rng(11)
     keys, xs = _draw(rng, 80_000, 12, 1, 101, nulls=0.02)
-    got, _ = _run(_conf("chip"), keys, xs)
+    got, _ = _run(_conf("default"), keys, xs)
     acero = pa.table({"k": pa.array(keys, pa.int64()),
                       "x": pa.array(xs, pa.int64())}).group_by("k").aggregate(
         [("x", "stddev", pc.VarianceOptions(ddof=1))])
@@ -175,7 +173,7 @@ def test_double_argument_stays_on_the_host_table():
     xs[::97] = [None] * len(xs[::97])
     keys += [99]
     xs += [1.5]
-    got, counted = _run(_conf("chip"), keys, xs, x_type=pa.float64())
+    got, counted = _run(_conf("default"), keys, xs, x_type=pa.float64())
     groups = {}
     for k, x in zip(keys, xs):
         if x is not None:

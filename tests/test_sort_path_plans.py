@@ -1,8 +1,7 @@
 """q67's and q29's plans as the chip runs them, end to end on the CPU.
 
-On the CPU ``radix_agg`` defaults on and the aggregation goes through the
-radix slot table, so tier-1 otherwise never runs the chip's aggregation
-under a whole query (ROADMAP.md D12). Here it is off, as on the chip, over a
+The default configuration plans the chip's aggregation on every backend.
+Here it runs under a whole query, over a
 cut-down ``tpcds_star`` data set whose (item, store) range passes
 ``dense_agg_max_buckets`` as SF1's does: every PARTIAL batch reduces by one
 sort of its packed slot id (``jit(agg_dense_partial)``, slot-sorted), or with
@@ -53,8 +52,7 @@ def test_the_chips_plan_takes_the_sort_path_and_answers_as_acero(
 
     cls = registry.module("queries", query)
     overrides = registry.data("configs", CONFIGS[query])["session"]["conf"]
-    conf = dataclasses.replace(get_config(), radix_agg=False,
-                               fused_filter_agg=False, dense_agg=dense_agg,
+    conf = dataclasses.replace(get_config(), dense_agg=dense_agg,
                                **overrides)
     want = plans.rows_of(cls.reference({t: star.table(t) for t in cls.TABLES}),
                          cls.REFERENCE_COLUMNS, cls.ORDERED)

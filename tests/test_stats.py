@@ -2,7 +2,7 @@
 fingerprint-keyed query profiles.
 
 Covers the acceptance surface: a QueryProfile with per-stage partition
-sizes/rows, skew summaries and est-vs-actual cardinalities; fingerprint
+sizes/rows and est-vs-actual cardinalities; fingerprint
 stability across runs (and across data directories — paths are normalized
 out); the capped/GC'd profile store and its HTTP surface
 (``/debug/profiles[/<fp>]``, ``stage_stats`` lines in ``/debug/queries``);
@@ -23,9 +23,9 @@ from blaze_tpu.core import ColumnarBatch
 from blaze_tpu.ir import exprs as E
 from blaze_tpu.ir import nodes as N
 from blaze_tpu.ir import types as T
-from blaze_tpu.obs.stats import (STATS_HUB, StatsPlane, list_profiles,
-                                 load_profile, plan_fingerprint, save_profile,
-                                 skew_summary, stage_summary_line)
+from blaze_tpu.obs.stats import (list_profiles, load_profile,
+                                 plan_fingerprint, save_profile,
+                                 stage_summary_line)
 from blaze_tpu.runtime.session import Session
 
 F = E.AggFunction
@@ -90,67 +90,38 @@ def _pq_plan(tmp_path, fname="t.parquet", rows=10_000, keys=7):
                               M.FINAL, "s")])
 
 
-# -- skew / hub units ----------------------------------------------------------
+# -- stage records ---------------------------------------------------------------
 
 
 @pytest.mark.quick
-def test_skew_summary_unit():
-    rec = {"bucket_rows": [10, 0, 100, 12, 9], "bucket_groups": [5, 0, 2, 6, 4],
-           "radix_passes": 3}
-    s = skew_summary(rec)
-    assert s["buckets"] == 5
-    assert s["min_bucket_rows"] == 9
-    assert s["max_bucket_rows"] == 100
-    assert s["p50_bucket_rows"] in (10, 12)  # median of live buckets
-    assert s["hot_bucket_ids"] == [2]  # 100 > 2x median; index into rows
-    assert s["radix_passes"] == 3
-    assert skew_summary(None) is None
-    assert skew_summary({"bucket_rows": [0, 0]}) is None
+def test_stage_summary_line_unit():
     line = stage_summary_line({"stage": 0, "kind": "shuffle_map/shm",
                                "partitions": 4, "total_bytes": 2048,
                                "total_rows": 10, "partition_skew_ratio": 2.5,
-                               "skew": s})
-    assert "stage 0" in line and "max/med=2.5" in line and "radix[" in line
+                               "recovered_tasks": 1})
+    assert line.startswith("stage 0 [shuffle_map/shm] partitions=4")
+    assert "row_count=10" in line and "max/med=2.5" in line
+    assert "recovered=1" in line and "rows=" not in line.replace(
+        "row_count=", "")
 
 
 @pytest.mark.quick
-def test_stats_hub_scoping_and_drain():
-    key = ("test", 1)
-    with STATS_HUB.scoped(key):
-        STATS_HUB.note_radix([1, 2], [1, 1])
-        STATS_HUB.note_radix([3, 4, 5], [1, 2, 3])
-    rec = STATS_HUB.drain(key)
-    assert rec["bucket_rows"] == [4, 6, 5]
-    assert rec["radix_passes"] == 2
-    assert STATS_HUB.drain(key) is None  # drained once
-    # disabled: one attribute check, nothing recorded
-    STATS_HUB.enabled = False
-    try:
-        with STATS_HUB.scoped(key):
-            STATS_HUB.note_radix([9], [9])
-        assert STATS_HUB.drain(key) is None
-    finally:
-        STATS_HUB.enabled = True
-
-
-@pytest.mark.quick
-def test_worker_radix_merges_into_stage(tmp_path):
-    """The pool merge path: reply["stats"] folds into the stage record the
-    next on_map_stage commits (same stage id)."""
-    plane = StatsPlane(N.FFIReader(schema=ColumnarBatch.from_pydict(
-        {"k": [1]}).schema, resource_id="x", num_partitions=1), Config())
-    plane.merge_task_stats(0, {"bucket_rows": [10, 50], "bucket_groups": [1, 2],
-                               "radix_passes": 1})
-    plane.merge_task_stats(0, {"bucket_rows": [5, 5], "bucket_groups": [1, 1],
-                               "radix_passes": 1})
-    plane.on_map_stage(0, "shuffle_map/shm", 2, 3,
-                       indexes=[("d0", [0, 10, 20, 60]),
-                                ("d1", [0, 10, 20, 40])])
-    rec = plane._stages[0]
-    assert rec["skew"]["max_bucket_rows"] == 55
-    assert rec["skew"]["radix_passes"] == 2
-    assert rec["partition_bytes"] == [20, 20, 60]
-    assert rec["partition_skew_ratio"] == 3.0
+@pytest.mark.parametrize("enabled", [True, False])
+def test_partition_rows_follow_stats_enabled(tmp_path, enabled):
+    """The shuffle writer counts a map's rows a partition only where the
+    session's conf has stats on."""
+    parts = _make_parts(n=4_000)
+    with config_override(profile_store_dir=str(tmp_path / "profiles"),
+                         stats_enabled=enabled):
+        with Session() as sess:
+            sess.resources["src"] = lambda p: [x.to_arrow() for x in parts[p]]
+            out = sess.execute_to_pydict(
+                _two_stage_plan(parts[0][0].schema, len(parts)))
+            profile = sess.profile()
+            rows = sum(sess.metrics.total(f"part_rows_{r}") for r in range(3))
+    assert len(out["k"]) == 300
+    assert (profile is not None) is enabled
+    assert (rows > 0) is enabled
 
 
 # -- fingerprints --------------------------------------------------------------
@@ -333,13 +304,14 @@ def test_http_profiles_and_query_stage_stats(tmp_path):
 @pytest.mark.quick
 def test_stats_disabled_overhead_under_5_percent(tmp_path):
     """Mirror of the telemetry guard: with stats_enabled=False no plane is
-    built, and the per-note cost of the disabled hub (one attribute check)
-    scaled by a generous event count stays under 5% of the query wall."""
+    built, and the cost of the check that remains (the shuffle writers'
+    ``conf.stats_enabled`` test before a partition's row count) scaled by a
+    generous event count stays under 5% of the query wall."""
     n = 500_000
     b = ColumnarBatch.from_pydict({"k": [i % 97 for i in range(n)],
                                    "v": list(range(n))})
     with Session(conf=Config(batch_size=65_536, stats_enabled=False)) as sess:
-        assert not STATS_HUB.enabled
+        assert not sess.conf.stats_enabled
         sess.resources["src"] = lambda p: [b.to_arrow()]
         scan = N.FFIReader(schema=b.schema, resource_id="src",
                            num_partitions=1)
@@ -354,11 +326,13 @@ def test_stats_disabled_overhead_under_5_percent(tmp_path):
         events = sess.metrics.total("output_batches")
 
         ITER = 100_000
+        conf, noted = sess.conf, 0
         t0 = time.perf_counter_ns()
         for _ in range(ITER):
-            STATS_HUB.note_radix([1], [1])
+            if conf.stats_enabled:
+                noted += 1
         bench_ns = time.perf_counter_ns() - t0
-    STATS_HUB.enabled = True
+        assert noted == 0
     per_note_ns = bench_ns / ITER
     overhead_ns = per_note_ns * 4 * max(events, 32)
     assert overhead_ns < 0.05 * wall_ns, (
@@ -394,39 +368,3 @@ def test_pool_two_workers_stats(tmp_path, tier_conf, expect_kind):
     assert sum(stages[0]["partition_rows"]) == 7
     assert os.path.exists(os.path.join(
         store, profile["fingerprint"] + ".json"))
-
-
-@pytest.mark.slow
-def test_pool_worker_radix_rides_reply(tmp_path):
-    """A radix-agg map stage run IN WORKER PROCESSES must still produce a
-    driver-side skew summary: the histogram rides reply["stats"]."""
-    import pyarrow.parquet as pq
-
-    from blaze_tpu.ops.parquet import scan_node_for_files
-
-    rng = np.random.default_rng(5)
-    n = 200_000
-    path = str(tmp_path / "hi.parquet")
-    pq.write_table(pa.table({
-        "a": pa.array(rng.integers(0, 2000, n), type=pa.int64()),
-        "b": pa.array(rng.integers(0, 100, n), type=pa.int64()),
-        "v": pa.array(rng.integers(0, 100, n), type=pa.int64())}), path)
-    scan = scan_node_for_files([path], num_partitions=2)
-    groupings = [("a", _col("a")), ("b", _col("b"))]
-    partial = N.Agg(scan, HASH, groupings,
-                    [N.AggColumn(E.AggExpr(F.SUM, [_col("v")], T.I64),
-                                 M.PARTIAL, "s")])
-    ex = N.ShuffleExchange(partial, N.HashPartitioning([_col("a")], 3))
-    plan = N.Agg(ex, HASH, groupings,
-                 [N.AggColumn(E.AggExpr(F.SUM, [_col("v")], T.I64),
-                              M.FINAL, "s")])
-    with config_override(radix_agg=True,
-                         profile_store_dir=str(tmp_path / "p")):
-        with Session(num_worker_processes=2) as sess:
-            out = sess.execute_to_pydict(plan)
-            profile = sess.profile()
-    assert len(out["a"]) > 100_000
-    assert profile is not None
-    skews = [s["skew"] for s in profile["stages"] if s.get("skew")]
-    assert skews, "worker radix histograms must reach the driver profile"
-    assert skews[0]["buckets"] > 0 and skews[0]["max_bucket_rows"] > 0

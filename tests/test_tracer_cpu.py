@@ -5,6 +5,7 @@ across it, so ``dur - cpu_us`` is the time the thread did not run. No other
 span is stamped (a read of that clock is a system call), nothing is with the
 tracer disabled, and the flight-recorder ring's spans carry none."""
 
+import threading
 import time
 
 import pytest
@@ -40,18 +41,38 @@ def _busy(seconds: float):
 
 
 def test_a_span_around_a_busy_loop_is_nearly_all_cpu():
+    """The stamp is the recording thread's own CPU clock across the span:
+    at least the ``time.thread_time`` delta read inside it, and within
+    ``SLACK_US`` of it, however often the machine deschedules the thread. A
+    sleep in the span and a second thread busy throughout tell it from wall
+    and process time."""
     tr = Tracer()
     tr.enable()
-    # the best of a few: a busy thread the machine deschedules reads low,
-    # which is the stamp doing its work and not what is tested here
-    shares = []
-    for _ in range(5):
-        with tr.span("task", "task"):
-            _busy(0.05)
-        ev = tr.snapshot()[-1]
-        assert 0 <= ev["args"]["cpu_us"] <= ev["dur"] + SLACK_US
-        shares.append(ev["args"]["cpu_us"] / ev["dur"])
-    assert max(shares) >= 0.8, shares
+    stop = threading.Event()
+
+    def other():
+        while not stop.is_set():
+            pass
+
+    spinner = threading.Thread(target=other, daemon=True)
+    spinner.start()
+    over = []
+    try:
+        # the least of a few: an interrupt the thread is charged for between
+        # the stamp and the read beside it is not what is tested here
+        for _ in range(3):
+            with tr.span("task", "task"):
+                t0 = time.thread_time_ns()
+                _busy(0.02)
+                time.sleep(0.01)
+                own_us = (time.thread_time_ns() - t0) / 1e3
+            ev = tr.snapshot()[-1]
+            assert own_us <= ev["args"]["cpu_us"] <= ev["dur"] + SLACK_US
+            over.append(ev["args"]["cpu_us"] - own_us)
+    finally:
+        stop.set()
+        spinner.join()
+    assert min(over) <= SLACK_US, over
 
 
 def test_a_span_around_a_sleep_is_nearly_no_cpu():
